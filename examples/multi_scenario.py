@@ -32,11 +32,6 @@ import urllib.request
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
-# run-anywhere guard: pin CPU before any backend init (see day_loop.py)
-import jax  # noqa: E402
-
-jax.config.update("jax_platforms", "cpu")
-
 
 def main():
     ap = argparse.ArgumentParser()
@@ -44,7 +39,14 @@ def main():
     ap.add_argument("--stream-seconds", type=float, default=6.0)
     ap.add_argument("--staleness", type=float, default=1.5,
                     help="feed scenario's freshness budget (s)")
+    ap.add_argument("--cpu", action="store_true",
+                    help="run on the CPU backend instead of the "
+                         "accelerator JAX finds")
     args = ap.parse_args()
+
+    from paddlebox_tpu.utils.backend import setup_backend
+
+    setup_backend(cpu=args.cpu)
 
     import numpy as np
 

@@ -4,14 +4,20 @@ The reference keeps its whole data layer in C++ because host feed was the
 production bottleneck (SURVEY.md §2.4); here the parser is the native hot
 path and the rest of the pipeline stays numpy (already vectorized).  The
 shared library builds on demand with g++ (no pybind11 in the image — plain
-C ABI + ctypes), is cached next to the source keyed by source mtime, and
-anything failing (no compiler, build error) falls back to the pure-Python
-parser transparently.
+C ABI + ctypes) into a file named by a hash of its source and build flags,
+so a binary is only ever loaded if it was built from exactly this source
+with exactly these flags; the flags target the baseline ISA, so a tree
+copied to another machine carries nothing host-specific.  Anything failing
+(no compiler, build error) falls back to the pure-Python parser; entry
+points that must not run degraded call :func:`require_native`.
 """
 
 from __future__ import annotations
 
+import contextlib
 import ctypes
+import glob
+import hashlib
 import logging
 import os
 import subprocess
@@ -24,38 +30,53 @@ logger = logging.getLogger(__name__)
 
 _DIR = os.path.dirname(os.path.abspath(__file__))
 _SRC = os.path.join(_DIR, "slot_parser.cpp")
-_SO = os.path.join(_DIR, "_slot_parser.so")
+_CXX = ("g++", "-O3", "-std=c++17", "-shared", "-fPIC")
 
 _lock = threading.Lock()
 _lib = None
 _tried = False
 
 
-def _build_so(src: str, so: str, extra_flags=()) -> Optional[str]:
-    """Build ``so`` from ``src`` if stale; None on ANY failure (including a
-    missing source file — a cached .so without its source must fall back,
-    not raise)."""
+def _build_so(src: str) -> Optional[str]:
+    """Path of the library built from ``src`` (``_<name>.<hash>.so`` beside
+    it), building it first unless that exact file exists; None on ANY
+    failure (missing source, no compiler, build error)."""
     try:
-        if os.path.exists(so) and \
-                os.path.getmtime(so) >= os.path.getmtime(src):
-            return so
+        with open(src, "rb") as f:
+            digest = hashlib.sha256(
+                " ".join(_CXX).encode() + b"\0" + f.read()
+            ).hexdigest()[:16]
     except OSError:
         return None
-    tmp = so + f".tmp-{os.getpid()}"
-    cmd = ["g++", "-O3", "-march=native", "-std=c++17", "-shared", "-fPIC",
-           *extra_flags, "-o", tmp, src]
-    try:
-        subprocess.run(cmd, check=True, capture_output=True, timeout=120)
-        os.replace(tmp, so)
+    stem = os.path.join(
+        os.path.dirname(src),
+        "_" + os.path.splitext(os.path.basename(src))[0],
+    )
+    so = f"{stem}.{digest}.so"
+    if os.path.exists(so):
         return so
-    except (OSError, subprocess.SubprocessError):
+    tmp = so + f".tmp-{os.getpid()}"
+    try:
+        subprocess.run([*_CXX, "-o", tmp, src], check=True,
+                       capture_output=True, timeout=120)
+        os.replace(tmp, so)
+    except (OSError, subprocess.SubprocessError) as e:
+        logger.warning(
+            "native build of %s failed: %s", src,
+            (getattr(e, "stderr", None) or b"").decode(errors="replace") or e,
+        )
         if os.path.exists(tmp):
             os.remove(tmp)
         return None
+    for stale in glob.glob(stem + "*.so"):  # builds of older sources
+        if stale != so:
+            with contextlib.suppress(FileNotFoundError):  # a racing build
+                os.remove(stale)
+    return so
 
 
 def _build() -> Optional[str]:
-    return _build_so(_SRC, _SO)
+    return _build_so(_SRC)
 
 
 def get_lib():
@@ -88,37 +109,19 @@ def get_lib():
         lib.pbx_fill.argtypes = [ctypes.c_void_p] + [ctypes.c_void_p] * 10
         lib.pbx_free.restype = None
         lib.pbx_free.argtypes = [ctypes.c_void_p]
-        try:
-            # absent from pre-hash builds of the .so (a stale cache with a
-            # flattened mtime): parser keeps working, hashing falls back
-            lib.pbx_hash_ids.restype = None
-            lib.pbx_hash_ids.argtypes = [
-                ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
-                ctypes.c_int64, ctypes.POINTER(ctypes.c_uint64),
-            ]
-        except AttributeError:
-            lib = _LibWithoutHash(lib)
+        lib.pbx_hash_ids.restype = None
+        lib.pbx_hash_ids.argtypes = [
+            ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
+            ctypes.c_int64, ctypes.POINTER(ctypes.c_uint64),
+        ]
         _lib = lib
         return _lib
-
-
-class _LibWithoutHash:
-    """Wraps a stale .so lacking pbx_hash_ids; every other symbol passes
-    through, hash callers see None and use the numpy fallback."""
-
-    pbx_hash_ids = None
-
-    def __init__(self, lib):
-        self._lib = lib
-
-    def __getattr__(self, name):
-        return getattr(self._lib, name)
 
 
 def hash_ids_native(ins_ids) -> Optional[np.ndarray]:
     """Batch FNV-1a 64 via the native lib; None when it is unavailable."""
     lib = get_lib()
-    if lib is None or getattr(lib, "pbx_hash_ids", None) is None:
+    if lib is None:
         return None
     enc = [s.encode() for s in ins_ids]
     buf = b"".join(enc)
@@ -233,14 +236,13 @@ class NativeParser:
 # Native batch planner (plan_resolve.cpp) — own .so, same build discipline
 # --------------------------------------------------------------------------- #
 _PLAN_SRC = os.path.join(_DIR, "plan_resolve.cpp")
-_PLAN_SO = os.path.join(_DIR, "_plan_resolve.so")
 _plan_lock = threading.Lock()
 _plan_lib = None
 _plan_tried = False
 
 
 def _build_plan() -> Optional[str]:
-    return _build_so(_PLAN_SRC, _PLAN_SO)
+    return _build_so(_PLAN_SRC)
 
 
 def get_plan_lib():
@@ -256,15 +258,31 @@ def get_plan_lib():
         if so is None:
             return None
         lib = ctypes.CDLL(so)
-        try:
-            _bind_plan_symbols(lib)
-        except AttributeError:
-            # a cached .so from an older source (flattened mtimes skip the
-            # rebuild) lacks newer symbols: fall back to numpy rather than
-            # crash the planner — same discipline as pbx_hash_ids
-            return None
+        _bind_plan_symbols(lib)
         _plan_lib = lib
         return _plan_lib
+
+
+def require_native() -> dict:
+    """{"parser": bool, "planner": bool} — which native libraries the
+    flags ask for AND are loaded.  Raises when a flag asks for one that
+    did not build: entry points that measure or prove the system must not
+    run on the 4-5x slower Python fallback unnoticed."""
+    from paddlebox_tpu.config import flags
+
+    wanted = {"parser": (flags.use_native_parser, get_lib),
+              "planner": (flags.use_native_planner, get_plan_lib)}
+    loaded = {name: bool(flag and load() is not None)
+              for name, (flag, load) in wanted.items()}
+    missing = [name for name, (flag, _) in wanted.items()
+               if flag and not loaded[name]]
+    if missing:
+        raise RuntimeError(
+            f"native {' and '.join(missing)} did not build (is g++ "
+            "installed?) — set PBOX_USE_NATIVE_PARSER=0 / "
+            "PBOX_USE_NATIVE_PLANNER=0 to accept the Python fallback"
+        )
+    return loaded
 
 
 def _bind_plan_symbols(lib) -> None:

@@ -34,8 +34,6 @@ class _Flags:
         "check_nan_inf": False,
         # reference: FLAGS_enable_pull_box_padding_zero (pull_box_sparse_op.h)
         "enable_pull_box_padding_zero": True,
-        # use pallas kernels for sparse gather/scatter where available
-        "use_pallas_sparse": False,
         # use the native (C++/ctypes) slot parser when it builds; falls back
         # to the pure-Python parser automatically
         "use_native_parser": True,

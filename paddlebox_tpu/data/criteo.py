@@ -16,8 +16,8 @@ this module provides everything EXCEPT the bytes:
   * ``write_criteo_format_sample`` — a spec-exact synthetic sample (hex
     category tokens, empty fields, heavy-tailed ints, a planted learnable
     signal) for tests and for the "Criteo-sample" benchmark row, honestly
-    labeled: real FORMAT, synthetic VALUES (BASELINE.md documents the
-    dataset blocker).
+    labeled: real FORMAT, synthetic VALUES (the real files cannot be
+    fetched without network access).
 
 Point ``convert_criteo_files`` at real ``day_*`` files and the same code
 path produces the real benchmark row.

@@ -434,8 +434,7 @@ class PadBoxSlotDataset:
 def _shuffle_slots(block: RecordBlock, slot_idxs, rng) -> RecordBlock:
     """Permute the chosen slots' (values, length) pairs across instances,
     fully vectorized: one CSR gather builds the new key array — no per-
-    instance Python loop (VERDICT r2 weak #9; the reference's C++
-    slots_shuffle exists because this is a host hot path at pass scale)."""
+    instance Python loop."""
     s = block.n_sparse_slots
     n = block.n_ins
     lens = np.diff(block.key_offsets).reshape(n, s)

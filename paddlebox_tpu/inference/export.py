@@ -29,9 +29,6 @@ import json
 import os
 
 import jax
-import jax.export  # noqa: F401  -- on jax 0.4.x the submodule is not an
-# attribute of the bare `jax` import; accessing jax.export.export without
-# this raises AttributeError
 import jax.numpy as jnp
 import numpy as np
 
@@ -255,8 +252,7 @@ def export_model(
     "arbitrary batch size" serving (the reference's AnalysisPredictor
     resizes feed tensors freely, analysis_predictor.cc) becomes the
     standard TPU recipe instead: export a ladder of shape buckets and let
-    the Predictor pad each request up to the smallest bucket that fits
-    (VERDICT r3 missing #5).
+    the Predictor pad each request up to the smallest bucket that fits.
     feed_conf: the training DataFeedConfig — serialized into the artifact
     (feed.json) so a serving host can parse request lines from the
     artifact ALONE (ScoringServer.register without a Python-side config),

@@ -105,8 +105,6 @@ class Predictor:
 
     def _program(self, fname: str):
         import jax
-        import jax.export  # noqa: F401  -- explicit: not reachable via the
-        # bare `jax` import on 0.4.x (AttributeError without it)
 
         from paddlebox_tpu.telemetry.compiles import install_compile_listener
 

@@ -36,7 +36,7 @@ from paddlebox_tpu.models.layers import (
 )
 from paddlebox_tpu.ops import fused_seqpool_cvm, pooled_width
 from paddlebox_tpu.parallel.expert import EXPERT_AXIS, expert_parallel_mlp_mix
-from paddlebox_tpu.utils.jax_compat import axis_size, shard_map
+from paddlebox_tpu.parallel.mesh import inherit_shard_map
 
 
 class MMoE:
@@ -153,7 +153,7 @@ class MMoE:
             # trace-time validation for "inherit" mode (no concrete mesh at
             # __init__): axis_size is static here, so raise the same clear
             # error the Mesh path raises instead of an opaque shard error
-            p_ax = axis_size(EXPERT_AXIS)
+            p_ax = jax.lax.axis_size(EXPERT_AXIS)
             if E % p_ax:
                 raise ValueError(
                     f"n_experts {E} not divisible by the {EXPERT_AXIS!r} "
@@ -166,12 +166,12 @@ class MMoE:
             # composed mode: an OUTER shard_map (e.g. MultiChipTrainer on a
             # data x expert mesh) already established the context mesh; bind
             # only the expert axis here and let the rest stay as-is
-            sm = shard_map(
+            sm = inherit_shard_map(
                 checked_mix, in_specs=in_specs, out_specs=P(),
-                axis_names={EXPERT_AXIS}, check_vma=False,
+                axis_name=EXPERT_AXIS,
             )
         else:
-            sm = shard_map(
+            sm = jax.shard_map(
                 expert_parallel_mlp_mix, mesh=self.expert_mesh,
                 in_specs=in_specs, out_specs=P(),
             )

@@ -40,7 +40,6 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddlebox_tpu.telemetry.compiles import counted_jit
-from paddlebox_tpu.utils.jax_compat import axis_size, pcast
 
 PIPE_AXIS = "pipe"
 
@@ -108,7 +107,7 @@ def gpipe_run(stage_fn, emit_fn, n_microbatches: int, act0: jax.Array):
           is_last & tick within range).
     Returns emissions stacked [T, ...].
     """
-    p_axis = axis_size(PIPE_AXIS)
+    p_axis = jax.lax.axis_size(PIPE_AXIS)
     idx = jax.lax.axis_index(PIPE_AXIS)
     M = n_microbatches
     T = M + p_axis - 1
@@ -131,7 +130,7 @@ def gpipe_run(stage_fn, emit_fn, n_microbatches: int, act0: jax.Array):
 
     # the carry becomes device-varying after the first tick: mark it so up
     # front (shard_map's varying-axes typing requires carry in/out to match)
-    vary = lambda v: pcast(v, (PIPE_AXIS,), to="varying")
+    vary = lambda v: jax.lax.pcast(v, (PIPE_AXIS,), to="varying")
     _, emits = jax.lax.scan(tick, vary(act0), jnp.arange(T))
     return emits
 
@@ -227,16 +226,10 @@ class PipelineTrainer:
             loss, grads = jax.value_and_grad(pipeline_forward_loss)(
                 p, x, y, mask
             )
-            # value_and_grad runs INSIDE the shard_map body, so every
-            # stage differentiates its own copy of the SAME replicated
-            # psum'd scalar: the psum transpose sums all P cotangent
-            # seeds and the per-device grad comes out exactly P x the
-            # true gradient (measured: uniform x n_stages).  Normalize
-            # once.  (models/pipelined_ctr.py doesn't need this — its
-            # shard_map is differentiated as a whole, one output, one
-            # seed.)
-            p_axis = axis_size(PIPE_AXIS)
-            grads = jax.tree.map(lambda g: g / p_axis, grads)
+            # value_and_grad runs INSIDE the shard_map body; with varying-
+            # axes typing on (check_vma, the default) the psum'd loss is
+            # invariant over the pipe axis and its transpose seeds each
+            # stage's cotangent once, so the per-stage grad is exact
             updates, o = optimizer.update(grads, o, p)
             p = optax.apply_updates(p, updates)
             restack = lambda t: jax.tree.map(lambda l: l[None], t)
@@ -244,9 +237,8 @@ class PipelineTrainer:
 
         spec = P(PIPE_AXIS)
         rep = P()  # microbatches replicated across stages
-        from paddlebox_tpu.utils.jax_compat import shard_map
 
-        mapped = shard_map(
+        mapped = jax.shard_map(
             body,
             mesh=self.mesh,
             in_specs=(spec, spec, rep, rep, rep),

@@ -30,7 +30,6 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
-from paddlebox_tpu.utils.jax_compat import axis_size, pcast
 
 SEQ_AXIS = "seq"
 
@@ -85,7 +84,7 @@ def ring_attention(
     nested axis does not lower.  Default: derived from axis_index
     (standalone use).
     """
-    p_axis = axis_size(axis_name)
+    p_axis = jax.lax.axis_size(axis_name)
     b, t, h, d = q.shape
     scale = 1.0 / jnp.sqrt(float(d))
     # positions are only consumed by causal masking: derive (axis_index) and
@@ -153,7 +152,9 @@ def ring_attention(
 
     # accumulate in f32 whatever the input dtype (flash-attention practice:
     # bf16 inputs, f32 running max/normalizer/weighted-sum)
-    vary = lambda x: pcast(x, (axis_name,), to="varying")
+    # over every axis q varies over: nested in an outer shard_map that is
+    # the outer (data) axis as well as axis_name
+    vary = lambda x: jax.lax.pcast(x, tuple(jax.typeof(q).vma), to="varying")
     # the synthesized all-ones mask is replicated; the ring shift needs it
     # device-varying like the K/V blocks it rides with
     kv_valid = (
@@ -190,7 +191,7 @@ def ulysses_attention(
     key_valid: optional bool [B, T_local] — local chunk's key validity,
     allgathered to the full sequence for the head-sharded attention.
     """
-    p_axis = axis_size(axis_name)
+    p_axis = jax.lax.axis_size(axis_name)
     b, t, h, d = q.shape
     if h % p_axis != 0:
         raise ValueError(f"heads {h} not divisible by seq axis size {p_axis}")

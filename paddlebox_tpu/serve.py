@@ -22,7 +22,8 @@ applied delta count, publish time) and freshness age.
 --replicas N switches to FLEET mode (serving_fleet/): a
 ReplicaSupervisor spawns N single-server replica processes of this same
 command (each with its own Syncer when --sync-root is given, its own
-admission queue always) and a FleetRouter front door on --router-port
+admission queue always; with --cpu on a TPU host, whose chips one process
+must own — a replica that cannot get the TPU exits non-zero and says so) and a FleetRouter front door on --router-port
 spreads /score traffic over them with health-checked membership,
 per-request failover and crash restarts — a killed replica is never
 client-visible.  Router endpoints: POST /score[/NAME], GET /healthz
@@ -64,7 +65,9 @@ def _build_parser() -> argparse.ArgumentParser:
     ap.add_argument("--port", type=int, default=8080)
     ap.add_argument("--host", default="127.0.0.1")
     ap.add_argument("--cpu", action="store_true",
-                    help="pin the CPU backend before any device init")
+                    help="run on the CPU backend instead of the "
+                         "accelerator JAX finds (required for --replicas "
+                         "> 1 on one TPU host: a chip has one owner)")
     ap.add_argument("--sync-root", default=None,
                     help="publish root to keep a model synced from "
                          "(serving_sync delivery plane)")
@@ -243,13 +246,23 @@ def main(argv=None) -> None:
     if args.replicas and args.replicas > 0:
         # fleet mode needs no device in THIS process: the router is pure
         # host I/O; the replicas it spawns load the artifacts
+        if (args.replicas > 1 or args.autoscale) and not args.cpu:
+            from paddlebox_tpu.utils.backend import host_tpu_chips
+
+            if host_tpu_chips():
+                ap.error(
+                    "several replica processes on a TPU host need --cpu: a "
+                    "chip belongs to one process at a time, so the second "
+                    "replica could not get one.  One server process "
+                    "(--replicas 0) serves from all local chips."
+                )
         _main_fleet(args)
         return
 
-    if args.cpu:
-        import jax
+    from paddlebox_tpu.utils.backend import claim_devices, setup_backend
 
-        jax.config.update("jax_platforms", "cpu")
+    setup_backend(cpu=args.cpu)
+    claim_devices()
 
     from paddlebox_tpu import telemetry
     from paddlebox_tpu.inference import ScoringServer

@@ -13,10 +13,7 @@ every key decision in this system (census resolve, batch planning, shard
 routing) already happens on the host where dynamic shapes are free — the
 directory is ~tens of bytes per slot and mutates once per pass, while the
 rows are the multi-KB-per-slot payload whose round trip the cache exists to
-eliminate.  A device mirror of the sorted key index (uint32 (hi, lo) pairs)
-is built on demand for the Pallas sorted-search resolve when
-``flags.use_pallas_sparse`` is on; both resolve paths return identical
-plans.
+eliminate.
 
 Policy: LFU with aging.  Every pass multiplies all resident frequencies by
 ``aging`` and adds 1 to this census's hits; admission (at end_pass, from
@@ -102,13 +99,15 @@ class HbmCache:
         self.capacity = int(capacity)
         self.n_cols = int(n_cols)
         self.aging = float(aging)
-        if materialize_rows:
-            rows = jnp.zeros((self.capacity, self.n_cols), jnp.float32)
-            if device is not None:
-                rows = jax.device_put(rows, device)
-        else:
-            rows = None
-        self.rows: Optional[jax.Array] = rows
+        # the device the rows live on (None = the default device); slot
+        # indices are placed there too, so gathers and scatters are
+        # single-device ops wherever the caller's other arrays live
+        self.device = device
+        self.rows: Optional[jax.Array] = (
+            jnp.zeros((self.capacity, self.n_cols), jnp.float32,
+                      device=device)
+            if materialize_rows else None
+        )
         # directory (slot-indexed)
         self.keys = np.zeros(self.capacity, dtype=np.uint64)
         self.used = np.zeros(self.capacity, dtype=bool)
@@ -119,7 +118,6 @@ class HbmCache:
         # sorted view for the key→slot resolve (rebuilt on membership change)
         self._sorted_keys = _EMPTY_U64
         self._sorted_slots = _EMPTY_I32
-        self._dev_index: Optional[tuple] = None  # lazy Pallas mirror
 
     # -- introspection ---------------------------------------------------- #
     @property
@@ -158,44 +156,16 @@ class HbmCache:
         else:
             self._sorted_keys = _EMPTY_U64
             self._sorted_slots = _EMPTY_I32
-        self._dev_index = None
-
-    def _device_positions(self, pk: np.ndarray) -> np.ndarray:
-        """Sorted-view positions of ``pk`` (-1 = miss) via the Pallas
-        sorted-search kernel over the device key mirror."""
-        from paddlebox_tpu.ops.pallas_sparse import (
-            pallas_sorted_search,
-            split_u64,
-        )
-
-        if self._dev_index is None:
-            n = self._sorted_keys.shape[0]
-            cpad = 1 << max(0, (n - 1).bit_length()) if n else 0
-            hay = np.full((cpad, 2), 0xFFFFFFFF, dtype=np.uint32)
-            if n:
-                hay[:n] = np.asarray(split_u64(self._sorted_keys))
-            self._dev_index = (
-                jnp.asarray(hay),
-                jnp.asarray([n], dtype=np.int32),
-            )
-        hay, n_real = self._dev_index
-        return np.asarray(pallas_sorted_search(hay, n_real, split_u64(pk)))
 
     def lookup(self, pk: np.ndarray) -> CachePlan:
         """Resolve a sorted unique census against the directory."""
-        from paddlebox_tpu.config import flags
-
         n = pk.shape[0]
         sk = self._sorted_keys
         if n == 0 or sk.shape[0] == 0:
             return CachePlan(np.zeros(n, dtype=bool), _EMPTY_I32, _EMPTY_I32)
-        if flags.use_pallas_sparse:
-            pos = self._device_positions(pk)
-            hit = pos >= 0
-        else:
-            pos = np.searchsorted(sk, pk)
-            pos = np.minimum(pos, sk.shape[0] - 1)
-            hit = sk[pos] == pk
+        pos = np.searchsorted(sk, pk)
+        pos = np.minimum(pos, sk.shape[0] - 1)
+        hit = sk[pos] == pk
         hit_pos = np.nonzero(hit)[0].astype(np.int32)
         return CachePlan(hit, hit_pos, self._sorted_slots[pos[hit]])
 
@@ -308,29 +278,20 @@ class HbmCache:
         return mask, rows
 
     # -- row movement ------------------------------------------------------ #
-    def gather_rows(self, slots: np.ndarray) -> jax.Array:
-        """Device gather of ``slots`` rows (Pallas cache-slot gather when
-        the flag is on, XLA take otherwise — identical results)."""
-        from paddlebox_tpu.config import flags
+    def _slot_index(self, slots: np.ndarray) -> jax.Array:
+        return jax.device_put(np.asarray(slots, dtype=np.int32), self.device)
 
+    def gather_rows(self, slots: np.ndarray) -> jax.Array:
+        """Device gather of ``slots`` rows."""
         if self.rows is None:
             raise RuntimeError(
                 "metadata-only cache twin has no rows to gather "
                 "(materialize_rows=False)"
             )
-
-        idx = jnp.asarray(np.asarray(slots, dtype=np.int32))
-        if flags.use_pallas_sparse:
-            from paddlebox_tpu.ops.pallas_sparse import pallas_gather_slots
-
-            return pallas_gather_slots(self.rows, idx)
-        return jnp.take(self.rows, idx, axis=0)
+        return jnp.take(self.rows, self._slot_index(slots), axis=0)
 
     def set_rows(self, slots: np.ndarray, rows: jax.Array) -> None:
-        """Device scatter-replace of ``rows`` into ``slots`` (Pallas
-        cache-slot scatter when the flag is on)."""
-        from paddlebox_tpu.config import flags
-
+        """Device scatter-replace of ``rows`` into ``slots``."""
         if np.asarray(slots).shape[0] == 0:
             return
         if self.rows is None:
@@ -338,13 +299,7 @@ class HbmCache:
                 "metadata-only cache twin has no rows to set "
                 "(materialize_rows=False)"
             )
-        idx = jnp.asarray(np.asarray(slots, dtype=np.int32))
-        if flags.use_pallas_sparse:
-            from paddlebox_tpu.ops.pallas_sparse import pallas_scatter_rows
-
-            self.rows = pallas_scatter_rows(self.rows, idx, rows)
-        else:
-            self.rows = self.rows.at[idx].set(rows)
+        self.rows = self.rows.at[self._slot_index(slots)].set(rows)
 
     # -- coherence --------------------------------------------------------- #
     def drain(self) -> Tuple[np.ndarray, np.ndarray]:

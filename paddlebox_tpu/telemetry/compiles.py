@@ -47,6 +47,10 @@ from paddlebox_tpu.telemetry import metrics
 #: never on a cache hit — the whole witness keys on it.
 _COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
+#: fired (inside the compile event's window) when the persistent compile
+#: cache supplied the executable, so XLA compiled nothing after all.
+_CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
+
 #: stage attributed to compiles outside any scope (import-time warmup,
 #: library internals) — visible, not silently dropped.
 UNTAGGED = "untagged"
@@ -57,6 +61,10 @@ _COMPILES = metrics.counter(
 )
 _COMPILE_SECONDS = metrics.histogram(
     "jit.compile_seconds", "XLA backend compile wall time by stage",
+)
+_CACHE_HITS = metrics.counter(
+    "jit.cache_hits",
+    "jit.compiles events served by the persistent compile cache, by stage",
 )
 
 _tls = threading.local()
@@ -102,6 +110,11 @@ def _on_event(event: str, duration_secs: float, **kwargs) -> None:
     _COMPILE_SECONDS.observe(duration_secs, stage=stage)
 
 
+def _on_plain_event(event: str, **kwargs) -> None:
+    if event == _CACHE_HIT_EVENT:
+        _CACHE_HITS.inc(stage=current_stage())
+
+
 def install_compile_listener() -> bool:
     """Register the jax.monitoring listener (idempotent, thread-safe).
     Returns False when jax or the monitoring API is unavailable — the
@@ -121,6 +134,7 @@ def install_compile_listener() -> bool:
         if register is None:
             return False
         register(_on_event)
+        monitoring.register_event_listener(_on_plain_event)
         _installed = True
         return True
 
@@ -136,6 +150,22 @@ def compiles_by_stage() -> dict:
 
 def total_compiles() -> int:
     return sum(compiles_by_stage().values())
+
+
+def compile_summary() -> dict:
+    """{stage: {"compiles", "cache_hits", "seconds"}}: XLA backend compiles
+    that really ran (compile events minus the ones the persistent cache
+    served), those cache hits, and the wall time of both."""
+    out: dict = {}
+    for stage, events in compiles_by_stage().items():
+        hits = int(_CACHE_HITS.value(stage=stage))
+        out[stage] = {
+            "compiles": events - hits,
+            "cache_hits": hits,
+            "seconds": round(
+                _COMPILE_SECONDS.summary(stage=stage)["sum"] or 0.0, 3),
+        }
+    return out
 
 
 class CountedJit:
@@ -190,7 +220,7 @@ def counted_jit(fn=None, *, stage: str, **jit_kwargs):
 
     Usable directly (``counted_jit(f, stage="train.step",
     donate_argnums=(0,))``) or as a decorator factory
-    (``@counted_jit(stage="pallas.gather", static_argnames=("n",))``).
+    (``@counted_jit(stage="cache.gather", static_argnames=("n",))``).
     """
     if fn is None:
         return lambda f: CountedJit(f, stage=stage, **jit_kwargs)

@@ -488,7 +488,7 @@ class Trainer:
     def _build_scan_step(self):
         """k steps in ONE dispatch: lax.scan over stacked feeds.  Amortizes
         per-step Python + runtime dispatch (pays off where dispatch is
-        expensive relative to the step: small models, remote/tunneled
+        expensive relative to the step: small models, remote
         devices, pods with deep software stacks).  XLA compiles the k-step
         program once; preds/dump are unavailable (use scan_steps=1 when
         dumping)."""

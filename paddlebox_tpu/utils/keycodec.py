@@ -221,3 +221,14 @@ def decode_zigzag_delta(buf, n: int) -> np.ndarray:
     z = zz.view(np.int64)
     d = (z >> 1) ^ -(z & 1)
     return np.cumsum(d, dtype=np.int64)
+
+
+def split_u64(keys) -> np.ndarray:
+    """np.uint64 [N] -> np.uint32 [N, 2] (hi, lo): the only form in which
+    keys may ride a device array (JAX runs x64-disabled, so
+    ``jnp.asarray(u64)`` silently keeps the low 32 bits)."""
+    keys = np.asarray(keys, dtype=np.uint64)
+    out = np.empty((keys.shape[0], 2), dtype=np.uint32)
+    out[:, 0] = (keys >> np.uint64(32)).astype(np.uint32)
+    out[:, 1] = (keys & np.uint64(0xFFFFFFFF)).astype(np.uint32)
+    return out

@@ -19,7 +19,7 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), ".."))
 
 from paddlebox_tpu.parallel.mesh import initialize_distributed  # noqa: E402
 
-initialize_distributed()  # applies PBOX_FORCE_CPU + joins the coordinator
+initialize_distributed()  # joins the launcher's coordinator
 
 import jax  # noqa: E402
 import numpy as np  # noqa: E402

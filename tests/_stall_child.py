@@ -35,7 +35,7 @@ if rank == stall_rank:
 
 from paddlebox_tpu.parallel.mesh import initialize_distributed  # noqa: E402
 
-initialize_distributed()  # applies PBOX_FORCE_CPU + joins the coordinator
+initialize_distributed()  # joins the launcher's coordinator
 
 
 def main() -> int:

@@ -1,4 +1,4 @@
-"""Streaming AUC vs sklearn oracle (VERDICT item 6).
+"""Streaming AUC vs sklearn oracle.
 
 Reference: BasicAucCalculator (fleet/box_wrapper.h:61-138, bucket kernels
 box_wrapper.cu:1035-1060, final reduction box_wrapper.cc:321-400).
@@ -95,8 +95,7 @@ def test_degenerate_single_class_auc():
 
 def test_exact_accumulation_past_2pow24():
     """f32 saturates at 2^24 (x + 1.0 == x); uint32 buckets and Kahan moment
-    sums must keep counting exactly (VERDICT r2 weak #10; reference uses
-    double tables, box_wrapper.h:61)."""
+    sums must keep counting exactly."""
     import jax
     from paddlebox_tpu.metrics.auc import kahan_value
 

@@ -1,6 +1,4 @@
-"""Kill-and-resume determinism for the AutoCheckpointer (VERDICT r2 weak /
-missing #4: auto-checkpoint + deterministic pass replay; reference:
-incubate/checkpoint/auto_checkpoint.py, SURVEY.md §5.3)."""
+"""Kill-and-resume determinism for the AutoCheckpointer."""
 
 import numpy as np
 

@@ -177,8 +177,7 @@ def test_profiler_report(tmp_path):
 
 
 def test_disk_spill_bounded_memory(tmp_path):
-    """Streaming spill keeps at most read_threads parsed blocks in flight
-    (VERDICT r2 weak #8: a larger-than-RAM pass must actually load);
+    """Streaming spill keeps at most read_threads parsed blocks in flight;
     batches stream back identical to the memory path."""
     from paddlebox_tpu.data.dataset import PadBoxSlotDataset
     from paddlebox_tpu.data.synth import make_synth_config, write_synth_files

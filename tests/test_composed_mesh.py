@@ -24,15 +24,6 @@ from paddlebox_tpu.parallel.trainer import MultiChipTrainer
 
 S, DENSE, B, E = 3, 2, 16, 4
 
-# the inner 'inherit' shard_map needs the context-mesh mode of modern
-# jax.shard_map; legacy builds (jax.experimental.shard_map only) have no
-# equivalent (utils/jax_compat raises NotImplementedError naming the
-# version) — the composed tests are a platform gap there, not a failure
-needs_context_mesh = pytest.mark.skipif(
-    not hasattr(jax, "shard_map"),
-    reason="composed (context-mesh) shard_map needs modern jax.shard_map",
-)
-
 
 def _data(tmp_path, n_ins=256):
     conf = make_synth_config(
@@ -73,14 +64,12 @@ def test_mesh_helpers():
     assert data_axis_size(make_mesh(8)) == 8
     with pytest.raises(ValueError, match="need"):
         make_composed_mesh(8, 2, EXPERT_AXIS)
-    # a 1-sized data axis is an explicit config error (VERDICT r4 next #6:
-    # formerly an XLA RET_CHECK at jit time / a silent dryrun skip), and the
+    # a 1-sized data axis is an explicit config error, and the
     # message must point at the supported alternative
     with pytest.raises(ValueError, match="single-chip Trainer"):
         make_composed_mesh(1, 2, EXPERT_AXIS)
 
 
-@needs_context_mesh
 def test_composed_mesh_odd_device_total(tmp_path):
     """Odd device totals compose: 3x2 uses 6 of the 8 virtual devices (the
     remainder stays out of the mesh) and trains to the same kind of state
@@ -97,7 +86,6 @@ def test_composed_mesh_odd_device_total(tmp_path):
     assert s["values"][:, 0].sum() > 0  # show counters accumulated
 
 
-@needs_context_mesh
 def test_composed_data_expert_matches_data_only(tmp_path):
     kw = dict(dense_dim=DENSE, n_tasks=2, n_experts=E, expert_hidden=(16,),
               expert_dim=8, tower_hidden=(8,))
@@ -127,7 +115,6 @@ def test_composed_data_expert_matches_data_only(tmp_path):
     np.testing.assert_allclose(s1["values"], s2["values"], atol=2e-2)
 
 
-@needs_context_mesh
 def test_composed_data_seq_matches_data_only(tmp_path):
     """data x seq composition: LongSeqCtrDnn's ring attention (positions
     riding the ring — no axis_index) nested inside MultiChipTrainer's

@@ -1,7 +1,7 @@
 """Criteo-format adapter: TSV parse, hashing stability, conversion into
 the canonical pipeline, and an e2e learnability gate on the spec-exact
 sample (reference analog: the dist-CTR e2e tier, ctr_dataset_reader.py,
-whose data download is unavailable offline — BASELINE.md blocker)."""
+whose data download is unavailable offline)."""
 
 import numpy as np
 import pytest

@@ -14,7 +14,6 @@ from paddlebox_tpu.parallel.expert import (
     expert_parallel_forward,
     serial_expert_forward,
 )
-from paddlebox_tpu.utils.jax_compat import shard_map
 
 P_DEV, E, B, D_IN, D_HID = 4, 8, 16, 10, 12
 
@@ -35,7 +34,7 @@ def _inputs(seed=0):
 
 def _sharded_fn(mesh):
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             expert_parallel_forward,
             mesh=mesh,
             in_specs=(P(EXPERT_AXIS), P(EXPERT_AXIS), P(),
@@ -66,7 +65,7 @@ def test_gradients_match_serial():
     )
 
     def loss_sharded(w_, b_):
-        body = shard_map(
+        body = jax.shard_map(
             expert_parallel_forward,
             mesh=mesh,
             in_specs=(P(EXPERT_AXIS), P(EXPERT_AXIS), P(),

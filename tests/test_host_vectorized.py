@@ -1,7 +1,4 @@
-"""Parity of the vectorized host hot paths vs straightforward loop oracles
-(VERDICT r2 weak #9: _hash_ins_ids / _shuffle_slots / build_rank_offset are
-per-record Python loops that die at pass scale; the reference keeps this
-layer in C++ for the same reason, SURVEY.md §2.4)."""
+"""Parity of the vectorized host hot paths vs straightforward loop oracles."""
 
 import numpy as np
 
@@ -164,8 +161,7 @@ def test_build_rank_offset_no_ranked():
 
 
 def test_vectorized_paths_scale(capsys):
-    """Micro-bench at meaningful scale — results land in BASELINE.md.
-    Fails only on gross (>60s) regression; prints throughput."""
+    """Micro-bench at meaningful scale.  Fails only on gross (>60s) regression; prints throughput."""
     import time
 
     n = 200_000

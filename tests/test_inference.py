@@ -307,7 +307,7 @@ def test_export_respects_create_threshold(tmp_path):
 
 
 def test_shape_buckets_serve_any_smaller_batch(tmp_path):
-    """VERDICT r3 missing #5: the artifact serves batches of ANY real size
+    """The artifact serves batches of ANY real size
     that fits a bucket — scores are bucket-invariant (padding rows are zero
     and padding segments drop out of the pooling segment_sum)."""
     conf, ds, model, table, trainer = _train_small(str(tmp_path / "data"))

@@ -137,7 +137,11 @@ def test_steady_state_zero_retrace_single_chip_trainer(tmp_path):
 
 def test_steady_state_zero_retrace_multichip_trainer(tmp_path):
     """The SPMD path's pin: shard_mapped step/sync stages stay cached
-    across steady-state passes on the 8-device mesh."""
+    across steady-state passes on the 8-device mesh.  Warmup is THREE
+    passes: the first two as on one chip, and pass 3 is where a key first
+    reaches the planner's enter frequency (1, 1.8, 2.44 >= 2.0), so the
+    first hot set is promoted out of the per-shard caches there — one
+    padded gather per shard device, compiled once for the table's life."""
     from paddlebox_tpu.parallel import (
         MultiChipTrainer,
         ShardedSparseTable,
@@ -155,10 +159,11 @@ def test_steady_state_zero_retrace_multichip_trainer(tmp_path):
     table = ShardedSparseTable(tconf, mesh, seed=5, bucket_slack=8.0)
     keys = ds.unique_keys()
 
-    for _ in range(2):  # warmup: compile + capacity-fit recompile
+    for _ in range(3):  # warmup: compile, capacity fit, first promotion
         table.begin_pass(keys)
         trainer.train_from_dataset(ds, table)
         table.end_pass()
+    assert table.hot_resident_keys().shape[0], "no hot set was realized"
 
     before = _counts()
     table.begin_pass(keys)

@@ -1,7 +1,7 @@
 """Long-sequence CTR: ordered behavior feed + attention tower + seq mesh.
 
-VERDICT r3 weak #8: sequence parallelism was "well-tested pure functions no
-model consumes".  These tests pin the full consumable path: the feed's
+Sequence parallelism as a model consumes it, not as pure functions alone.
+These tests pin the full consumable path: the feed's
 seq_pos construction, masked attention (key_valid) parity, LongSeqCtrDnn
 training end-to-end through the unmodified Trainer, and single-device vs
 sequence-parallel (ring AND ulysses) output parity on the virtual mesh.

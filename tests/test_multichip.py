@@ -113,8 +113,7 @@ class TestShardedTable:
 
     def test_skewed_group_bumps_capacity_no_drops(self, mesh):
         """A group whose keys all hash to ONE shard must grow the a2a
-        bucket (power-of-two bump), not silently drop keys (VERDICT r3
-        weak #5: 'counted != handled').  Every key must resolve to its
+        bucket (power-of-two bump), not silently drop keys.  Every key must resolve to its
         owner's row."""
         from paddlebox_tpu.data.feed import HostBatch
 
@@ -158,9 +157,7 @@ class TestShardedTable:
 class TestMultiChipPrefetch:
     def test_prefetch_matches_serial(self, mesh, tmp_path):
         """The background plan+stack+H2D producer must be a pure overlap:
-        bitwise-identical metrics to the serial path (VERDICT r3 next #6a
-        — the multi-chip tier previously planned serially on the
-        critical path)."""
+        bitwise-identical metrics to the serial path."""
         tconf = SparseTableConfig(embedding_dim=8)
 
         def run(prefetch, sub):

@@ -3,6 +3,7 @@ pure-Python reference implementation on every feature (labels, task labels,
 dense, sparse, skip slots, ins_id, logkey, gz, errors)."""
 
 import gzip
+import os
 
 import numpy as np
 import pytest
@@ -121,3 +122,27 @@ def test_gz_and_dataset_path(tmp_path):
 def test_empty_input():
     got, want = _both(_conf(), "")
     assert got.n_ins == 0 == want.n_ins
+
+
+def test_library_is_keyed_by_source_not_by_what_came_with_the_tree(tmp_path):
+    """A .so that arrived with the checkout — the old fixed name, or a
+    build of another source — is never the one loaded: the library's file
+    name is a hash of source + flags, anything else is rebuilt from the
+    .cpp and swept away."""
+    import shutil
+
+    from paddlebox_tpu import _native
+
+    src = str(tmp_path / "slot_parser.cpp")
+    shutil.copy(_native._SRC, src)
+    foreign = [tmp_path / "_slot_parser.so",
+               tmp_path / "_slot_parser.0123456789abcdef.so"]
+    for f in foreign:
+        f.write_bytes(b"built on another machine")
+    so = _native._build_so(src)
+    assert so is not None and os.path.dirname(so) == str(tmp_path)
+    assert [f.name for f in tmp_path.glob("*.so")] == [os.path.basename(so)]
+    assert _native._build_so(src) == so  # cached: same source, same file
+    with open(src, "a") as f:
+        f.write("\n// edited\n")
+    assert _native._build_so(src) != so  # new source, new library

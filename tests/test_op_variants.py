@@ -207,7 +207,7 @@ def test_quant_pull_descale():
 def test_conv_counter_push_end_to_end(tmp_path):
     """cvm_offset=3 table + counter_label_tasks: the third (conv) counter
     accumulates the conversion task label of each key's instance
-    (parser -> push counter update -> CVM, VERDICT r3 item #5)."""
+    (parser -> push counter update -> CVM)."""
     from paddlebox_tpu.config import (
         DataFeedConfig,
         SlotConfig,

@@ -1930,7 +1930,7 @@ def test_key_width_bad_sink_family(tmp_path, expr, needle):
 
 def test_key_width_good_split_convention(tmp_path):
     """The split itself — shift/mask with np.uint64 then narrow — is the
-    sanctioned uint64->uint32 path (ops/pallas_sparse.py split_u64)."""
+    sanctioned uint64->uint32 path (utils/keycodec.py split_u64)."""
     src = """\
         import numpy as np
 
@@ -1958,7 +1958,7 @@ def test_key_width_good_comparisons_and_searchsorted(tmp_path):
 
 def test_key_width_bad_32bit_recombine(tmp_path):
     src = """\
-        from paddlebox_tpu.ops.pallas_sparse import split_u64
+        from paddlebox_tpu.utils.keycodec import split_u64
 
         def roundtrip(keys):
             pairs = split_u64(keys)

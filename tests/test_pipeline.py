@@ -14,7 +14,6 @@ from paddlebox_tpu.parallel.pipeline import (
     pipeline_forward_loss,
     reference_forward_loss,
 )
-from paddlebox_tpu.utils.jax_compat import shard_map
 
 P_STAGES, M, MB, D_IN, WIDTH = 4, 8, 16, 10, 32
 
@@ -42,7 +41,7 @@ def test_forward_matches_sequential():
     from jax.sharding import NamedSharding, PartitionSpec as PS
 
     piped = jax.jit(
-        shard_map(
+        jax.shard_map(
             lambda p, a, b, c: pipeline_forward_loss(
                 jax.tree.map(lambda l: l[0], p), a, b, c
             )[None],

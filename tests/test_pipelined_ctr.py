@@ -1,6 +1,6 @@
 """Pipeline parallelism over the REAL CTR tower (models/pipelined_ctr.py).
 
-VERDICT r3 next #7: "one model from models/ trains pipelined to parity" —
+One model from models/ trains pipelined to parity:
 PipelinedCtrDnn is CtrDnn's tower as GPipe stages, driven by the
 unmodified Trainer with stage 0 consuming pooled sparse features.
 """

@@ -15,7 +15,6 @@ from paddlebox_tpu.parallel.sequence import (
     ring_attention,
     ulysses_attention,
 )
-from paddlebox_tpu.utils.jax_compat import shard_map
 
 P_DEV, B, T_LOCAL, H, D = 4, 2, 8, 4, 8
 T = P_DEV * T_LOCAL
@@ -37,7 +36,7 @@ def _sharded(mesh, fn, causal):
     spec = P(None, SEQ_AXIS)  # shard the T axis
 
     return jax.jit(
-        shard_map(
+        jax.shard_map(
             functools.partial(fn, causal=causal),
             mesh=mesh,
             in_specs=(spec, spec, spec),
@@ -83,7 +82,7 @@ def test_gradients_match_full_attention(fn, causal):
     spec = P(None, SEQ_AXIS)
 
     def loss_sharded(q_, k_, v_):
-        body = shard_map(
+        body = jax.shard_map(
             functools.partial(fn, causal=causal),
             mesh=mesh,
             in_specs=(spec, spec, spec),

@@ -1,6 +1,6 @@
 """Sparse table: pull/push/update parity vs a numpy oracle + pass lifecycle.
 
-Covers VERDICT item 1: numeric parity for pull/push/update and the
+Covers numeric parity for pull/push/update and the
 begin_pass -> train -> end_pass -> shrink cycle (reference semantics:
 fleet/box_wrapper_impl.h:24-255, box_wrapper.cc:609-673,496-499).
 """

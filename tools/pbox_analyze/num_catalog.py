@@ -13,7 +13,7 @@ planes document in prose:
     (payload 29.93% of fp32) — hence :data:`FUSED_DEQUANT_FILES`.
   * the whole stack runs on np.uint64 keys; JAX arrays are x64-disabled,
     so keys ride devices as uint32 ``(hi, lo)`` pairs via
-    ``ops/pallas_sparse.py split_u64``.  ``jnp.asarray(u64)`` silently
+    ``utils/keycodec.py split_u64``.  ``jnp.asarray(u64)`` silently
     truncates to uint32 (top 32 bits GONE), float arithmetic promotes to
     float64 (exact only below 2^53), and ``int64`` flips the sign of
     keys >= 2^63 — the three sink families of ``num-key-width``.

@@ -29,7 +29,7 @@ catalog in :mod:`num_catalog`:
     NEGATIVE) / 32-bit dtypes (truncation), float arithmetic (numpy
     promotes u64 x float to float64), any ``jnp.*``/``device_put`` call
     on a u64 value (x64-disabled: silent uint32 truncation — keys must
-    ride as (hi, lo) uint32 pairs via pallas_sparse ``split_u64``), and
+    ride as (hi, lo) uint32 pairs via keycodec ``split_u64``), and
     32-bit recombination of split halves (``hi << 32`` overflows; the
     convention is ``np.uint64(hi) << np.uint64(32) | lo``).  The
     split itself (``(keys >> np.uint64(32)).astype(np.uint32)``) is the
@@ -586,7 +586,7 @@ def _key_width(eng: NumEngine, fi, env, fnodes) -> list:
                             "53 mantissa bits, keys above 2^53 collide "
                             "silently; keep keys u64 host-side and ride "
                             "devices as split_u64 (hi, lo) uint32 pairs "
-                            "(ops/pallas_sparse.py)",
+                            "(utils/keycodec.py)",
                         ))
                     elif to in _NARROW_CAST_MSG and not (
                             shifted and to == "u32"):
@@ -625,7 +625,7 @@ def _key_width(eng: NumEngine, fi, env, fnodes) -> list:
                             "uint64 keys fed to jnp/device_put — JAX "
                             "runs x64-disabled, so the array silently "
                             "truncates to uint32 (top 32 bits GONE); "
-                            "use ops/pallas_sparse.split_u64 to carry "
+                            "use utils/keycodec.split_u64 to carry "
                             "(hi, lo) uint32 pairs",
                         ))
                         break
