@@ -770,7 +770,8 @@ def bench_sustained(n_passes: int, tconf, trconf, n_slots: int, dense_dim: int,
     pipeline — the per-pass steady-state bench hides parse cost entirely.
     Reports sustained samples/sec over the whole day (excluding only the
     first pass's un-overlappable parse + the compile) and, with profile,
-    the StepProfiler plan/feed/step breakdown of the final pass."""
+    the profiler's per-stage report (plan/feed/step/complete) of the final
+    pass."""
     from paddlebox_tpu.data.dataset import PadBoxSlotDataset
     from paddlebox_tpu.data.synth import make_synth_config, write_synth_files
     from paddlebox_tpu.models import CtrDnn
@@ -847,7 +848,8 @@ def bench_sustained(n_passes: int, tconf, trconf, n_slots: int, dense_dim: int,
     log(f"sustained: {total} samples / {n_passes} passes in {dt:.2f}s "
         f"= {sps:,.0f} samples/s (incl. compile in pass 0)")
     if profile:
-        # one more pass with the profiler on (synchronous steps: honest split)
+        # one more pass with the per-stage report on (the same loop: the
+        # report is the pass's delta of the always-on stage histograms)
         trainer.conf.profile = True
         files = files_for(n_passes)
         ds = PadBoxSlotDataset(conf, read_threads=4)
@@ -3411,7 +3413,7 @@ def main() -> None:
     ap.add_argument("--sustained", type=int, default=0, metavar="N_PASSES",
                     help="sustained multi-pass bench with preload overlap")
     ap.add_argument("--profile", action="store_true",
-                    help="with --sustained: StepProfiler breakdown pass")
+                    help="with --sustained: one more pass with the per-stage report")
     ap.add_argument("--compute-dtype", default="",
                     choices=["", "float32", "bfloat16"],
                     help="dense tower compute dtype (default: flags)")

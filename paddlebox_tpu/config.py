@@ -902,14 +902,14 @@ class TrainerConfig:
     # host->device transfer for the next batches while the current step
     # computes, bounded at this queue depth (the pinned-arena/double-buffered
     # staging analog, SURVEY.md §2.3 — reference data_feed pipelines blocks
-    # through SlotObjPool + a CUDA copy stream).  0 = serial feed; profiling
-    # (profile=True) always runs serial so the plan/feed/step split stays
-    # honest.
+    # through SlotObjPool + a CUDA copy stream).  0 = serial feed.  Profiling
+    # and tracing never change it: they report the loop that runs.
     prefetch_batches: int = 2
     # multi-step dispatch: run this many train steps per device program via
     # lax.scan over host-stacked feeds — amortizes per-step Python/dispatch
     # overhead (small models, remote devices).  1 = one dispatch per step.
-    # Per-batch dump (need_dump_field) and the step profiler force 1.
+    # Per-batch dump (need_dump_field) forces 1 (it needs every batch's
+    # predictions); profiling and tracing do not.
     # With check_nan_inf, the host still only sees the flag after the whole
     # k-step group, but the scan body short-circuits: ticks after the first
     # non-finite one pass state through untouched, so at most ONE corrupted
@@ -931,10 +931,14 @@ class TrainerConfig:
     # PBOX_TRACE_DIR / PBOX_EVENTS_PATH still apply through
     # TelemetryConfig.from_flags()); attach one to pin it in code.
     telemetry: Optional["TelemetryConfig"] = None
-    # per-stage host timing (reference: TrainFilesWithProfiler — a slower
-    # diagnostic mode: the device step is synchronized every batch)
+    # per-stage report (reference: TrainFilesWithProfiler): the pass's
+    # metrics gain "profile" — per-stage seconds, counts, ms per step and
+    # quantiles, the pass's delta of the always-on trainer.stage_seconds
+    # and trainer.step_complete_seconds — and a [profile] line is printed.
+    # The loop is the one every run has: no serial feed, no sync per step.
     profile: bool = False
-    # jax.profiler trace dir for one-pass device timeline capture ("" = off).
-    # Also enables the HOST span trace: each pass additionally writes a
-    # Chrome-trace JSON of nested plan/feed/step/dump spans here.
+    # jax.profiler trace dir for one-pass device timeline capture ("" = off):
+    # the real loop, with the program's pbox.* stages on the trace's host
+    # plane.  Also enables the HOST span trace: each pass additionally
+    # writes a Chrome-trace JSON of the stage spans nested under "pass".
     trace_dir: str = ""

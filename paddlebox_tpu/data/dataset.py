@@ -33,6 +33,7 @@ from paddlebox_tpu.data.feed import BatchBuilder, HostBatch
 from paddlebox_tpu.data.record import RecordBlock
 from paddlebox_tpu.data.slot_parser import SlotParser
 from paddlebox_tpu.utils.monitor import stats
+from paddlebox_tpu.utils.profiler import timed
 from paddlebox_tpu.utils.retry import retry_call
 from paddlebox_tpu.utils.timer import Timer
 
@@ -376,11 +377,17 @@ class PadBoxSlotDataset:
         return 0 if self._block is None else self._block.n_ins
 
     def unique_keys(self) -> np.ndarray:
-        if self._spill is not None:
-            return self._spill.unique_keys
-        if self._block is None:
-            raise RuntimeError("load before key census")
-        return self._block.unique_keys()
+        """The pass's key census.  Timed on both paths (memory: the
+        ``np.unique`` over every occurrence; spill: the census taken at
+        spill time) as ``data.census_seconds`` / ``pbox.data.census``: the
+        examples' loop pays it inside every pass boundary."""
+        with timed("data.census_seconds", "data.census",
+                   "dataset key census (unique_keys) wall time"):
+            if self._spill is not None:
+                return self._spill.unique_keys
+            if self._block is None:
+                raise RuntimeError("load before key census")
+            return self._block.unique_keys()
 
     def _disk_batches(self, drop_last: bool) -> Iterator[HostBatch]:
         """Stream batches from spill archives, carrying partial-batch

@@ -33,6 +33,7 @@ pooled cotangent back to every surviving occurrence unchanged).
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -147,6 +148,20 @@ def _cvm_transform(pooled: jax.Array, cvm_offset: int) -> jax.Array:
     return jnp.concatenate([log_show, ctr, pooled[..., cvm_offset:]], axis=-1)
 
 
+def _scoped(fn):
+    """Run ``fn`` under ``jax.named_scope("seqpool_cvm")``: the name every
+    operation of the pool + CVM stage (and of its transpose) carries in a
+    device trace.  Metadata only."""
+
+    @functools.wraps(fn)
+    def scoped(*args, **kwargs):
+        with jax.named_scope("seqpool_cvm"):
+            return fn(*args, **kwargs)
+
+    return scoped
+
+
+@_scoped
 def fused_seqpool_cvm(
     rows: jax.Array,
     key_segments: jax.Array,
@@ -208,6 +223,7 @@ def fused_seqpool_cvm_with_diff_thres(
     )
 
 
+@_scoped
 def fused_seqpool_cvm_with_conv(
     rows: jax.Array,
     key_segments: jax.Array,
@@ -254,6 +270,7 @@ def fused_seqpool_cvm_with_conv(
     return out.reshape(batch_size, -1)
 
 
+@_scoped
 def fused_seqpool_cvm_with_pcoc(
     rows: jax.Array,
     key_segments: jax.Array,
@@ -307,6 +324,7 @@ def fused_seqpool_cvm_with_pcoc(
     return out.reshape(batch_size, -1)
 
 
+@_scoped
 def fused_seqpool_cvm_extended(
     rows: jax.Array,
     key_segments: jax.Array,

@@ -93,7 +93,13 @@ from paddlebox_tpu.parallel.multiprocess import (
     local_device_indices,
     local_view,
 )
-from paddlebox_tpu.sparse.table import SparseTable, _next_pow2
+from paddlebox_tpu.sparse.table import (
+    _PASS,
+    SparseTable,
+    _count_begin,
+    _next_pow2,
+)
+from paddlebox_tpu.telemetry.compiles import stage_scope
 
 # lockstep census-channel naming: every process constructs its sharded
 # tables in the same order, so the counter agrees fleet-wide (the same
@@ -1036,7 +1042,9 @@ class ShardedSparseTable(SparseTable):
                 hit = caches[i].lookup(sk).hit_mask
                 miss_pos = np.nonzero(~hit)[0]
                 if miss_pos.shape[0]:
-                    lvals[i, miss_pos] = self._cache_fetch_rows(sk[miss_pos])
+                    with _PASS.stage("fetch"):
+                        lvals[i, miss_pos] = self._cache_fetch_rows(
+                            sk[miss_pos])
         except faults.FaultInjected:
             telemetry.counter(
                 "cache.fetch_fallbacks",
@@ -1044,12 +1052,14 @@ class ShardedSparseTable(SparseTable):
             ).inc()
             self._cache_degrade(pk)
             lvals[:] = 0.0
-            for i, o in enumerate(self._local_pos):
-                sk = shard_keys[o]
-                lvals[i, : sk.shape[0]] = self._resolve_or_init(sk)
+            with _PASS.stage("fetch"):
+                for i, o in enumerate(self._local_pos):
+                    sk = shard_keys[o]
+                    lvals[i, : sk.shape[0]] = self._resolve_or_init(sk)
             return []
         return caches
 
+    @stage_scope("pass.begin")
     def begin_pass(self, pass_keys: np.ndarray) -> None:
         """Promote the pass working set (this process's shards) to device.
 
@@ -1062,12 +1072,14 @@ class ShardedSparseTable(SparseTable):
             raise RuntimeError("end_pass the previous pass first")
         from paddlebox_tpu.utils.monitor import stats
 
-        pk = np.unique(np.asarray(pass_keys, dtype=np.uint64))
-        # global census: the shared-dictionary exchange (hot/cached keys
-        # ride as membership bits, the cold tail as varint deltas —
-        # parallel/census.py) with byte-identical union semantics; the
-        # legacy codec keeps the raw device-collective union
-        pk = self._exchange_census(pk)
+        _count_begin("begin_entry", [c.rows for c in self._caches()])
+        with _PASS.stage("census"):
+            pk = np.unique(np.asarray(pass_keys, dtype=np.uint64))
+            # global census: the shared-dictionary exchange (hot/cached
+            # keys ride as membership bits, the cold tail as varint deltas
+            # — parallel/census.py) with byte-identical union semantics;
+            # the legacy codec keeps the raw device-collective union
+            pk = self._exchange_census(pk)
         w = self.conf.row_width
         cold_pk = pk
         if self._hot_realize:
@@ -1075,12 +1087,14 @@ class ShardedSparseTable(SparseTable):
             # updated) plan, THEN split: the cold working set excludes
             # every resident hot key — caches, staging and the mirror all
             # see only the cold tail (module docstring)
-            self._sync_hot_block()
-            if self._hot_keys.shape[0]:
-                cold_pk = np.setdiff1d(
-                    pk, self._hot_keys, assume_unique=True
-                )
-        payload, patches = self._pop_stage()
+            with _PASS.stage("hot_sync"):
+                self._sync_hot_block()
+                if self._hot_keys.shape[0]:
+                    cold_pk = np.setdiff1d(
+                        pk, self._hot_keys, assume_unique=True
+                    )
+        with _PASS.stage("take_stage"):
+            payload, patches = self._pop_stage()
         lvals = None
         if payload is not None:
             spk, owner, shard_keys, row_within, svals, shot, _ = payload
@@ -1104,7 +1118,8 @@ class ShardedSparseTable(SparseTable):
         caches = self._caches()
         pass_hits = 0  # cache hits filled from device THIS pass
         if lvals is None:
-            owner, shard_keys, row_within = self._shard_split(cold_pk)
+            with _PASS.stage("census"):
+                owner, shard_keys, row_within = self._shard_split(cold_pk)
             cap = self._sharded_cap(shard_keys)
             # materialize only the local shards: rows come from this
             # process's host store (each process persists exactly its owned
@@ -1112,17 +1127,23 @@ class ShardedSparseTable(SparseTable):
             # (_key_uniform), so any process layout produces identical rows.
             # With the HBM cache, the host supplies only the cache MISSES
             # per shard — the hit positions are filled from device below.
-            lvals = np.zeros((self.n_local, cap, w + 1), dtype=np.float32)
+            with _PASS.stage("alloc"):
+                lvals = np.zeros(
+                    (self.n_local, cap, w + 1), dtype=np.float32)
             if caches:
                 caches = self._cached_sync_resolve(
                     caches, shard_keys, lvals, cold_pk
                 )
             else:
-                for i, o in enumerate(self._local_pos):
-                    sk = shard_keys[o]
-                    lvals[i, : sk.shape[0]] = self._resolve_or_init(sk)
+                with _PASS.stage("fetch"):
+                    for i, o in enumerate(self._local_pos):
+                        sk = shard_keys[o]
+                        lvals[i, : sk.shape[0]] = self._resolve_or_init(sk)
         sharding = NamedSharding(self.mesh, P(DATA_AXIS))
         self._cache_plans = None
+        # the uploaded and filled per-shard buffers: what begin_exit asks
+        # is_ready of (cache off: the pass arrays themselves)
+        self._begin_bufs = []
         if caches:
             # cached assembly: strictly per-shard single-device ops, then
             # one process-local global-array construction — a computation
@@ -1131,8 +1152,10 @@ class ShardedSparseTable(SparseTable):
             self._assemble_cached(lvals, shard_keys, caches, cold_pk, sharding)
             pass_hits = self.last_cache_hits
         else:
-            self.values = global_from_local(sharding, lvals[:, :, :w])
-            self.g2sum = global_from_local(sharding, lvals[:, :, w])
+            with _PASS.stage("upload"):
+                self.values = global_from_local(sharding, lvals[:, :, :w])
+                self.g2sum = global_from_local(sharding, lvals[:, :, w])
+            self._begin_bufs = [self.values, self.g2sum]
         # boundary host traffic: rows that actually crossed host->device
         # (cache misses; everything, cache-off).  With realization on, the
         # hot tier never lands here — bench pins the collapse to O(cold)
@@ -1169,6 +1192,9 @@ class ShardedSparseTable(SparseTable):
         else:
             self._delta_keys.append(pk)
         self._observe_gap()
+        _count_begin("begin_exit", self._begin_bufs + [
+            c.rows for c in self._caches()])
+        self._begin_bufs = []
 
     def _assemble_cached(self, lvals, shard_keys, caches, pk,
                          sharding) -> None:
@@ -1189,23 +1215,28 @@ class ShardedSparseTable(SparseTable):
         total_hits = 0
         for i, o in enumerate(self._local_pos):
             sk = shard_keys[o]
-            lv = jax.device_put(lvals[i], devs[i])  # [cap, W+1]
+            with _PASS.stage("upload"):
+                lv = jax.device_put(lvals[i], devs[i])  # [cap, W+1]
             plan = caches[i].lookup(sk)
             if plan.n_hits:
-                hr = caches[i].gather_rows(plan.hit_slots)
-                lv = lv.at[jax.device_put(plan.hit_pos, devs[i])].set(hr)
+                with _PASS.stage("fill"):
+                    hr = caches[i].gather_rows(plan.hit_slots)
+                    lv = lv.at[
+                        jax.device_put(plan.hit_pos, devs[i])].set(hr)
             caches[i].touch(plan)
             plans.append(plan)
             total_hits += plan.n_hits
             vbufs.append(lv[None, :, :w])
             gbufs.append(lv[None, :, w])
+            self._begin_bufs.append(lv)
         n = self.n_shards
-        self.values = jax.make_array_from_single_device_arrays(
-            (n, cap, w), sharding, vbufs
-        )
-        self.g2sum = jax.make_array_from_single_device_arrays(
-            (n, cap), sharding, gbufs
-        )
+        with _PASS.stage("upload"):
+            self.values = jax.make_array_from_single_device_arrays(
+                (n, cap, w), sharding, vbufs
+            )
+            self.g2sum = jax.make_array_from_single_device_arrays(
+                (n, cap), sharding, gbufs
+            )
         self._cache_plans = plans
         # local-shard hit accounting (pk is global; the per-process miss
         # count is relative to the keys THIS process's shards own)
@@ -1254,8 +1285,9 @@ class ShardedSparseTable(SparseTable):
                 "cache admissions degraded to the full host write-back",
             ).inc()
         if upds is None:
-            vals = local_view(self.values)
-            g2 = local_view(self.g2sum)
+            with _PASS.stage("d2h"):
+                vals = local_view(self.values)
+                g2 = local_view(self.g2sum)
             ks, vs = [], []
             with self._cache_lock:
                 for i, o in enumerate(self._local_pos):
@@ -1289,16 +1321,19 @@ class ShardedSparseTable(SparseTable):
             upd_pos = np.concatenate([plan.hit_pos, upd.admit_pos])
             if upd_pos.shape[0]:
                 if upd.victim_slots.shape[0]:
-                    victim_rows = np.asarray(
-                        caches[i].gather_rows(upd.victim_slots)
+                    with _PASS.stage("d2h"):
+                        victim_rows = np.asarray(
+                            caches[i].gather_rows(upd.victim_slots)
+                        )
+                with _PASS.stage("set_rows"):
+                    caches[i].set_rows(
+                        np.concatenate([plan.hit_slots, upd.admit_slots]),
+                        rows_at(upd_pos),
                     )
-                caches[i].set_rows(
-                    np.concatenate([plan.hit_slots, upd.admit_slots]),
-                    rows_at(upd_pos),
-                )
             cold = empty_rows
             if upd.cold_pos.shape[0]:
-                cold = np.asarray(rows_at(upd.cold_pos))
+                with _PASS.stage("d2h"):
+                    cold = np.asarray(rows_at(upd.cold_pos))
             ks += [sk[upd.cold_pos], upd.victim_keys]
             vs += [cold, victim_rows]
             n_evicted += int(upd.victim_slots.shape[0])
@@ -1312,6 +1347,7 @@ class ShardedSparseTable(SparseTable):
                 "rows evicted from the HBM cache (written back to the host)",
             ).inc(n_evicted)
 
+    @_PASS.wrap("write_back")
     def _sorted_write_back(self, ks: list, vs: list) -> None:
         """One globally-sorted write-back from per-shard key/row pieces
         (shards partition the key space, so the concat is unique; the
@@ -1345,21 +1381,22 @@ class ShardedSparseTable(SparseTable):
         self._census_index = None
         caches = self._caches()
         plans, self._cache_plans = self._cache_plans, None
-        if caches and plans is not None:
-            self._end_pass_cached_sharded(caches, plans)
-        else:
-            vals = local_view(self.values)  # [L, cap, W]
-            g2 = local_view(self.g2sum)  # [L, cap]
-            ks, vs = [], []
-            for i, o in enumerate(self._local_pos):
-                sk = self._shard_keys[o]
-                m = sk.shape[0]
-                if m:
-                    ks.append(sk)
-                    vs.append(
-                        np.concatenate([vals[i, :m], g2[i, :m, None]], axis=1)
-                    )
-            self._sorted_write_back(ks, vs)
+        with stage_scope("pass.end"):
+            if caches and plans is not None:
+                self._end_pass_cached_sharded(caches, plans)
+            else:
+                with _PASS.stage("d2h"):
+                    vals = local_view(self.values)  # [L, cap, W]
+                    g2 = local_view(self.g2sum)  # [L, cap]
+                ks, vs = [], []
+                for i, o in enumerate(self._local_pos):
+                    sk = self._shard_keys[o]
+                    m = sk.shape[0]
+                    if m:
+                        ks.append(sk)
+                        vs.append(np.concatenate(
+                            [vals[i, :m], g2[i, :m, None]], axis=1))
+                self._sorted_write_back(ks, vs)
         self.values = None
         self.g2sum = None
         # the hot block stays device-resident across passes — its rows

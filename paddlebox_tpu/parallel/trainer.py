@@ -64,7 +64,7 @@ from paddlebox_tpu.parallel.multiprocess import (
 from paddlebox_tpu.parallel.sharded_table import ShardedBatchPlan, ShardedSparseTable
 from paddlebox_tpu.sparse.optimizer import sparse_adagrad_update
 from paddlebox_tpu.sparse.table import scatter_add_rows
-from paddlebox_tpu.telemetry.compiles import counted_jit
+from paddlebox_tpu.telemetry.compiles import counted_jit, stage_scope
 from paddlebox_tpu.utils import faults
 from paddlebox_tpu.train.slot_policy import (
     normalize_slot_mask,
@@ -146,7 +146,8 @@ def sharded_pull(values: jax.Array, serve_rows: jax.Array, occ_flat: jax.Array,
     n, C = serve_rows.shape
     W = values.shape[1]
     served = jnp.take(values, serve_rows.reshape(-1), axis=0)  # [n*C, W]
-    got = jax.lax.all_to_all(served.reshape(n, C, W), DATA_AXIS, 0, 0)
+    with jax.named_scope("exchange"):
+        got = jax.lax.all_to_all(served.reshape(n, C, W), DATA_AXIS, 0, 0)
     got_flat = jnp.concatenate(
         [got.reshape(n * C, W), jnp.zeros((1, W), values.dtype)]
     )
@@ -235,11 +236,12 @@ def hybrid_hot_update(
             [counters, jnp.zeros((H, co - 2), counters.dtype)], axis=1
         )
     contrib = jnp.concatenate([counters, merged[:, co:]], axis=1)  # [H, W]
-    gathered = jax.lax.all_gather(contrib, DATA_AXIS)  # [n, H, W]
-    acc = gathered[0]
-    for i in range(1, gathered.shape[0]):  # unrolled: fixed fold order
-        acc = acc + gathered[i]
-    lr = jax.lax.pmax(hot_lr, DATA_AXIS)
+    with jax.named_scope("hot_fold"):
+        gathered = jax.lax.all_gather(contrib, DATA_AXIS)  # [n, H, W]
+        acc = gathered[0]
+        for i in range(1, gathered.shape[0]):  # unrolled: fixed fold order
+            acc = acc + gathered[i]
+        lr = jax.lax.pmax(hot_lr, DATA_AXIS)
     w_delta, g2_delta = sparse_adagrad_update(
         hot_g2sum, acc[:, co:], lr, conf.initial_g2sum, conf.grad_clip,
     )
@@ -287,7 +289,8 @@ def sharded_push_and_update(
             [counters, jnp.zeros((n * C, co - 2), counters.dtype)], axis=1
         )
     send = jnp.concatenate([counters, merged[:, co:]], axis=1).reshape(n, C, W)
-    recv = jax.lax.all_to_all(send, DATA_AXIS, 0, 0)  # [n, C, W]
+    with jax.named_scope("exchange"):
+        recv = jax.lax.all_to_all(send, DATA_AXIS, 0, 0)  # [n, C, W]
     # cross-requester merge: duplicate rows across devices fold together
     acc = jax.ops.segment_sum(
         recv.reshape(n * C, W), serve_map.reshape(-1), num_segments=US
@@ -364,11 +367,12 @@ class MultiChipTrainer:
         # "step" mode every device holds an identical copy (grads are
         # psummed); in "kstep" mode copies drift and sync_params() re-averages
         # them (the reference's CopyParameters broadcast + K-step SyncParam).
-        p0 = model.init(jax.random.PRNGKey(seed))
-        o0 = self.optimizer.init(p0)
         self._sharding = NamedSharding(mesh, P(DATA_AXIS))
-        self.params = self._stack_local(p0)
-        self.opt_state = self._stack_local(o0)
+        with stage_scope("train.init"):
+            p0 = model.init(jax.random.PRNGKey(seed))
+            o0 = self.optimizer.init(p0)
+            self.params = self._stack_local(p0)
+            self.opt_state = self._stack_local(o0)
         self._step_fn = None
         self._step_hot_cap = -1  # hot capacity the step was built for
         self._sync_fn = None
@@ -378,6 +382,9 @@ class MultiChipTrainer:
         self.async_dense = None  # lazily created in "async" mode
         self.global_step = 0
         self.last_metric_state = None  # dict after a pass (Trainer parity)
+        from paddlebox_tpu.utils.profiler import CompletionWatcher
+
+        self._watch = CompletionWatcher()  # thread starts at first dispatch
 
     # -- jitted bodies ----------------------------------------------------- #
     def _build_step(self, hot_cap: int = 0):
@@ -416,18 +423,23 @@ class MultiChipTrainer:
             values, g2sum = values[0], g2sum[0]
             batch = unstack(batch)
 
+            # named scopes: metadata on the same operations (the names a
+            # device trace shows; the single-chip step's, plus ``exchange``
+            # and ``hot_fold`` inside pull / push)
             if hot_cap:
                 hot_values, hot_g2sum = hot_values[0], hot_g2sum[0]
-                rows = hybrid_pull(
-                    values, hot_values, batch["serve_rows"],
-                    batch["occ_flat"], batch["hot_occ"],
-                    tconf.create_threshold, tconf.cvm_offset,
-                )
+                with jax.named_scope("pull"):
+                    rows = hybrid_pull(
+                        values, hot_values, batch["serve_rows"],
+                        batch["occ_flat"], batch["hot_occ"],
+                        tconf.create_threshold, tconf.cvm_offset,
+                    )
             else:
-                rows = sharded_pull(
-                    values, batch["serve_rows"], batch["occ_flat"],
-                    tconf.create_threshold, tconf.cvm_offset,
-                )
+                with jax.named_scope("pull"):
+                    rows = sharded_pull(
+                        values, batch["serve_rows"], batch["occ_flat"],
+                        tconf.create_threshold, tconf.cvm_offset,
+                    )
             bsz = batch["labels"].shape[0]
             extra = {"rank_offset": batch["rank_offset"]} if uses_rank else {}
             if uses_seq:
@@ -441,6 +453,7 @@ class MultiChipTrainer:
             else:
                 key_part = None
 
+            @jax.named_scope("tower")
             def loss_fn(p, r):
                 if key_part is not None:
                     r = r * key_part[:, None]
@@ -465,33 +478,60 @@ class MultiChipTrainer:
             (loss, preds), (pgrads, row_grads) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(params, rows)
-            if sync_step:
-                pgrads = jax.lax.psum(pgrads, DATA_AXIS)
-                loss = jax.lax.psum(loss, DATA_AXIS)
-
-            if not async_dense:
-                updates, opt_state = optimizer.update(pgrads, opt_state, params)
-                params = optax.apply_updates(params, updates)
+            with jax.named_scope("dense_opt"):
+                if sync_step:
+                    pgrads = jax.lax.psum(pgrads, DATA_AXIS)
+                    loss = jax.lax.psum(loss, DATA_AXIS)
+                if not async_dense:
+                    updates, opt_state = optimizer.update(
+                        pgrads, opt_state, params)
+                    params = optax.apply_updates(params, updates)
             key_mask = batch["key_mask"]
             key_clicks = batch["key_clicks"]
             if key_part is not None:
                 # excluded slots increment no show/clk counters either
                 key_mask = key_mask * key_part
                 key_clicks = key_clicks * key_part
-            values, g2sum = sharded_push_and_update(
-                values, g2sum, row_grads, batch["occ_flat"], batch["serve_map"],
-                batch["serve_uniq"], key_mask, key_clicks, tconf,
-                uniq_lr=batch.get("uniq_lr"),
-            )
-            if hot_cap:
-                # hot occurrences carried the cold sink above, so their
-                # grads/counters reach exactly one of the two updates
-                hot_values, hot_g2sum = hybrid_hot_update(
-                    hot_values, hot_g2sum, row_grads, batch["hot_occ"],
-                    batch["hot_lr"], key_mask, key_clicks, tconf,
+            with jax.named_scope("push"):
+                values, g2sum = sharded_push_and_update(
+                    values, g2sum, row_grads, batch["occ_flat"],
+                    batch["serve_map"], batch["serve_uniq"], key_mask,
+                    key_clicks, tconf, uniq_lr=batch.get("uniq_lr"),
                 )
+                if hot_cap:
+                    # hot occurrences carried the cold sink above, so their
+                    # grads/counters reach exactly one of the two updates
+                    hot_values, hot_g2sum = hybrid_hot_update(
+                        hot_values, hot_g2sum, row_grads, batch["hot_occ"],
+                        batch["hot_lr"], key_mask, key_clicks, tconf,
+                    )
             primary = preds[:, 0] if n_tasks > 1 else preds
             mstate = dict(mstate)
+            with jax.named_scope("metrics"):
+                mstate, finite = step_metrics(
+                    mstate, batch, loss, preds, primary, pgrads, row_grads)
+            restack = lambda t: jax.tree.map(lambda x: x[None], t)
+            cnt = batch["ins_mask"].sum()
+            hot_out = (
+                (hot_values[None], hot_g2sum[None]) if hot_cap else ()
+            )
+            out = (
+                restack(params), restack(opt_state), values[None], g2sum[None],
+            ) + hot_out + (
+                restack(mstate), loss[None], cnt[None], finite[None],
+            )
+            if async_dense:
+                out = out + (restack(pgrads),)
+            if dump_preds:
+                # per-instance predictions for the field dumper — an extra
+                # output only in dump mode, so the normal step never pays
+                # the readback surface (reference: DumpField runs in the
+                # production multi-GPU workers, device_worker.cc)
+                out = out + (primary[None],)
+            return out
+
+        def step_metrics(mstate, batch, loss, preds, primary, pgrads,
+                         row_grads):
             mstate["auc"] = update_auc_state(
                 mstate["auc"], primary, batch["labels"], batch["ins_mask"]
             )
@@ -532,25 +572,7 @@ class MultiChipTrainer:
                 finite = bad == 0
             else:
                 finite = jnp.array(True)
-            restack = lambda t: jax.tree.map(lambda x: x[None], t)
-            cnt = batch["ins_mask"].sum()
-            hot_out = (
-                (hot_values[None], hot_g2sum[None]) if hot_cap else ()
-            )
-            out = (
-                restack(params), restack(opt_state), values[None], g2sum[None],
-            ) + hot_out + (
-                restack(mstate), loss[None], cnt[None], finite[None],
-            )
-            if async_dense:
-                out = out + (restack(pgrads),)
-            if dump_preds:
-                # per-instance predictions for the field dumper — an extra
-                # output only in dump mode, so the normal step never pays
-                # the readback surface (reference: DumpField runs in the
-                # production multi-GPU workers, device_worker.cc)
-                out = out + (primary[None],)
-            return out
+            return mstate, finite
 
         spec = P(DATA_AXIS)
         n_state = 8 if hot_cap else 6
@@ -635,7 +657,9 @@ class MultiChipTrainer:
         self.async_dense.push(jax.tree.map(lambda x: local_view(x)[0], g))
 
     def close(self) -> None:
-        """Stop background machinery (the async dense update thread)."""
+        """Stop background machinery (the completion watcher's thread,
+        the async dense update thread)."""
+        self._watch.close()
         if self.async_dense is not None:
             try:
                 self.async_dense.stop()  # raises if the update thread died
@@ -745,8 +769,8 @@ class MultiChipTrainer:
             )
         # telemetry: exporter/event log are process singletons (first pass
         # starts them); host stage timing always feeds the per-stage
-        # latency histograms (plan/feed here run on the producer thread —
-        # the device step is async and is NOT wall-timed per batch)
+        # latency histograms (plan/feed run on the producer thread; ``step``
+        # is the enqueue, the device's side is the completion watcher's)
         from paddlebox_tpu import telemetry
         from paddlebox_tpu.config import TelemetryConfig
         from paddlebox_tpu.utils.profiler import StatsProfiler
@@ -756,22 +780,25 @@ class MultiChipTrainer:
         event_log = telemetry.ensure_event_log(tele.events_path or None)
         sprof = StatsProfiler()
 
+        watch = self._watch
         pending_grads: list = []  # device grads fetched one step behind
         pull_every = max(self.conf.sync_weight_step, 1)
-        mstate = self._init_mstate(auc_state)
         from paddlebox_tpu.parallel.multiprocess import merge_device_axis
 
-        # grad-norm baseline: the accumulator carries across continued
-        # passes — snapshot NOW (a lockstep device-axis merge on every
-        # rank), the first step donates the buffer
-        gn_base = np.asarray(
-            merge_device_axis(mstate["gn"]), dtype=np.float64
-        )
+        with stage_scope("train.init"):
+            mstate = self._init_mstate(auc_state)
+            # grad-norm baseline: the accumulator carries across continued
+            # passes — snapshot NOW (a lockstep device-axis merge on every
+            # rank), the first step donates the buffer
+            gn_base = np.asarray(
+                merge_device_axis(mstate["gn"]), dtype=np.float64
+            )
         pass_t0 = time.monotonic()
         values, g2sum = table.values, table.g2sum
         hot_values = hot_g2sum = None
         if hot_cap:
-            hot_values, hot_g2sum = self._hot_state(table, hot_cap)
+            with stage_scope("train.init"):
+                hot_values, hot_g2sum = self._hot_state(table, hot_cap)
         losses, counts, n_steps = [], [], 0
         uses_rank = getattr(self.model, "uses_rank_offset", False)
         uses_seq = getattr(self.model, "uses_seq_pos", False)
@@ -822,7 +849,7 @@ class MultiChipTrainer:
             All its cross-process exchanges ride the host-plane KV channel
             above — it never touches the device queues, so it cannot
             deadlock against the consumer's step collectives."""
-            groups_it = iter(groups)
+            groups_it = sprof.iterate("batch", groups)
             template = None  # last real batch: shapes for tail-padding
             n_slots = None
             while True:
@@ -908,76 +935,79 @@ class MultiChipTrainer:
             )
         feed_iter = produce_feeds()
         prefetcher = None
-        if self.conf.prefetch_batches > 0:
-            from paddlebox_tpu.train.trainer import _FeedPrefetcher
-
-            prefetcher = _FeedPrefetcher(
-                feed_iter, self.conf.prefetch_batches
-            )
-            feed_iter = prefetcher
         try:
-            for feed, dump_group in feed_iter:
-                # chaos site: a hang here simulates a stalled device step
-                # on this process; the watchdog bounds it fleet-wide
-                faults.inject("train.step")
-                if hot_cap:
-                    out = self._step_fn(
-                        self.params, self.opt_state, values, g2sum, mstate,
-                        feed, hot_values, hot_g2sum,
-                    )
-                    (self.params, self.opt_state, values, g2sum, hot_values,
-                     hot_g2sum, mstate, loss, cnt, finite) = out[:10]
-                    n_fixed = 10
-                else:
-                    out = self._step_fn(
-                        self.params, self.opt_state, values, g2sum, mstate,
-                        feed,
-                    )
-                    (self.params, self.opt_state, values, g2sum, mstate, loss,
-                     cnt, finite) = out[:8]
-                    n_fixed = 8
-                if wd is not None:
-                    wd.report("step")
-                if dumper is not None:
-                    # [L, B] local predictions; pad batches dump nothing
-                    preds = local_view(out[-1])
-                    for d, b in enumerate(dump_group):
-                        dumper.dump_batch(b, np.asarray(preds[d]))
-                if async_dense:
-                    # push one step BEHIND: step t's grad is already computed
-                    # when step t+1 dispatches, so reading it never stalls
-                    # the device pipeline
-                    pending_grads.append(out[n_fixed])
-                    if len(pending_grads) > 1:
-                        self._push_async_grad(pending_grads.pop(0))
-                    if (self.global_step + 1) % pull_every == 0:
-                        self.params = self._stack_local(self.async_dense.pull())
-                if self.conf.check_nan_inf and not bool(
-                    local_view(finite).all()
-                ):
-                    raise FloatingPointError(
-                        f"non-finite loss/grad at step {self.global_step} "
-                        "(FLAGS_check_nan_inf analog)"
-                    )
-                losses.append(loss)
-                counts.append(cnt)
-                n_steps += 1
-                self.global_step += 1
-                if (
-                    self.conf.sync_dense_mode == "kstep"
-                    and self.global_step % max(self.conf.sync_weight_step, 1) == 0
-                ):
-                    self.params, self.opt_state = self._sync_fn(
-                        self.params, self.opt_state
-                    )
-            if async_dense:
-                # pass boundary: flush the lagged grad, wait for the master
-                # copy to absorb everything, refresh device params
-                for g in pending_grads:
-                    self._push_async_grad(g)
-                pending_grads.clear()
-                self.async_dense.drain()
-                self.params = self._stack_local(self.async_dense.pull())
+          with telemetry.span("pass", global_step=self.global_step):
+              if self.conf.prefetch_batches > 0:
+                  from paddlebox_tpu.train.trainer import _FeedPrefetcher
+
+                  # started inside the pass span: the producer's plan/feed
+                  # spans inherit it as their parent
+                  prefetcher = _FeedPrefetcher(
+                      feed_iter, self.conf.prefetch_batches, sprof
+                  )
+                  feed_iter = prefetcher
+              for feed, dump_group in feed_iter:
+                  # chaos site: a hang here simulates a stalled device step
+                  # on this process; the watchdog bounds it fleet-wide
+                  faults.inject("train.step")
+                  t_dispatch = time.perf_counter()
+                  with sprof.stage("step"):
+                      hot = (hot_values, hot_g2sum) if hot_cap else ()
+                      out = self._step_fn(
+                          self.params, self.opt_state, values, g2sum, mstate,
+                          feed, *hot,
+                      )
+                  if hot_cap:
+                      (self.params, self.opt_state, values, g2sum, hot_values,
+                       hot_g2sum, mstate, loss, cnt, finite) = out[:10]
+                      n_fixed = 10
+                  else:
+                      (self.params, self.opt_state, values, g2sum, mstate, loss,
+                       cnt, finite) = out[:8]
+                      n_fixed = 8
+                  watch.dispatched(loss, t_dispatch)
+                  if wd is not None:
+                      wd.report("step")
+                  if dumper is not None:
+                      # [L, B] local predictions; pad batches dump nothing
+                      preds = local_view(out[-1])
+                      for d, b in enumerate(dump_group):
+                          dumper.dump_batch(b, np.asarray(preds[d]))
+                  if async_dense:
+                      # push one step BEHIND: step t's grad is already computed
+                      # when step t+1 dispatches, so reading it never stalls
+                      # the device pipeline
+                      pending_grads.append(out[n_fixed])
+                      if len(pending_grads) > 1:
+                          self._push_async_grad(pending_grads.pop(0))
+                      if (self.global_step + 1) % pull_every == 0:
+                          self.params = self._stack_local(self.async_dense.pull())
+                  if self.conf.check_nan_inf and not bool(
+                      local_view(finite).all()
+                  ):
+                      raise FloatingPointError(
+                          f"non-finite loss/grad at step {self.global_step} "
+                          "(FLAGS_check_nan_inf analog)"
+                      )
+                  losses.append(loss)
+                  counts.append(cnt)
+                  n_steps += 1
+                  self.global_step += 1
+                  if (
+                      self.conf.sync_dense_mode == "kstep"
+                      and self.global_step % max(self.conf.sync_weight_step, 1) == 0
+                  ):
+                      self.params, self.opt_state = self._sync_fn(
+                          self.params, self.opt_state
+                      )
+              if async_dense:
+                  # pass boundary: flush the lagged grad, wait for the master
+                  # copy to absorb everything, refresh device params
+                  for g in pending_grads:
+                      self._push_async_grad(g)
+                  pending_grads.clear()
+                  self.async_dense.drain()
+                  self.params = self._stack_local(self.async_dense.pull())
         except _wd_mod.DistributedStallError:
             # coordinated abort: every process converges on the same
             # structured error (poison key); teardown in the finally below
@@ -1012,68 +1042,18 @@ class MultiChipTrainer:
             prepare = getattr(table, "prepare_pass", None)
             if prepare is not None:
                 prepare(next_pass_keys)
-        # cross-device merge: sum each stream's histograms over the device
-        # axis (multi-host: jitted replicated sum + local read,
-        # collect_data_nccl analog)
-        from paddlebox_tpu.parallel.multiprocess import merge_device_axis
-
-        merged = merge_device_axis(mstate["auc"])
-        metrics = compute_metrics(merged)
-        if self.n_tasks > 1:
-            task_merged = merge_device_axis(mstate["task"])
-            metrics.update(
-                compute_metrics_stacked(
-                    task_merged, [f"task{t}" for t in range(self.n_tasks)]
-                )
-            )
-        if self.metric_group is not None:
-            group_merged = merge_device_axis(mstate["group"])
-            metrics.update(self.metric_group.compute(group_merged))
-        if losses:
-            # [T, L] local views; multi-host: gather to [T, D]
-            per_step = np.stack([local_view(l) for l in losses])
-            cnts = np.stack([local_view(c) for c in counts])
-            if multiproc:
-                per_step = np.moveaxis(
-                    host_allgather(per_step), 0, 1
-                ).reshape(len(losses), -1)
-                cnts = np.moveaxis(
-                    host_allgather(cnts), 0, 1
-                ).reshape(len(counts), -1)
-            if self.conf.sync_dense_mode == "kstep":
-                # local losses are local means: recombine weighted by real
-                # instance counts so padded empty batches don't bias the pass
-                num = (per_step * cnts).sum(axis=1)
-                den = np.maximum(cnts.sum(axis=1), 1.0)
-                metrics["loss"] = float((num / den).mean())
-            else:
-                # psummed loss is replicated across the axis
-                metrics["loss"] = float(per_step[:, 0].mean())
-            metrics["samples"] = float(cnts.sum())
-        else:
-            metrics["loss"] = 0.0
-            metrics["samples"] = 0.0
+        # the devices' tail: the metric merge below waits for the last
+        # queued step anyway; waiting here first gives the wait its own
+        # name and leaves ``readback`` the merges and eager programs alone
+        with sprof.stage("drain"):
+            if losses:
+                losses[-1].block_until_ready()
+            watch.settle()
+        with stage_scope("train.readback"), sprof.stage("readback"):
+            metrics = self._read_back(mstate, losses, counts, gn_base,
+                                      multiproc)
         metrics["steps"] = n_steps
         metrics["duration_s"] = time.monotonic() - pass_t0
-        gn_now = np.asarray(merge_device_axis(mstate["gn"]), dtype=np.float64)
-        d_sq, d_n = gn_now[0] - gn_base[0], gn_now[1] - gn_base[1]
-        if d_n > 0:
-            grad_norm = float(np.sqrt(d_sq / d_n)) if d_sq >= 0 else float(
-                "nan")
-            metrics["grad_norm"] = grad_norm
-            telemetry.gauge(
-                "train.grad_norm",
-                "per-pass RMS global gradient norm (dense + sparse)",
-            ).set(grad_norm)
-        wsq = sum(
-            float(jnp.sum(jnp.square(read_replicated(leaf).astype(
-                jnp.float32))))
-            for leaf in jax.tree.leaves(self.params)
-        )
-        metrics["weight_norm"] = math.sqrt(wsq) if wsq >= 0 else float("nan")
-        telemetry.gauge(
-            "train.weight_norm", "dense parameter L2 norm at pass end"
-        ).set(metrics["weight_norm"])
         metrics["missing_keys"] = table.missing_key_count
         metrics["overflow_keys"] = table.overflow_key_count  # always 0 now
         metrics["capacity_bumps"] = table.capacity_bumps
@@ -1124,6 +1104,74 @@ class MultiChipTrainer:
             # (Skipped on the exception path: peers may still be blocked on
             # a get; two leaked keys on a dying pass is the lesser evil.)
             plan_channel.close()
+        return metrics
+
+    def _read_back(self, mstate: dict, losses: list, counts: list,
+                   gn_base, multiproc: bool) -> dict:
+        """The pass's metrics from the devices' metric state (eager
+        programs and lockstep device-axis merges, tagged ``train.readback``
+        by the caller)."""
+        from paddlebox_tpu import telemetry
+        from paddlebox_tpu.parallel.multiprocess import merge_device_axis
+
+        # cross-device merge: sum each stream's histograms over the device
+        # axis (multi-host: jitted replicated sum + local read,
+        # collect_data_nccl analog)
+        merged = merge_device_axis(mstate["auc"])
+        metrics = compute_metrics(merged)
+        if self.n_tasks > 1:
+            task_merged = merge_device_axis(mstate["task"])
+            metrics.update(
+                compute_metrics_stacked(
+                    task_merged, [f"task{t}" for t in range(self.n_tasks)]
+                )
+            )
+        if self.metric_group is not None:
+            group_merged = merge_device_axis(mstate["group"])
+            metrics.update(self.metric_group.compute(group_merged))
+        if losses:
+            # [T, L] local views; multi-host: gather to [T, D]
+            per_step = np.stack([local_view(l) for l in losses])
+            cnts = np.stack([local_view(c) for c in counts])
+            if multiproc:
+                per_step = np.moveaxis(
+                    host_allgather(per_step), 0, 1
+                ).reshape(len(losses), -1)
+                cnts = np.moveaxis(
+                    host_allgather(cnts), 0, 1
+                ).reshape(len(counts), -1)
+            if self.conf.sync_dense_mode == "kstep":
+                # local losses are local means: recombine weighted by real
+                # instance counts so padded empty batches don't bias the pass
+                num = (per_step * cnts).sum(axis=1)
+                den = np.maximum(cnts.sum(axis=1), 1.0)
+                metrics["loss"] = float((num / den).mean())
+            else:
+                # psummed loss is replicated across the axis
+                metrics["loss"] = float(per_step[:, 0].mean())
+            metrics["samples"] = float(cnts.sum())
+        else:
+            metrics["loss"] = 0.0
+            metrics["samples"] = 0.0
+        gn_now = np.asarray(merge_device_axis(mstate["gn"]), dtype=np.float64)
+        d_sq, d_n = gn_now[0] - gn_base[0], gn_now[1] - gn_base[1]
+        if d_n > 0:
+            grad_norm = float(np.sqrt(d_sq / d_n)) if d_sq >= 0 else float(
+                "nan")
+            metrics["grad_norm"] = grad_norm
+            telemetry.gauge(
+                "train.grad_norm",
+                "per-pass RMS global gradient norm (dense + sparse)",
+            ).set(grad_norm)
+        wsq = sum(
+            float(jnp.sum(jnp.square(read_replicated(leaf).astype(
+                jnp.float32))))
+            for leaf in jax.tree.leaves(self.params)
+        )
+        metrics["weight_norm"] = math.sqrt(wsq) if wsq >= 0 else float("nan")
+        telemetry.gauge(
+            "train.weight_norm", "dense parameter L2 norm at pass end"
+        ).set(metrics["weight_norm"])
         return metrics
 
     # -- inference / evaluation -------------------------------------------- #

@@ -47,6 +47,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddlebox_tpu.utils.profiler import StatsProfiler
+
+# the directory's share of a pass boundary, by stage (lookup / touch /
+# plan_update / commit): pass.stage_seconds, pbox.pass.<stage> on a trace
+_PASS = StatsProfiler("pass.stage_seconds")
+
 _EMPTY_U64 = np.empty(0, dtype=np.uint64)
 _EMPTY_I32 = np.empty(0, dtype=np.int32)
 
@@ -157,6 +163,7 @@ class HbmCache:
             self._sorted_keys = _EMPTY_U64
             self._sorted_slots = _EMPTY_I32
 
+    @_PASS.wrap("lookup")
     def lookup(self, pk: np.ndarray) -> CachePlan:
         """Resolve a sorted unique census against the directory."""
         n = pk.shape[0]
@@ -170,6 +177,7 @@ class HbmCache:
         return CachePlan(hit, hit_pos, self._sorted_slots[pos[hit]])
 
     # -- policy ----------------------------------------------------------- #
+    @_PASS.wrap("touch")
     def touch(self, plan: CachePlan) -> None:
         """One pass observed: age every resident frequency, credit this
         census's hits (metadata only — membership is untouched, so the
@@ -181,6 +189,7 @@ class HbmCache:
             self.last_seen[plan.hit_slots] = self.tick
         self.tick += 1
 
+    @_PASS.wrap("plan_update")
     def plan_update(self, pk: np.ndarray, plan: CachePlan) -> UpdatePlan:
         """Admission/eviction for the finished pass's census: misses fill
         free slots first, then evict the coldest non-census residents whose
@@ -214,6 +223,7 @@ class HbmCache:
             cold_pos=miss_pos[n_admit:],
         )
 
+    @_PASS.wrap("commit")
     def commit_update(self, plan: CachePlan, upd: UpdatePlan) -> None:
         """Apply an UpdatePlan to the directory: victims leave, admits
         enter (fresh frequency 1.0), and every row the pass touched —

@@ -47,8 +47,36 @@ import numpy as np
 from paddlebox_tpu.config import SparseTableConfig
 from paddlebox_tpu.data.feed import HostBatch
 from paddlebox_tpu.sparse.optimizer import sparse_adagrad_update
+from paddlebox_tpu.telemetry.compiles import stage_scope
+from paddlebox_tpu.utils.profiler import StatsProfiler
 
 logger = logging.getLogger(__name__)
+
+# the pass boundary by stage (pass.stage_seconds{stage=}, pbox.pass.<stage>
+# on a running device trace): begin_pass = census / take_stage / alloc /
+# lookup / fetch / upload / fill / touch, end_pass = pack / plan_update /
+# d2h / set_rows / commit / write_back.  lookup, touch, plan_update and
+# commit are timed inside HbmCache, where both tables call them.
+_PASS = StatsProfiler("pass.stage_seconds")
+
+
+def _count_begin(at: str, arrays) -> None:
+    """Was the device still working when begin_pass got here?  ``is_ready``
+    on the arrays the boundary's device work produces (the cache's rows
+    after end_pass's set_rows; the pass buffer after upload and fill, before
+    it is split into values and g2sum): a question, never a wait."""
+    from paddlebox_tpu import telemetry
+
+    if at == "begin_entry":
+        telemetry.counter(
+            "pass.begins", "begin_pass calls (pass.device_pending's base)"
+        ).inc()
+    if any(a is not None and not a.is_ready() for a in arrays):
+        telemetry.counter(
+            "pass.device_pending",
+            "begin_pass calls that found (at=begin_entry) or left "
+            "(at=begin_exit) boundary device work still running",
+        ).inc(at=at)
 
 
 class _SerialWorker:
@@ -729,9 +757,10 @@ class SparseTable:
 
         plan = cache.lookup(pk)
         if plan.n_hits:
-            v = v.at[jnp.asarray(plan.hit_pos)].set(
-                cache.gather_rows(plan.hit_slots)
-            )
+            with _PASS.stage("fill"):
+                v = v.at[jnp.asarray(plan.hit_pos)].set(
+                    cache.gather_rows(plan.hit_slots)
+                )
         cache.touch(plan)
         n = pk.shape[0]
         self.last_cache_hits = plan.n_hits
@@ -742,6 +771,7 @@ class SparseTable:
         ).set(plan.n_hits / max(n, 1))
         return plan, v
 
+    @stage_scope("pass.begin")
     def begin_pass(self, pass_keys: np.ndarray) -> None:
         """Promote the pass working set to device (reference: EndFeedPass
         SSD->CPU->HBM promote + BeginPass, box_wrapper.cc:630-659).  When
@@ -749,12 +779,15 @@ class SparseTable:
         intersection patch + jnp.asarray; with the HBM cache, the host
         only ever supplies the cache MISSES (the promotion patch) and hit
         rows are filled by a device gather."""
+        if self._in_pass:
+            raise RuntimeError("end_pass the previous pass first")
         from paddlebox_tpu import telemetry
         from paddlebox_tpu.utils import faults
 
-        if self._in_pass:
-            raise RuntimeError("end_pass the previous pass first")
-        pk = np.unique(np.asarray(pass_keys, dtype=np.uint64))
+        cache = self._get_cache()
+        _count_begin("begin_entry", [cache.rows] if cache is not None else [])
+        with _PASS.stage("census"):
+            pk = np.unique(np.asarray(pass_keys, dtype=np.uint64))
         w = self.conf.row_width
         # layout: [0, n) live rows | [n, cap-1) plan scratch | cap-1 dead.
         # Scratch rows give every padding/missing plan slot a distinct
@@ -765,18 +798,22 @@ class SparseTable:
         # gracefully if a later batch needs more).
         cap = self._stage_cap(pk.shape[0])
         n = pk.shape[0]
-        cache = self._get_cache()
-        staged = self._take_stage(pk, cap)
+        with _PASS.stage("take_stage"):
+            staged = self._take_stage(pk, cap)
         vals = staged
         if vals is None:
-            vals = np.zeros((cap, w + 1), dtype=np.float32)
+            with _PASS.stage("alloc"):
+                vals = np.zeros((cap, w + 1), dtype=np.float32)
             if cache is None:
-                vals[:n] = self._resolve_or_init(pk)
+                with _PASS.stage("fetch"):
+                    vals[:n] = self._resolve_or_init(pk)
             else:
                 try:
                     miss_pos = np.nonzero(~cache.lookup(pk).hit_mask)[0]
                     if miss_pos.shape[0]:
-                        vals[miss_pos] = self._cache_fetch_rows(pk[miss_pos])
+                        with _PASS.stage("fetch"):
+                            vals[miss_pos] = self._cache_fetch_rows(
+                                pk[miss_pos])
                 except faults.FaultInjected:
                     # degraded pass: dirty rows drain to the host tier,
                     # census keys leave the cache, full host resolve (the
@@ -787,9 +824,11 @@ class SparseTable:
                     ).inc()
                     self._cache_degrade(pk)
                     cache = None
-                    vals[:n] = self._resolve_or_init(pk)
+                    with _PASS.stage("fetch"):
+                        vals[:n] = self._resolve_or_init(pk)
         plan = None
-        v = jnp.asarray(vals)
+        with _PASS.stage("upload"):
+            v = jnp.asarray(vals)
         if cache is not None:
             # staged path included: current-miss positions carry staged
             # rows (+ write-back patches — evictions always write back),
@@ -812,6 +851,10 @@ class SparseTable:
         self._in_pass = True
         self._delta_keys.append(pk)
         self._observe_gap()
+        # the uploaded and filled buffer, not the two slices taken of it a
+        # moment ago: is the boundary's own device work still running?
+        _count_begin("begin_exit", [
+            v, cache.rows if cache is not None else None])
 
     def _cache_update_plan(self, cache, pk: np.ndarray, plan):
         """Admission/eviction decision for the finished pass — chaos site
@@ -840,10 +883,13 @@ class SparseTable:
         pass can always be patched current from the write-back log."""
         from paddlebox_tpu import telemetry
 
-        full = jnp.concatenate([self.values, self.g2sum[:, None]], axis=1)
+        with _PASS.stage("pack"):
+            full = jnp.concatenate(
+                [self.values, self.g2sum[:, None]], axis=1)
         upd = self._cache_update_plan(cache, pk, plan)
         if upd is None:
-            vals = np.asarray(full[:n])
+            with _PASS.stage("d2h"):
+                vals = np.asarray(full[:n])
             telemetry.counter(
                 "pass.host_row_bytes_out",
                 "embedding-row bytes written back device->host at "
@@ -851,22 +897,25 @@ class SparseTable:
             ).inc(vals.nbytes)
             with self._cache_lock:
                 cache.evict_keys(pk[plan.hit_mask])
-                self._write_back(pk, vals)
+                with _PASS.stage("write_back"):
+                    self._write_back(pk, vals)
             return
         upd_pos = np.concatenate([plan.hit_pos, upd.admit_pos])
         upd_slots = np.concatenate([plan.hit_slots, upd.admit_slots])
-        victim_rows = (
-            np.asarray(cache.gather_rows(upd.victim_slots))
-            if upd.victim_slots.shape[0]
-            else np.empty((0, cache.n_cols), np.float32)
-        )
-        cold_rows = (
-            np.asarray(full[jnp.asarray(upd.cold_pos)])
-            if upd.cold_pos.shape[0]
-            else np.empty((0, cache.n_cols), np.float32)
-        )
+        with _PASS.stage("d2h"):
+            victim_rows = (
+                np.asarray(cache.gather_rows(upd.victim_slots))
+                if upd.victim_slots.shape[0]
+                else np.empty((0, cache.n_cols), np.float32)
+            )
+            cold_rows = (
+                np.asarray(full[jnp.asarray(upd.cold_pos)])
+                if upd.cold_pos.shape[0]
+                else np.empty((0, cache.n_cols), np.float32)
+            )
         if upd_slots.shape[0]:
-            cache.set_rows(upd_slots, full[jnp.asarray(upd_pos)])
+            with _PASS.stage("set_rows"):
+                cache.set_rows(upd_slots, full[jnp.asarray(upd_pos)])
         wb_keys = np.concatenate([pk[upd.cold_pos], upd.victim_keys])
         order = np.argsort(wb_keys, kind="stable")
         telemetry.counter(
@@ -876,10 +925,11 @@ class SparseTable:
         ).inc(cold_rows.nbytes + victim_rows.nbytes)
         with self._cache_lock:
             cache.commit_update(plan, upd)
-            self._write_back(
-                wb_keys[order],
-                np.concatenate([cold_rows, victim_rows])[order],
-            )
+            with _PASS.stage("write_back"):
+                self._write_back(
+                    wb_keys[order],
+                    np.concatenate([cold_rows, victim_rows])[order],
+                )
         if upd.victim_slots.shape[0]:
             telemetry.counter(
                 "cache.evicted_rows",
@@ -899,21 +949,25 @@ class SparseTable:
         n = pk.shape[0]
         cache = self._get_cache()
         plan, self._cache_plan = self._cache_plan, None
-        if cache is not None and plan is not None and n:
-            self._end_pass_cached(cache, plan, pk, n)
-        else:
-            from paddlebox_tpu import telemetry
+        with stage_scope("pass.end"):
+            if cache is not None and plan is not None and n:
+                self._end_pass_cached(cache, plan, pk, n)
+            else:
+                from paddlebox_tpu import telemetry
 
-            vals = np.concatenate(
-                [np.asarray(self.values), np.asarray(self.g2sum)[:, None]],
-                axis=1,
-            )[:n]
-            telemetry.counter(
-                "pass.host_row_bytes_out",
-                "embedding-row bytes written back device->host at "
-                "end_pass (cold + evicted rows)",
-            ).inc(vals.nbytes)
-            self._write_back(pk, vals)
+                with _PASS.stage("d2h"):
+                    vals = np.concatenate(
+                        [np.asarray(self.values),
+                         np.asarray(self.g2sum)[:, None]],
+                        axis=1,
+                    )[:n]
+                telemetry.counter(
+                    "pass.host_row_bytes_out",
+                    "embedding-row bytes written back device->host at "
+                    "end_pass (cold + evicted rows)",
+                ).inc(vals.nbytes)
+                with _PASS.stage("write_back"):
+                    self._write_back(pk, vals)
         self.values = None
         self.g2sum = None
         # DROP the native index reference rather than eagerly closing it: a
@@ -1094,6 +1148,7 @@ class SparseTable:
         keys, vals = self._store.materialize()
         return {"keys": keys, "values": vals}
 
+    @stage_scope("table.load")
     def load_state_dict(self, state: dict) -> None:
         self.flush()  # pending merges must not land on top of the restore
         self._discard_stage()  # a staged pass resolved pre-restore is stale

@@ -53,6 +53,9 @@ from paddlebox_tpu.telemetry.events import (  # noqa: F401
 )
 from paddlebox_tpu.telemetry.trace import (  # noqa: F401
     Tracer,
+    adopt_span,
+    annotation,
+    current_span,
     disable_tracing,
     enable_tracing,
     flush_trace,

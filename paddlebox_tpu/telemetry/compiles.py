@@ -39,6 +39,7 @@ checkout and the serving-side quant tooling.
 
 from __future__ import annotations
 
+import contextlib
 import threading
 
 from paddlebox_tpu.telemetry import metrics
@@ -84,9 +85,10 @@ def current_stage() -> str:
     return st[-1] if st else UNTAGGED
 
 
-class stage_scope:
+class stage_scope(contextlib.ContextDecorator):
     """Attribute backend compiles on this thread to ``stage`` while the
-    scope is active.  Reentrant; innermost scope wins."""
+    scope is active (a ``with`` block, or a decorated function's calls).
+    Reentrant; innermost scope wins."""
 
     def __init__(self, stage: str):
         self.stage = stage

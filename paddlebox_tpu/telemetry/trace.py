@@ -23,6 +23,14 @@ trace for ``tools/pbox_doctor.py --trace <id>``.
 Trace files carry a wall-clock anchor (``pboxWallT0``) next to the
 perf-counter timestamps, so the doctor can merge spans from many
 processes onto one wall-time axis.
+
+One clock with the device: every span also enters a
+``jax.profiler.TraceAnnotation("pbox.<name>")`` (:func:`annotation`), so
+whenever a ``jax.profiler`` trace is running — an operator's
+``TrainerConfig.trace_dir`` or a benchmark's own — the span lands on that
+trace's host plane in the same nanoseconds as the device's ``XLA Ops``
+line and can be laid over a device gap.  With no trace running the
+annotation is a flag test.
 """
 
 from __future__ import annotations
@@ -30,12 +38,32 @@ from __future__ import annotations
 import contextlib
 import json
 import os
+import sys
 import threading
 import time
 from typing import Iterator, Optional
 
 from paddlebox_tpu.telemetry import context as _context
 from paddlebox_tpu.telemetry import flight as _flight
+
+
+ANNOTATION_PREFIX = "pbox."
+_trace_annotation = None  # jax.profiler.TraceAnnotation once imported
+
+
+def annotation(name: str):
+    """``jax.profiler.TraceAnnotation("pbox." + name)``: the span on the
+    device trace's own clock.  A process that has not imported jax cannot
+    be running its profiler, so it gets a ``nullcontext`` and stays
+    jax-free (the router, the doctor, serving-side tooling)."""
+    global _trace_annotation
+    if _trace_annotation is None:
+        if "jax" not in sys.modules:
+            return contextlib.nullcontext()
+        from jax.profiler import TraceAnnotation
+
+        _trace_annotation = TraceAnnotation
+    return _trace_annotation(ANNOTATION_PREFIX + name)
 
 
 class Tracer:
@@ -61,6 +89,17 @@ class Tracer:
         if st is None:
             st = self._tls.stack = []
         return st
+
+    def current(self) -> Optional[str]:
+        """The innermost open span of the calling thread."""
+        st = self._stack()
+        return st[-1] if st else None
+
+    def adopt(self, parent: Optional[str]) -> None:
+        """Start the calling thread's span stack under ``parent``: a worker
+        thread's spans then name the span that caused them (the feed
+        producer under its consumer's ``pass``) instead of no parent."""
+        self._tls.stack = [parent] if parent else []
 
     @contextlib.contextmanager
     def span(self, name: str, **meta) -> Iterator[None]:
@@ -195,7 +234,7 @@ def _recorded_span(t: Optional[Tracer], name: str, meta: dict):
     start_wall = time.time()
     t0 = time.perf_counter()
     try:
-        with _context.activate(child):
+        with _context.activate(child), annotation(name):
             if t is not None:
                 with t.span(name, **{**meta, **tf}):
                     yield
@@ -213,10 +252,26 @@ def _recorded_span(t: Optional[Tracer], name: str, meta: dict):
 
 
 def span(name: str, **meta):
-    """Record a span: always into the flight ring, into the Chrome-trace
-    tracer when one is enabled, and under the active distributed trace
-    context when one is installed."""
+    """Record a span: always into the flight ring and onto any running
+    ``jax.profiler`` trace (``pbox.<name>``), into the Chrome-trace tracer
+    when one is enabled, and under the active distributed trace context
+    when one is installed."""
     return _recorded_span(_tracer, name, meta)
+
+
+def current_span() -> Optional[str]:
+    """The calling thread's innermost open span (None when file tracing
+    is off: the span stack is the tracer's)."""
+    t = _tracer
+    return t.current() if t is not None else None
+
+
+def adopt_span(parent: Optional[str]) -> None:
+    """Call first on a worker thread: its spans record ``parent`` (the
+    spawning thread's :func:`current_span`) as the span that caused them."""
+    t = _tracer
+    if t is not None:
+        t.adopt(parent)
 
 
 def instant(name: str, **meta) -> None:

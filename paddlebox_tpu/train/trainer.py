@@ -38,7 +38,7 @@ from paddlebox_tpu.metrics.auc import (
 from paddlebox_tpu.metrics.variants import MetricGroup
 from paddlebox_tpu.models.layers import bce_with_logits
 from paddlebox_tpu.sparse.table import SparseTable, pull_rows, push_and_update
-from paddlebox_tpu.telemetry.compiles import counted_jit
+from paddlebox_tpu.telemetry.compiles import counted_jit, stage_scope
 from paddlebox_tpu.utils import faults
 from paddlebox_tpu.utils.monitor import stats
 
@@ -192,30 +192,46 @@ class _FeedPrefetcher:
     (the pinned-arena double buffer of SURVEY.md §2.3, as a thread + queue;
     JAX's device_put already stages through pinned runtime buffers, so the
     missing piece was only the OVERLAP, provided here).  Exceptions raised
-    by the producer re-raise at the consumer's next() call."""
+    by the producer re-raise at the consumer's next() call.
+
+    Both sides of the queue are timed (``prof``, the trainer's
+    StatsProfiler): ``feed_wait`` is the consumer blocked on an empty
+    queue — the device's next feed was not ready — and ``feed_put_wait``
+    the producer blocked on a full one, the host's slack."""
 
     _SENTINEL = object()
 
-    def __init__(self, gen, depth: int):
+    def __init__(self, gen, depth: int, prof=None):
         import queue
         import threading
+
+        from paddlebox_tpu.telemetry import trace
+        from paddlebox_tpu.utils.profiler import StatsProfiler
 
         self._q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
         self._stop = False
         self._done = False
+        self._prof = prof or StatsProfiler()
+        # the producer's plan/feed spans name the consumer's open span
+        # (the pass) as the span that caused them
+        self._parent_span = trace.current_span()
         self._thread = threading.Thread(
             target=self._run, args=(gen,), name="feed-prefetch", daemon=True
         )
         self._thread.start()
 
     def _run(self, gen) -> None:
+        from paddlebox_tpu.telemetry import trace
         from paddlebox_tpu.utils.queues import bounded_put
+
+        trace.adopt_span(self._parent_span)
 
         def put(item) -> bool:
             # re-checks _stop: close() drains the queue, so a blocking put
             # would otherwise race it and the producer could keep planning
             # batches (and touching the table) after the caller ended the pass
-            return bounded_put(self._q, item, lambda: self._stop)
+            with self._prof.stage("feed_put_wait"):
+                return bounded_put(self._q, item, lambda: self._stop)
 
         try:
             for item in gen:
@@ -234,16 +250,18 @@ class _FeedPrefetcher:
         if self._done:  # keep raising after exhaustion/producer death —
             raise StopIteration  # the producer will never put again
         wd_mod = _watchdog_mod()
-        while True:
-            # bounded get: a coordinated liveness abort must interrupt a
-            # consumer blocked on a stalled producer within one poll slice
-            if wd_mod is not None:
-                wd_mod.check()
-            try:
-                item = self._q.get(timeout=0.2)
-                break
-            except queue.Empty:
-                continue
+        with self._prof.stage("feed_wait"):
+            while True:
+                # bounded get: a coordinated liveness abort must interrupt
+                # a consumer blocked on a stalled producer within one poll
+                # slice
+                if wd_mod is not None:
+                    wd_mod.check()
+                try:
+                    item = self._q.get(timeout=0.2)
+                    break
+                except queue.Empty:
+                    continue
         if item is self._SENTINEL:
             self._done = True
             raise StopIteration
@@ -330,8 +348,9 @@ class Trainer:
         # AutoCheckpointer for nan_policy="rollback" (assign after
         # construction); without one, rollback degrades to raise
         self.checkpointer = None
-        self.params = model.init(jax.random.PRNGKey(seed))
-        self.opt_state = self.optimizer.init(self.params)
+        with stage_scope("train.init"):
+            self.params = model.init(jax.random.PRNGKey(seed))
+            self.opt_state = self.optimizer.init(self.params)
         self._step_fn = None
         self._step_body = None
         self._scan_fn = None
@@ -339,12 +358,15 @@ class Trainer:
         self.global_step = 0
         self._pass_idx = 0
         self.last_metric_state = None
+        from paddlebox_tpu.utils.profiler import CompletionWatcher
+
+        self._watch = CompletionWatcher()  # thread starts at first dispatch
 
     def close(self) -> None:
-        """API parity with MultiChipTrainer.close(): the single-chip
-        trainer holds no background threads (its per-pass prefetcher is
-        closed by train_from_dataset itself), so this is a no-op —
-        TwoPhaseTrainer.close() calls it on either path."""
+        """Retire the completion watcher's thread (the per-pass prefetcher
+        is closed by train_from_dataset itself).  The trainer stays usable:
+        the next dispatch starts a new watcher thread."""
+        self._watch.close()
 
     @property
     def _check_nan(self) -> bool:
@@ -366,13 +388,18 @@ class Trainer:
             self.slot_mask, model.n_sparse_slots
         )
 
+        # named scopes are metadata on the same operations: they put a
+        # stage's name into every op of a device trace (pull / seqpool_cvm
+        # / tower / dense_opt / push / metrics) where XLA alone numbers its
+        # fusions anew with every change
         def step(params, opt_state, values, g2sum, mstate, batch):
-            rows = pull_rows(
-                values, batch["idx"],
-                create_threshold=tconf.create_threshold,
-                cvm_offset=tconf.cvm_offset,
-                pull_embedx_scale=tconf.pull_embedx_scale,
-            )
+            with jax.named_scope("pull"):
+                rows = pull_rows(
+                    values, batch["idx"],
+                    create_threshold=tconf.create_threshold,
+                    cvm_offset=tconf.cvm_offset,
+                    pull_embedx_scale=tconf.pull_embedx_scale,
+                )
             bsz = batch["labels"].shape[0]
             extra = {"rank_offset": batch["rank_offset"]} if uses_rank else {}
             if uses_seq:
@@ -387,6 +414,7 @@ class Trainer:
             else:
                 key_part = None
 
+            @jax.named_scope("tower")
             def loss_fn(p, r):
                 if key_part is not None:
                     r = r * key_part[:, None]
@@ -409,8 +437,10 @@ class Trainer:
                 loss_fn, argnums=(0, 1), has_aux=True
             )(params, rows)
 
-            updates, opt_state = optimizer.update(pgrads, opt_state, params)
-            params = optax.apply_updates(params, updates)
+            with jax.named_scope("dense_opt"):
+                updates, opt_state = optimizer.update(
+                    pgrads, opt_state, params)
+                params = optax.apply_updates(params, updates)
             key_mask = batch["key_mask"]
             key_clicks = batch["key_clicks"]
             key_extras = batch.get("key_extras")
@@ -420,14 +450,22 @@ class Trainer:
                 key_clicks = key_clicks * key_part
                 if key_extras is not None:
                     key_extras = key_extras * key_part[:, None]
-            values, g2sum = push_and_update(
-                values, g2sum, row_grads, batch["idx"], batch["uniq_idx"],
-                batch["inverse"], key_mask, key_clicks, tconf,
-                key_extras=key_extras,
-                uniq_lr=batch.get("uniq_lr"),
-            )
+            with jax.named_scope("push"):
+                values, g2sum = push_and_update(
+                    values, g2sum, row_grads, batch["idx"], batch["uniq_idx"],
+                    batch["inverse"], key_mask, key_clicks, tconf,
+                    key_extras=key_extras,
+                    uniq_lr=batch.get("uniq_lr"),
+                )
             primary = preds[:, 0] if n_tasks > 1 else preds
             mstate = dict(mstate)
+            with jax.named_scope("metrics"):
+                mstate, finite = step_metrics(
+                    mstate, batch, loss, preds, primary, pgrads, row_grads)
+            return params, opt_state, values, g2sum, mstate, loss, finite, primary
+
+        def step_metrics(mstate, batch, loss, preds, primary, pgrads,
+                         row_grads):
             mstate["auc"] = update_auc_state(
                 mstate["auc"], primary, batch["labels"], batch["ins_mask"]
             )
@@ -460,7 +498,7 @@ class Trainer:
                 finite &= jnp.isfinite(row_grads).all()
             else:
                 finite = jnp.array(True)
-            return params, opt_state, values, g2sum, mstate, loss, finite, primary
+            return mstate, finite
 
         self._step_body = step
         if check_nan and self.conf.nan_policy == "skip_batch":
@@ -662,11 +700,13 @@ class Trainer:
         """
         if self._step_fn is None:
             self._step_fn = self._build_step()
-        mstate = self._init_mstate(auc_state)
-        # grad-norm baseline: the accumulator carries across continued
-        # passes, so the per-pass value is a delta between host snapshots
-        # (materialized NOW — the first step donates the buffer)
-        gn_base = np.asarray(mstate["gn"], dtype=np.float64)
+        with stage_scope("train.init"):
+            mstate = self._init_mstate(auc_state)
+            # grad-norm baseline: the accumulator carries across continued
+            # passes, so the per-pass value is a delta between host
+            # snapshots (materialized NOW — the first step donates the
+            # buffer)
+            gn_base = np.asarray(mstate["gn"], dtype=np.float64)
         pass_t0 = time.monotonic()
         n_samples = [0.0]
         values, g2sum = table.values, table.g2sum
@@ -684,8 +724,8 @@ class Trainer:
                 self.conf.dump_fields,
             )
         from paddlebox_tpu.utils.profiler import (
+            CompletionWatcher,
             StatsProfiler,
-            StepProfiler,
             device_trace,
         )
         from paddlebox_tpu import telemetry
@@ -708,14 +748,14 @@ class Trainer:
 
             telemetry.enable_tracing(pid=_default_rank())
 
-        # full profiler under profile/tracing (serial feed, synced steps:
-        # honest splits + spans); otherwise histogram-only stage timing so
-        # every run still carries per-stage p50/p99 in its metrics
-        prof = (
-            StepProfiler()
-            if (self.conf.profile or host_trace_dir)
-            else StatsProfiler()
-        )
+        # ONE profiler, always on, and the same loop whatever is asked for:
+        # profile / the trace dirs only decide what is reported and written
+        # after the pass, from the registry's delta over it
+        prof = StatsProfiler()
+        watch = self._watch
+        want_report = bool(self.conf.profile or host_trace_dir)
+        prof_mark = prof.mark() if want_report else None
+        complete_mark = CompletionWatcher.mark() if want_report else None
 
         # distributed-liveness watchdog: stage-reported progress (feed /
         # step) with a stall deadline; single-process runs get local stall
@@ -734,16 +774,17 @@ class Trainer:
                     wd.start()
 
         # scan grouping: k steps per device dispatch (disabled while dumping
-        # per-batch fields or profiling per-step)
+        # per-batch fields: the dump needs every batch's predictions)
         scan_k = self.conf.scan_steps
-        if dumper is not None or prof.enabled:
+        if dumper is not None:
             scan_k = 1
         if scan_k > 1 and self._scan_fn is None:
             self._scan_fn = self._build_scan_step()
 
         def host_feeds():
             """(batch, host feed dict) stream: validation + host planning."""
-            for batch in dataset.batches(drop_last=drop_last):
+            for batch in prof.iterate(
+                    "batch", dataset.batches(drop_last=drop_last)):
                 if wd is not None:
                     wd.report("feed")
                 if uses_rank and batch.rank_offset is None:
@@ -810,23 +851,7 @@ class Trainer:
             for host in buf:  # ragged tail: single-step dispatches
                 yield "one", None, _to_device(host)
 
-        # profiling/tracing keep the serial path so the plan/feed/step split
-        # (and the captured timeline) stay honest; otherwise feed assembly
-        # overlaps the device step
         prefetcher = None
-        if (
-            self.conf.prefetch_batches > 0
-            and not prof.enabled
-            and not host_trace_dir
-        ):
-            # queue slots hold scan GROUPS in scan mode: shrink the depth so
-            # staged device memory stays ~prefetch_batches batches either way
-            depth = max(1, self.conf.prefetch_batches // max(scan_k, 1))
-            prefetcher = _FeedPrefetcher(feeds(), depth)
-            feed_iter = prefetcher
-        else:
-            feed_iter = feeds()
-
         check_nan = self._check_nan
         skip_batches = check_nan and self.conf.nan_policy == "skip_batch"
         try:
@@ -834,16 +859,30 @@ class Trainer:
             with telemetry.span("pass", pass_idx=self._pass_idx,
                                 global_step=self.global_step), \
                  device_trace(self.conf.trace_dir or None):
+              if self.conf.prefetch_batches > 0:
+                # feed assembly overlaps the device step.  Queue slots hold
+                # scan GROUPS in scan mode: shrink the depth so staged
+                # device memory stays ~prefetch_batches batches either way.
+                # Started inside the pass span: the producer's plan/feed
+                # spans inherit it as their parent.
+                depth = max(1, self.conf.prefetch_batches // max(scan_k, 1))
+                prefetcher = _FeedPrefetcher(feeds(), depth, prof)
+                feed_iter = prefetcher
+              else:
+                feed_iter = feeds()
               for kind, batch, dev in feed_iter:
                 # chaos site: a hang here simulates a stalled device step;
                 # the watchdog bounds it and names this process + stage
                 faults.inject("train.step")
+                t_dispatch = time.perf_counter()
                 if kind == "scan":
-                    (self.params, self.opt_state, values, g2sum, mstate,
-                     loss_k, finites) = (
-                        self._scan_fn(self.params, self.opt_state, values,
-                                      g2sum, mstate, dev)
-                    )
+                    with prof.stage("step"):
+                        (self.params, self.opt_state, values, g2sum, mstate,
+                         loss_k, finites) = (
+                            self._scan_fn(self.params, self.opt_state,
+                                          values, g2sum, mstate, dev)
+                        )
+                    watch.dispatched(loss_k, t_dispatch)
                     if wd is not None:
                         wd.report("step")
                     k = int(loss_k.shape[0])
@@ -879,11 +918,9 @@ class Trainer:
                         self._step_fn(self.params, self.opt_state, values,
                                       g2sum, mstate, dev)
                     )
-                    if prof.enabled:
-                        loss.block_until_ready()  # sync for honest timing
+                watch.dispatched(loss, t_dispatch)
                 if wd is not None:
                     wd.report("step")
-                prof.step_done()
                 # pbox-lint: ignore[host-sync-in-hot-loop] nan gate: with
                 # check_nan on, the per-step finite readback IS the
                 # feature (opt-in; default-off config pays nothing —
@@ -958,52 +995,24 @@ class Trainer:
                 table=table,
                 select=self.conf.dump_param,
             )
-        metrics = compute_metrics(mstate["auc"])
-        if self.n_tasks > 1:
-            metrics.update(
-                compute_metrics_stacked(
-                    mstate["task"], [f"task{t}" for t in range(self.n_tasks)]
-                )
-            )
-        if self.metric_group is not None:
-            metrics.update(self.metric_group.compute(mstate["group"]))
-        metrics["loss"] = (
-            float(
-                jnp.concatenate([jnp.atleast_1d(l) for l in losses]).mean()
-            )
-            if losses
-            else 0.0
-        )
+        # the device's tail: the read-back below waits for the last queued
+        # step anyway; waiting here first gives the wait its own name and
+        # leaves ``readback`` the eager metric programs alone
+        with prof.stage("drain"):
+            if losses:
+                losses[-1].block_until_ready()
+            watch.settle()
+        with stage_scope("train.readback"), prof.stage("readback"):
+            metrics = self._read_back(mstate, losses, gn_base)
         metrics["steps"] = n_steps
         # samples/s without trace files: the pass_end record carries
         # wall-clock duration and the instance count it covered
         metrics["duration_s"] = time.monotonic() - pass_t0
         metrics["samples"] = float(n_samples[0])
-        gn_now = np.asarray(mstate["gn"], dtype=np.float64)
-        d_sq, d_n = gn_now[0] - gn_base[0], gn_now[1] - gn_base[1]
-        if d_n > 0:
-            grad_norm = float(np.sqrt(d_sq / d_n)) if d_sq >= 0 else float(
-                "nan")
-            metrics["grad_norm"] = grad_norm
-            telemetry.gauge(
-                "train.grad_norm",
-                "per-pass RMS global gradient norm (dense + sparse)",
-            ).set(grad_norm)
-        wsq = sum(
-            float(jnp.sum(jnp.square(leaf.astype(jnp.float32))))
-            for leaf in jax.tree.leaves(self.params)
-        )
-        metrics["weight_norm"] = math.sqrt(wsq) if wsq >= 0 else float("nan")
-        telemetry.gauge(
-            "train.weight_norm", "dense parameter L2 norm at pass end"
-        ).set(metrics["weight_norm"])
-        if prof.enabled:
-            metrics["profile"] = prof.report()
-            stage_q = prof.quantiles()
-            if stage_q:
-                metrics["profile"]["stage_quantiles"] = stage_q
+        if want_report:
+            metrics["profile"] = prof.report(prof_mark, n_steps, complete_mark)
             if self.conf.profile:
-                print("[profile]", prof.log_line())
+                print("[profile]", prof.log_line(metrics["profile"]))
         if host_trace_dir:
             from paddlebox_tpu.telemetry.events import _default_rank
 
@@ -1026,6 +1035,48 @@ class Trainer:
         self._pass_idx += 1
         self.last_auc_state = mstate["auc"]
         self.last_metric_state = mstate
+        return metrics
+
+    def _read_back(self, mstate: dict, losses: list, gn_base) -> dict:
+        """The pass's metrics from the device's metric state: AUC streams,
+        mean loss, gradient and weight norms (eager programs, tagged
+        ``train.readback`` by the caller)."""
+        from paddlebox_tpu import telemetry
+
+        metrics = compute_metrics(mstate["auc"])
+        if self.n_tasks > 1:
+            metrics.update(
+                compute_metrics_stacked(
+                    mstate["task"], [f"task{t}" for t in range(self.n_tasks)]
+                )
+            )
+        if self.metric_group is not None:
+            metrics.update(self.metric_group.compute(mstate["group"]))
+        metrics["loss"] = (
+            float(
+                jnp.concatenate([jnp.atleast_1d(l) for l in losses]).mean()
+            )
+            if losses
+            else 0.0
+        )
+        gn_now = np.asarray(mstate["gn"], dtype=np.float64)
+        d_sq, d_n = gn_now[0] - gn_base[0], gn_now[1] - gn_base[1]
+        if d_n > 0:
+            grad_norm = float(np.sqrt(d_sq / d_n)) if d_sq >= 0 else float(
+                "nan")
+            metrics["grad_norm"] = grad_norm
+            telemetry.gauge(
+                "train.grad_norm",
+                "per-pass RMS global gradient norm (dense + sparse)",
+            ).set(grad_norm)
+        wsq = sum(
+            float(jnp.sum(jnp.square(leaf.astype(jnp.float32))))
+            for leaf in jax.tree.leaves(self.params)
+        )
+        metrics["weight_norm"] = math.sqrt(wsq) if wsq >= 0 else float("nan")
+        telemetry.gauge(
+            "train.weight_norm", "dense parameter L2 norm at pass end"
+        ).set(metrics["weight_norm"])
         return metrics
 
     # -- inference / evaluation -------------------------------------------- #

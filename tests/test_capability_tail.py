@@ -173,6 +173,11 @@ def test_profiler_report(tmp_path):
         assert prof[f"{stage}_sec"] >= 0.0
         assert f"{stage}_ms_per_step" in prof
     assert prof["step_sec"] > 0.0
+    # the pass's own delta, not the process's total; ``step`` is the
+    # enqueue and ``complete`` the device's side, one sample a dispatch
+    assert prof["step_count"] == prof["plan_count"] == m["steps"]
+    assert prof["complete_count"] == m["steps"]
+    assert prof["complete_sec"] > 0.0
     ds.close()
 
 

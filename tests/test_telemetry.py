@@ -152,30 +152,40 @@ def test_stats_reset_keeps_cached_metric_handles_registered():
 # profiler: auto-created stages + counts (satellites 1-2)
 # --------------------------------------------------------------------------- #
 def test_step_profiler_auto_creates_stages():
-    from paddlebox_tpu.utils.profiler import StepProfiler
-
-    p = StepProfiler()
-    with p.stage("brand_new_stage"):  # KeyError before this PR
-        pass
-    with p.stage("plan"):
-        pass
-    with p.stage("plan"):
-        pass
-    p.step_done()
-    r = p.report()
-    assert r["brand_new_stage_count"] == 1
-    assert r["plan_count"] == 2  # resume/pause cycles now reported
-    assert "brand_new_stage_sec" in r
-    assert "plan" in p.log_line()
-    q = p.quantiles()
-    assert q["plan"]["count"] == 2 and q["plan"]["p99_ms"] >= 0
-
-
-def test_stats_profiler_records_histograms_without_enabling():
     from paddlebox_tpu.utils.profiler import StatsProfiler
 
     p = StatsProfiler()
-    assert p.enabled is False
+    mark = p.mark()
+    with p.stage("brand_new_stage"):  # KeyError before PR 3
+        pass
+    with p.stage("plan"):
+        pass
+    with p.stage("plan"):
+        pass
+    r = p.report(mark, n_steps=1)
+    assert r["steps"] == 1
+    assert r["brand_new_stage_count"] == 1
+    assert r["plan_count"] == 2  # the pass's delta, not the process's total
+    assert "brand_new_stage_sec" in r and "plan_ms_per_step" in r
+    assert "plan" in p.log_line(r)
+    q = r["stage_quantiles"]
+    assert q["plan"]["count"] == 2 and q["plan"]["p99_ms"] >= 0
+    # a second window sees only its own stages
+    mark = p.mark()
+    with p.stage("plan"):
+        pass
+    r = p.report(mark, n_steps=1)
+    assert r["plan_count"] == 1 and "brand_new_stage_count" not in r
+
+
+def test_stats_profiler_records_histograms_without_enabling():
+    from paddlebox_tpu.utils import profiler
+
+    # one profiler, always on: there is no second, "enabled" mode to enter
+    assert not hasattr(profiler, "StepProfiler")
+    assert not hasattr(profiler, "NullProfiler")
+    p = profiler.StatsProfiler()
+    assert not hasattr(p, "enabled")
     with p.stage("plan"):
         pass
     h = telemetry.registry.get("trainer.stage_seconds")
@@ -517,10 +527,17 @@ def test_traced_training_pass_writes_nested_chrome_trace(tmp_path):
     doc = json.load(open(os.path.join(trace_dir, tf[0])))
     spans = [e for e in doc["traceEvents"] if e.get("ph") == "X"]
     names = {e["name"] for e in spans}
-    assert {"pass", "plan", "feed", "step", "dump"} <= names
+    assert {"pass", "plan", "feed", "feed_wait", "step", "dump"} <= names
     for e in spans:
-        if e["name"] in ("plan", "feed", "step", "dump"):
+        if e["name"] in ("plan", "feed", "feed_wait", "step", "dump"):
             assert e["args"]["parent"] == "pass"
+    # tracing does not change the loop: plan and feed come from the
+    # prefetch producer's thread (the pass inherited as their parent), the
+    # step and the dump from the thread that dispatches
+    tid = {n: {e["tid"] for e in spans if e["name"] == n} for n in names}
+    assert len(tid["plan"]) == 1 and tid["plan"] != tid["step"]
+    assert tid["step"] == tid["dump"] == tid["pass"]
+    assert metrics["profile"]["complete_count"] == metrics["steps"]
     # existing stats.add call-sites unmodified + per-stage distributions
     assert metrics["profile"]["stage_quantiles"]["step"]["count"] > 0
     # JSONL pass record, rank-tagged
