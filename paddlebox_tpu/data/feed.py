@@ -167,7 +167,11 @@ def build_rank_offset(
 
 
 class BatchBuilder:
-    """Packs instance index ranges of a RecordBlock into HostBatches."""
+    """Packs instance index ranges of a RecordBlock into HostBatches.
+
+    Invariant (pinned by tests/test_feed_batch_cost.py): a batch's host
+    cost is O(its own keys and instances), independent of the block's
+    size — ``build`` reads the block only at the selected rows."""
 
     def __init__(self, conf: DataFeedConfig):
         self.conf = conf
@@ -207,8 +211,11 @@ class BatchBuilder:
         b = int(ids.shape[0])
         assert b <= B
 
+        # lengths from the batch's own rows: O(b*S) offsets read, never a
+        # difference over the whole block (the class docstring's invariant)
         sel_rows = (ids[:, None] * S + np.arange(S)[None, :]).reshape(-1)
-        lens = np.diff(block.key_offsets)[sel_rows]
+        starts = block.key_offsets[sel_rows]
+        lens = block.key_offsets[sel_rows + 1] - starts
         total = int(lens.sum())
         if total > K:
             # clip overflowing tail rows (counted; raise capacity if it matters)
@@ -218,15 +225,14 @@ class BatchBuilder:
             total = int(lens.sum())
         new_off = np.zeros(sel_rows.shape[0] + 1, dtype=np.int64)
         np.cumsum(lens, out=new_off[1:])
-        starts = block.key_offsets[sel_rows]
         pos = np.arange(total, dtype=np.int64) - np.repeat(new_off[:-1], lens)
         src_idx = np.repeat(starts, lens) + pos
 
         keys = np.zeros(K, dtype=np.uint64)
         keys[:total] = block.keys[src_idx]
         segs = np.full(K, B * S, dtype=np.int32)
-        row_seg = (np.arange(b * S) // S) * S + (np.arange(b * S) % S)  # = arange(b*S)
-        segs[:total] = np.repeat(row_seg.astype(np.int32), lens)
+        # row r = ins_in_batch * S + slot is its own segment id
+        segs[:total] = np.repeat(np.arange(b * S, dtype=np.int32), lens)
 
         seq_pos = None
         if self.seq_slot_idx is not None:
