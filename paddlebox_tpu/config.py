@@ -302,7 +302,10 @@ class DataFeedConfig:
     # positions for it (padding = key capacity).  The slot still
     # participates in normal pooled features.  The reference has no
     # long-sequence path (SURVEY §5.7); this feeds the beyond-parity
-    # sequence-parallel tower (models/longseq_ctr.py).
+    # sequence-parallel tower (models/longseq_ctr.py) and, as a token
+    # stream, the decoder (models/decoder_lm.py: a model that declares
+    # ``vocab_keys`` also gets each occurrence's class, data/feed.py
+    # key_classes -- no config key: the vocabulary is the model's).
     sequence_slot: str = ""
     max_seq_len: int = 64
 

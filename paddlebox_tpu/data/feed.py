@@ -103,6 +103,43 @@ def empty_like(batch: HostBatch) -> HostBatch:
     )
 
 
+def key_classes(
+    keys: np.ndarray,  # uint64 [K] padded key buffer of one batch
+    n_keys: int,  # its real keys
+    vocab_keys: np.ndarray,  # sorted distinct uint64: the model's classes
+    inverse: Optional[np.ndarray] = None,  # BatchPlan.inverse
+) -> np.ndarray:
+    """int32 [K]: each key occurrence's class -- its key's rank in the
+    model's fixed vocabulary (``model.vocab_keys``: a tokenizer's vocabulary
+    does not grow) -- padding -1.  The device never sees 64-bit keys: a
+    loss over the vocabulary takes its targets from here, as it takes the
+    order from ``seq_pos``.  A key outside the vocabulary is an error,
+    never a silent class.
+
+    With the plan's ``inverse`` (occurrence -> distinct slot) the search
+    runs once per distinct key and is expanded by it; without (a sharded
+    plan has no such map) once per occurrence."""
+    out = np.full(keys.shape[0], -1, dtype=np.int32)
+    if not n_keys:
+        return out
+    real = keys[:n_keys]
+    if inverse is not None:
+        inv = inverse[:n_keys]
+        distinct = np.empty(int(inv.max()) + 1, dtype=np.uint64)
+        distinct[inv] = real  # every occurrence of a slot writes its key
+    else:
+        inv, distinct = slice(None), real
+    rank = np.searchsorted(vocab_keys, distinct)
+    hit = vocab_keys[np.minimum(rank, vocab_keys.shape[0] - 1)] == distinct
+    if not hit.all():
+        raise ValueError(
+            f"{int((~hit).sum())} keys of the batch are outside the model's "
+            f"vocabulary of {vocab_keys.shape[0]} (first: "
+            f"{int(distinct[~hit][0])})")
+    out[:n_keys] = rank[inv]
+    return out
+
+
 def build_rank_offset(
     block: RecordBlock,
     ids: np.ndarray,

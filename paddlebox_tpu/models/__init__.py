@@ -2,6 +2,7 @@
 
 from paddlebox_tpu.models.ctr_dnn import CtrDnn
 from paddlebox_tpu.models.dcn import DCN
+from paddlebox_tpu.models.decoder_lm import DecoderMoeLM
 from paddlebox_tpu.models.deepfm import DeepFM
 from paddlebox_tpu.models.layers import bce_with_logits, init_mlp, linear, mlp
 from paddlebox_tpu.models.longseq_ctr import LongSeqCtrDnn
@@ -15,6 +16,7 @@ from paddlebox_tpu.models.xdeepfm import XDeepFM
 __all__ = [
     "CtrDnn",
     "DCN",
+    "DecoderMoeLM",
     "DeepFM",
     "LongSeqCtrDnn",
     "MMoE",

@@ -1,0 +1,294 @@
+"""A decoder language model as a pass-loop training job.
+
+A language model is a pass loop like every other model here: the token
+ids are the feasigns of ONE sparse slot, the input embedding IS the sparse
+table (rows ``[show, click, hidden floats]`` + ``g2sum``: sparse adagrad
+and the show/click counters as for every model), the decoder, the final
+norm and the output head are the dense tower under the trainer's dense
+optimizer.  ``BoxPSDataset`` -> ``table.begin_pass(census)`` ->
+``Trainer.train_from_dataset`` -> ``end_pass`` run it unchanged.
+
+What it asks of the system beyond a CTR tower, each read from the model
+object (no flag, no config key):
+
+  * ``loss(params, rows, batch)`` -- the model half of the step.  The
+    trainers take it where a model defines it (train/step_loss.py) in
+    place of ``apply`` -> sigmoid cross-entropy: here a softmax
+    cross-entropy at every position against the NEXT token's class.
+  * ``vocab_keys`` -- the tokenizer's vocabulary as sorted feasigns, fixed
+    at construction.  The feed then ships ``key_class`` [K], each
+    occurrence's rank in it (data/feed.py ``key_classes``): the device
+    never sees 64-bit keys, and the head's classes are those ranks.
+  * ``uses_seq_pos`` -- the slot's keys in file order (``seq_pos`` [B, T]).
+  * ``step_counters`` -- named sums that ride the donated metric state and
+    are published as telemetry counters at the pass's read-back.
+
+The layer, for x [B, T, H] (no biases; ``n`` = RMSNorm, eps 1e-6, learned
+scale):
+
+    x += o(attn(rope(q(n1 x)), rope(k(n1 x)), v(n1 x); mask_l))
+    x += moe(n2 x)
+
+``layer_types[l]`` is ``"sliding_attention"`` (causal, a window of
+``window`` keys, plain rotary code) or ``"full_attention"`` (causal, YaRN
+rotary code).  Grouped queries: ``n_heads`` query heads over
+``n_kv_heads`` key-value heads (parallel/sequence.py, blockwise: no [T, T]
+tensor).  ``moe``: a router over all ``n_experts``, the ``n_experts_per_
+tok`` best renormalised, SwiGLU experts of width ``expert_width``, of which
+this share holds ``experts_held = (lo, hi)`` and computes their part of the
+sum (parallel/expert.py ``routed_experts``: drop-free, what absent experts
+would add is left out).  The head scores the ``len(vocab_keys)`` classes
+held here; the loss is the mean over positions t < T-1 with a next token of
+the softmax cross-entropy against that token's class, in token chunks so
+the [tokens, classes] logits are never whole in memory.  Each layer is
+rematerialised in the backward pass (``jax.checkpoint``, one per layer).
+"""
+
+from __future__ import annotations
+
+from typing import Optional, Sequence
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+
+from paddlebox_tpu.parallel.expert import route_tokens, routed_experts
+from paddlebox_tpu.parallel.sequence import (
+    apply_rotary,
+    full_attention,
+    rotary_tables,
+)
+
+SLIDING, FULL = "sliding_attention", "full_attention"
+
+
+def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
+    return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+@jax.custom_vjp
+def _take_once(rows_pad, pos):
+    """``rows_pad[pos]`` where no position but the padding row (the last)
+    occurs twice in ``pos``: the cotangent is gathered back through the
+    inverse map, where autodiff of ``take`` would scatter-add wide rows."""
+    return jnp.take(rows_pad, pos, axis=0)
+
+
+def _take_once_bwd(res, g):
+    pos, n_rows = res
+    flat = g.reshape(-1, g.shape[-1])
+    n = flat.shape[0]
+    # inverse map: row -> which entry of ``pos`` read it (n = none)
+    inv = jnp.full((n_rows,), n, jnp.int32).at[pos.reshape(-1)].set(
+        jnp.arange(n, dtype=jnp.int32))
+    inv = inv.at[n_rows - 1].set(n)  # the padding row takes no gradient
+    flat = jnp.concatenate([flat, jnp.zeros((1, flat.shape[1]), g.dtype)])
+    return jnp.take(flat, inv, axis=0), None
+
+
+_take_once.defvjp(
+    lambda rows_pad, pos: (jnp.take(rows_pad, pos, axis=0),
+                           (pos, rows_pad.shape[0])),
+    _take_once_bwd)
+
+
+class DecoderMoeLM:
+    """Decoder with window and full attention layers and token-routed
+    experts, trained on next-token prediction through the pass loop."""
+
+    uses_seq_pos = True
+    n_sparse_slots = 1
+    # sums over a pass's steps (and layers), published at the read-back:
+    # positions with a target; token-expert pairs computed here and
+    # n_experts_per_tok x tokens; the largest held expert's tokens beside
+    # the held experts' mean
+    step_counters = ("trainer.tokens", "moe.pairs_local", "moe.pairs_routed",
+                     "moe.expert_load_max", "moe.expert_load_mean")
+
+    def __init__(
+        self,
+        emb_width: int,  # pulled row width (cvm_offset + hidden)
+        vocab_keys,  # sorted uint64 feasigns: the classes of the head
+        max_seq_len: int,
+        n_heads: int,
+        n_kv_heads: int,
+        head_dim: int,
+        layer_types: Sequence[str],
+        window: int,
+        n_experts: int,
+        n_experts_per_tok: int,
+        expert_width: int,
+        experts_held: Optional[tuple] = None,  # (lo, hi); None = all
+        rope_theta: float = 10000.0,
+        yarn: Optional[dict] = None,  # rotary_tables' keys, full layers
+        rms_eps: float = 1e-6,
+        cvm_offset: int = 2,
+        block_q: int = 256,
+        loss_chunk: int = 2048,
+    ):
+        vocab_keys = np.asarray(vocab_keys, dtype=np.uint64)
+        if vocab_keys.ndim != 1 or not np.all(vocab_keys[1:] > vocab_keys[:-1]):
+            raise ValueError("vocab_keys must be sorted, distinct feasigns")
+        bad = [t for t in layer_types if t not in (SLIDING, FULL)]
+        if bad:
+            raise ValueError(f"unknown layer types {sorted(set(bad))}")
+        if n_heads % n_kv_heads:
+            raise ValueError(
+                f"{n_heads} query heads over {n_kv_heads} key-value heads")
+        lo, hi = experts_held or (0, n_experts)
+        if not 0 <= lo < hi <= n_experts:
+            raise ValueError(f"experts_held {(lo, hi)} of {n_experts}")
+        self.vocab_keys = vocab_keys
+        self.n_classes = int(vocab_keys.shape[0])
+        self.hidden = emb_width - cvm_offset
+        if self.hidden <= 0:
+            raise ValueError("emb_width leaves no embedding columns")
+        self.emb_width, self.cvm_offset = emb_width, cvm_offset
+        self.max_seq_len = max_seq_len
+        self.n_heads, self.n_kv_heads = n_heads, n_kv_heads
+        self.head_dim = head_dim
+        self.layer_types = tuple(layer_types)
+        self.window = window
+        self.n_experts, self.top_k = n_experts, n_experts_per_tok
+        self.expert_width = expert_width
+        self.experts_held = (lo, hi)
+        self.rope_theta, self.yarn = rope_theta, yarn
+        self.rms_eps = rms_eps
+        self.block_q, self.loss_chunk = block_q, loss_chunk
+
+    # -- params ------------------------------------------------------------ #
+    def init(self, key: jax.Array) -> dict:
+        """Normal weights scaled by 1/sqrt(fan-in), norm scales 1."""
+        H, F = self.hidden, self.expert_width
+        hq, hkv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
+        held = self.experts_held[1] - self.experts_held[0]
+
+        def w(k, *shape, fan_in):
+            return jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)
+
+        layers = []
+        for lk in jax.random.split(key, len(self.layer_types) + 1)[1:]:
+            ks = jax.random.split(lk, 8)
+            layers.append({
+                "n1": jnp.ones((H,), jnp.float32),
+                "n2": jnp.ones((H,), jnp.float32),
+                "wq": w(ks[0], H, hq, fan_in=H),
+                "wk": w(ks[1], H, hkv, fan_in=H),
+                "wv": w(ks[2], H, hkv, fan_in=H),
+                "wo": w(ks[3], hq, H, fan_in=hq),
+                "router": w(ks[4], H, self.n_experts, fan_in=H),
+                "w_gate": w(ks[5], held, H, F, fan_in=H),
+                "w_up": w(ks[6], held, H, F, fan_in=H),
+                "w_down": w(ks[7], held, F, H, fan_in=F),
+            })
+        return {
+            "layers": layers,
+            "norm_f": jnp.ones((H,), jnp.float32),
+            "head": w(jax.random.split(key)[0], self.n_classes, H, fan_in=H),
+        }
+
+    # -- forward ----------------------------------------------------------- #
+    def _layer(self, lp: dict, x: jax.Array, valid: jax.Array, kind: str):
+        """One decoder layer; ``valid`` [B, T] marks the positions that
+        hold a token (the others are routed to no expert).  Returns (x,
+        [pairs held here, largest held expert's tokens])."""
+        B, T, H = x.shape
+        sliding = kind == SLIDING
+        with jax.named_scope("attn_window" if sliding else "attn_full"):
+            h = rms_norm(x, lp["n1"], self.rms_eps)
+            shape = (B, T, -1, self.head_dim)
+            q = (h @ lp["wq"]).reshape(shape)
+            k = (h @ lp["wk"]).reshape(shape)
+            v = (h @ lp["wv"]).reshape(shape)
+            cos, sin = rotary_tables(
+                jnp.arange(T), self.head_dim, self.rope_theta,
+                None if sliding else self.yarn)
+            a = full_attention(
+                apply_rotary(q, cos, sin), apply_rotary(k, cos, sin), v,
+                causal=True, window=self.window if sliding else None,
+                block_q=self.block_q)
+            x = x + a.reshape(B, T, -1) @ lp["wo"]
+        h = rms_norm(x, lp["n2"], self.rms_eps).reshape(B * T, H)
+        with jax.named_scope("router"):
+            top_w, top_e = route_tokens(h, lp["router"], self.top_k)
+            top_e = jnp.where(valid.reshape(-1, 1), top_e, -1)
+        with jax.named_scope("experts"):
+            y, load = routed_experts(
+                h, top_w, top_e, lp["w_gate"], lp["w_up"], lp["w_down"],
+                self.experts_held[0])
+        return x + y.reshape(B, T, H), jnp.stack(
+            [load.sum(), load.max()]).astype(jnp.float32)
+
+    def _token_losses(self, params: dict, x: jax.Array, target: jax.Array):
+        """Softmax cross-entropy of every token against ``target`` (class
+        ids; anything where there is none), [N], in chunks of
+        ``loss_chunk`` tokens: one chunk's [chunk, classes] logits are all
+        that is held, forward and (rematerialised) backward."""
+        N, H = x.shape
+        chunk = min(self.loss_chunk, N)
+        pad = -N % chunk
+        x = jnp.pad(x, ((0, pad), (0, 0))).reshape(-1, chunk, H)
+        target = jnp.pad(target, (0, pad)).reshape(-1, chunk)
+
+        @jax.checkpoint
+        def one(args):
+            xc, tc = args
+            xc = rms_norm(xc, params["norm_f"], self.rms_eps)
+            logits = jnp.dot(xc, params["head"].T,
+                             preferred_element_type=jnp.float32)
+            picked = jnp.take_along_axis(
+                logits, jnp.clip(tc, 0, self.n_classes - 1)[:, None], axis=1)
+            return jax.nn.logsumexp(logits, axis=-1) - picked[:, 0]
+
+        return jax.lax.map(one, (x, target)).reshape(-1)[:N]
+
+    def loss(self, params: dict, rows: jax.Array, batch: dict):
+        """The model half of the training step (train/step_loss.py):
+        ``rows`` [K, emb_width] the pulled occurrence rows, ``batch`` the
+        step's device feed (``seq_pos`` [B, T], ``key_class`` [K]).
+
+        Returns (loss, preds [B], counts): the mean next-token
+        cross-entropy over the positions that have a next token; a number
+        in (0, 1] a sequence -- exp(-its mean loss), the geometric mean of
+        the probability it gave its next tokens -- so that the trainers'
+        AUC and metric state keep their shapes (the AUC of such numbers
+        against ``click`` means nothing; the loss is the metric); and
+        ``step_counters``' values for this step."""
+        seq_pos = batch["seq_pos"]
+        B, T = seq_pos.shape
+        K = rows.shape[0]
+        if T != self.max_seq_len:
+            raise ValueError(
+                f"seq_pos width {T} != model max_seq_len {self.max_seq_len}")
+        with jax.named_scope("embed"):
+            # padding positions (== K) read the appended zero row
+            rows_pad = jnp.concatenate(
+                [rows, jnp.zeros((1, rows.shape[1]), rows.dtype)])
+            x = _take_once(rows_pad, seq_pos)[..., self.cvm_offset:]
+            cls = jnp.take(
+                jnp.concatenate([batch["key_class"],
+                                 jnp.full((1,), -1, jnp.int32)]), seq_pos)
+        # position t is scored against the class at t + 1
+        target = jnp.concatenate(
+            [cls[:, 1:], jnp.full((B, 1), -1, jnp.int32)], axis=1)
+        scored = (target >= 0).astype(jnp.float32)
+        valid = seq_pos < K
+        moe = jnp.zeros((2,), jnp.float32)
+        for lp, kind in zip(params["layers"], self.layer_types):
+            x, m = jax.checkpoint(self._layer, static_argnums=(3,))(
+                lp, x, valid, kind)
+            moe = moe + m
+        with jax.named_scope("lm_head"):
+            ce = self._token_losses(
+                params, x.reshape(B * T, -1), target.reshape(-1))
+            ce = ce.reshape(B, T) * scored
+        n_scored = scored.sum()
+        loss = ce.sum() / jnp.maximum(n_scored, 1.0)
+        preds = jnp.exp(-ce.sum(axis=1) / jnp.maximum(scored.sum(axis=1), 1.0))
+        held = self.experts_held[1] - self.experts_held[0]
+        n_tokens = valid.sum().astype(jnp.float32)
+        counts = jnp.stack([
+            n_scored, moe[0],
+            n_tokens * self.top_k * len(self.layer_types),
+            moe[1], moe[0] / held])
+        return loss, preds, counts

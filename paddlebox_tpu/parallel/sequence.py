@@ -22,6 +22,14 @@ chip's memory.  Two TPU-native strategies over one ``seq`` mesh axis:
 
 Both are pure shard_map bodies (jit + autodiff through scan/ppermute/
 all_to_all work out of the box) and reduce to plain attention at P=1.
+
+On one device ``full_attention`` is that plain attention.  Its mask is a
+description -- ``causal``, and with it a ``window`` of keys -- and queries
+may be grouped over fewer key-value heads; asked for a window, a query
+block or grouped queries it runs blockwise (no [T, T] tensor, blocks
+outside the mask never computed).  ``rotary_tables`` / ``apply_rotary``
+are the rotary position code, plain and YaRN.  The ring and Ulysses forms
+take ``causal`` only: a window on them is not written yet.
 """
 
 from __future__ import annotations
@@ -36,15 +44,31 @@ SEQ_AXIS = "seq"
 
 def full_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
-    key_valid: Optional[jax.Array] = None,
+    key_valid: Optional[jax.Array] = None, window: Optional[int] = None,
+    block_q: Optional[int] = None,
 ) -> jax.Array:
-    """Plain softmax attention (the single-device reference semantics).
+    """Softmax attention on one device.
 
-    q/k/v: [B, T, H, D]; returns [B, T, H, D].
-    key_valid: optional bool [B, Tk] — padded key positions read zero
-    attention weight (variable-length sequences); a query whose keys are
-    ALL masked reads a zero vector, not NaN.
+    q: [B, T, H, D]; k/v: [B, T, Hkv, D]; returns [B, T, H, D].
+
+    The mask is described, never passed: ``causal`` (key j <= query i) and
+    ``window`` (with causal: i - j < window, a query sees itself and the
+    window - 1 keys before it).  Given ``window``, ``block_q`` or fewer
+    key-value heads than query heads (grouped queries: query head h reads
+    key-value head h // (H / Hkv); K and V are never repeated in memory),
+    the blockwise form runs: the queries in blocks of ``block_q``, no
+    [T, T] tensor, blocks wholly outside the mask never computed.
+
+    key_valid: optional bool [B, Tk] (dense form only) — padded key
+    positions read zero attention weight (variable-length sequences); a
+    query whose keys are ALL masked reads a zero vector, not NaN.
     """
+    if (window is not None or block_q is not None
+            or q.shape[2] != k.shape[2]):
+        if key_valid is not None:
+            raise ValueError("the blockwise form takes no key_valid mask")
+        return _blockwise_attention(
+            q, k, v, causal, window, block_q or DEFAULT_BLOCK_Q)
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(float(d))
     if causal:
@@ -59,6 +83,116 @@ def full_attention(
     w = jnp.exp(s - jnp.where(jnp.isneginf(m), 0.0, m))
     p = w / jnp.maximum(w.sum(axis=-1, keepdims=True), 1e-30)
     return jnp.einsum("bhqk,bkhd->bqhd", p, v)
+
+
+DEFAULT_BLOCK_Q = 256
+_KEY_ALIGN = 128  # a strip's first key sits on a lane-tile boundary
+
+
+def _visible_keys(q0: int, q1: int, t: int, causal: bool,
+                  window: Optional[int]) -> tuple:
+    """[k0, k1): the keys any query of [q0, q1) may see."""
+    k1 = q1 if causal else t
+    k0 = max(0, q0 - window + 1) if window is not None else 0
+    return (k0 // _KEY_ALIGN) * _KEY_ALIGN, k1
+
+
+def _strip_attention(qb, ks, vs, q0: int, k0: int, causal: bool,
+                     window: Optional[int]):
+    """One block of queries against the keys it may see.  qb: [B, bq, Hkv,
+    G, D] (query heads grouped over their key-value head); ks/vs: [B, S,
+    Hkv, D].  The scores [B, Hkv, G, bq, S] are the only score tensor."""
+    d = qb.shape[-1]
+    s = jnp.einsum("bqhgd,bkhd->bhgqk", qb, ks,
+                   preferred_element_type=jnp.float32) / jnp.sqrt(float(d))
+    if causal:
+        qi = q0 + jnp.arange(qb.shape[1], dtype=jnp.int32)[:, None]
+        kj = k0 + jnp.arange(ks.shape[1], dtype=jnp.int32)[None, :]
+        mask = kj <= qi
+        if window is not None:
+            mask &= qi - kj < window
+        s = jnp.where(mask, s, -jnp.inf)
+    # causal: every query sees itself, so no row is all -inf
+    p = jax.nn.softmax(s, axis=-1)
+    return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(vs.dtype), vs,
+                      preferred_element_type=jnp.float32).astype(qb.dtype)
+
+
+def _blockwise_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, causal: bool,
+    window: Optional[int], block_q: int,
+) -> jax.Array:
+    """``full_attention``'s blockwise form: the queries in blocks of
+    ``block_q`` (the last may be shorter), each against the contiguous
+    strip of keys its mask allows -- for a window a strip of at most
+    ``window + block_q`` keys, for plain causal the keys up to the block's
+    end -- so keys wholly outside the mask cost nothing, and one strip of
+    scores is all that is ever held.  Each strip is rematerialised in the
+    backward pass (``jax.checkpoint``): what a layer keeps of its attention
+    is q, k, v and the output, never a probability.  The mask is static,
+    so the strips are unrolled: no loop carries, no dynamic slices."""
+    if window is not None and not causal:
+        raise ValueError("a window is defined on causal attention only")
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    if h % hkv:
+        raise ValueError(f"{h} query heads over {hkv} key-value heads")
+    qg = q.reshape(b, t, hkv, h // hkv, d)
+    out = []
+    for q0 in range(0, t, block_q):
+        q1 = min(q0 + block_q, t)
+        k0, k1 = _visible_keys(q0, q1, k.shape[1], causal, window)
+        strip = jax.checkpoint(
+            lambda qb, ks, vs, q0=q0, k0=k0: _strip_attention(
+                qb, ks, vs, q0, k0, causal, window))
+        out.append(strip(qg[:, q0:q1], k[:, k0:k1], v[:, k0:k1]))
+    return jnp.concatenate(out, axis=1).reshape(b, t, h, d)
+
+
+def rotary_tables(positions: jax.Array, head_dim: int, theta: float,
+                  yarn: Optional[dict] = None) -> tuple:
+    """(cos, sin), each [T, head_dim], of the rotary position code on the
+    whole head dimension: angle(t, i) = t * inv_freq[i], i < head_dim / 2,
+    laid out twice (the rotate-half pairing of dimension i with i +
+    head_dim / 2).  Plain: inv_freq[i] = theta ** (-2i / head_dim).
+
+    ``yarn`` (keys ``factor``, ``original_max_position_embeddings``,
+    ``beta_fast``, ``beta_slow``, ``attention_factor``) blends, per
+    dimension, the plain frequency with the same divided by ``factor``:
+    dimensions that turn more than ``beta_fast`` times within the original
+    length keep the plain one, those that turn less than ``beta_slow``
+    times take the divided one, a linear ramp between the two correction
+    dimensions (floor / ceil, clipped to [0, head_dim - 1]) in between; cos
+    and sin are scaled by ``attention_factor``."""
+    half = head_dim // 2
+    inv = theta ** (-jnp.arange(half, dtype=jnp.float32) * 2.0 / head_dim)
+    scale = 1.0
+    if yarn is not None:
+        import math
+
+        def correction_dim(turns: float) -> float:
+            return head_dim * math.log(
+                yarn["original_max_position_embeddings"]
+                / (turns * 2.0 * math.pi)) / (2.0 * math.log(theta))
+
+        low = max(math.floor(correction_dim(yarn["beta_fast"])), 0)
+        high = min(math.ceil(correction_dim(yarn["beta_slow"])), head_dim - 1)
+        if low == high:
+            high += 0.001
+        ramp = jnp.clip(
+            (jnp.arange(half, dtype=jnp.float32) - low) / (high - low), 0, 1)
+        inv = inv / yarn["factor"] * ramp + inv * (1.0 - ramp)
+        scale = float(yarn["attention_factor"])
+    ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
+    ang = jnp.concatenate([ang, ang], axis=-1)
+    return jnp.cos(ang) * scale, jnp.sin(ang) * scale
+
+
+def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
+    """x [B, T, H, D] turned by the tables of ``rotary_tables`` ([T, D])."""
+    half = x.shape[-1] // 2
+    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+    return x * cos[None, :, None, :] + turned * sin[None, :, None, :]
 
 
 def ring_attention(

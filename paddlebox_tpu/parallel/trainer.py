@@ -42,7 +42,7 @@ import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from paddlebox_tpu.config import SparseTableConfig, TrainerConfig
-from paddlebox_tpu.data.feed import HostBatch, empty_like
+from paddlebox_tpu.data.feed import HostBatch, empty_like, key_classes
 from paddlebox_tpu.metrics.auc import (
     AucState,
     compute_metrics,
@@ -52,7 +52,6 @@ from paddlebox_tpu.metrics.auc import (
     update_auc_state,
 )
 from paddlebox_tpu.metrics.variants import MetricGroup
-from paddlebox_tpu.models.layers import bce_with_logits
 from paddlebox_tpu.parallel.mesh import DATA_AXIS
 from paddlebox_tpu.parallel.multiprocess import (
     global_from_local,
@@ -70,6 +69,12 @@ from paddlebox_tpu.train.slot_policy import (
     normalize_slot_mask,
     resolve_slot_lr_vec,
     slot_participation_vec,
+)
+from paddlebox_tpu.train.step_loss import (
+    add_counts,
+    counter_names,
+    make_model_loss,
+    publish_counters,
 )
 
 
@@ -90,8 +95,11 @@ def _stack_group(
     plan: ShardedBatchPlan,
     n_slots: int,
     metric_group: Optional[MetricGroup] = None,
+    vocab_keys: Optional[np.ndarray] = None,
 ) -> dict:
-    """Stack per-device batches + plan into [D, ...] arrays (numpy)."""
+    """Stack per-device batches + plan into [D, ...] arrays (numpy).
+    ``vocab_keys``: the model's fixed vocabulary; the feed then carries
+    each occurrence's class (data/feed.py key_classes)."""
     key_clicks = []
     for b, m in zip(batches, plan.key_mask):
         ins = np.minimum(b.key_segments // n_slots, b.batch_size - 1)
@@ -103,6 +111,9 @@ def _stack_group(
         extra["seq_pos"] = np.stack([b.seq_pos for b in batches])
     if batches[0].task_labels is not None:
         extra["task_labels"] = np.stack([b.task_labels for b in batches])
+    if vocab_keys is not None:
+        extra["key_class"] = np.stack(
+            [key_classes(b.keys, b.n_keys, vocab_keys) for b in batches])
     if metric_group is not None:
         extra["metric_masks"] = np.stack(
             [metric_group.masks(b) for b in batches]
@@ -406,13 +417,16 @@ class MultiChipTrainer:
         async_dense = conf.sync_dense_mode == "async"
         dump_preds = bool(conf.need_dump_field and conf.dump_fields_path)
         check_nan = conf.check_nan_inf
-        uses_rank = getattr(model, "uses_rank_offset", False)
-        uses_seq = getattr(model, "uses_seq_pos", False)
         n_tasks = self.n_tasks
         has_group = self.metric_group is not None
         part_vec = slot_participation_vec(
             self.slot_mask, model.n_sparse_slots
         )
+        # the model half of the step, the single-chip trainer's (the
+        # model's own ``loss``, else apply -> sigmoid cross-entropy); with
+        # psummed gradients the mean runs over the whole axis
+        model_loss = make_model_loss(
+            model, n_tasks, mean_axis=DATA_AXIS if sync_step else None)
 
         def body(params, opt_state, values, g2sum, mstate, batch,
                  hot_values=None, hot_g2sum=None):
@@ -440,10 +454,6 @@ class MultiChipTrainer:
                         values, batch["serve_rows"], batch["occ_flat"],
                         tconf.create_threshold, tconf.cvm_offset,
                     )
-            bsz = batch["labels"].shape[0]
-            extra = {"rank_offset": batch["rank_offset"]} if uses_rank else {}
-            if uses_seq:
-                extra["seq_pos"] = batch["seq_pos"]
             if part_vec is not None:
                 # occurrence-level participation (seg = ins*S + slot):
                 # gating inside loss_fn zeroes excluded slots' pooled
@@ -457,25 +467,9 @@ class MultiChipTrainer:
             def loss_fn(p, r):
                 if key_part is not None:
                     r = r * key_part[:, None]
-                logits = model.apply(
-                    p, r, batch["key_segments"], batch["dense"], bsz, **extra
-                )
-                mask = batch["ins_mask"]
-                if n_tasks > 1:
-                    per_ins = (
-                        bce_with_logits(logits, batch["task_labels"]).mean(axis=1)
-                        * mask
-                    )
-                else:
-                    per_ins = bce_with_logits(logits, batch["labels"]) * mask
-                local_cnt = mask.sum()
-                if sync_step:
-                    denom = jnp.maximum(jax.lax.psum(local_cnt, DATA_AXIS), 1.0)
-                else:
-                    denom = jnp.maximum(local_cnt, 1.0)
-                return per_ins.sum() / denom, jax.nn.sigmoid(logits)
+                return model_loss(p, r, batch)
 
-            (loss, preds), (pgrads, row_grads) = jax.value_and_grad(
+            (loss, (preds, counts)), (pgrads, row_grads) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(params, rows)
             with jax.named_scope("dense_opt"):
@@ -506,7 +500,7 @@ class MultiChipTrainer:
                         batch["hot_lr"], key_mask, key_clicks, tconf,
                     )
             primary = preds[:, 0] if n_tasks > 1 else preds
-            mstate = dict(mstate)
+            mstate = add_counts(dict(mstate), counts)
             with jax.named_scope("metrics"):
                 mstate, finite = step_metrics(
                     mstate, batch, loss, preds, primary, pgrads, row_grads)
@@ -687,6 +681,7 @@ class MultiChipTrainer:
     def _init_mstate(self, auc_state=None) -> dict:
         """Per-device metric streams, each leaf stacked [n_dev, ...] and
         mesh-sharded (merged by summing over devices at read time)."""
+        n_counters = len(counter_names(self.model))
         if isinstance(auc_state, dict):
             # the step donates mstate: copy so the caller's reference (often
             # trainer.last_metric_state itself) is not invalidated by the
@@ -694,6 +689,9 @@ class MultiChipTrainer:
             out = self._copy_state(auc_state)
             if "gn" not in out:
                 out["gn"] = self._stack_local(jnp.zeros((2,), jnp.float32))
+            if n_counters and "counters" not in out:
+                out["counters"] = self._stack_local(
+                    jnp.zeros((n_counters,), jnp.float32))
             return out
         if auc_state is not None and (self.n_tasks > 1 or self.metric_group):
             raise ValueError(
@@ -707,6 +705,10 @@ class MultiChipTrainer:
             else self.init_auc(),
             "gn": self._stack_local(jnp.zeros((2,), jnp.float32)),
         }
+        if n_counters:
+            # the model's per-step sums (step_loss.counter_names)
+            mstate["counters"] = self._stack_local(
+                jnp.zeros((n_counters,), jnp.float32))
         if self.n_tasks > 1:
             base = stack_auc_states(
                 init_auc_state(self.conf.auc_buckets), self.n_tasks
@@ -793,6 +795,10 @@ class MultiChipTrainer:
             gn_base = np.asarray(
                 merge_device_axis(mstate["gn"]), dtype=np.float64
             )
+            counters_base = np.asarray(
+                merge_device_axis(mstate["counters"]), dtype=np.float64
+            ) if "counters" in mstate else None
+        vocab_keys = getattr(self.model, "vocab_keys", None)
         pass_t0 = time.monotonic()
         values, g2sum = table.values, table.g2sum
         hot_values = hot_g2sum = None
@@ -910,7 +916,8 @@ class MultiChipTrainer:
                     )
                 with sprof.stage("feed"):
                     feed = _stack_group(
-                        group, plan, n_slots, self.metric_group
+                        group, plan, n_slots, self.metric_group,
+                        vocab_keys=vocab_keys,
                     )
                 yield (
                     global_from_local(self._sharding, feed),
@@ -1052,6 +1059,12 @@ class MultiChipTrainer:
         with stage_scope("train.readback"), sprof.stage("readback"):
             metrics = self._read_back(mstate, losses, counts, gn_base,
                                       multiproc)
+            if counters_base is not None:
+                metrics.update(publish_counters(
+                    self.model,
+                    np.asarray(merge_device_axis(mstate["counters"]),
+                               dtype=np.float64),
+                    counters_base))
         metrics["steps"] = n_steps
         metrics["duration_s"] = time.monotonic() - pass_t0
         metrics["missing_keys"] = table.missing_key_count

@@ -801,7 +801,15 @@ class SparseTable:
         with _PASS.stage("take_stage"):
             staged = self._take_stage(pk, cap)
         vals = staged
-        if vals is None:
+        # every census key resident in the row cache and nothing staged:
+        # the host has no row to supply, so the pass buffer starts as
+        # zeros ON the device -- no [cap, W+1] host buffer allocated and
+        # uploaded only to be overwritten by the cache's fill (at rows
+        # 2,307 floats wide that buffer is 302 MB a boundary)
+        hit_mask = (cache.lookup(pk).hit_mask
+                    if vals is None and cache is not None else None)
+        all_hit = hit_mask is not None and bool(hit_mask.all())
+        if vals is None and not all_hit:
             with _PASS.stage("alloc"):
                 vals = np.zeros((cap, w + 1), dtype=np.float32)
             if cache is None:
@@ -809,7 +817,7 @@ class SparseTable:
                     vals[:n] = self._resolve_or_init(pk)
             else:
                 try:
-                    miss_pos = np.nonzero(~cache.lookup(pk).hit_mask)[0]
+                    miss_pos = np.nonzero(~hit_mask)[0]
                     if miss_pos.shape[0]:
                         with _PASS.stage("fetch"):
                             vals[miss_pos] = self._cache_fetch_rows(
@@ -827,8 +835,12 @@ class SparseTable:
                     with _PASS.stage("fetch"):
                         vals[:n] = self._resolve_or_init(pk)
         plan = None
-        with _PASS.stage("upload"):
-            v = jnp.asarray(vals)
+        if all_hit:
+            with _PASS.stage("alloc"):  # on the device: nothing to upload
+                v = jnp.zeros((cap, w + 1), jnp.float32)
+        else:
+            with _PASS.stage("upload"):
+                v = jnp.asarray(vals)
         if cache is not None:
             # staged path included: current-miss positions carry staged
             # rows (+ write-back patches — evictions always write back),

@@ -26,7 +26,7 @@ import numpy as np
 import optax
 
 from paddlebox_tpu.config import SparseTableConfig, TrainerConfig
-from paddlebox_tpu.data.feed import HostBatch
+from paddlebox_tpu.data.feed import HostBatch, key_classes
 from paddlebox_tpu.metrics.auc import (
     AucState,
     compute_metrics,
@@ -36,7 +36,6 @@ from paddlebox_tpu.metrics.auc import (
     update_auc_state,
 )
 from paddlebox_tpu.metrics.variants import MetricGroup
-from paddlebox_tpu.models.layers import bce_with_logits
 from paddlebox_tpu.sparse.table import SparseTable, pull_rows, push_and_update
 from paddlebox_tpu.telemetry.compiles import counted_jit, stage_scope
 from paddlebox_tpu.utils import faults
@@ -87,6 +86,12 @@ from paddlebox_tpu.train.slot_policy import (  # noqa: E402,F401
     resolve_slot_lr_vec,
     slot_participation_vec,
 )
+from paddlebox_tpu.train.step_loss import (  # noqa: E402
+    add_counts,
+    counter_names,
+    make_model_loss,
+    publish_counters,
+)
 
 
 @dataclasses.dataclass
@@ -103,10 +108,15 @@ class TrainState:
 def _host_batch_dict(
     batch: HostBatch, plan, n_slots: int, counter_label_tasks=(),
     slot_lr_vec: Optional[np.ndarray] = None,
+    vocab_keys: Optional[np.ndarray] = None,
 ) -> dict:
     """Assemble the static-shape feed (numpy leaves) from a HostBatch +
     BatchPlan — _device_batch without the H2D transfer, so multi-step scan
     groups can stack on the host and transfer once.
+
+    vocab_keys: a model's fixed vocabulary (sorted feasigns); the feed
+    then carries "key_class" [K], each occurrence's rank in it
+    (data/feed.py key_classes).
 
     slot_lr_vec: [S] per-slot learning rates; when given the feed carries
     "uniq_lr" [K], each unique key's lr resolved from the slot of (one of)
@@ -131,6 +141,9 @@ def _host_batch_dict(
         dev["seq_pos"] = batch.seq_pos
     if batch.task_labels is not None:
         dev["task_labels"] = batch.task_labels
+    if vocab_keys is not None:
+        dev["key_class"] = key_classes(
+            batch.keys, batch.n_keys, vocab_keys, plan.inverse)
     if counter_label_tasks:
         if batch.task_labels is None:
             raise RuntimeError(
@@ -380,13 +393,14 @@ class Trainer:
         tconf = self.table_conf
         optimizer = self.optimizer
         check_nan = self._check_nan
-        uses_rank = getattr(model, "uses_rank_offset", False)
-        uses_seq = getattr(model, "uses_seq_pos", False)
         n_tasks = self.n_tasks
         has_group = self.metric_group is not None
         part_vec = slot_participation_vec(
             self.slot_mask, model.n_sparse_slots
         )
+        # the model half of the step: the model's own ``loss`` where it
+        # defines one, else apply -> sigmoid cross-entropy
+        model_loss = make_model_loss(model, n_tasks)
 
         # named scopes are metadata on the same operations: they put a
         # stage's name into every op of a device trace (pull / seqpool_cvm
@@ -400,10 +414,6 @@ class Trainer:
                     cvm_offset=tconf.cvm_offset,
                     pull_embedx_scale=tconf.pull_embedx_scale,
                 )
-            bsz = batch["labels"].shape[0]
-            extra = {"rank_offset": batch["rank_offset"]} if uses_rank else {}
-            if uses_seq:
-                extra["seq_pos"] = batch["seq_pos"]
             if part_vec is not None:
                 # occurrence-level participation: seg = ins*S + slot, so
                 # seg % S is the slot (padding occurrences are already
@@ -418,22 +428,9 @@ class Trainer:
             def loss_fn(p, r):
                 if key_part is not None:
                     r = r * key_part[:, None]
-                logits = model.apply(
-                    p, r, batch["key_segments"], batch["dense"], bsz, **extra
-                )
-                mask = batch["ins_mask"]
-                denom = jnp.maximum(mask.sum(), 1.0)
-                if n_tasks > 1:
-                    # [B, T] logits vs [B, T] task labels; mean over tasks
-                    per_ins = (
-                        bce_with_logits(logits, batch["task_labels"]).mean(axis=1)
-                        * mask
-                    )
-                else:
-                    per_ins = bce_with_logits(logits, batch["labels"]) * mask
-                return per_ins.sum() / denom, jax.nn.sigmoid(logits)
+                return model_loss(p, r, batch)
 
-            (loss, preds), (pgrads, row_grads) = jax.value_and_grad(
+            (loss, (preds, counts)), (pgrads, row_grads) = jax.value_and_grad(
                 loss_fn, argnums=(0, 1), has_aux=True
             )(params, rows)
 
@@ -458,7 +455,7 @@ class Trainer:
                     uniq_lr=batch.get("uniq_lr"),
                 )
             primary = preds[:, 0] if n_tasks > 1 else preds
-            mstate = dict(mstate)
+            mstate = add_counts(dict(mstate), counts)
             with jax.named_scope("metrics"):
                 mstate, finite = step_metrics(
                     mstate, batch, loss, preds, primary, pgrads, row_grads)
@@ -599,6 +596,7 @@ class Trainer:
         ``trainer.last_metric_state`` (a dict) to carry EVERY stream forward;
         a bare AucState continues only the primary stream and is rejected
         when task/group streams exist (they would silently reset)."""
+        n_counters = len(counter_names(self.model))
         if isinstance(auc_state, dict):
             # the step donates mstate: copy so the caller's reference (often
             # trainer.last_metric_state itself) is not invalidated by the
@@ -606,6 +604,8 @@ class Trainer:
             out = jax.tree.map(jnp.array, auc_state)
             if "gn" not in out:
                 out["gn"] = jnp.zeros((2,), jnp.float32)
+            if n_counters and "counters" not in out:
+                out["counters"] = jnp.zeros((n_counters,), jnp.float32)
             return out
         if auc_state is not None and (self.n_tasks > 1 or self.metric_group):
             raise ValueError(
@@ -619,6 +619,9 @@ class Trainer:
             else init_auc_state(self.conf.auc_buckets),
             "gn": jnp.zeros((2,), jnp.float32),
         }
+        if n_counters:
+            # the model's per-step sums (step_loss.counter_names)
+            mstate["counters"] = jnp.zeros((n_counters,), jnp.float32)
         if self.n_tasks > 1:
             mstate["task"] = stack_auc_states(
                 init_auc_state(self.conf.auc_buckets), self.n_tasks
@@ -707,6 +710,9 @@ class Trainer:
             # snapshots (materialized NOW — the first step donates the
             # buffer)
             gn_base = np.asarray(mstate["gn"], dtype=np.float64)
+            counters_base = np.asarray(
+                mstate.get("counters", ()), dtype=np.float64)
+        vocab_keys = getattr(self.model, "vocab_keys", None)
         pass_t0 = time.monotonic()
         n_samples = [0.0]
         values, g2sum = table.values, table.g2sum
@@ -819,6 +825,7 @@ class Trainer:
                         batch, plan, batch.n_sparse_slots,
                         self.conf.counter_label_tasks,
                         slot_lr_vec=self._slot_lr_vec,
+                        vocab_keys=vocab_keys,
                     )
                     if self.metric_group is not None:
                         host["metric_masks"] = self.metric_group.masks(batch)
@@ -1004,6 +1011,11 @@ class Trainer:
             watch.settle()
         with stage_scope("train.readback"), prof.stage("readback"):
             metrics = self._read_back(mstate, losses, gn_base)
+            if "counters" in mstate:
+                metrics.update(publish_counters(
+                    self.model,
+                    np.asarray(mstate["counters"], dtype=np.float64),
+                    counters_base))
         metrics["steps"] = n_steps
         # samples/s without trace files: the pass_end record carries
         # wall-clock duration and the instance count it covered

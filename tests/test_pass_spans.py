@@ -89,9 +89,15 @@ def _host_events(trace_dir: str) -> list:
     return out
 
 
-def test_stages_land_on_a_live_device_trace_inside_the_callers_span(tmp_path):
+@pytest.mark.parametrize("warm", [False, True], ids=["cold", "warm"])
+def test_stages_land_on_a_live_device_trace_inside_the_callers_span(
+        tmp_path, warm):
+    """cold: the pass's census misses the row cache, so its rows are
+    fetched from the host store and uploaded; warm: every census key is
+    resident, the pass buffer starts on the device and nothing crosses."""
     ds, trainer, table = _world(tmp_path)
-    _one_pass(ds, trainer, table)  # compile outside the trace
+    if warm:
+        _one_pass(ds, trainer, table)  # misses and compiles outside the trace
     trace_dir = str(tmp_path / "xtrace")
     opts = jax.profiler.ProfileOptions()
     opts.python_tracer_level = 0
@@ -119,8 +125,15 @@ def test_stages_land_on_a_live_device_trace_inside_the_callers_span(tmp_path):
         return bool(got) and all(o0 <= s and e <= o1 for s, e in got)
 
     assert inside("pbox.data.census", "outer.begin_pass")
-    for stage in ("census", "lookup", "upload", "fill", "touch"):
+    for stage in ("census", "lookup", "alloc", "touch"):
         assert inside(f"pbox.pass.{stage}", "outer.begin_pass"), stage
+    # (an empty row cache has nothing to fill a cold pass's buffer with)
+    here, absent = ((("fill",), ("fetch", "upload")) if warm
+                    else (("fetch", "upload"), ("fill",)))
+    for stage in here:
+        assert inside(f"pbox.pass.{stage}", "outer.begin_pass"), stage
+    for stage in absent:
+        assert f"pbox.pass.{stage}" not in names, stage
     for stage in ("batch", "plan", "feed", "feed_wait", "step", "drain",
                   "readback"):
         assert inside(f"pbox.trainer.{stage}", "outer.train"), stage
@@ -164,8 +177,40 @@ def test_one_pass_counts_every_series_and_sums_within_wall(tmp_path):
     import time
 
     ds, trainer, table = _world(tmp_path)
-    _one_pass(ds, trainer, table)  # cold pass: misses are fetched, compiles
+
+    def grew(series: str) -> tuple:
+        a = after["histograms"].get(series, {"count": 0, "sum": 0.0})
+        b = before["histograms"].get(series, {"count": 0, "sum": 0.0})
+        return a["count"] - b["count"], a["sum"] - b["sum"]
+
+    def counted(series: str) -> float:
+        return (after["counters"].get(series, 0.0)
+                - before["counters"].get(series, 0.0))
+
+    def stage_s(stages) -> float:
+        return sum(grew(f"pass.stage_seconds{{stage={s}}}")[1]
+                   for s in stages)
+
+    # the cold pass: its census misses the (empty) row cache, so the host
+    # buffer is allocated, filled from the host store and uploaded
+    before = telemetry.registry.snapshot()
+    keys = ds.unique_keys()
+    t1 = time.perf_counter()
+    table.begin_pass(keys)
+    t2 = time.perf_counter()
+    after = telemetry.registry.snapshot()
+    begin = ["census", "take_stage", "alloc", "lookup", "fetch", "upload",
+             "touch"]
+    for stage in begin:
+        assert grew(f"pass.stage_seconds{{stage={stage}}}")[0] >= 1, stage
+    assert grew("pass.stage_seconds{stage=lookup}")[0] == 2
+    assert stage_s(begin) <= t2 - t1
+    trainer.train_from_dataset(ds, table)  # compiles
+    table.end_pass()
     trainer._watch.settle()
+
+    # the warm pass: every census key is resident, so the pass buffer is
+    # allocated on the device and neither fetched nor uploaded
     before = telemetry.registry.snapshot()
     t0 = time.perf_counter()
     keys = ds.unique_keys()
@@ -178,31 +223,19 @@ def test_one_pass_counts_every_series_and_sums_within_wall(tmp_path):
     t4 = time.perf_counter()
     after = telemetry.registry.snapshot()
 
-    def grew(series: str) -> tuple:
-        a = after["histograms"].get(series, {"count": 0, "sum": 0.0})
-        b = before["histograms"].get(series, {"count": 0, "sum": 0.0})
-        return a["count"] - b["count"], a["sum"] - b["sum"]
-
-    def counted(series: str) -> float:
-        return (after["counters"].get(series, 0.0)
-                - before["counters"].get(series, 0.0))
-
     steps = m["steps"]
     assert steps == 6
     assert grew("data.census_seconds")[0] == 1
     assert grew("data.census_seconds")[1] <= t1 - t0
-    begin = ["census", "take_stage", "alloc", "lookup", "upload", "fill",
-             "touch"]
+    begin = ["census", "take_stage", "alloc", "lookup", "fill", "touch"]
     end = ["pack", "plan_update", "d2h", "set_rows", "commit", "write_back"]
     for stage in begin + end:
         assert grew(f"pass.stage_seconds{{stage={stage}}}")[0] >= 1, stage
+    for stage in ("fetch", "upload"):
+        assert grew(f"pass.stage_seconds{{stage={stage}}}")[0] == 0, stage
     assert grew("pass.stage_seconds{stage=lookup}")[0] == 2
-    assert sum(grew(f"pass.stage_seconds{{stage={s}}}")[1]
-               for s in begin) <= t2 - t1
-    assert sum(grew(f"pass.stage_seconds{{stage={s}}}")[1]
-               for s in end) <= t4 - t3
-    # the cold pass fetched its misses from the host store
-    assert _stage("pass", "fetch")["count"] >= 1
+    assert stage_s(begin) <= t2 - t1
+    assert stage_s(end) <= t4 - t3
     for stage, n in (("batch", steps + 1), ("plan", steps), ("step", steps),
                      ("drain", 1),
                      ("readback", 1), ("feed_wait", steps + 1),
@@ -216,6 +249,16 @@ def test_one_pass_counts_every_series_and_sums_within_wall(tmp_path):
     assert counted("pass.begins") == 1
     # is_ready is asked, never waited for: the counter may or may not move
     assert counted("pass.device_pending{at=begin_exit}") in (0.0, 1.0)
+
+    # a census of resident keys and new ones takes the host's path again:
+    # the misses fetched and uploaded, the hits filled from the cache
+    before = telemetry.registry.snapshot()
+    new = np.arange(1, 9, dtype=np.uint64) + keys.max()
+    table.begin_pass(np.concatenate([keys, new]))
+    after = telemetry.registry.snapshot()
+    for stage in ("alloc", "fetch", "upload", "fill"):
+        assert grew(f"pass.stage_seconds{{stage={stage}}}")[0] == 1, stage
+    table.end_pass()
     trainer.close()
     ds.close()
 
