@@ -628,8 +628,10 @@ def device_profile(ds, tconf, trconf, model, scan_k: int = 8, seed=0):
         scan_k = min(scan_k, len(hosts))  # ticks actually stacked
         trainer.conf = dataclasses.replace(trainer.conf, scan_steps=scan_k)
         scan_fn = trainer._build_scan_step()
+        # the pass's LAST plans: the table's unique-slot bucket has settled
+        # there (an early batch may have moved it; two lengths do not stack)
         stacked_host = {
-            k: np.stack([h[k] for h in hosts[:scan_k]]) for k in hosts[0]
+            k: np.stack([h[k] for h in hosts[-scan_k:]]) for k in hosts[0]
         }
         stacked = _to_device(stacked_host)
         jax.block_until_ready(stacked)

@@ -296,9 +296,10 @@ def _bind_plan_symbols(lib) -> None:
     lib.pbx_plan_resolve.argtypes = [
         ctypes.c_void_p,
         ctypes.POINTER(ctypes.c_uint64), ctypes.c_int64, ctypes.c_int64,
-        ctypes.c_int, ctypes.c_int,
+        ctypes.c_int, ctypes.c_int, ctypes.c_int64,
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_int32),
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_float),
+        ctypes.POINTER(ctypes.c_int64),
     ]
     lib.pbx_dedup_rows.restype = ctypes.c_int64
     lib.pbx_dedup_rows.argtypes = [
@@ -368,14 +369,19 @@ class CensusIndex:
         return (inverse[:n_real], uniq_key[:n_uniq], uniq_pos[:n_uniq])
 
     def resolve(self, keys: np.ndarray, n_real: int, dead: int,
-                scratch_base: int):
-        """(idx, uniq_idx, inverse, key_mask, n_missing) or None."""
+                scratch_base: int, n_slots: int):
+        """(idx, uniq_idx, inverse, key_mask, n_missing, n_uniq) or None,
+        with ``uniq_idx`` of ``n_slots`` slots.  ``n_uniq`` (the batch's
+        distinct keys) is exact whatever ``n_slots``; the arrays are a
+        plan only when the keys fit, n_uniq <= n_slots - 1 or n_slots ==
+        len(keys) — the caller sizes ``n_slots`` and asks again."""
         keys = np.ascontiguousarray(keys, dtype=np.uint64)
         K = keys.shape[0]
         idx = np.empty(K, dtype=np.int32)
-        uniq_idx = np.empty(K, dtype=np.int32)
+        uniq_idx = np.empty(n_slots, dtype=np.int32)
         inverse = np.empty(K, dtype=np.int32)
         key_mask = np.empty(K, dtype=np.float32)
+        n_uniq = ctypes.c_int64(0)
         i32p = lambda a: a.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
         with self._lock:
             if not self._handle:
@@ -383,13 +389,15 @@ class CensusIndex:
             n_missing = self._lib.pbx_plan_resolve(
                 self._handle,
                 keys.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
-                K, int(n_real), int(dead), int(scratch_base),
+                K, int(n_real), int(dead), int(scratch_base), int(n_slots),
                 i32p(idx), i32p(uniq_idx), i32p(inverse),
                 key_mask.ctypes.data_as(ctypes.POINTER(ctypes.c_float)),
+                ctypes.byref(n_uniq),
             )
         if n_missing < 0:
             return None
-        return idx, uniq_idx, inverse, key_mask, int(n_missing)
+        return (idx, uniq_idx, inverse, key_mask, int(n_missing),
+                int(n_uniq.value))
 
 
 def build_census_index(census: np.ndarray):

@@ -21,13 +21,21 @@
 // rows consistently through inverse/uniq_idx, so training results match
 // the numpy path BIT-FOR-BIT (pinned end-to-end by test_native_planner).
 //
-// Contract (order-insensitive form of plan_keys):
+// Contract (order-insensitive form of plan_keys).  The occurrence side
+// is K long (the key buffer's capacity); the unique side is U long, the
+// caller's bucket for the batch's distinct keys (U <= K):
 //   idx[occ]      = found ? census_row : dead        (occ < n_real)
 //                 = dead                             (padding)
 //   uniq_idx[j]   = found ? census_row : min(scratch_base + j, dead)
-//   inverse[occ]  = first-seen slot of the occurrence; K-1 for padding
+//                                                    (j < U)
+//   inverse[occ]  = first-seen slot of the occurrence; U-1 for padding
 //   key_mask[occ] = 1.0 real / 0.0 padding
+//   *n_uniq_out   = distinct keys of the batch, found or missing
 //   returns n_missing (unique keys absent from the census)
+// The walk is what counts the distinct keys, so the caller learns from
+// *n_uniq_out whether they fit: the outputs are a plan when n_uniq <= U-1
+// (slot U-1 is the padding's and stays non-live) or U == K; otherwise
+// slots >= U were not written and the caller asks again with a larger U.
 
 #include <cstdint>
 #include <cstring>
@@ -89,19 +97,21 @@ void pbx_census_index_free(void* handle) {
 long long pbx_plan_resolve(
     void* handle,
     const unsigned long long* keys, long long K, long long n_real,
-    int dead, int scratch_base,
-    int* idx, int* uniq_idx, int* inverse, float* key_mask) {
-  if (n_real < 0 || n_real > K) return -1;
+    int dead, int scratch_base, long long U,
+    int* idx, int* uniq_idx, int* inverse, float* key_mask,
+    long long* n_uniq_out) {
+  if (n_real < 0 || n_real > K || U < 0 || U > K) return -1;
   const CensusIndex* ix = static_cast<CensusIndex*>(handle);
+  *n_uniq_out = 0;
 
   // padding defaults (tail slots + tail occurrences)
-  for (long long j = 0; j < K; ++j) {
+  for (long long j = 0; j < U; ++j) {
     long long scratch = (long long)scratch_base + j;
     uniq_idx[j] = (int)(scratch < dead ? scratch : dead);
   }
   for (long long o = n_real; o < K; ++o) {
     idx[o] = dead;
-    inverse[o] = (int)(K - 1);
+    inverse[o] = (int)(U - 1);
     key_mask[o] = 0.0f;
   }
   if (n_real == 0) return 0;
@@ -144,7 +154,7 @@ long long pbx_plan_resolve(
       }
       if (row >= 0) {
         pull_row[(size_t)slot] = (int)row;
-        uniq_idx[slot] = (int)row;
+        if (slot < U) uniq_idx[slot] = (int)row;
       } else {
         pull_row[(size_t)slot] = dead;
         ++n_missing;  // uniq_idx keeps the slot's scratch default
@@ -154,6 +164,7 @@ long long pbx_plan_resolve(
     inverse[o] = (int)slot;
     key_mask[o] = 1.0f;
   }
+  *n_uniq_out = n_uniq;
   return n_missing;
 }
 

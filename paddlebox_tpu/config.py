@@ -505,13 +505,15 @@ class SparseTableConfig:
     # pass size, not total features ever seen (sparse/store.py).
     store_buckets: int = 256
     # device-table scratch rows reserved past the pass working set, one per
-    # key-buffer slot, so every padding/missing plan slot scatters into its
-    # OWN row instead of all duplicating the dead row.  Push indices are
-    # then unique by construction and the jitted push claims
+    # slot of the plan's unique side (uniq_idx: the table's unique-slot
+    # bucket, which follows the batches' distinct keys and is at most the
+    # key buffer's capacity), so every padding/missing plan slot scatters
+    # into its OWN row instead of all duplicating the dead row.  Push
+    # indices are then unique by construction and the jitted push claims
     # unique_indices=True, unlocking XLA's parallel scatter lowering (the
     # serial duplicate-safe lowering is the sparse push's worst case on
     # TPU).  Used for PASS 1 only — later passes size the region exactly
-    # from the observed plan (key buffer single-chip, serve buffer
+    # from the observed plan (unique-slot bucket single-chip, serve buffer
     # sharded), so a mis-set default costs at most one extra pass-boundary
     # recompile, never correctness: slots past the region clamp to the
     # dead row and the push zeroes every dead-targeted delta before the

@@ -20,7 +20,9 @@ BeginFeedPass/EndFeedPass trick, §3.4):
     duplicate keys exactly like the reference's ``DedupKeysAndFillIdx`` +
     ``PushMergeCopy`` (box_wrapper.cu:457-1034), but on the host where
     dynamic shapes are free.  Everything handed to the device has a static
-    shape.
+    shape: the occurrence side at the batch's key capacity, the unique
+    side at the table's unique-slot bucket (``_uniq_slots``), which follows
+    the distinct keys of the batches seen.
   * pull_rows / push_and_update — pure jittable functions: gather, and
     segment-sum merge + sparse adagrad + show/clk counter scatter-add.
   * end_pass() — write the working set back into the host store.
@@ -47,6 +49,7 @@ import numpy as np
 from paddlebox_tpu.config import SparseTableConfig
 from paddlebox_tpu.data.feed import HostBatch
 from paddlebox_tpu.sparse.optimizer import sparse_adagrad_update
+from paddlebox_tpu.telemetry import metrics as _tm
 from paddlebox_tpu.telemetry.compiles import stage_scope
 from paddlebox_tpu.utils.profiler import StatsProfiler
 
@@ -58,6 +61,17 @@ logger = logging.getLogger(__name__)
 # d2h / set_rows / commit / write_back.  lookup, touch, plan_update and
 # commit are timed inside HbmCache, where both tables call them.
 _PASS = StatsProfiler("pass.stage_seconds")
+
+# the plan's unique side (SparseTable._uniq_slots): keys / slots is its fill
+_UNIQ_KEYS = _tm.counter(
+    "plan.uniq_keys", "distinct keys of the planned batches (found or "
+    "census-missing): the live slots of uniq_idx")
+_UNIQ_SLOTS = _tm.counter(
+    "plan.uniq_slots", "uniq_idx slots of the planned batches: the push's "
+    "scatter indices")
+_UNIQ_GROWS = _tm.counter(
+    "plan.uniq_grows", "times a batch did not fit the table's unique-slot "
+    "bucket and moved it (each is a new step shape)")
 
 
 def _count_begin(at: str, arrays) -> None:
@@ -122,12 +136,20 @@ class BatchPlan:
 
     idx:      int32 [K] — table row per key occurrence (dead row for padding
               or keys absent from the pass census).
-    uniq_idx: int32 [U] — table row per *unique* batch key (U == K capacity;
-              tail padded with the dead row).
+    uniq_idx: int32 [U] — scatter target per *unique* batch key.  U is the
+              table's unique-slot bucket (SparseTable._uniq_slots): it
+              follows the distinct keys of the batches planned so far, not
+              the key capacity K (U <= K).  Slots [0, n_uniq) hold the
+              batch's distinct keys (live row, or the slot's scratch row
+              for a census-missing key); every slot past them aims at its
+              own scratch row.
     inverse:  int32 [K] — position of each occurrence in uniq_idx (padding
-              occurrences point at slot U-1).
+              occurrences point at slot U-1, which is never a key's unless
+              the buffer has no padding).
     key_mask: float32 [K] — 1.0 for real key occurrences.
     n_missing: keys that were not in the pass census (observability).
+    n_uniq:   distinct keys of the batch, found or missing (the slots in
+              use; plan.uniq_keys / plan.uniq_slots is the fill).
     """
 
     idx: np.ndarray
@@ -135,6 +157,7 @@ class BatchPlan:
     inverse: np.ndarray
     key_mask: np.ndarray
     n_missing: int = 0
+    n_uniq: int = 0
 
 
 def _next_pow2(n: int) -> int:
@@ -211,9 +234,11 @@ class SparseTable:
         self._in_pass = False
         # delta tracking for SaveDelta-style incremental checkpoints
         self._delta_keys: list[np.ndarray] = []
-        # largest key buffer planned so far: sizes the next pass's scratch
-        # region (pass 1 falls back to conf.plan_scratch_rows)
-        self._last_plan_k = 0
+        # unique-slot bucket of the plans (uniq_idx's length): a high-water
+        # mark over the batches planned so far (_uniq_slots).  It also
+        # sizes the next pass's scratch region (pass 1 falls back to
+        # conf.plan_scratch_rows)
+        self._plan_uniq_slots = 0
         # native per-pass census hash index (lazily built on first plan;
         # borrows self._pass_keys, so it must drop with the pass)
         self._census_index = None
@@ -538,7 +563,7 @@ class SparseTable:
         return self._stage_future.result()[0]
 
     def _stage_cap(self, n_keys: int) -> int:
-        scratch = self._last_plan_k or self.conf.plan_scratch_rows
+        scratch = self._plan_uniq_slots or self.conf.plan_scratch_rows
         return _next_pow2(n_keys + 1 + scratch)
 
     def _stage_snapshot(self):
@@ -792,7 +817,7 @@ class SparseTable:
         # layout: [0, n) live rows | [n, cap-1) plan scratch | cap-1 dead.
         # Scratch rows give every padding/missing plan slot a distinct
         # scatter target (see SparseTableConfig.plan_scratch_rows).  Once a
-        # plan has run, the observed key-buffer size is the exact need;
+        # plan has run, the plans' unique-slot bucket is the exact need;
         # pass 1 uses the config default (over-provisioning only rounds
         # into the same pow2 in the common case, and plan_keys degrades
         # gracefully if a later batch needs more).
@@ -1060,65 +1085,103 @@ class SparseTable:
     def plan_batch(self, batch: HostBatch) -> BatchPlan:
         return self.plan_keys(batch.keys, batch.n_keys)
 
+    def _uniq_slots(self, n_uniq: int, K: int) -> int:
+        """U, the length of a plan's unique side, for a batch of ``n_uniq``
+        distinct keys in a ``K``-slot key buffer.
+
+        The push costs per scatter index, not per byte, so U follows the
+        distinct keys the table has seen in a batch and not the buffer's
+        capacity.  It is the TABLE's high-water mark, never the batch's:
+        every plan of a settled stream has one length (one compiled step;
+        a scan group stacks).  A batch fits while its keys leave slot U-1
+        to the padding occurrences; one that does not moves the mark to a
+        power of two with a quarter of headroom — a count that sits on a
+        power of two must not flip between two step shapes — and never
+        past K, where every batch fits by construction."""
+        U = min(self._plan_uniq_slots, K)
+        if U < K and n_uniq > U - 1:
+            self._plan_uniq_slots = max(
+                self._plan_uniq_slots,
+                min(K, _next_pow2(n_uniq + n_uniq // 4 + 1)))
+            U = min(self._plan_uniq_slots, K)
+            _UNIQ_GROWS.inc()
+        return U
+
     def plan_keys(self, keys: np.ndarray, n_real: int) -> BatchPlan:
         """Resolve a padded key buffer to device row indices + dedup maps.
 
-        ``idx`` (the pull side) maps missing/padding occurrences to the
-        dead row (reads zeros).  ``uniq_idx`` (the push side) maps every
-        non-live slot to its OWN scratch row (scratch_base + slot), so push
-        indices are unique by construction — push_and_update scatters with
-        unique_indices=True and XLA never pays the duplicate-safe serial
-        lowering.  Scratch rows are never pulled and never merged back."""
+        ``idx`` (the pull side, [K]) maps missing/padding occurrences to
+        the dead row (reads zeros).  ``uniq_idx`` (the push side, [U] with
+        U = _uniq_slots: the batch's distinct keys first, U <= K) maps
+        every non-live slot to its OWN scratch row (scratch_base + slot),
+        so push indices are unique by construction — push_and_update
+        scatters with unique_indices=True and XLA never pays the
+        duplicate-safe serial lowering.  Scratch rows are never pulled and
+        never merged back."""
         if not self._in_pass:
             raise RuntimeError("begin_pass before planning batches")
         K = keys.shape[0]
         dead = self.dead_row
         scratch_base = self._pass_keys.shape[0]
-        self._last_plan_k = max(self._last_plan_k, K)
+        plan = self._plan_native(keys, n_real, dead, scratch_base)
+        if plan is None:
+            plan = self._plan_numpy(keys, n_real, dead, scratch_base)
+        self.missing_key_count += plan.n_missing
+        _UNIQ_KEYS.inc(plan.n_uniq)
+        _UNIQ_SLOTS.inc(plan.uniq_idx.shape[0])
+        return plan
 
-        # C++ planner (_native/plan_resolve.cpp): a per-pass census hash
-        # index + one sort-free O(K) batch walk (first-seen slot order).
-        # Training results are BIT-identical to the numpy path — idx is
-        # order-free and the push permutes inverse/uniq_idx consistently —
-        # pinned by test_native_planner's e2e equality.
+    def _plan_native(self, keys, n_real, dead, scratch_base):
+        """C++ planner (_native/plan_resolve.cpp): a per-pass census hash
+        index + one sort-free O(K) batch walk (first-seen slot order).
+        Training results are BIT-identical to the numpy path — idx is
+        order-free and the push permutes inverse/uniq_idx consistently —
+        pinned by test_native_planner's e2e equality.  The walk is what
+        counts the batch's distinct keys, so a batch that moves the mark
+        is resolved a second time at the new length."""
         ix = self._native_index()
-        if ix is not None:
-            out = ix.resolve(keys, n_real, dead, scratch_base)
-            if out is not None:
-                idx, uniq_idx, inverse, mask, n_missing = out
-                self.missing_key_count += n_missing
-                return BatchPlan(idx, uniq_idx, inverse, mask, n_missing)
+        if ix is None:
+            return None
+        K = keys.shape[0]
+        U = min(self._plan_uniq_slots, K)
+        out = ix.resolve(keys, n_real, dead, scratch_base, U)
+        if out is not None:
+            n_uniq = out[-1]
+            fit = self._uniq_slots(n_uniq, K)
+            if fit != U:
+                out = ix.resolve(keys, n_real, dead, scratch_base, fit)
+        return None if out is None else BatchPlan(*out)
 
+    def _plan_numpy(self, keys, n_real, dead, scratch_base):
+        K = keys.shape[0]
         idx = np.full(K, dead, dtype=np.int32)
+        mask = np.zeros(K, dtype=np.float32)
+        nu = n_missing = 0
+        if n_real:
+            uk, inv = np.unique(keys[:n_real], return_inverse=True)
+            nu = uk.shape[0]
+            npk = self._pass_keys.shape[0]
+            pos_c = np.minimum(np.searchsorted(self._pass_keys, uk),
+                               max(npk - 1, 0))
+            found = (self._pass_keys[pos_c] == uk) if npk else np.zeros(nu, bool)
+            n_missing = int((~found).sum())
+        U = self._uniq_slots(nu, K)
         # slots beyond the provisioned scratch clamp to the dead row:
         # push_and_update zeroes every dead-targeted delta, so the clamped
         # duplicates only ever write unchanged bytes (real unique slots sit
         # at the front and win scratch rows first; clamped missing-key
         # grads were headed for the post-push dead-row scrub regardless)
         uniq_idx = np.minimum(
-            scratch_base + np.arange(K, dtype=np.int32), dead
+            scratch_base + np.arange(U, dtype=np.int32), dead
         )
-        inverse = np.full(K, K - 1, dtype=np.int32)
-        mask = np.zeros(K, dtype=np.float32)
-        n_missing = 0
+        inverse = np.full(K, U - 1, dtype=np.int32)
         if n_real:
-            real = keys[:n_real]
-            uk, inv = np.unique(real, return_inverse=True)
-            pos = np.searchsorted(self._pass_keys, uk)
-            npk = self._pass_keys.shape[0]
-            pos_c = np.minimum(pos, max(npk - 1, 0))
-            found = (self._pass_keys[pos_c] == uk) if npk else np.zeros(uk.shape[0], bool)
-            nu = uk.shape[0]
             # push target: live row when found, the slot's scratch row else
-            rows_push = np.where(found, pos_c, uniq_idx[:nu]).astype(np.int32)
-            rows_pull = np.where(found, pos_c, dead).astype(np.int32)
-            n_missing = int((~found).sum())
-            uniq_idx[:nu] = rows_push
-            idx[:n_real] = rows_pull[inv]
+            uniq_idx[:nu] = np.where(found, pos_c, uniq_idx[:nu])
+            idx[:n_real] = np.where(found, pos_c, dead).astype(np.int32)[inv]
             inverse[:n_real] = inv
             mask[:n_real] = 1.0
-        self.missing_key_count += n_missing
-        return BatchPlan(idx, uniq_idx, inverse, mask, n_missing)
+        return BatchPlan(idx, uniq_idx, inverse, mask, n_missing, nu)
 
     # -- maintenance (day boundary) --------------------------------------- #
     def shrink(self) -> int:

@@ -119,7 +119,7 @@ def _host_batch_dict(
     (data/feed.py key_classes).
 
     slot_lr_vec: [S] per-slot learning rates; when given the feed carries
-    "uniq_lr" [K], each unique key's lr resolved from the slot of (one of)
+    "uniq_lr" [U], each unique key's lr resolved from the slot of (one of)
     its occurrences — the host side of the BoxPS LR map
     (box_wrapper.h:631)."""
     ins = np.minimum(batch.key_segments // n_slots, batch.batch_size - 1)
@@ -167,8 +167,8 @@ def _host_batch_dict(
         ).astype(np.float32)
         dev["key_extras"] = extras
     if slot_lr_vec is not None:
-        K = batch.key_segments.shape[0]
-        uniq_lr = np.full(K, slot_lr_vec.mean(), np.float32)  # padding tail
+        uniq_lr = np.full(  # padding tail: any finite lr, its delta is 0
+            plan.uniq_idx.shape[0], slot_lr_vec.mean(), np.float32)
         n_real = batch.n_keys
         if n_real:
             # inverse[:n_real] maps occurrences -> unique slots; last
@@ -850,11 +850,17 @@ class Trainer:
                     continue
                 buf.append(host)
                 if len(buf) == scan_k:
-                    stacked = _to_device(
-                        {k: np.stack([h[k] for h in buf]) for k in buf[0]}
-                    )
+                    if len({h["uniq_idx"].shape[0] for h in buf}) > 1:
+                        # a batch of the group moved the table's unique-slot
+                        # bucket (table._uniq_slots): plans of two lengths
+                        # do not stack, so this one group goes step by step
+                        for host in buf:
+                            yield "one", None, _to_device(host)
+                    else:
+                        yield "scan", None, _to_device(
+                            {k: np.stack([h[k] for h in buf]) for k in buf[0]}
+                        )
                     buf = []
-                    yield "scan", None, stacked
             for host in buf:  # ragged tail: single-step dispatches
                 yield "one", None, _to_device(host)
 

@@ -104,6 +104,42 @@ def test_parity_under_provisioned_scratch():
     _assert_equal(*_plans(pass_keys, keys, 100, conf=conf))
 
 
+@pytest.mark.parametrize("n_hit,n_miss,n_real", [
+    (500, 60, 3000),   # duplicates, census-missing keys and padding
+    (819, 0, 1500),    # 1.25 x 819 + 1 = 1,024: the headroom just fits
+    (816, 4, 1024),    # one key more: a fresh table takes the next bucket
+    (0, 0, 0),         # all padding
+])
+def test_parity_bucketed_unique_side(n_hit, n_miss, n_real):
+    """Both planners emit the unique side at the same bucket U_b < K, count
+    the same distinct keys, and park the padding at slot U_b - 1."""
+    rng = np.random.default_rng(n_real)
+    pass_keys = np.arange(1000, 9000, dtype=np.uint64)
+    K = 4096
+    pool = np.concatenate([
+        rng.choice(pass_keys, n_hit, replace=False),
+        np.arange(1 << 40, (1 << 40) + n_miss, dtype=np.uint64)])
+    keys = np.zeros(K, np.uint64)
+    keys[:n_real] = np.concatenate(
+        [pool, rng.choice(pool, n_real - pool.shape[0])
+         if n_real else pool])[rng.permutation(n_real)]
+    native, numpy_ = _plans(pass_keys, keys, n_real, conf=SparseTableConfig(
+        embedding_dim=4, plan_scratch_rows=K))
+    _assert_equal(native, numpy_)
+    n_uniq = n_hit + n_miss
+    want = 1024 if n_uniq + n_uniq // 4 + 1 <= 1024 else 2048
+    for plan, _ in (native, numpy_):
+        assert plan.uniq_idx.shape[0] == want < K
+        assert plan.n_uniq == n_uniq and plan.n_missing == n_miss
+        assert (plan.inverse[:n_real] < n_uniq).all()
+        assert (plan.inverse[n_real:] == want - 1).all()
+        # targets pairwise distinct (the scratch region holds them all)
+        assert np.unique(plan.uniq_idx).shape[0] == want
+    # the slots past the keys are the same scratch rows on both sides
+    np.testing.assert_array_equal(native[0].uniq_idx[n_uniq:],
+                                  numpy_[0].uniq_idx[n_uniq:])
+
+
 def test_e2e_training_same_result(tmp_path):
     """One real training pass, native vs numpy planner: identical loss and
     table state (the planner feeds the jitted step, so full-step parity is

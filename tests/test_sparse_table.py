@@ -5,11 +5,21 @@ begin_pass -> train -> end_pass -> shrink cycle (reference semantics:
 fleet/box_wrapper_impl.h:24-255, box_wrapper.cc:609-673,496-499).
 """
 
+import dataclasses
+
+import jax
 import jax.numpy as jnp
 import numpy as np
+import pytest
 
-from paddlebox_tpu.config import SparseTableConfig
+from paddlebox_tpu import telemetry
+from paddlebox_tpu._native import build_census_index
+from paddlebox_tpu.config import SparseTableConfig, TrainerConfig, flags
+from paddlebox_tpu.data.dataset import PadBoxSlotDataset
+from paddlebox_tpu.data.synth import make_synth_config, write_synth_files
+from paddlebox_tpu.models import CtrDnn
 from paddlebox_tpu.sparse import SparseTable, pull_rows, push_and_update
+from paddlebox_tpu.train.trainer import Trainer
 
 
 def _conf(**kw):
@@ -184,3 +194,197 @@ def test_delta_tracking():
     t2 = SparseTable(conf)
     t2.apply_delta(delta)
     assert t2.n_features == 2
+
+
+# -- the plan's unique side: a bucket over the distinct keys, not K -------- #
+_NATIVE = build_census_index(np.arange(4, dtype=np.uint64)) is not None
+_PLANNERS = [
+    pytest.param(False, id="numpy"),
+    pytest.param(True, id="native", marks=pytest.mark.skipif(
+        not _NATIVE, reason="native planner did not build")),
+]
+_CENSUS = np.arange(1000, 9000, dtype=np.uint64)
+
+
+@pytest.fixture
+def planner(request):
+    flags.set("use_native_planner", request.param)
+    yield request.param
+    flags.set("use_native_planner", True)
+
+
+def _buffer(K, n_hit, n_miss, n_real, seed=0):
+    """A K-slot key buffer: n_real occurrences drawn (with duplicates) from
+    n_hit census keys and n_miss keys the census lacks, then padding."""
+    rng = np.random.default_rng(seed)
+    pool = np.concatenate([
+        rng.choice(_CENSUS, n_hit, replace=False),
+        np.arange(1 << 40, (1 << 40) + n_miss, dtype=np.uint64)])
+    keys = np.zeros(K, np.uint64)
+    keys[:n_real] = np.concatenate(
+        [pool, rng.choice(pool, n_real - pool.shape[0])])[rng.permutation(n_real)]
+    return keys
+
+
+def _uniq_counters():
+    return tuple(telemetry.counter(f"plan.uniq_{n}").value()
+                 for n in ("keys", "slots", "grows"))
+
+
+@pytest.mark.parametrize("planner", _PLANNERS, indirect=True)
+def test_plan_unique_side_follows_distinct_keys(planner):
+    """Duplicates, census-missing keys and padding in one buffer: uniq_idx
+    is U_b < K long, its targets are distinct, the keys sit at the front
+    and the padding at the bucket's last slot."""
+    t = SparseTable(_conf(), seed=0)
+    t.begin_pass(_CENSUS)
+    K, n_real, n_uniq = 4096, 3000, 560
+    keys = _buffer(K, 500, 60, n_real)
+    plan = t.plan_keys(keys, n_real)
+    U = plan.uniq_idx.shape[0]
+    assert U == 1024 < K  # 1.25 x 560 rounds up to the smallest bucket
+    assert plan.n_uniq == n_uniq and plan.n_missing == 60
+    for a in (plan.idx, plan.inverse, plan.key_mask):
+        assert a.shape == (K,)  # the occurrence side keeps the capacity
+    assert plan.inverse[:n_real].max() == n_uniq - 1 <= U - 2
+    assert (plan.inverse[n_real:] == U - 1).all()
+    assert (plan.idx[n_real:] == t.dead_row).all()
+    live = plan.uniq_idx[plan.uniq_idx != t.dead_row]
+    assert np.unique(live).shape[0] == live.shape[0] == U  # scratch fits
+    # the keys' slots: the live row when found, the slot's scratch row else
+    tgt = plan.uniq_idx[plan.inverse[:n_real]]
+    found = np.isin(keys[:n_real], _CENSUS)
+    np.testing.assert_array_equal(
+        tgt[found], np.searchsorted(_CENSUS, keys[:n_real][found]))
+    n = _CENSUS.shape[0]
+    np.testing.assert_array_equal(
+        tgt[~found], n + plan.inverse[:n_real][~found])
+    # every slot past the keys aims at its own scratch row
+    np.testing.assert_array_equal(
+        plan.uniq_idx[n_uniq:], n + np.arange(n_uniq, U))
+
+
+@pytest.mark.parametrize("planner", _PLANNERS, indirect=True)
+def test_plan_bucket_grows_once_and_never_shrinks(planner):
+    t = SparseTable(_conf(), seed=0)
+    t.begin_pass(_CENSUS)
+    K = 4096
+    k0, s0, g0 = _uniq_counters()
+    sizes = []
+    # (distinct keys, bucket): 1023 keys still leave slot 1023 to the
+    # padding; 1024 do not, and the bucket jumps past 1.25 x 1024
+    for n_uniq, want in ((300, 1024), (1023, 1024), (1024, 2048),
+                         (200, 2048), (1600, 2048)):
+        plan = t.plan_keys(_buffer(K, n_uniq, 0, n_uniq + 50, seed=n_uniq),
+                           n_uniq + 50)
+        assert plan.n_uniq == n_uniq
+        assert plan.uniq_idx.shape[0] == want
+        assert plan.inverse[:n_uniq + 50].max() == n_uniq - 1 <= want - 2
+        assert (plan.inverse[n_uniq + 50:] == want - 1).all()
+        sizes.append(want)
+    k1, s1, g1 = _uniq_counters()
+    assert k1 - k0 == 300 + 1023 + 1024 + 200 + 1600
+    assert s1 - s0 == sum(sizes)
+    assert g1 - g0 == 2  # the first plan's 0 -> 1024, then 1024 -> 2048
+    # the mark is the table's: it outlives the pass, and sizes the next
+    # pass's scratch region in place of the key capacity
+    t.end_pass()
+    assert t._stage_cap(8000) == 16384  # 8000 + 1 + 2048, not + 4096
+    t.begin_pass(_CENSUS)
+    assert t.plan_keys(_buffer(K, 10, 0, 20), 20).uniq_idx.shape[0] == 2048
+    assert _uniq_counters()[2] - g0 == 2
+
+
+@pytest.mark.parametrize("planner", _PLANNERS, indirect=True)
+def test_plan_bucket_stops_at_the_key_capacity(planner):
+    """A buffer with no room for a bucket (all keys distinct, no padding)
+    plans at U == K as before: every batch fits there by construction."""
+    t = SparseTable(_conf(), seed=0)
+    t.begin_pass(_CENSUS)
+    K = 1500
+    keys = _CENSUS[:K].copy()
+    plan = t.plan_keys(keys, K)
+    assert plan.uniq_idx.shape[0] == K and plan.n_uniq == K
+    np.testing.assert_array_equal(np.sort(plan.uniq_idx), np.arange(K))
+    # a smaller buffer than the mark plans at its own capacity
+    small = t.plan_keys(_CENSUS[:64].copy(), 40)
+    assert small.uniq_idx.shape[0] == 64
+    assert (small.inverse[40:] == 63).all()
+
+
+# case -> (per-slot lr, (scan_steps, unique side) of the run under test and
+# of the run it must equal).  "bucket" is the plan as the table emits it,
+# "capacity" the same plan with the scratch slots it dropped appended again
+# (uniq_idx at K, as before the bucket), "moved" the bucket for the first
+# batch and K from the second on: a mark that moves inside a scan group.
+_PUSH_CASES = {
+    "plain": (False, (1, "bucket"), (1, "capacity")),
+    "slot_lr": (True, (1, "bucket"), (1, "capacity")),
+    "scan2": (False, (2, "bucket"), (2, "capacity")),
+    # plans of two lengths do not stack: the group goes step by step, and
+    # so equals the single-step run bit for bit
+    "scan2_moved": (False, (2, "moved"), (1, "bucket")),
+}
+
+
+@pytest.mark.parametrize("case", list(_PUSH_CASES))
+def test_bucketed_push_equals_push_at_capacity(tmp_path, case):
+    """Same work, same result: three train_from_dataset steps with the
+    plan's unique side at its bucket against the same steps with uniq_idx
+    at the key capacity K — live rows, g2sum, show/click and the dense
+    parameters are bit-identical."""
+    S, B, K = 3, 64, 64 * 32
+    slot_lr, under_test, reference = _PUSH_CASES[case]
+    conf = make_synth_config(n_sparse_slots=S, dense_dim=2, batch_size=B,
+                             max_feasigns_per_ins=32)
+    files = write_synth_files(str(tmp_path), n_files=1, ins_per_file=3 * B,
+                              n_sparse_slots=S, vocab_per_slot=40,
+                              dense_dim=2, seed=5)
+
+    def run(scan_steps, side):
+        ds = PadBoxSlotDataset(conf, read_threads=1)
+        ds.set_filelist(files)
+        ds.load_into_memory()
+        tconf = _conf(slot_learning_rates=((0, 0.3), (2, 0.02))
+                      if slot_lr else ())
+        table = SparseTable(tconf, seed=0)
+        trainer = Trainer(
+            CtrDnn(S, tconf.row_width, dense_dim=2, hidden=(8,)), tconf,
+            TrainerConfig(auc_buckets=1 << 10, scan_steps=scan_steps), seed=0)
+        assert (trainer._slot_lr_vec is not None) == slot_lr
+        plan_keys = table.plan_keys
+        lengths = []
+
+        def plan_at(keys, n_real):
+            plan = plan_keys(keys, n_real)
+            U = plan.uniq_idx.shape[0]
+            if side == "capacity" or (side == "moved" and lengths):
+                tail = np.minimum(
+                    table._pass_keys.shape[0] + np.arange(U, K), table.dead_row)
+                plan = dataclasses.replace(plan, uniq_idx=np.concatenate(
+                    [plan.uniq_idx, tail.astype(np.int32)]))
+            lengths.append(plan.uniq_idx.shape[0])
+            return plan
+
+        table.plan_keys = plan_at
+        table.begin_pass(ds.unique_keys())
+        m = trainer.train_from_dataset(ds, table)
+        assert m["steps"] == 3
+        assert lengths == {"bucket": [1024] * 3, "capacity": [K] * 3,
+                           "moved": [1024, K, K]}[side]
+        n = table._pass_keys.shape[0]
+        live = (np.asarray(table.values)[:n].copy(),
+                np.asarray(table.g2sum)[:n].copy())
+        table.end_pass()
+        state = table.state_dict()
+        ds.close()
+        return m, live, state, jax.tree.leaves(trainer.params)
+
+    m_a, live_a, state_a, params_a = run(*under_test)
+    m_b, live_b, state_b, params_b = run(*reference)
+    assert m_a["loss"] == m_b["loss"]
+    for a, b in zip(live_a + tuple(params_a), live_b + tuple(params_b)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    assert live_a[0][:, 0].sum() > 0  # shows were counted
+    np.testing.assert_array_equal(state_a["keys"], state_b["keys"])
+    np.testing.assert_array_equal(state_a["values"], state_b["values"])
