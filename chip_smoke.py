@@ -52,8 +52,8 @@ import numpy as np
 
 @dataclasses.dataclass(frozen=True)
 class Sizes:
-    """The north-star width (bench.py run_all's sustained stage,
-    examples/train_criteo.py); depth is what is cut: steps per pass."""
+    """The north-star width (examples/train_criteo.py, the benchmark's
+    ctr_dnn configuration); depth is what is cut: steps per pass."""
 
     slots: int = 26
     dense: int = 13
@@ -69,7 +69,7 @@ class Sizes:
     small_bucket: int = 64
 
     def key_capacity(self, batch: int) -> int:
-        return batch * self.slots * 4  # bench.py build_data's sizing
+        return batch * self.slots * 4  # room for 4 keys a slot
 
 
 def log(msg: str) -> None:
@@ -259,7 +259,7 @@ def leg_serve(sz: Sizes, conf, files, model, table, trainer, work: str) -> dict:
 
 
 def leg_sparse_ops(sz: Sizes, capacity_rows: int, row_width: int) -> dict:
-    """jnp.take and scatter_add_rows — what pull and push lower to — at
+    """jnp.take and the row scatter-add — what pull and push lower to — at
     the train leg's shapes (K = batch key capacity, W = row width, P = the
     pass capacity) against numpy, duplicate indices included, and the
     unique_indices claim on indices that are unique."""
@@ -279,16 +279,16 @@ def leg_sparse_ops(sz: Sizes, capacity_rows: int, row_width: int) -> dict:
     got = np.asarray(jax.jit(lambda v, i: jnp.take(v, i, axis=0))(dv, dup))
     np.testing.assert_array_equal(got, values[dup])
 
-    add = jax.jit(scatter_add_rows, static_argnames=("unique",))
     want = values.copy()
     np.add.at(want, dup, delta)
-    got = np.asarray(add(dv, dup, delta, unique=False))
+    got = np.asarray(jax.jit(lambda v, i, d: v.at[i].add(d))(dv, dup, delta))
     # duplicates accumulate in an order the backend chooses
     np.testing.assert_allclose(got, want, rtol=1e-4, atol=1e-4)
 
     want = values.copy()
     want[uniq] += delta[: uniq.shape[0]]
-    got = np.asarray(add(dv, uniq, delta[: uniq.shape[0]], unique=True))
+    got = np.asarray(
+        jax.jit(scatter_add_rows)(dv, uniq, delta[: uniq.shape[0]]))
     np.testing.assert_allclose(got, want, rtol=1e-6, atol=1e-6)
     return {"K": K, "W": W, "P": P, "checked": [
         "take", "scatter_add(duplicates)", "scatter_add(unique_indices)"]}

@@ -86,8 +86,7 @@ class _Flags:
         # dictionary (hot keys cost one BIT on the wire); "hash" = the
         # flat key%n placement and full-key census wire (the ablation
         # baseline / kill switch); "loopback" = hybrid plus the
-        # encode->decode wire path exercised even single-process (tests,
-        # bench).
+        # encode->decode wire path exercised even single-process (tests).
         "placement": "hybrid",
         # hybrid-placement device realization kill switch
         # (parallel/sharded_table.py): PBOX_PLACEMENT_REALIZE=0 keeps the
@@ -213,10 +212,6 @@ class _Flags:
         "health_ewma_alpha": 0.3,
         "health_warmup": 3,
         "health_max_alerts": 256,
-        # bench trend history (bench.py + tools/bench_trend.py): path of
-        # the JSONL every emitted bench row appends to ("" = the default
-        # BENCH_HISTORY.jsonl next to bench.py)
-        "bench_history": "",
     }
 
     def __getattr__(self, name: str):
@@ -910,16 +905,6 @@ class TrainerConfig:
     # through SlotObjPool + a CUDA copy stream).  0 = serial feed.  Profiling
     # and tracing never change it: they report the loop that runs.
     prefetch_batches: int = 2
-    # multi-step dispatch: run this many train steps per device program via
-    # lax.scan over host-stacked feeds — amortizes per-step Python/dispatch
-    # overhead (small models, remote devices).  1 = one dispatch per step.
-    # Per-batch dump (need_dump_field) forces 1 (it needs every batch's
-    # predictions); profiling and tracing do not.
-    # With check_nan_inf, the host still only sees the flag after the whole
-    # k-step group, but the scan body short-circuits: ticks after the first
-    # non-finite one pass state through untouched, so at most ONE corrupted
-    # update lands (same blast radius as scan_steps=1).
-    scan_steps: int = 1
     # multi-host planning-plane patience: how long one host-plane KV
     # gather waits for a straggling peer (covers first-compile and
     # capacity-bump recompile stalls; the device collectives it replaced

@@ -1,6 +1,6 @@
 """Synthetic Criteo-like slot data with a learnable click signal.
 
-Used by the e2e tests and bench.py (the reference's e2e template writes
+Used by the e2e tests and chip_smoke.py (the reference's e2e template writes
 inline temp slot files the same way: python/paddle/fluid/tests/unittests/
 test_paddlebox_datafeed.py:71-87).  Each feature sign carries a latent
 weight; the click label is Bernoulli(sigmoid(sum of weights)), so a model
@@ -58,7 +58,7 @@ def stream_line(
     hot_keys: one key per slot that appears in EVERY record (plus one
     noise key drawn per slot) — the controllable signal a streaming test
     flips the label of to watch the served score move.  None = noise
-    keys only (an uncorrelated stream, the bench's append-rate filler).
+    keys only (an uncorrelated stream).
     """
     parts = [f"1 {label}"]
     for s in range(n_sparse_slots):

@@ -55,8 +55,7 @@ from paddlebox_tpu.utils.monitor import stats
 # per-request serving telemetry: counts split by HTTP status class and
 # latency histograms split by (model, status class) — recorded on EVERY
 # path including errors, so a 5xx storm is visible as a latency series,
-# not just a count (the per-shape-bucket p50/p99 bench.py measures
-# offline, live).
+# not just a count.
 _REQUESTS = telemetry.counter(
     "server.requests", help="scoring requests by model + status class"
 )
@@ -575,7 +574,7 @@ class ScoringServer:
                 self.send_header("Content-Length", str(len(body)))
                 if self._trace_id:
                     # echo the request's trace ID on EVERY outcome, so a
-                    # client (or the fleet router's bench) can correlate
+                    # client can correlate
                     # any response — 200 or 500 — with server-side spans
                     self.send_header(
                         trace_context.TRACE_ID_RESPONSE_HEADER,

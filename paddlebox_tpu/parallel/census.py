@@ -23,7 +23,7 @@ collapsed per-process.  This module applies the same collapse to the wire:
     a different wire format) raises the structured
     :class:`CensusProtocolError` instead of silently mis-decoding.
 
-Transports: :class:`LoopbackTransport` (single process — lets tests/bench
+Transports: :class:`LoopbackTransport` (single process — lets tests
 drive the full encode->decode path in vivo), a ``KvChannel.gather_bytes``
 bound method (real multi-host, host-side KV store, main-thread begin_pass
 per the spmd-collective-on-thread contract), and
@@ -130,8 +130,8 @@ class LoopbackTransport:
 class InProcessCensusGroup:
     """N simulated ranks (threads) exchanging census payloads through a
     barrier-coordinated mailbox — the CPU-admissible fleet harness for
-    tests and ``bench.py --hostplane`` (real multi-process JAX collectives
-    cannot execute on the CPU backend; the wire logic is identical)."""
+    tests (real multi-process JAX collectives cannot execute on the CPU
+    backend; the wire logic is identical)."""
 
     def __init__(self, n_ranks: int):
         if n_ranks < 1:

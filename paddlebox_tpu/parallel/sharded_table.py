@@ -414,7 +414,7 @@ class ShardedSparseTable(SparseTable):
 
     def placement_plan(self):
         """The current PlacementPlan, or None when the planner is off —
-        bench/test introspection."""
+        test introspection."""
         if self._census is None or self._census.planner is None:
             return None
         return self._census.planner.plan()
@@ -430,7 +430,7 @@ class ShardedSparseTable(SparseTable):
 
     def hot_resident_keys(self) -> np.ndarray:
         """The device-resident hot set (sorted; slot i of the hot block
-        holds key i) — bench/test introspection."""
+        holds key i) — test introspection."""
         return self._hot_keys
 
     def _drop_hot_residency(self) -> None:
@@ -1158,7 +1158,8 @@ class ShardedSparseTable(SparseTable):
             self._begin_bufs = [self.values, self.g2sum]
         # boundary host traffic: rows that actually crossed host->device
         # (cache misses; everything, cache-off).  With realization on, the
-        # hot tier never lands here — bench pins the collapse to O(cold)
+        # hot tier never lands here — test_placement pins the collapse
+        # to O(cold)
         from paddlebox_tpu import telemetry as _tm
 
         owned = sum(int(shard_keys[o].shape[0]) for o in self._local_pos)

@@ -319,8 +319,7 @@ def sharded_push_and_update(
     # dead-targeted delta first: duplicates then only write unchanged
     # bytes and the unique_indices claim stays benign under any lowering.
     ok = (serve_uniq != cap - 1).astype(delta.dtype)
-    values = scatter_add_rows(values, serve_uniq, delta * ok[:, None],
-                              unique=True)
+    values = scatter_add_rows(values, serve_uniq, delta * ok[:, None])
     g2sum = g2sum.at[serve_uniq].add(g2_delta * ok, unique_indices=True)
     values = values.at[cap - 1].set(0.0)
     g2sum = g2sum.at[cap - 1].set(0.0)

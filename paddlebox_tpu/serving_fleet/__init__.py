@@ -20,8 +20,7 @@ The fleet layer over the packaged scoring stack (ROADMAP item 2(c)):
     saturation, it sheds at the edge.
 
 ``python -m paddlebox_tpu.serve --replicas N --router-port P`` wires all
-three together; ``bench.py --fleet`` proves the SLO story open-loop
-under real SIGKILL chaos.
+three together.
 """
 
 from paddlebox_tpu.serving_fleet.router import (  # noqa: F401

@@ -433,7 +433,7 @@ class FleetRouter:
             _ROUTE_SECONDS.observe(time.perf_counter() - t0,
                                    outcome=outcome)
             # which replica actually served, after any failover: clients
-            # and the bench attribute tail latency without log-diving
+            # attribute tail latency without log-diving
             hdrs[trace_context.REPLICA_RESPONSE_HEADER] = r.addr
             return status, data, hdrs
         if shed is not None:

@@ -442,8 +442,7 @@ class BucketStore:
         ``resident_rows`` report host-tier pressure (captured BEFORE the
         scan below faults spilled buckets back in): how much of the warm
         tier has fallen to disk and how many rows are actually RAM-held —
-        the inputs to HBM-cache sizing and the bench ablation's
-        host-pressure column."""
+        the inputs to HBM-cache sizing."""
         spilled_buckets = int(self._spilled.sum())
         resident_rows = int(
             sum(

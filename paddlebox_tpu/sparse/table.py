@@ -277,7 +277,7 @@ class SparseTable:
         self._cache_tried = False
         self._cache_lock = threading.Lock()
         self._cache_plan = None
-        self.last_cache_hits = 0  # bench/ablation introspection
+        self.last_cache_hits = 0  # of the last begin_pass
         self.last_cache_misses = 0  # == the begin-pass promotion patch rows
         # stats
         self.missing_key_count = 0
@@ -1092,8 +1092,8 @@ class SparseTable:
         The push costs per scatter index, not per byte, so U follows the
         distinct keys the table has seen in a batch and not the buffer's
         capacity.  It is the TABLE's high-water mark, never the batch's:
-        every plan of a settled stream has one length (one compiled step;
-        a scan group stacks).  A batch fits while its keys leave slot U-1
+        every plan of a settled stream has one length (one compiled
+        step).  A batch fits while its keys leave slot U-1
         to the padding occurrences; one that does not moves the mark to a
         power of two with a quarter of headroom — a count that sits on a
         power of two must not flip between two step shapes — and never
@@ -1290,22 +1290,20 @@ class SparseTable:
 # ------------------------------------------------------------------------- #
 # Pure device functions (jit these, or call them inside a larger train_step)
 # ------------------------------------------------------------------------- #
-def scatter_add_rows(values: jax.Array, idx: jax.Array, delta: jax.Array,
-                     unique: bool = False) -> jax.Array:
-    """Row scatter-add; duplicate indices accumulate.  ``unique=True``
-    promises the caller's indices are distinct (the plan's scratch-row
-    construction) and unlocks XLA's parallel scatter lowering.
+def scatter_add_rows(values: jax.Array, idx: jax.Array,
+                     delta: jax.Array) -> jax.Array:
+    """Row scatter-add that promises XLA the indices are distinct (the
+    plan's scratch-row construction), which unlocks its parallel scatter
+    lowering.
 
-    Caveat on the ``unique=True`` promise (ADVICE r4): plan index vectors
-    can still repeat DEAD-ROW entries (scratch-clamped pad slots and the
-    census-missing sink).  Callers zero every dead-targeted delta before
-    the scatter, so any lowering that races duplicate writes only ever
-    writes identical (unchanged) bytes — the claim relies on that
-    add-of-zero idempotence, which XLA's semantics leave formally
-    undefined for non-unique indices.  bench.py's ``--device-profile``
-    push vs push-dup ablation is the A/B check; pass ``unique=False``
-    here if a backend ever miscompiles the pattern."""
-    return values.at[idx].add(delta, unique_indices=unique)
+    Caveat on the promise (ADVICE r4): plan index vectors can still repeat
+    DEAD-ROW entries (scratch-clamped pad slots and the census-missing
+    sink).  Callers zero every dead-targeted delta before the scatter, so
+    any lowering that races duplicate writes only ever writes identical
+    (unchanged) bytes — the claim relies on that add-of-zero idempotence,
+    which XLA's semantics leave formally undefined for non-unique
+    indices."""
+    return values.at[idx].add(delta, unique_indices=True)
 
 
 def pull_rows(
@@ -1349,7 +1347,6 @@ def push_and_update(
     conf: SparseTableConfig,
     key_extras: Optional[jax.Array] = None,
     uniq_lr: Optional[jax.Array] = None,
-    unique_indices: bool = True,
 ):
     """Merge per-occurrence gradients by unique key and apply the sparse
     optimizer + show/clk counter update (reference: PushSparseGradCase,
@@ -1365,12 +1362,9 @@ def push_and_update(
     uniq_lr: optional [U] per-unique-key learning rates (the BoxPS LR-map
         analog: the Trainer resolves each key's slot-group lr host-side,
         reference box_wrapper.h:631 GetLRMap).  None = conf.learning_rate.
-    unique_indices: claim the plan's scatter targets are distinct (True —
-        the plan_keys scratch-row construction guarantees it) and let XLA
-        use the parallel scatter lowering.  False forces the
-        duplicate-safe lowering: numerics are identical either way; the
-        flag exists so bench.py can A/B the lowering cost on hardware.
-    Returns (values, g2sum) updated.
+    Returns (values, g2sum) updated.  Both scatters claim the plan's
+    targets are distinct (the plan_keys scratch-row construction
+    guarantees it; see scatter_add_rows for the dead-row caveat).
     """
     del plan_idx  # pull-side only; kept in the signature for symmetry
     U = plan_uniq_idx.shape[0]
@@ -1405,12 +1399,8 @@ def push_and_update(
     # under any scatter lowering.
     dead = values.shape[0] - 1
     ok = (plan_uniq_idx != dead).astype(delta.dtype)
-    values = scatter_add_rows(
-        values, plan_uniq_idx, delta * ok[:, None], unique=unique_indices
-    )
-    g2sum = g2sum.at[plan_uniq_idx].add(
-        g2_delta * ok, unique_indices=unique_indices
-    )
+    values = scatter_add_rows(values, plan_uniq_idx, delta * ok[:, None])
+    g2sum = g2sum.at[plan_uniq_idx].add(g2_delta * ok, unique_indices=True)
     # the dead row must stay zero (pulls read it as the zero row)
     values = values.at[dead].set(0.0)
     g2sum = g2sum.at[dead].set(0.0)

@@ -142,7 +142,7 @@ def install_compile_listener() -> bool:
 
 
 def compiles_by_stage() -> dict:
-    """{stage: backend-compile count} — the bench-row / pin read surface."""
+    """{stage: backend-compile count} — the read surface of the tier-1 pins."""
     out: dict = {}
     for key, cell in _COMPILES.series().items():
         stage = dict(key).get("stage", UNTAGGED)

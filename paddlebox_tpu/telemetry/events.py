@@ -1,6 +1,6 @@
 """Rank-tagged JSONL event/metrics log.
 
-A headless run (bench, a cron-driven day loop, a pod rank with its stdout
+A headless run (a cron-driven day loop, a pod rank with its stdout
 tee'd away) must leave an ANALYZABLE artifact, not just log lines: one
 JSON object per line, each tagged with wall time and rank, so a pass's
 counters/latency distributions can be joined across ranks and plotted

@@ -68,7 +68,7 @@ def _default_rank() -> int:
 
 
 # --------------------------------------------------------------------------- #
-# run identity: the correlation key across bench rows, dumps and history
+# run identity: the correlation key across a run's dumps
 # --------------------------------------------------------------------------- #
 _identity_lock = threading.Lock()
 _identity: Optional[dict] = None

@@ -111,8 +111,7 @@ def _host_batch_dict(
     vocab_keys: Optional[np.ndarray] = None,
 ) -> dict:
     """Assemble the static-shape feed (numpy leaves) from a HostBatch +
-    BatchPlan — _device_batch without the H2D transfer, so multi-step scan
-    groups can stack on the host and transfer once.
+    BatchPlan — _device_batch without the H2D transfer.
 
     vocab_keys: a model's fixed vocabulary (sorted feasigns); the feed
     then carries "key_class" [K], each occurrence's rank in it
@@ -366,7 +365,6 @@ class Trainer:
             self.opt_state = self.optimizer.init(self.params)
         self._step_fn = None
         self._step_body = None
-        self._scan_fn = None
         self._eval_fn = None
         self.global_step = 0
         self._pass_idx = 0
@@ -519,77 +517,6 @@ class Trainer:
                 guarded, stage="train.step", donate_argnums=(0, 1, 2, 3, 4))
         return counted_jit(
             step, stage="train.step", donate_argnums=(0, 1, 2, 3, 4))
-
-    def _build_scan_step(self):
-        """k steps in ONE dispatch: lax.scan over stacked feeds.  Amortizes
-        per-step Python + runtime dispatch (pays off where dispatch is
-        expensive relative to the step: small models, remote
-        devices, pods with deep software stacks).  XLA compiles the k-step
-        program once; preds/dump are unavailable (use scan_steps=1 when
-        dumping)."""
-        body = self._step_body
-        check_nan = self._check_nan
-        skip_mode = check_nan and self.conf.nan_policy == "skip_batch"
-
-        def scan_fn(params, opt_state, values, g2sum, mstate, feeds):
-            def tick(carry, feed):
-                (p, o, v, g, m), ok = carry
-                if not check_nan:
-                    p, o, v, g, m, loss, finite, _ = body(p, o, v, g, m, feed)
-                    return ((p, o, v, g, m), ok & finite), (loss, finite)
-
-                if skip_mode:
-                    # each tick independently discards its own batch when
-                    # non-finite (state passes through untouched) and later
-                    # ticks proceed normally — the scan analog of the
-                    # guarded single step
-                    np_, no_, nv_, ng_, nm_, loss, finite, _ = body(
-                        p, o, v, g, m, feed
-                    )
-                    state = jax.lax.cond(
-                        finite,
-                        lambda _: (np_, no_, nv_, ng_, nm_),
-                        lambda _: (p, o, v, g, m),
-                        None,
-                    )
-                    return (state, ok), (loss.astype(jnp.float32), finite)
-
-                # with a raising policy, a NaN at tick j must not let ticks
-                # j+1..k-1 keep applying corrupted dense/sparse updates
-                # before the host sees the flag (advisor r3): once ok goes
-                # False the remaining ticks pass state through untouched
-                def run(st):
-                    p, o, v, g, m = st
-                    p, o, v, g, m, loss, finite, _ = body(p, o, v, g, m, feed)
-                    # f32 so both cond branches agree on the loss aval even
-                    # under a bf16 tower
-                    return (p, o, v, g, m), loss.astype(jnp.float32), finite
-
-                def skip(st):
-                    return (
-                        st,
-                        jnp.full((), jnp.nan, jnp.float32),
-                        jnp.array(False),
-                    )
-
-                state, loss, finite = jax.lax.cond(
-                    ok, run, skip, (p, o, v, g, m)
-                )
-                return (state, ok & finite), (loss, finite)
-
-            ((params, opt_state, values, g2sum, mstate), _), (
-                losses, finites
-            ) = jax.lax.scan(
-                tick,
-                ((params, opt_state, values, g2sum, mstate), jnp.array(True)),
-                feeds,
-            )
-            return (
-                params, opt_state, values, g2sum, mstate, losses, finites,
-            )
-
-        return counted_jit(
-            scan_fn, stage="train.scan", donate_argnums=(0, 1, 2, 3, 4))
 
     def _init_mstate(self, auc_state=None) -> dict:
         """Fresh metric state, or continuation: pass the previous pass's
@@ -779,16 +706,9 @@ class Trainer:
                 if wd is not None:
                     wd.start()
 
-        # scan grouping: k steps per device dispatch (disabled while dumping
-        # per-batch fields: the dump needs every batch's predictions)
-        scan_k = self.conf.scan_steps
-        if dumper is not None:
-            scan_k = 1
-        if scan_k > 1 and self._scan_fn is None:
-            self._scan_fn = self._build_scan_step()
-
-        def host_feeds():
-            """(batch, host feed dict) stream: validation + host planning."""
+        def feeds():
+            """(batch, device feed) stream: validation, host planning and
+            the transfer."""
             for batch in prof.iterate(
                     "batch", dataset.batches(drop_last=drop_last)):
                 if wd is not None:
@@ -835,34 +755,9 @@ class Trainer:
                     # nan_policy is exercised end to end on device
                     host["labels"] = np.full_like(host["labels"], np.nan)
                 n_samples[0] += float(batch.ins_mask.sum())
-                yield batch, host
-
-        def feeds():
-            """(kind, batch, device feed): "one" = a single-step feed, "scan"
-            = scan_k host-stacked feeds transferred as one [k, ...] block
-            (the tail shorter than scan_k falls back to single steps)."""
-            buf = []
-            for batch, host in host_feeds():
-                if scan_k <= 1:
-                    with prof.stage("feed"):
-                        dev = _to_device(host)
-                    yield "one", batch, dev
-                    continue
-                buf.append(host)
-                if len(buf) == scan_k:
-                    if len({h["uniq_idx"].shape[0] for h in buf}) > 1:
-                        # a batch of the group moved the table's unique-slot
-                        # bucket (table._uniq_slots): plans of two lengths
-                        # do not stack, so this one group goes step by step
-                        for host in buf:
-                            yield "one", None, _to_device(host)
-                    else:
-                        yield "scan", None, _to_device(
-                            {k: np.stack([h[k] for h in buf]) for k in buf[0]}
-                        )
-                    buf = []
-            for host in buf:  # ragged tail: single-step dispatches
-                yield "one", None, _to_device(host)
+                with prof.stage("feed"):
+                    dev = _to_device(host)
+                yield batch, dev
 
         prefetcher = None
         check_nan = self._check_nan
@@ -873,58 +768,19 @@ class Trainer:
                                 global_step=self.global_step), \
                  device_trace(self.conf.trace_dir or None):
               if self.conf.prefetch_batches > 0:
-                # feed assembly overlaps the device step.  Queue slots hold
-                # scan GROUPS in scan mode: shrink the depth so staged
-                # device memory stays ~prefetch_batches batches either way.
-                # Started inside the pass span: the producer's plan/feed
-                # spans inherit it as their parent.
-                depth = max(1, self.conf.prefetch_batches // max(scan_k, 1))
-                prefetcher = _FeedPrefetcher(feeds(), depth, prof)
+                # feed assembly overlaps the device step.  Started inside
+                # the pass span: the producer's plan/feed spans inherit it
+                # as their parent.
+                prefetcher = _FeedPrefetcher(
+                    feeds(), self.conf.prefetch_batches, prof)
                 feed_iter = prefetcher
               else:
                 feed_iter = feeds()
-              for kind, batch, dev in feed_iter:
+              for batch, dev in feed_iter:
                 # chaos site: a hang here simulates a stalled device step;
                 # the watchdog bounds it and names this process + stage
                 faults.inject("train.step")
                 t_dispatch = time.perf_counter()
-                if kind == "scan":
-                    with prof.stage("step"):
-                        (self.params, self.opt_state, values, g2sum, mstate,
-                         loss_k, finites) = (
-                            self._scan_fn(self.params, self.opt_state,
-                                          values, g2sum, mstate, dev)
-                        )
-                    watch.dispatched(loss_k, t_dispatch)
-                    if wd is not None:
-                        wd.report("step")
-                    k = int(loss_k.shape[0])
-                    # pbox-lint: ignore[host-sync-in-hot-loop] nan gate
-                    # (FLAGS_check_nan_inf analog): the finite flags must
-                    # be read per dispatch to stop/skip; the scan path
-                    # amortizes this one sync over k steps
-                    fin = np.asarray(finites)
-                    if check_nan and not fin.all():
-                        if skip_batches:
-                            # bad ticks already kept pre-batch state on
-                            # device; account for them and keep going
-                            n_bad = int((~fin).sum())
-                            stats.add("train.nan_skipped_steps", n_bad)
-                            good = np.nonzero(fin)[0]
-                            if good.size:
-                                losses.append(loss_k[good])
-                            n_steps += k - n_bad
-                            self.global_step += k - n_bad
-                            continue
-                        raise NonFiniteBatchError(
-                            f"non-finite loss/grad within steps "
-                            f"{self.global_step}..{self.global_step + k - 1} "
-                            "(FLAGS_check_nan_inf analog)"
-                        )
-                    losses.append(loss_k)  # [k] device vector
-                    n_steps += k
-                    self.global_step += k
-                    continue
                 with prof.stage("step"):
                     (self.params, self.opt_state, values, g2sum, mstate,
                      loss, finite, preds) = (
@@ -944,11 +800,10 @@ class Trainer:
                         # state: this batch contributed nothing — no
                         # update, no metrics, no dump, no step count
                         stats.add("train.nan_skipped_steps")
-                        if batch is not None:
-                            stats.add(
-                                "train.nan_skipped_ins",
-                                float(batch.ins_mask.sum()),
-                            )
+                        stats.add(
+                            "train.nan_skipped_ins",
+                            float(batch.ins_mask.sum()),
+                        )
                         continue
                     raise NonFiniteBatchError(
                         f"non-finite loss/grad at step {self.global_step} "

@@ -3,7 +3,7 @@
 A cold start compiles every program the run uses, and on the chip that is
 the larger part of a short run.  JAX can keep compiled programs on disk,
 keyed by program, compile options, device kind — and the directory's own
-path, so a cache that moves never hits.  Process entry points (bench.py,
+path, so a cache that moves never hits.  Process entry points (benchmark/run.py,
 chip_smoke.py, ``python -m paddlebox_tpu.serve``, the examples; launch.py
 hands it to its children through the environment) call
 :func:`enable_compile_cache` before their first compile.  The package never

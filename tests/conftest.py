@@ -21,13 +21,6 @@ os.environ["XLA_FLAGS"] = flags.strip()
 os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ.setdefault("JAX_ENABLE_X64", "0")
 
-# Tests that exercise bench.py stages go through its emit() path, which
-# appends every row to the bench-trend history file.  Test rows must
-# never pollute the checked-in BENCH_HISTORY at the repo root.
-os.environ.setdefault(
-    "PBOX_BENCH_HISTORY", os.path.join("/tmp", f"pbox-test-bench-{os.getpid()}.jsonl")
-)
-
 import jax  # noqa: E402
 
 jax.config.update("jax_platforms", "cpu")

@@ -53,7 +53,6 @@ def test_scanner_finds_literal_reads():
     refs = mod.referenced_vars()
     assert "PBOX_COORDINATOR_ADDRESS" in refs  # launch.py env injection
     assert "PBOX_HADOOP_BIN" in refs  # utils/fs.py direct read
-    assert "PBOX_BENCH_HISTORY" in refs  # bench.py history target
 
 
 def test_docs_cover_referenced_vars():

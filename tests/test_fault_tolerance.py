@@ -5,6 +5,7 @@ under injected failures, and the trainer's NaN policies."""
 import json
 import os
 
+import jax
 import numpy as np
 import pytest
 
@@ -380,47 +381,93 @@ class TestNanPolicy:
         table.end_pass()
         ds.close()
 
-    def test_skip_batch_discards_only_the_bad_batch(self, tmp_path):
-        clean_ds, clean_table, clean_trainer = _world(tmp_path, sub="c")
-        m_clean = _run_pass(clean_ds, clean_table, clean_trainer)
-        clean_ds.close()
+    @pytest.mark.parametrize(
+        "bad", [(1,), (1, 2), (7,)], ids=["one", "two_in_a_row", "last"])
+    def test_skip_batch_discards_only_the_bad_batch(self, tmp_path, bad):
+        """A skipped batch leaves nothing behind, wherever it falls in the
+        pass: the run equals, bit for bit, the same program fed the pass
+        without the poisoned batches."""
+        conf = TrainerConfig(auc_buckets=1 << 10, nan_policy="skip_batch")
+        ref_ds, ref_table, ref_trainer = _world(
+            tmp_path, sub="c", trainer_conf=conf)
+        ref_table.begin_pass(ref_ds.unique_keys())
+        m_ref = ref_trainer.train_steps(
+            ref_table,
+            (b for i, b in enumerate(ref_ds.batches()) if i not in bad))
+        ref_table.end_pass()
+        ref_ds.close()
 
-        ds, table, trainer = _world(
-            tmp_path, sub="c",
-            trainer_conf=TrainerConfig(
-                auc_buckets=1 << 10, nan_policy="skip_batch",
-            ),
-        )
-        with fault_plan({"train.nan": "at:1"}):  # poison the second batch
+        ds, table, trainer = _world(tmp_path, sub="c", trainer_conf=conf)
+        with fault_plan({"train.nan": "at:" + ",".join(map(str, bad))}):
             m = _run_pass(ds, table, trainer)
         ds.close()
-        assert m["steps"] == m_clean["steps"] - 1
+        assert m["steps"] == m_ref["steps"] == 128 // B - len(bad)
         assert trainer.global_step == m["steps"]
-        assert stats.get("train.nan_skipped_steps") == 1
-        assert stats.get("train.nan_skipped_ins") == B
-        # skipped batch's instances are absent from the metrics
-        assert m["count"] == m_clean["count"] - B
-        # and the model still learned from everything else
-        assert np.isfinite(m["loss"])
-        assert abs(m["auc"] - m_clean["auc"]) < 0.1
+        assert stats.get("train.nan_skipped_steps") == len(bad)
+        assert stats.get("train.nan_skipped_ins") == len(bad) * B
+        # skipped batches' instances are absent from the metrics
+        assert m["count"] == m_ref["count"] == 128 - len(bad) * B
+        assert m["loss"] == m_ref["loss"] and np.isfinite(m["loss"])
+        assert m["auc"] == m_ref["auc"]
+        got, want = table.state_dict(), ref_table.state_dict()
+        np.testing.assert_array_equal(got["keys"], want["keys"])
+        np.testing.assert_array_equal(got["values"], want["values"])
+        for got, want in zip(jax.tree.leaves(trainer.params),
+                             jax.tree.leaves(ref_trainer.params)):
+            np.testing.assert_array_equal(got, want)
 
-    def test_skip_batch_under_scan(self, tmp_path):
-        """Scan groups skip per-tick: one poisoned batch inside a 2-step
-        group discards only that tick's update and metrics."""
-        ds, table, trainer = _world(
-            tmp_path, sub="c3",
-            trainer_conf=TrainerConfig(
-                auc_buckets=1 << 10, nan_policy="skip_batch", scan_steps=2,
-            ),
-        )
-        with fault_plan({"train.nan": "at:1"}):
-            m = _run_pass(ds, table, trainer)
+    @pytest.mark.parametrize("policy", ["raise", "skip_batch"])
+    def test_poisoned_batch_blast_radius(self, tmp_path, policy):
+        """What one poisoned batch (the third of eight) leaves behind at
+        one step a dispatch: the table holds live buffers again and
+        end_pass() succeeds; rows of keys the batch does not touch equal
+        the run of the clean batches that were dispatched; under
+        ``raise`` no later batch is dispatched, under ``skip_batch`` not
+        even the batch's own rows move."""
+        from paddlebox_tpu import telemetry
+
+        BAD = 2
+        conf = TrainerConfig(
+            auc_buckets=1 << 10, nan_policy=policy, check_nan_inf=True)
+        ran = (0, 1) if policy == "raise" else tuple(
+            i for i in range(128 // B) if i != BAD)
+        ref_ds, ref_table, ref_trainer = _world(
+            tmp_path, sub="b", trainer_conf=conf)
+        batches = list(ref_ds.batches())
+        bad_keys = batches[BAD].keys[: batches[BAD].n_keys]
+        ref_table.begin_pass(ref_ds.unique_keys())
+        ref_trainer.train_steps(ref_table, (batches[i] for i in ran))
+        ref_table.end_pass()
+        ref_ds.close()
+
+        def dispatches():
+            return telemetry.registry.snapshot()["counters"].get(
+                "trainer.dispatches", 0)
+
+        ds, table, trainer = _world(tmp_path, sub="b", trainer_conf=conf)
+        table.begin_pass(ds.unique_keys())
+        n0 = dispatches()
+        with fault_plan({"train.nan": f"at:{BAD}"}):
+            if policy == "raise":
+                with pytest.raises(FloatingPointError):
+                    trainer.train_from_dataset(ds, table)
+            else:
+                trainer.train_from_dataset(ds, table)
+        assert dispatches() - n0 == (BAD + 1 if policy == "raise" else 8)
+        assert trainer.global_step == len(ran)
+        # the donated buffers were handed back: readable, and end_pass works
+        assert np.asarray(table.values).shape[0] == np.asarray(
+            table.g2sum).shape[0]
+        table.end_pass()
         ds.close()
-        assert stats.get("train.nan_skipped_steps") == 1
-        assert m["steps"] == 128 // B - 1
-        assert m["count"] == 128 - B
-        assert trainer.global_step == m["steps"]
-        assert np.isfinite(m["loss"])
+        got, want = table.state_dict(), ref_table.state_dict()
+        np.testing.assert_array_equal(got["keys"], want["keys"])
+        clean = ~np.isin(got["keys"], bad_keys)
+        assert clean.any() and not clean.all()
+        np.testing.assert_array_equal(
+            got["values"][clean], want["values"][clean])
+        if policy == "skip_batch":
+            np.testing.assert_array_equal(got["values"], want["values"])
 
     def test_skip_batch_is_deterministic(self, tmp_path):
         runs = []

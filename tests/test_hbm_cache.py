@@ -50,25 +50,43 @@ def _tconf(cache_rows: int, **kw) -> SparseTableConfig:
     )
 
 
-@pytest.fixture(scope="module")
-def pass_datasets(tmp_path_factory):
-    """N_PASSES loaded datasets over a SHARED key space (vocab 40: heavy
-    census overlap, so steady-state passes have real cache hits)."""
+def _load_passes(tmp_path_factory, name: str, **synth_kw):
     conf = make_synth_config(
         n_sparse_slots=N_SLOTS, dense_dim=DENSE, batch_size=64,
         max_feasigns_per_ins=16,
     )
     datasets = []
     for p in range(N_PASSES):
-        d = tmp_path_factory.mktemp(f"cpass{p}")
+        d = tmp_path_factory.mktemp(f"{name}{p}")
         files = write_synth_files(
             str(d), n_files=2, ins_per_file=192, n_sparse_slots=N_SLOTS,
-            vocab_per_slot=40, dense_dim=DENSE, seed=23 + p,
+            dense_dim=DENSE, seed=23 + p, **synth_kw,
         )
         ds = PadBoxSlotDataset(conf, read_threads=2)
         ds.set_filelist(files)
         ds.load_into_memory()
         datasets.append(ds)
+    return conf, datasets
+
+
+@pytest.fixture(scope="module")
+def pass_datasets(tmp_path_factory):
+    """N_PASSES loaded datasets over a SHARED key space (vocab 40: heavy
+    census overlap, so steady-state passes have real cache hits)."""
+    conf, datasets = _load_passes(
+        tmp_path_factory, "cpass", vocab_per_slot=40)
+    yield conf, datasets
+    for ds in datasets:
+        ds.close()
+
+
+@pytest.fixture(scope="module")
+def zipf_datasets(tmp_path_factory):
+    """N_PASSES over a Zipf-skewed stream (a = 1.3 over 300 ids a slot): a
+    hot head every pass sees and a cold tail that turns over, which is
+    what the row cache is for."""
+    conf, datasets = _load_passes(
+        tmp_path_factory, "zpass", vocab_per_slot=300, zipf_a=1.3)
     yield conf, datasets
     for ds in datasets:
         ds.close()
@@ -119,16 +137,28 @@ def _assert_state_equal(a, b):
 
 
 class TestBitExact:
-    def test_single_chip_cached_matches_uncached(self, pass_datasets):
-        _, datasets = pass_datasets
-        sd_u, delta_u, m_u, _ = _run_single_chip(datasets, 0)
-        sd_c, delta_c, m_c, table = _run_single_chip(datasets, 1 << 16)
+    # zipf: no checkpoint restore, no shrink — nothing empties the cache,
+    # so the last pass reads its hot head from it
+    @pytest.mark.parametrize("stream, kw", [
+        ("pass_datasets", {}),
+        ("zipf_datasets", {"shrink_at": -1, "ckpt_at": -1}),
+    ], ids=["uniform", "zipf"])
+    def test_single_chip_cached_matches_uncached(self, request, stream, kw):
+        _, datasets = request.getfixturevalue(stream)
+        sd_u, delta_u, m_u, _ = _run_single_chip(datasets, 0, **kw)
+        sd_c, delta_c, m_c, table = _run_single_chip(datasets, 1 << 16, **kw)
         _assert_state_equal(sd_u, sd_c)
         _assert_state_equal(delta_u, delta_c)
         assert m_u["auc"] == m_c["auc"]
         assert m_u["loss"] == m_c["loss"]
         # the cache actually participated: post-shrink passes re-warm it
         assert table.last_cache_hits + table.last_cache_misses > 0
+        if stream == "zipf_datasets":
+            # the host supplied the cold tail only, not the census
+            census = int(datasets[-1].unique_keys().shape[0])
+            assert table.last_cache_hits > 0
+            assert 0 < table.last_cache_misses < census
+            assert table.last_cache_hits + table.last_cache_misses == census
 
     def test_single_chip_tiny_cache_eviction_churn(self, pass_datasets):
         # capacity far below the working set: admission + eviction every
@@ -390,22 +420,3 @@ class TestTelemetryAndKillSwitch:
         ram.update(keys, np.ones((4000, 3), np.float32))
         st = ram.stats()
         assert st["spilled_buckets"] == 0 and st["resident_rows"] == 4000
-
-
-def test_bench_hbm_cache_smoke():
-    """Fast CPU smoke of the bench ablation: bit-exact, a positive hit
-    rate on the skewed stream, and the cached promotion patch strictly
-    below the census (the cold-key count)."""
-    from bench import bench_hbm_cache
-
-    res = bench_hbm_cache(
-        3, SparseTableConfig(embedding_dim=4),
-        TrainerConfig(auc_buckets=1 << 10), n_slots=2, dense=2, bsz=32,
-        ins_per_pass=64, hidden=(8,), vocab_per_slot=300,
-    )
-    assert res["bitexact"]
-    assert res["cached_hit_rate"] > 0
-    assert (
-        res["cached_promotion_patch_rows"]
-        < res["uncached_promotion_patch_rows"]
-    )

@@ -386,24 +386,3 @@ def test_error_isolation_in_shared_batch(tmp_path):
         assert st_b == 400
     finally:
         srv.stop()
-
-
-# --------------------------------------------------------------------------- #
-# bench sweep smoke: the qps-sweep path cannot rot
-# --------------------------------------------------------------------------- #
-def test_bench_qps_sweep_smoke():
-    """One tiny open-loop point through bench.py's sweep driver: both the
-    batched and the max_batch=1 baseline curves come back with zero
-    failed requests (the non-slow guard for `bench.py --serving
-    --qps-sweep`)."""
-    from bench import bench_serving_sweep
-
-    res = bench_serving_sweep([8.0], duration_s=1.2, n_slots=3, dense=2,
-                              req_lines=4, ins_per_file=48, hidden=(8,))
-    for curve in ("batched_curve", "unbatched_curve"):
-        pts = res[curve]
-        assert len(pts) == 1
-        assert pts[0]["failed"] == 0
-        assert pts[0]["ok"] > 0
-        assert pts[0]["p99_ms"] is not None
-    assert res["max_batch"] > 1

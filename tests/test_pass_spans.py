@@ -312,43 +312,37 @@ def test_profiling_runs_the_same_loop(tmp_path, mode, monkeypatch):
             threads.append(self._thread)
 
     monkeypatch.setattr(trainer_mod, "_FeedPrefetcher", Spy)
-    built = []
-    real_scan = Trainer._build_scan_step
-    monkeypatch.setattr(
-        Trainer, "_build_scan_step",
-        lambda self: built.append(1) or real_scan(self))
 
     def run(sub, **kw):
         threads.clear()
-        built.clear()
         n_sync = _hist("trainer.step_complete_seconds")["count"]
-        ds, trainer, table = _world(tmp_path / sub, scan_steps=2, **kw)
+        ds, trainer, table = _world(tmp_path / sub, **kw)
         m = _one_pass(ds, trainer, table)
         trainer.close()
         ds.close()
-        return m, len(threads), len(built), (
+        return m, len(threads), (
             _hist("trainer.step_complete_seconds")["count"] - n_sync)
 
-    plain, plain_threads, plain_scan, plain_dispatches = run("plain")
+    plain, plain_threads, plain_dispatches = run("plain")
     kw = ({"profile": True} if mode == "profile" else
           {"telemetry": TelemetryConfig(trace_dir=str(tmp_path / "tr"))})
     try:
-        prof, prof_threads, prof_scan, prof_dispatches = run(mode, **kw)
+        prof, prof_threads, prof_dispatches = run(mode, **kw)
     finally:
         telemetry.disable_tracing()
     assert "profile" not in plain
-    assert prof["loss"] == plain["loss"] and prof["steps"] == plain["steps"]
+    assert prof["loss"] == plain["loss"]
+    assert prof["steps"] == plain["steps"] == 6
     assert prof_threads == plain_threads == 1  # a live prefetch thread
-    assert prof_scan == plain_scan == 1  # scan_k stays 2
-    assert prof_dispatches == plain_dispatches == 3  # 6 steps, 2 a dispatch
+    assert prof_dispatches == plain_dispatches == 6  # one step a dispatch
     report = prof["profile"]
     assert report["steps"] == prof["steps"]
     for stage in ("plan", "feed", "step"):
         assert report[f"{stage}_sec"] >= 0.0
         assert report[f"{stage}_count"] >= 1
         assert f"{stage}_ms_per_step" in report
-    assert report["complete_count"] == 3
-    assert report["stage_quantiles"]["complete"]["count"] == 3
+    assert report["complete_count"] == 6
+    assert report["stage_quantiles"]["complete"]["count"] == 6
     assert set(report["stage_quantiles"]["step"]) == {
         "p50_ms", "p99_ms", "count"}
 

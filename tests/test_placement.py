@@ -38,7 +38,7 @@ from paddlebox_tpu.train.trainer import Trainer
 S, DENSE = 3, 2
 
 
-def _make_data(tmp_path, seed=7, n_ins=256, bsz=16, vocab=60):
+def _make_data(tmp_path, seed=7, n_ins=256, bsz=16, vocab=60, zipf_a=0.0):
     conf = make_synth_config(
         n_sparse_slots=S, dense_dim=DENSE, batch_size=bsz,
         max_feasigns_per_ins=16,
@@ -46,6 +46,7 @@ def _make_data(tmp_path, seed=7, n_ins=256, bsz=16, vocab=60):
     files = write_synth_files(
         str(tmp_path), n_files=2, ins_per_file=n_ins // 2,
         n_sparse_slots=S, vocab_per_slot=vocab, dense_dim=DENSE, seed=seed,
+        zipf_a=zipf_a,
     )
     ds = PadBoxSlotDataset(conf, read_threads=2)
     ds.set_filelist(files)
@@ -447,59 +448,92 @@ def test_hybrid_reduce_bitexact_across_reruns(tmp_path, n_dev):
     assert auc_a == auc_b
 
 
-def test_hybrid_zero_host_row_bytes_inside_pass(tmp_path):
-    """The structural pin of the realized layout: once a key is hot and
-    resident, its rows NEVER cross the host plane — zero row bytes of any
-    kind inside a pass, and boundary traffic exactly O(cold rows) with a
-    steady census (no churn -> zero hot migration bytes too)."""
+_HOST_CTRS = ("pass.host_row_bytes_in", "pass.host_row_bytes_out",
+              "placement.hot_row_host_bytes")
+
+
+def _host_plane_run(passes, hot_capacity, realize=True):
+    """Train ``passes`` (datasets) on a loopback-placement sharded table
+    with no row cache, so every host row move is counted.  Returns each
+    pass's counter deltas over (begin_pass, train, end_pass), the resident
+    hot keys and the final store."""
     from paddlebox_tpu.telemetry import registry
 
     mesh = make_mesh(min(8, len(jax.devices())))
     tconf = SparseTableConfig(
         embedding_dim=4, placement="loopback",
-        placement_update_interval=1, placement_hot_capacity=32,
-        hbm_cache_rows=0,  # no cache: every host row move is counted
+        placement_update_interval=1, placement_hot_capacity=hot_capacity,
+        placement_realize=realize, hbm_cache_rows=0,
     )
     trconf = TrainerConfig(auc_buckets=1 << 10)
     model = CtrDnn(S, tconf.row_width, dense_dim=DENSE, hidden=(8,))
     trainer = MultiChipTrainer(model, tconf, mesh, trconf, seed=3)
     table = ShardedSparseTable(tconf, mesh, seed=5, bucket_slack=8.0)
-    conf, ds = _make_data(tmp_path / "pin", seed=11)
-    keys = ds.unique_keys()
-    for _ in range(3):  # aged frequency clears enter_freq; block realizes
-        table.begin_pass(keys)
+
+    def ctrs():
+        c = registry.snapshot()["counters"]
+        return np.array([c.get(name, 0) for name in _HOST_CTRS])
+
+    deltas = []
+    for ds in passes:
+        s0 = ctrs()
+        table.begin_pass(ds.unique_keys())
+        s1 = ctrs()
         trainer.train_from_dataset(ds, table)
+        s2 = ctrs()
         table.end_pass()
-    n_hot = table.hot_resident_keys().shape[0]
-    assert n_hot > 0, "hot block never realized"
-    n_cold = int(keys.shape[0]) - n_hot
-    row_b = 4 * (tconf.row_width + 1)
-
-    def ctr(snap, name):
-        return snap["counters"].get(name, 0)
-
-    s0 = registry.snapshot()
-    table.begin_pass(keys)
-    s1 = registry.snapshot()
-    trainer.train_from_dataset(ds, table)
-    s2 = registry.snapshot()
-    table.end_pass()
-    s3 = registry.snapshot()
-    ds.close()
+        deltas.append((s1 - s0, s2 - s1, ctrs() - s2))
+    hot = table.hot_resident_keys()
+    state = table.state_dict()
     table.close()
-    # inside the pass: zero host-plane row bytes, hot or cold
-    for c in ("pass.host_row_bytes_in", "pass.host_row_bytes_out",
-              "placement.hot_row_host_bytes"):
-        assert ctr(s2, c) == ctr(s1, c), f"{c} moved inside a pass"
-    # steady census: zero hot-tier migration bytes across the boundary
-    assert ctr(s3, "placement.hot_row_host_bytes") == ctr(
-        s0, "placement.hot_row_host_bytes")
-    # boundary traffic is exactly the cold tail: resident hot rows ride
-    # neither the begin_pass fill nor the end_pass write-back
-    assert ctr(s1, "pass.host_row_bytes_in") - ctr(
-        s0, "pass.host_row_bytes_in") == n_cold * row_b
-    assert ctr(s3, "pass.host_row_bytes_out") - ctr(
-        s2, "pass.host_row_bytes_out") == n_cold * row_b
+    return deltas, hot, state, 4 * (tconf.row_width + 1)
+
+
+@pytest.mark.parametrize("case", ["steady_census", "zipf_against_wire"])
+def test_hybrid_zero_host_row_bytes_inside_pass(tmp_path, case):
+    """The structural pin of the realized layout: once a key is hot and
+    resident, its rows NEVER cross the host plane — zero row bytes of any
+    kind inside a pass.  ``steady_census``: boundary traffic exactly
+    O(cold rows), and no churn -> zero hot migration bytes too.
+    ``zipf_against_wire``: three passes of a Zipf-skewed stream, each
+    with its own census, against the wire-plane-only arm
+    (``placement_realize=False``): the realized arm's last begin_pass
+    pays less host row traffic, and the stores are bit-identical."""
+    if case == "steady_census":
+        conf, ds = _make_data(tmp_path / "pin", seed=11)
+        keys = ds.unique_keys()
+        # three passes: aged frequency clears enter_freq, the block
+        # realizes; the fourth is the one measured
+        deltas, hot, _, row_b = _host_plane_run([ds] * 4, hot_capacity=32)
+        ds.close()
+        assert hot.shape[0] > 0, "hot block never realized"
+        n_cold = int(keys.shape[0]) - hot.shape[0]
+        begin, inside, end = deltas[-1]
+        # inside the pass: zero host-plane row bytes, hot or cold
+        assert not inside.any(), dict(zip(_HOST_CTRS, inside))
+        # steady census: zero hot-tier migration bytes across the boundary
+        assert begin[2] == 0 and end[2] == 0
+        # boundary traffic is exactly the cold tail: resident hot rows ride
+        # neither the begin_pass fill nor the end_pass write-back
+        assert begin[0] == n_cold * row_b
+        assert end[1] == n_cold * row_b
+        return
+    passes = [
+        _make_data(tmp_path / f"z{p}", seed=91 + p, vocab=300, zipf_a=1.3)[1]
+        for p in range(3)
+    ]
+    wire, hot_w, st_w, _ = _host_plane_run(passes, 512, realize=False)
+    hybrid, hot_h, st_h, _ = _host_plane_run(passes, 512)
+    for ds in passes:
+        ds.close()
+    assert hot_w.shape[0] == 0 and hot_h.shape[0] > 0, \
+        "hybrid arm never realized"
+    for _, inside, _ in wire + hybrid:
+        assert not inside.any(), dict(zip(_HOST_CTRS, inside))
+    assert hybrid[-1][0][0] < wire[-1][0][0], \
+        "realized hot rows still paying begin-pass host traffic"
+    np.testing.assert_array_equal(st_w["keys"], st_h["keys"])
+    np.testing.assert_array_equal(st_w["values"], st_h["values"])
 
 
 # --------------------------------------------------------------------------- #
@@ -555,34 +589,3 @@ def test_plan_churn_zero_retrace(tmp_path):
         "traced shape"
     )
     assert versions[0] >= 1, "the planner never planned"
-
-
-# --------------------------------------------------------------------------- #
-# bench smoke (non-slow, CPU)
-# --------------------------------------------------------------------------- #
-def test_bench_hostplane_smoke():
-    """Fast CPU smoke of bench.py --hostplane: the collapse, the >= 4x
-    codec ratio and the bit-exact check all hold at toy scale, and the
-    emitted row carries every acceptance field."""
-    from bench import bench_hostplane
-
-    res = bench_hostplane(
-        3, SparseTableConfig(embedding_dim=4, placement_hot_capacity=512),
-        TrainerConfig(auc_buckets=1 << 10), n_slots=2, dense=2, bsz=32,
-        ins_per_pass=128, hidden=(8,), vocab_per_slot=300,
-    )
-    assert res["bitexact"]
-    assert res["hot_resident_rows"] > 0, "hybrid arm never realized"
-    assert (
-        res["hybrid_host_row_bytes_in_last_pass"]
-        < res["wire_host_row_bytes_in_last_pass"]
-    ), "realized hot rows still paying begin-pass host traffic"
-    assert res["census_compression_x"] >= 4.0
-    assert (
-        res["planned_varint_bytes_per_pass"]
-        < res["hash_raw_bytes_per_pass"]
-    )
-    assert res["shuffle_key_bytes_encoded"] < res["shuffle_key_bytes_raw"]
-    for field in ("gather_p50_ms", "gather_p99_ms"):
-        assert res[f"planned_varint_{field}"] >= 0
-    assert res["samples_per_sec"] > 0

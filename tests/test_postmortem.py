@@ -523,7 +523,7 @@ def test_stream_tail_hang_dumps_flight_with_feed_stage(tmp_path,
 # the headline e2e: fleet + SIGKILL + lineage, judged by the doctor
 # --------------------------------------------------------------------------- #
 def test_e2e_sigkill_fleet_postmortem_via_doctor(tmp_path, monkeypatch):
-    """A bench --fleet-style run: 3 real replica server PROCESSES behind
+    """A fleet run under chaos: 3 real replica server PROCESSES behind
     the router, one SIGKILLed mid-stream; a real Publisher→Syncer chain
     shipping lineage-stamped model units in parallel.  Everything is
     asserted on ``pbox_doctor.analyze``'s parsed output: the killed

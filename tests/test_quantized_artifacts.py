@@ -97,15 +97,17 @@ def _sparse_payload_bytes(art):
 def test_quantized_auc_delta_and_bytes(tmp_path):
     """int8 AND fp8 artifacts score the synthetic CTR eval within
     0.005 AUC of the fp32 artifact, at a fraction of its payload bytes
-    (the acceptance criterion's quality gate; the ~30%-of-fp32 bytes
-    figure at production embedding widths is bench.py --quantized's)."""
-    from bench import _rank_auc
+    (the acceptance criterion's quality gate).  AUC is the package's own
+    streaming one (metrics/auc.py), 2^20 score buckets."""
+    from paddlebox_tpu.metrics.auc import (
+        compute_metrics, init_auc_state, update_auc_state)
 
     conf, ds, model, table, trainer = _train_small(tmp_path / "d")
     kcap = conf.batch_key_capacity or KCAP
     labels = []
     for batch in ds.batches(drop_last=False):
         labels.extend(batch.labels[: batch.n_real_ins].tolist())
+    labels = np.asarray(labels, np.float32)
     auc, payload = {}, {}
     for dt in ("fp32", "int8", "fp8"):
         art = str(tmp_path / f"art-{dt}")
@@ -114,7 +116,9 @@ def test_quantized_auc_delta_and_bytes(tmp_path):
         pred = Predictor.load(art)
         assert pred.embedding_dtype == dt
         scores = np.concatenate(list(pred.predict_dataset(ds)))
-        auc[dt] = _rank_auc(scores, labels)
+        auc[dt] = compute_metrics(update_auc_state(
+            init_auc_state(), scores.astype(np.float32), labels,
+            np.ones_like(labels)))["auc"]
         payload[dt] = _sparse_payload_bytes(art)
         if dt != "fp32":
             assert pred._quantized and pred.artifact_bytes > 0

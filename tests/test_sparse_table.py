@@ -312,18 +312,17 @@ def test_plan_bucket_stops_at_the_key_capacity(planner):
     assert (small.inverse[40:] == 63).all()
 
 
-# case -> (per-slot lr, (scan_steps, unique side) of the run under test and
-# of the run it must equal).  "bucket" is the plan as the table emits it,
-# "capacity" the same plan with the scratch slots it dropped appended again
-# (uniq_idx at K, as before the bucket), "moved" the bucket for the first
-# batch and K from the second on: a mark that moves inside a scan group.
+# case -> (per-slot lr, unique side of the run under test, of the run it
+# must equal).  "bucket" is the plan as the table emits it, "capacity" the
+# same plan with the scratch slots it dropped appended again (uniq_idx at
+# K, as before the bucket), "moved" the bucket for the first batch and K
+# from the second on: a mark that moves between two steps of one pass.
 _PUSH_CASES = {
-    "plain": (False, (1, "bucket"), (1, "capacity")),
-    "slot_lr": (True, (1, "bucket"), (1, "capacity")),
-    "scan2": (False, (2, "bucket"), (2, "capacity")),
-    # plans of two lengths do not stack: the group goes step by step, and
-    # so equals the single-step run bit for bit
-    "scan2_moved": (False, (2, "moved"), (1, "bucket")),
+    "plain": (False, "bucket", "capacity"),
+    "slot_lr": (True, "bucket", "capacity"),
+    "moved": (False, "moved", "bucket"),
+    # the feed's uniq_lr follows the plan's length from step to step
+    "moved_slot_lr": (True, "moved", "bucket"),
 }
 
 
@@ -341,7 +340,7 @@ def test_bucketed_push_equals_push_at_capacity(tmp_path, case):
                               n_sparse_slots=S, vocab_per_slot=40,
                               dense_dim=2, seed=5)
 
-    def run(scan_steps, side):
+    def run(side):
         ds = PadBoxSlotDataset(conf, read_threads=1)
         ds.set_filelist(files)
         ds.load_into_memory()
@@ -350,7 +349,7 @@ def test_bucketed_push_equals_push_at_capacity(tmp_path, case):
         table = SparseTable(tconf, seed=0)
         trainer = Trainer(
             CtrDnn(S, tconf.row_width, dense_dim=2, hidden=(8,)), tconf,
-            TrainerConfig(auc_buckets=1 << 10, scan_steps=scan_steps), seed=0)
+            TrainerConfig(auc_buckets=1 << 10), seed=0)
         assert (trainer._slot_lr_vec is not None) == slot_lr
         plan_keys = table.plan_keys
         lengths = []
@@ -380,8 +379,8 @@ def test_bucketed_push_equals_push_at_capacity(tmp_path, case):
         ds.close()
         return m, live, state, jax.tree.leaves(trainer.params)
 
-    m_a, live_a, state_a, params_a = run(*under_test)
-    m_b, live_b, state_b, params_b = run(*reference)
+    m_a, live_a, state_a, params_a = run(under_test)
+    m_b, live_b, state_b, params_b = run(reference)
     assert m_a["loss"] == m_b["loss"]
     for a, b in zip(live_a + tuple(params_a), live_b + tuple(params_b)):
         np.testing.assert_array_equal(np.asarray(a), np.asarray(b))

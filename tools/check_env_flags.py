@@ -33,7 +33,7 @@ def flag_vars() -> dict:
 
 def referenced_vars() -> dict:
     """{var: first 'file:line' seen}: flag-shim entries + every literal
-    PBOX_* token in the package source and bench.py."""
+    PBOX_* token in the package source."""
     return rules_drift.env_referenced_vars()
 
 

@@ -1,8 +1,7 @@
 """Shared ARCHITECTURE.md catalog scraping and source discovery.
 
 The five original ``tools/check_*.py`` guards each re-implemented the
-same three pieces: walking ``paddlebox_tpu/`` + ``bench.py`` for source
-files, scraping backticked first-column names out of an ARCHITECTURE.md
+same three pieces: walking ``paddlebox_tpu/`` for source files, scraping backticked first-column names out of an ARCHITECTURE.md
 section's table, and turning a regex match offset into a ``file:line``
 string.  This module is the single home for all three; the drift passes
 (rules_drift.py) and the thin legacy wrappers both build on it.
@@ -18,10 +17,9 @@ from .core import REPO
 ARCH = os.path.join(REPO, "ARCHITECTURE.md")
 README = os.path.join(REPO, "README.md")
 
-#: the roots the legacy guards scan — the shipped package plus the bench
-#: driver, deliberately NOT tools/ (the guards' own regex fixture
-#: strings would self-trigger).
-GUARD_ROOTS = ("paddlebox_tpu", "bench.py")
+#: the roots the legacy guards scan — the shipped package, deliberately
+#: NOT tools/ (the guards' own regex fixture strings would self-trigger).
+GUARD_ROOTS = ("paddlebox_tpu",)
 
 # backticked names in a catalog table's first column
 _TABLE_ROW_RE = re.compile(r"^\|\s*`([^`]+)`")

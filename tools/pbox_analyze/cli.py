@@ -6,7 +6,7 @@ bad git ref).
 
 Modes:
 
-  --all                analyze the default roots (package, tools, bench)
+  --all                analyze the default roots (package, tools)
   PATH [PATH ...]      analyze specific files/directories instead
   --changed [REF]      findings only on lines touched vs the git ref
                        (default HEAD) — the fast pre-commit entry point
@@ -100,7 +100,7 @@ def main(argv=None) -> int:
                     help="files/dirs to analyze (default: --all roots)")
     ap.add_argument("--all", action="store_true",
                     help="analyze the default roots (paddlebox_tpu/, "
-                         "tools/, bench.py)")
+                         "tools/)")
     ap.add_argument("--json", action="store_true",
                     help="emit findings as JSON")
     ap.add_argument("--rules", metavar="A,B",

@@ -39,10 +39,10 @@ def cached_walk(node: "ast.AST"):
     _WALK_CACHE[key] = (node, nodes)
     return nodes
 
-#: what ``--all`` analyzes: the package, the tools themselves, and the
-#: bench driver.  tests/ is deliberately out — test code wedges threads
-#: and swallows exceptions on purpose.
-DEFAULT_ROOTS = ("paddlebox_tpu", "tools", "bench.py")
+#: what ``--all`` analyzes: the package and the tools themselves.
+#: tests/ is deliberately out — test code wedges threads and swallows
+#: exceptions on purpose.
+DEFAULT_ROOTS = ("paddlebox_tpu", "tools")
 
 _SUPPRESS_RE = re.compile(
     r"#\s*pbox-lint:\s*ignore\[([a-z0-9_\-, ]+)\]\s*(.*)"

@@ -117,13 +117,10 @@ NP_MATERIALIZERS = frozenset({"asarray", "array"})
 
 #: iterator call bases that mark a loop as per-batch/per-step even when
 #: no jitted dispatch is visible in its body (prefetchers hide it).
-HOT_ITER_CALLS = frozenset({"batches", "feeds", "host_feeds"})
+HOT_ITER_CALLS = frozenset({"batches", "feeds"})
 
 #: a sink under an ``if`` whose condition mentions one of these tokens
 #: is a deliberate, gated readback (profiling sync, field dumping) —
 #: recognized legal, no annotation needed.
 GUARD_TOKENS = ("prof", "debug", "trace", "dump", "verbose")
 
-#: files exempt from host-sync-in-hot-loop: the bench driver's timing
-#: loops synchronize per step ON PURPOSE — that is the measurement.
-HOST_SYNC_EXEMPT_FILES = ("bench.py",)

@@ -10,8 +10,8 @@ These passes scan text with regexes rather than the AST: metric/span
 names live inside f-strings and comments as much as calls, and the env
 check deliberately reads *prose* (a comment citing a stale flag name
 should fail too).  They share the Context only for suppression and
-reporting; their file set is the guard roots (package + bench.py), not
-the analyzer roots.
+reporting; their file set is the guard roots (the package), not the
+analyzer roots.
 """
 
 from __future__ import annotations

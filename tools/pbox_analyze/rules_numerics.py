@@ -58,8 +58,7 @@ catalog in :mod:`num_catalog`:
     Recognized-legal without annotation: syncs AFTER the loop (the
     pass-boundary D2H snapshot / end-of-pass merge idiom), and syncs
     under a profiling/dump/debug guard (``if prof.enabled:`` — the
-    deliberate instrumented path).  bench.py is exempt by catalog: its
-    timing loops synchronize per step on purpose.
+    deliberate instrumented path).
 
 All per-function memos (dtype envs, sync summaries, jit-bound tables)
 live under ``ctx.caches["numerics"]`` so a full ``--all`` stays inside
@@ -78,7 +77,6 @@ from .num_catalog import (
     FLOAT_TAGS,
     FUSED_DEQUANT_FILES,
     GUARD_TOKENS,
-    HOST_SYNC_EXEMPT_FILES,
     HOT_ITER_CALLS,
     JIT_WRAP_CALLS,
     KEY_ATTR_NAMES,
@@ -863,8 +861,6 @@ def _device_env(eng: NumEngine, fi, fnodes) -> set:
 def _host_sync(eng: NumEngine, fi, fnodes) -> list:
     findings: list = []
     sf = fi.sf
-    if sf.rel.endswith(HOST_SYNC_EXEMPT_FILES):
-        return findings
     loops = fnodes.loops
     if not loops:
         return findings
