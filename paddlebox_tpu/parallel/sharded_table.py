@@ -363,7 +363,7 @@ class ShardedSparseTable(SparseTable):
                 for c in self._caches():
                     used = np.nonzero(c.used)[0]
                     if used.shape[0]:
-                        planner.seed(c.keys[used], c.freq[used])
+                        planner.seed(c.keys[used], c.frequency(used))
                 # evidence carried across a reshard cutover: the previous
                 # planner's full tracker, so the hot set stays warm
                 if self._carry_freq is not None:
@@ -1024,23 +1024,24 @@ class ShardedSparseTable(SparseTable):
         # the overlay consistency point for patch-log filtering
         return pk, owner, shard_keys, row_within, lvals, shot, stage_seq
 
-    def _cached_sync_resolve(self, caches, shard_keys, lvals, pk) -> list:
+    def _cached_sync_resolve(self, caches, shard_keys, lvals, pk):
         """Synchronous per-shard census resolve against the HBM cache:
-        fill only each shard's cache misses from the host store.  A
+        fill only each shard's cache misses from the host store.  Returns
+        (caches, plans): each local shard's census as resolved against
+        its directory, for the device hit-fill to reuse.  A
         fault-injected promotion fetch (site ``cache.fetch``) degrades the
         whole pass to the uncached host resolve — dirty rows drain first,
-        census keys leave every cache — and returns [] so the caller skips
-        the device hit-fill."""
+        census keys leave every cache — and returns ([], None) so the
+        caller skips the device hit-fill."""
         from paddlebox_tpu import telemetry
         from paddlebox_tpu.utils import faults
 
+        plans = []
         try:
             for i, o in enumerate(self._local_pos):
                 sk = shard_keys[o]
-                if not sk.shape[0]:
-                    continue
-                hit = caches[i].lookup(sk).hit_mask
-                miss_pos = np.nonzero(~hit)[0]
+                plans.append(caches[i].lookup(sk))
+                miss_pos = np.nonzero(~plans[i].hit_mask)[0]
                 if miss_pos.shape[0]:
                     with _PASS.stage("fetch"):
                         lvals[i, miss_pos] = self._cache_fetch_rows(
@@ -1056,8 +1057,8 @@ class ShardedSparseTable(SparseTable):
                 for i, o in enumerate(self._local_pos):
                     sk = shard_keys[o]
                     lvals[i, : sk.shape[0]] = self._resolve_or_init(sk)
-            return []
-        return caches
+            return [], None
+        return caches, plans
 
     @stage_scope("pass.begin")
     def begin_pass(self, pass_keys: np.ndarray) -> None:
@@ -1116,6 +1117,7 @@ class ShardedSparseTable(SparseTable):
             else:
                 stats.add("pass.stage_discards")
         caches = self._caches()
+        plans = None  # the staged path resolves at the fill
         pass_hits = 0  # cache hits filled from device THIS pass
         if lvals is None:
             with _PASS.stage("census"):
@@ -1131,7 +1133,7 @@ class ShardedSparseTable(SparseTable):
                 lvals = np.zeros(
                     (self.n_local, cap, w + 1), dtype=np.float32)
             if caches:
-                caches = self._cached_sync_resolve(
+                caches, plans = self._cached_sync_resolve(
                     caches, shard_keys, lvals, cold_pk
                 )
             else:
@@ -1149,7 +1151,8 @@ class ShardedSparseTable(SparseTable):
             # one process-local global-array construction — a computation
             # over the GLOBAL arrays here would be a collective whose
             # program depends on per-rank cache state (deadlock multi-host)
-            self._assemble_cached(lvals, shard_keys, caches, cold_pk, sharding)
+            self._assemble_cached(
+                lvals, shard_keys, caches, cold_pk, sharding, plans)
             pass_hits = self.last_cache_hits
         else:
             with _PASS.stage("upload"):
@@ -1198,7 +1201,7 @@ class ShardedSparseTable(SparseTable):
         self._begin_bufs = []
 
     def _assemble_cached(self, lvals, shard_keys, caches, pk,
-                         sharding) -> None:
+                         sharding, plans=None) -> None:
         """Cached promotion: per LOCAL shard, put the miss-filled host
         buffer on the shard's own device, overwrite the cache hits with a
         single-device gather out of that shard's persistent cache, and
@@ -1206,26 +1209,28 @@ class ShardedSparseTable(SparseTable):
         (make_array_from_single_device_arrays — a pure construction, no
         collective).  Multi-host, the census exchange already agreed pk
         fleet-wide, so shapes match across ranks even though every rank's
-        hit pattern differs."""
+        hit pattern differs.  ``plans``: the per-shard resolves of the
+        sync miss fetch; without them (the staged path) each shard's
+        census is resolved here."""
         from paddlebox_tpu import telemetry
 
         w = self.conf.row_width
         cap = lvals.shape[1]
         devs = [self.mesh.devices[int(o)] for o in self._local_pos]
-        vbufs, gbufs, plans = [], [], []
+        vbufs, gbufs = [], []
+        if plans is None:
+            plans = [caches[i].lookup(shard_keys[o])
+                     for i, o in enumerate(self._local_pos)]
         total_hits = 0
-        for i, o in enumerate(self._local_pos):
-            sk = shard_keys[o]
+        for i, plan in enumerate(plans):
             with _PASS.stage("upload"):
                 lv = jax.device_put(lvals[i], devs[i])  # [cap, W+1]
-            plan = caches[i].lookup(sk)
             if plan.n_hits:
                 with _PASS.stage("fill"):
                     hr = caches[i].gather_rows(plan.hit_slots)
                     lv = lv.at[
                         jax.device_put(plan.hit_pos, devs[i])].set(hr)
             caches[i].touch(plan)
-            plans.append(plan)
             total_hits += plan.n_hits
             vbufs.append(lv[None, :, :w])
             gbufs.append(lv[None, :, w])

@@ -15,11 +15,20 @@ directory is ~tens of bytes per slot and mutates once per pass, while the
 rows are the multi-KB-per-slot payload whose round trip the cache exists to
 eliminate.
 
-Policy: LFU with aging.  Every pass multiplies all resident frequencies by
+Policy: LFU with aging.  Every pass ages all resident frequencies by
 ``aging`` and adds 1 to this census's hits; admission (at end_pass, from
 the pass census) fills free slots first, then evicts the
 lowest-(frequency, recency) resident slots not touched by the current pass
-whose aged frequency has fallen below a fresh candidate's (1.0).  Eviction
+whose aged frequency has fallen below a fresh candidate's (1.0).  The
+ageing is applied on READ: a boundary's directory work follows its census,
+not the capacity.  The directory stores every frequency in units of
+``aging ** ticks`` (``_freq`` = frequency × ``_unit``, where ``_unit`` grows
+by 1/``aging`` a pass), so one scalar division ages every slot at once and
+``touch`` writes only the census's hits; ``frequency()`` reads a slot's
+value back, and the eviction test (< 1.0) compares the stored form with
+``_unit`` itself.
+Once in some hundreds of passes, before ``_unit`` leaves float64's range,
+the whole array is rescaled by an exact power of two.  Eviction
 and admission move only directory state here — the owning table moves the
 rows (device scatter for admits, D2H + host write-back for evictions: an
 evicted row is ALWAYS written back, dirty or not, so a pre-staged next
@@ -41,12 +50,14 @@ pair.
 from __future__ import annotations
 
 import dataclasses
+import math
 from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
+from paddlebox_tpu import telemetry
 from paddlebox_tpu.utils.profiler import StatsProfiler
 
 # the directory's share of a pass boundary, by stage (lookup / touch /
@@ -55,6 +66,10 @@ _PASS = StatsProfiler("pass.stage_seconds")
 
 _EMPTY_U64 = np.empty(0, dtype=np.uint64)
 _EMPTY_I32 = np.empty(0, dtype=np.int32)
+
+# ``_unit`` past this is folded back into the stored frequencies: far from
+# float64's 2**1024 even after the 1/(1-aging) a slot hit every pass sums to
+_RESCALE_AT = 2.0 ** 512
 
 
 @dataclasses.dataclass
@@ -117,7 +132,9 @@ class HbmCache:
         # directory (slot-indexed)
         self.keys = np.zeros(self.capacity, dtype=np.uint64)
         self.used = np.zeros(self.capacity, dtype=bool)
-        self.freq = np.zeros(self.capacity, dtype=np.float64)
+        # frequency × _unit (module docstring): read through frequency()
+        self._freq = np.zeros(self.capacity, dtype=np.float64)
+        self._unit = 1.0
         self.last_seen = np.full(self.capacity, -1, dtype=np.int64)
         self.dirty = np.zeros(self.capacity, dtype=bool)
         self.tick = 0
@@ -177,16 +194,31 @@ class HbmCache:
         return CachePlan(hit, hit_pos, self._sorted_slots[pos[hit]])
 
     # -- policy ----------------------------------------------------------- #
+    def frequency(self, slots: np.ndarray) -> np.ndarray:
+        """The aged frequency of ``slots`` as of this pass."""
+        return self._freq[slots] / self._unit
+
     @_PASS.wrap("touch")
     def touch(self, plan: CachePlan) -> None:
-        """One pass observed: age every resident frequency, credit this
-        census's hits (metadata only — membership is untouched, so the
-        staging snapshot stays valid without the table lock)."""
-        if self.used.any():
-            self.freq[self.used] *= self.aging
+        """One pass observed: age every resident frequency (one step of
+        ``_unit``), credit this census's hits (metadata only — membership
+        is untouched, so the staging snapshot stays valid without the
+        table lock)."""
+        if self._unit > _RESCALE_AT:
+            # the only pass over the capacity: an exact power of two, so
+            # no frequency is rounded and no order or tie changes
+            down = 0.5 ** math.frexp(self._unit)[1]
+            self._freq *= down
+            self._unit *= down
+        self._unit /= self.aging
         if plan.n_hits:
-            self.freq[plan.hit_slots] += 1.0
+            self._freq[plan.hit_slots] += self._unit
             self.last_seen[plan.hit_slots] = self.tick
+        telemetry.counter(
+            "cache.aged_slots",
+            "cache slots whose frequency a pass's touch aged and credited "
+            "(the census's hits, not the resident rows)",
+        ).inc(plan.n_hits)
         self.tick += 1
 
     @_PASS.wrap("plan_update")
@@ -197,17 +229,25 @@ class HbmCache:
         decision — ``commit_update`` applies it."""
         miss_pos = np.nonzero(~plan.hit_mask)[0].astype(np.int32)
         n_cand = miss_pos.shape[0]
+        if not n_cand:  # nothing to admit: no scan of the capacity
+            return UpdatePlan(
+                admit_pos=_EMPTY_I32, admit_keys=_EMPTY_U64,
+                admit_slots=_EMPTY_I32, victim_slots=_EMPTY_I32,
+                victim_keys=_EMPTY_U64, cold_pos=_EMPTY_I32,
+            )
         free = np.nonzero(~self.used)[0].astype(np.int32)
         n_free = min(n_cand, free.shape[0])
         victim_slots = _EMPTY_I32
         if n_cand > n_free:
-            evictable = self.used.copy()
+            # aged frequency < 1.0, on the stored form: no division of
+            # the whole capacity
+            evictable = self.used & (self._freq < self._unit)
             evictable[plan.hit_slots] = False  # never evict a current hit
-            cand_slots = np.nonzero(evictable & (self.freq < 1.0))[0]
+            cand_slots = np.nonzero(evictable)[0]
             if cand_slots.shape[0]:
                 order = np.lexsort(
                     (cand_slots, self.last_seen[cand_slots],
-                     self.freq[cand_slots])
+                     self.frequency(cand_slots))
                 )
                 n_evict = min(n_cand - n_free, cand_slots.shape[0])
                 victim_slots = cand_slots[order[:n_evict]].astype(np.int32)
@@ -234,7 +274,7 @@ class HbmCache:
         if upd.admit_slots.shape[0]:
             self.keys[upd.admit_slots] = upd.admit_keys
             self.used[upd.admit_slots] = True
-            self.freq[upd.admit_slots] = 1.0
+            self._freq[upd.admit_slots] = self._unit
             self.last_seen[upd.admit_slots] = self.tick
             self.dirty[upd.admit_slots] = True
         if plan.n_hits:
@@ -332,6 +372,7 @@ class HbmCache:
         decay/evict).  Callers needing the rows preserved drain() first."""
         self.used[:] = False
         self.dirty[:] = False
-        self.freq[:] = 0.0
+        self._freq[:] = 0.0
+        self._unit = 1.0
         self.last_seen[:] = -1
         self._rebuild_index()

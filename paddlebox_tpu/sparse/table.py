@@ -773,14 +773,17 @@ class SparseTable:
         ).observe(time.monotonic() - self._last_end_t)
         self._last_end_t = None
 
-    def _cache_plan_and_fill(self, cache, pk: np.ndarray, v: jax.Array):
-        """Resolve the census against the cache directory, fill every hit
-        position of the device buffer ``v`` [cap, W+1] straight from HBM
-        (hits never touch the host), and record the pass's plan + hit-rate
-        telemetry.  Returns (plan, v)."""
+    def _cache_plan_and_fill(self, cache, pk: np.ndarray, v: jax.Array,
+                             plan=None):
+        """Fill every hit position of the device buffer ``v`` [cap, W+1]
+        straight from HBM (hits never touch the host), and record the
+        pass's plan + hit-rate telemetry.  ``plan`` is the census as
+        begin_pass already resolved it against the directory; the staged
+        path has none and resolves here.  Returns (plan, v)."""
         from paddlebox_tpu import telemetry
 
-        plan = cache.lookup(pk)
+        if plan is None:
+            plan = cache.lookup(pk)
         if plan.n_hits:
             with _PASS.stage("fill"):
                 v = v.at[jnp.asarray(plan.hit_pos)].set(
@@ -831,9 +834,9 @@ class SparseTable:
         # zeros ON the device -- no [cap, W+1] host buffer allocated and
         # uploaded only to be overwritten by the cache's fill (at rows
         # 2,307 floats wide that buffer is 302 MB a boundary)
-        hit_mask = (cache.lookup(pk).hit_mask
-                    if vals is None and cache is not None else None)
-        all_hit = hit_mask is not None and bool(hit_mask.all())
+        plan = (cache.lookup(pk)
+                if vals is None and cache is not None else None)
+        all_hit = plan is not None and plan.n_hits == n
         if vals is None and not all_hit:
             with _PASS.stage("alloc"):
                 vals = np.zeros((cap, w + 1), dtype=np.float32)
@@ -842,7 +845,7 @@ class SparseTable:
                     vals[:n] = self._resolve_or_init(pk)
             else:
                 try:
-                    miss_pos = np.nonzero(~hit_mask)[0]
+                    miss_pos = np.nonzero(~plan.hit_mask)[0]
                     if miss_pos.shape[0]:
                         with _PASS.stage("fetch"):
                             vals[miss_pos] = self._cache_fetch_rows(
@@ -856,10 +859,9 @@ class SparseTable:
                         "promotion fetches degraded to the full host resolve",
                     ).inc()
                     self._cache_degrade(pk)
-                    cache = None
+                    cache = plan = None  # its directory moved
                     with _PASS.stage("fetch"):
                         vals[:n] = self._resolve_or_init(pk)
-        plan = None
         if all_hit:
             with _PASS.stage("alloc"):  # on the device: nothing to upload
                 v = jnp.zeros((cap, w + 1), jnp.float32)
@@ -870,7 +872,7 @@ class SparseTable:
             # staged path included: current-miss positions carry staged
             # rows (+ write-back patches — evictions always write back),
             # current hits are overwritten from HBM here
-            plan, v = self._cache_plan_and_fill(cache, pk, v)
+            plan, v = self._cache_plan_and_fill(cache, pk, v, plan)
         # host-plane promotion volume (same counter both planes —
         # parallel/sharded_table.py): every census row the device could
         # not fill from its own HBM tier crossed host->device here

@@ -299,6 +299,125 @@ class TestCacheBehavior:
         assert t.n_features == 79
 
 
+class _DenseRule:
+    """The eager form of the directory's policy, kept as the oracle of the
+    age-on-read one: every pass multiplies ALL resident frequencies by
+    ``aging``, then credits the census's hits."""
+
+    def __init__(self, capacity: int, aging: float):
+        self.aging = aging
+        self.keys = np.zeros(capacity, np.uint64)
+        self.used = np.zeros(capacity, bool)
+        self.freq = np.zeros(capacity, np.float64)
+        self.last_seen = np.full(capacity, -1, np.int64)
+        self.tick = 0
+
+    def observe(self, pk: np.ndarray) -> dict:
+        """One census through lookup, touch, plan_update, commit_update."""
+        slot_of = {int(k): s for s, k in enumerate(self.keys) if self.used[s]}
+        hit = np.array([int(k) in slot_of for k in pk])
+        hit_slots = np.array([slot_of[int(k)] for k in pk[hit]], np.int64)
+        self.freq[self.used] *= self.aging
+        self.freq[hit_slots] += 1.0
+        self.last_seen[hit_slots] = self.tick
+        self.tick += 1
+        miss_pos = np.nonzero(~hit)[0]
+        free = np.nonzero(~self.used)[0][: miss_pos.shape[0]]
+        evictable = self.used & (self.freq < 1.0)
+        evictable[hit_slots] = False
+        cand = np.nonzero(evictable)[0]
+        order = np.lexsort((cand, self.last_seen[cand], self.freq[cand]))
+        victims = cand[order[: miss_pos.shape[0] - free.shape[0]]]
+        victim_keys = self.keys[victims]
+        admit_slots = np.concatenate([free, victims])
+        admit_pos = miss_pos[: admit_slots.shape[0]]
+        self.keys[admit_slots] = pk[admit_pos]
+        self.used[admit_slots] = True
+        self.freq[admit_slots] = 1.0
+        self.last_seen[admit_slots] = self.tick
+        return dict(hit_slots=hit_slots, admit_slots=admit_slots,
+                    victim_slots=victims, victim_keys=victim_keys,
+                    cold_pos=miss_pos[admit_slots.shape[0]:])
+
+
+class TestAgeOnRead:
+    """The directory ages a frequency when it is read, not every slot at
+    every boundary: it must decide what the dense rule decides."""
+
+    # the long cases cross the point where the stored unit is folded back
+    # into the frequencies (aging ** -passes is past 2 ** 512; at 0.5 it
+    # would be past float64 altogether)
+    @pytest.mark.parametrize("aging, n_passes", [
+        (0.5, 60), (0.8, 60), (0.95, 60), (0.5, 1100), (0.8, 1700),
+    ])
+    def test_decides_what_the_dense_rule_decides(self, aging, n_passes):
+        from paddlebox_tpu.sparse.engine.hbm_cache import HbmCache
+
+        rng = np.random.default_rng(7)
+        cache = HbmCache(256, 4, aging=aging, materialize_rows=False)
+        dense = _DenseRule(256, aging)
+        n_victims = n_cold = 0
+        for p in range(n_passes):
+            # a Zipf head that stays and a tail that turns over: two
+            # passes of ~110 distinct keys fill the free slots, then
+            # 60-370 a pass, so the third pass on evicts and the large
+            # ones leave misses cold
+            size = 200 if p < 2 else int(rng.integers(100, 900))
+            pk = np.unique(rng.zipf(1.2, size=size).astype(np.uint64)
+                           % np.uint64(5000))
+            plan = cache.lookup(pk)
+            cache.touch(plan)
+            upd = cache.plan_update(pk, plan)
+            cache.commit_update(plan, upd)
+            want = dense.observe(pk)
+            got = dict(hit_slots=plan.hit_slots, admit_slots=upd.admit_slots,
+                       victim_slots=upd.victim_slots,
+                       victim_keys=upd.victim_keys, cold_pos=upd.cold_pos)
+            for name, w in want.items():
+                assert np.array_equal(got[name], w), (p, name)
+            assert np.array_equal(cache.used, dense.used), p
+            used = np.nonzero(dense.used)[0]
+            np.testing.assert_allclose(
+                cache.frequency(used), dense.freq[used], rtol=1e-12, atol=0)
+            assert np.array_equal(cache.last_seen[used],
+                                  dense.last_seen[used]), p
+            if p >= 2:
+                n_victims += upd.victim_slots.shape[0]
+                n_cold += upd.cold_pos.shape[0]
+        assert n_victims > n_passes and n_cold > 0  # there was turnover
+        assert np.isfinite(cache._freq).all()
+        if -np.log2(aging) * n_passes > 512:
+            assert cache._unit < 2.0 ** 513  # the rescale ran
+
+    def test_touch_ages_the_census_hits_and_resolves_once(self):
+        """Over passes whose census is all hits, ``cache.aged_slots``
+        follows the census, not the resident rows, and the directory is
+        resolved once a begin_pass."""
+        from paddlebox_tpu import telemetry
+
+        def read():
+            snap = telemetry.registry.snapshot()
+            return (snap["counters"].get("cache.aged_slots", 0.0),
+                    snap["histograms"].get(
+                        "pass.stage_seconds{stage=lookup}",
+                        {"count": 0})["count"])
+
+        t = SparseTable(_tconf(1 << 10), seed=0)
+        t.begin_pass(np.arange(1, 401, dtype=np.uint64))
+        t.end_pass()
+        assert t._caches()[0].resident == 400
+        census = np.arange(1, 51, dtype=np.uint64)
+        for _ in range(3):
+            aged, lookups = read()
+            t.begin_pass(census)
+            assert t.last_cache_hits == 50 and t.last_cache_misses == 0
+            aged1, lookups1 = read()
+            assert aged1 - aged == 50
+            assert lookups1 - lookups == 1
+            t.end_pass()
+        t.flush()
+
+
 class TestChaos:
     def test_fetch_fault_falls_back_to_host_resolve(self, pass_datasets):
         """An injected cache.fetch failure must degrade to the synchronous
