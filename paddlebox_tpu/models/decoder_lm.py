@@ -23,25 +23,39 @@ object (no flag, no config key):
   * ``step_counters`` -- named sums that ride the donated metric state and
     are published as telemetry counters at the pass's read-back.
 
-The layer, for x [B, T, H] (no biases; ``n`` = RMSNorm, eps 1e-6, learned
-scale):
+The layer is assembled from the model's description, for x [B, T, H] (no
+biases; ``n`` = RMSNorm, eps 1e-6, learned scale):
 
-    x += o(attn(rope(q(n1 x)), rope(k(n1 x)), v(n1 x); mask_l))
-    x += moe(n2 x)
+    x += attn_l(n1 x)        layer_types[l]: window, full or latent
+    x += ffn_l(n2 x)         mlp_types[l]: dense, or sparse (+ shared)
 
 ``layer_types[l]`` is ``"sliding_attention"`` (causal, a window of
-``window`` keys, plain rotary code) or ``"full_attention"`` (causal, YaRN
-rotary code).  Grouped queries: ``n_heads`` query heads over
-``n_kv_heads`` key-value heads (parallel/sequence.py, blockwise: no [T, T]
-tensor).  ``moe``: a router over all ``n_experts``, the ``n_experts_per_
-tok`` best renormalised, SwiGLU experts of width ``expert_width``, of which
-this share holds ``experts_held = (lo, hi)`` and computes their part of the
-sum (parallel/expert.py ``routed_experts``: drop-free, what absent experts
-would add is left out).  The head scores the ``len(vocab_keys)`` classes
-held here; the loss is the mean over positions t < T-1 with a next token of
-the softmax cross-entropy against that token's class, in token chunks so
-the [tokens, classes] logits are never whole in memory.  Each layer is
-rematerialised in the backward pass (``jax.checkpoint``, one per layer).
+``window`` keys, plain rotary code), ``"full_attention"`` (causal, YaRN
+rotary code) -- both ``o(attn(rope(q), rope(k), v))`` with grouped queries,
+``n_heads`` query heads over ``n_kv_heads`` key-value heads -- or
+``"latent_attention"`` (causal; ``latent`` gives its widths): the keys and
+values of all heads are projected up from ONE normed latent of ``kv_rank``
+floats a token, a head's query and key are ``qk_nope`` such floats beside
+``qk_rope`` floats that carry the rotary code (plain, adjacent pairs where
+``interleaved``), the turned key slice is one per token shared by all
+heads, and the value head is ``v_dim`` wide (parallel/sequence.py,
+blockwise: no [T, T] tensor).
+
+``mlp_types[l]`` is ``"dense"`` (one SwiGLU of ``dense_width``) or
+``"sparse"``: a router over all ``n_experts`` (``router_score`` softmax or
+sigmoid, with ``router_bias`` a per-expert selection bias that is a leaf
+of the tree, enters the choice only and so is never updated), the
+``n_experts_per_tok`` best renormalised and scaled by ``router_scale``,
+SwiGLU experts of width ``expert_width``, of which this share holds
+``experts_held = (lo, hi)`` and computes their part of the sum
+(parallel/expert.py ``routed_experts``: drop-free, what absent experts
+would add is left out), plus, where ``shared_width`` > 0, the experts every
+share computes alike as one unweighted SwiGLU of that width.  The head
+scores the ``len(vocab_keys)`` classes held here; the loss is the mean over
+positions t < T-1 with a next token of the softmax cross-entropy against
+that token's class, in token chunks so the [tokens, classes] logits are
+never whole in memory.  Each layer is rematerialised in the backward pass
+(``jax.checkpoint``, one per layer).
 """
 
 from __future__ import annotations
@@ -52,14 +66,21 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from paddlebox_tpu.parallel.expert import route_tokens, routed_experts
+from paddlebox_tpu.parallel.expert import (
+    route_tokens,
+    routed_experts,
+    swiglu,
+)
 from paddlebox_tpu.parallel.sequence import (
     apply_rotary,
     full_attention,
     rotary_tables,
 )
 
-SLIDING, FULL = "sliding_attention", "full_attention"
+SLIDING, FULL, LATENT = (
+    "sliding_attention", "full_attention", "latent_attention")
+DENSE, SPARSE = "dense", "sparse"
+LATENT_KEYS = ("kv_rank", "qk_nope", "qk_rope", "v_dim", "interleaved")
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
@@ -93,8 +114,10 @@ _take_once.defvjp(
 
 
 class DecoderMoeLM:
-    """Decoder with window and full attention layers and token-routed
-    experts, trained on next-token prediction through the pass loop."""
+    """Decoder whose layers are assembled from a description -- window,
+    full or latent attention; a dense feed-forward or token-routed experts
+    with or without shared ones -- trained on next-token prediction
+    through the pass loop."""
 
     uses_seq_pos = True
     n_sparse_slots = 1
@@ -125,13 +148,31 @@ class DecoderMoeLM:
         cvm_offset: int = 2,
         block_q: int = 256,
         loss_chunk: int = 2048,
+        mlp_types: Optional[Sequence[str]] = None,  # None = all sparse
+        dense_width: int = 0,  # the SwiGLU of a "dense" layer
+        shared_width: int = 0,  # shared experts as one SwiGLU; 0 = none
+        router_score: str = "softmax",  # or "sigmoid"
+        router_bias: bool = False,  # a selection-bias leaf, never updated
+        router_scale: float = 1.0,
+        latent: Optional[dict] = None,  # LATENT_KEYS, for latent layers
     ):
         vocab_keys = np.asarray(vocab_keys, dtype=np.uint64)
         if vocab_keys.ndim != 1 or not np.all(vocab_keys[1:] > vocab_keys[:-1]):
             raise ValueError("vocab_keys must be sorted, distinct feasigns")
-        bad = [t for t in layer_types if t not in (SLIDING, FULL)]
+        bad = [t for t in layer_types if t not in (SLIDING, FULL, LATENT)]
         if bad:
             raise ValueError(f"unknown layer types {sorted(set(bad))}")
+        mlp_types = tuple(mlp_types or (SPARSE,) * len(layer_types))
+        bad = [t for t in mlp_types if t not in (DENSE, SPARSE)]
+        if bad or len(mlp_types) != len(layer_types):
+            raise ValueError(
+                f"mlp_types {mlp_types} for {len(layer_types)} layers")
+        if DENSE in mlp_types and dense_width <= 0:
+            raise ValueError("a dense layer needs dense_width")
+        if LATENT in layer_types and set(latent or ()) != set(LATENT_KEYS):
+            raise ValueError(f"latent layers need latent={LATENT_KEYS}")
+        if router_score not in ("softmax", "sigmoid"):
+            raise ValueError(f"unknown router score {router_score!r}")
         if n_heads % n_kv_heads:
             raise ValueError(
                 f"{n_heads} query heads over {n_kv_heads} key-value heads")
@@ -148,6 +189,11 @@ class DecoderMoeLM:
         self.n_heads, self.n_kv_heads = n_heads, n_kv_heads
         self.head_dim = head_dim
         self.layer_types = tuple(layer_types)
+        self.mlp_types = mlp_types
+        self.dense_width, self.shared_width = dense_width, shared_width
+        self.router_score, self.router_bias = router_score, router_bias
+        self.router_scale = float(router_scale)
+        self.latent = latent
         self.window = window
         self.n_experts, self.top_k = n_experts, n_experts_per_tok
         self.expert_width = expert_width
@@ -157,42 +203,77 @@ class DecoderMoeLM:
         self.block_q, self.loss_chunk = block_q, loss_chunk
 
     # -- params ------------------------------------------------------------ #
-    def init(self, key: jax.Array) -> dict:
-        """Normal weights scaled by 1/sqrt(fan-in), norm scales 1."""
+    def _layer_weights(self, attn_kind: str, mlp_kind: str) -> list:
+        """(name, shape, what a standard normal draw is divided by) of one
+        layer's seeded leaves, in the order their keys are drawn."""
         H, F = self.hidden, self.expert_width
-        hq, hkv = self.n_heads * self.head_dim, self.n_kv_heads * self.head_dim
         held = self.experts_held[1] - self.experts_held[0]
 
-        def w(k, *shape, fan_in):
-            return jax.random.normal(k, shape, jnp.float32) / np.sqrt(fan_in)
+        def w(name, *shape, fan_in):
+            return name, shape, np.sqrt(fan_in)
 
+        if attn_kind == LATENT:
+            z, nh = self.latent, self.n_heads
+            out = [
+                w("wq", H, nh * (z["qk_nope"] + z["qk_rope"]), fan_in=H),
+                w("wkv_a", H, z["kv_rank"] + z["qk_rope"], fan_in=H),
+                w("wkv_b", z["kv_rank"], nh * (z["qk_nope"] + z["v_dim"]),
+                  fan_in=z["kv_rank"]),
+                w("wo", nh * z["v_dim"], H, fan_in=nh * z["v_dim"]),
+            ]
+        else:
+            hq = self.n_heads * self.head_dim
+            hkv = self.n_kv_heads * self.head_dim
+            out = [w("wq", H, hq, fan_in=H), w("wk", H, hkv, fan_in=H),
+                   w("wv", H, hkv, fan_in=H), w("wo", hq, H, fan_in=hq)]
+        if mlp_kind == DENSE:
+            D = self.dense_width
+            return out + [w("mlp_gate", H, D, fan_in=H),
+                          w("mlp_up", H, D, fan_in=H),
+                          w("mlp_down", D, H, fan_in=D)]
+        out.append(w("router", H, self.n_experts, fan_in=H))
+        if self.router_bias:  # wide enough to change some choices
+            out.append(("router_bias", (self.n_experts,), 10.0))
+        out += [w("w_gate", held, H, F, fan_in=H),
+                w("w_up", held, H, F, fan_in=H),
+                w("w_down", held, F, H, fan_in=F)]
+        if self.shared_width:
+            S = self.shared_width
+            out += [w("shared_gate", H, S, fan_in=H),
+                    w("shared_up", H, S, fan_in=H),
+                    w("shared_down", S, H, fan_in=S)]
+        return out
+
+    def init(self, key: jax.Array) -> dict:
+        """Normal weights scaled by 1/sqrt(fan-in), norm scales 1."""
+        H = self.hidden
         layers = []
-        for lk in jax.random.split(key, len(self.layer_types) + 1)[1:]:
-            ks = jax.random.split(lk, 8)
-            layers.append({
-                "n1": jnp.ones((H,), jnp.float32),
-                "n2": jnp.ones((H,), jnp.float32),
-                "wq": w(ks[0], H, hq, fan_in=H),
-                "wk": w(ks[1], H, hkv, fan_in=H),
-                "wv": w(ks[2], H, hkv, fan_in=H),
-                "wo": w(ks[3], hq, H, fan_in=hq),
-                "router": w(ks[4], H, self.n_experts, fan_in=H),
-                "w_gate": w(ks[5], held, H, F, fan_in=H),
-                "w_up": w(ks[6], held, H, F, fan_in=H),
-                "w_down": w(ks[7], held, F, H, fan_in=F),
-            })
+        for lk, attn_kind, mlp_kind in zip(
+                jax.random.split(key, len(self.layer_types) + 1)[1:],
+                self.layer_types, self.mlp_types):
+            weights = self._layer_weights(attn_kind, mlp_kind)
+            lp = {"n1": jnp.ones((H,), jnp.float32),
+                  "n2": jnp.ones((H,), jnp.float32)}
+            if attn_kind == LATENT:
+                lp["n_kv"] = jnp.ones((self.latent["kv_rank"],), jnp.float32)
+            for k, (name, shape, div) in zip(
+                    jax.random.split(lk, len(weights)), weights):
+                lp[name] = jax.random.normal(k, shape, jnp.float32) / div
+            layers.append(lp)
         return {
             "layers": layers,
             "norm_f": jnp.ones((H,), jnp.float32),
-            "head": w(jax.random.split(key)[0], self.n_classes, H, fan_in=H),
+            "head": jax.random.normal(
+                jax.random.split(key)[0], (self.n_classes, H), jnp.float32
+            ) / np.sqrt(H),
         }
 
     # -- forward ----------------------------------------------------------- #
-    def _layer(self, lp: dict, x: jax.Array, valid: jax.Array, kind: str):
-        """One decoder layer; ``valid`` [B, T] marks the positions that
-        hold a token (the others are routed to no expert).  Returns (x,
-        [pairs held here, largest held expert's tokens])."""
+    def _attend(self, lp: dict, x: jax.Array, kind: str) -> jax.Array:
+        """x + the layer's attention over n1(x)."""
         B, T, H = x.shape
+        if kind == LATENT:
+            return self._attend_latent(lp, x)
         sliding = kind == SLIDING
         with jax.named_scope("attn_window" if sliding else "attn_full"):
             h = rms_norm(x, lp["n1"], self.rms_eps)
@@ -207,15 +288,57 @@ class DecoderMoeLM:
                 apply_rotary(q, cos, sin), apply_rotary(k, cos, sin), v,
                 causal=True, window=self.window if sliding else None,
                 block_q=self.block_q)
-            x = x + a.reshape(B, T, -1) @ lp["wo"]
+            return x + a.reshape(B, T, -1) @ lp["wo"]
+
+    def _attend_latent(self, lp: dict, x: jax.Array) -> jax.Array:
+        B, T, H = x.shape
+        z, nh = self.latent, self.n_heads
+        rank, nope, rope = z["kv_rank"], z["qk_nope"], z["qk_rope"]
+        with jax.named_scope("attn_latent"):
+            h = rms_norm(x, lp["n1"], self.rms_eps)
+            q = (h @ lp["wq"]).reshape(B, T, nh, nope + rope)
+            kv_a = h @ lp["wkv_a"]  # [B, T, rank + rope]
+            kv = (rms_norm(kv_a[..., :rank], lp["n_kv"], self.rms_eps)
+                  @ lp["wkv_b"]).reshape(B, T, nh, nope + z["v_dim"])
+            cos, sin = rotary_tables(jnp.arange(T), rope, self.rope_theta,
+                                     interleaved=z["interleaved"])
+            q_pe = apply_rotary(q[..., nope:], cos, sin, z["interleaved"])
+            # the turned key slice: one per token, shared by every head
+            k_pe = apply_rotary(kv_a[:, :, None, rank:], cos, sin,
+                                z["interleaved"])
+            q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+            k = jnp.concatenate(
+                [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, nh, rope))],
+                axis=-1)
+            a = full_attention(q, k, kv[..., nope:], causal=True,
+                               block_q=self.block_q)
+            return x + a.reshape(B, T, -1) @ lp["wo"]
+
+    def _layer(self, lp: dict, x: jax.Array, valid: jax.Array, kinds: tuple):
+        """One decoder layer of ``kinds`` = (attention, feed-forward);
+        ``valid`` [B, T] marks the positions that hold a token (the others
+        are routed to no expert).  Returns (x, [pairs held here, largest
+        held expert's tokens]), zeros for a dense layer."""
+        B, T, H = x.shape
+        x = self._attend(lp, x, kinds[0])
         h = rms_norm(x, lp["n2"], self.rms_eps).reshape(B * T, H)
+        if kinds[1] == DENSE:
+            with jax.named_scope("dense_mlp"):
+                y = swiglu(h, lp["mlp_gate"], lp["mlp_up"], lp["mlp_down"])
+            return x + y.reshape(B, T, H), jnp.zeros((2,), jnp.float32)
         with jax.named_scope("router"):
-            top_w, top_e = route_tokens(h, lp["router"], self.top_k)
+            top_w, top_e = route_tokens(
+                h, lp["router"], self.top_k, self.router_score,
+                lp.get("router_bias"), self.router_scale)
             top_e = jnp.where(valid.reshape(-1, 1), top_e, -1)
         with jax.named_scope("experts"):
             y, load = routed_experts(
                 h, top_w, top_e, lp["w_gate"], lp["w_up"], lp["w_down"],
                 self.experts_held[0])
+        if self.shared_width:
+            with jax.named_scope("shared_experts"):
+                y = y + swiglu(h, lp["shared_gate"], lp["shared_up"],
+                               lp["shared_down"])
         return x + y.reshape(B, T, H), jnp.stack(
             [load.sum(), load.max()]).astype(jnp.float32)
 
@@ -274,9 +397,10 @@ class DecoderMoeLM:
         scored = (target >= 0).astype(jnp.float32)
         valid = seq_pos < K
         moe = jnp.zeros((2,), jnp.float32)
-        for lp, kind in zip(params["layers"], self.layer_types):
+        for lp, kinds in zip(params["layers"],
+                             zip(self.layer_types, self.mlp_types)):
             x, m = jax.checkpoint(self._layer, static_argnums=(3,))(
-                lp, x, valid, kind)
+                lp, x, valid, kinds)
             moe = moe + m
         with jax.named_scope("lm_head"):
             ce = self._token_losses(
@@ -289,6 +413,6 @@ class DecoderMoeLM:
         n_tokens = valid.sum().astype(jnp.float32)
         counts = jnp.stack([
             n_scored, moe[0],
-            n_tokens * self.top_k * len(self.layer_types),
+            n_tokens * self.top_k * self.mlp_types.count(SPARSE),
             moe[1], moe[0] / held])
         return loss, preds, counts

@@ -17,8 +17,9 @@ collective-light:
   * outputs are weighted by the local gate columns and psummed: one
     [B, D_out] all-reduce per mix, vs all-gathering E expert outputs.
 
-**Token routing** (``routed_experts``): every token scores all E experts,
-takes its k best and renormalises their weights; a device is told which
+**Token routing** (``route_tokens``, ``routed_experts``): every token
+scores all E experts (softmax, or sigmoid with a selection bias), takes
+its k best and renormalises their weights; a device is told which
 experts ``lo..hi`` it holds and computes, for the tokens routed to them,
 their part of the sum.  What the absent experts would add is left out:
 the parts of all the shares add up to the whole layer
@@ -112,14 +113,55 @@ def serial_expert_forward(
 
 
 # ------------------------------------------------------------ token routing
-def route_tokens(x: jax.Array, router_w: jax.Array, k: int) -> tuple:
-    """Router over ALL experts: softmax of ``x @ router_w`` (float32), the
-    k largest, renormalised to sum 1 over those k whether their experts are
-    held here or not.  x: [N, D]; router_w: [D, E].  Returns (weights
-    [N, k] float32, expert ids [N, k] int32)."""
+def route_tokens(x: jax.Array, router_w: jax.Array, k: int,
+                 score: str = "softmax",
+                 select_bias: jax.Array | None = None,
+                 scale: float = 1.0) -> tuple:
+    """Router over ALL experts: a score of ``x @ router_w`` (float32) per
+    expert, the k best, their scores renormalised to sum 1 over those k
+    whether their experts are held here or not, times ``scale``.
+
+    ``score``: ``"softmax"`` over the experts, or ``"sigmoid"`` of each
+    logit alone (its k scores are divided by their sum + 1e-20).
+    ``select_bias`` [E] is added to the scores for the CHOICE only (a
+    bias that balances the experts' load without an auxiliary loss): the
+    weights are the unbiased scores of the chosen, so the bias has no
+    gradient.  x: [N, D]; router_w: [D, E].  Returns (weights [N, k]
+    float32, expert ids [N, k] int32)."""
     logits = jnp.dot(x, router_w, preferred_element_type=jnp.float32)
-    top_w, top_e = jax.lax.top_k(jax.nn.softmax(logits, axis=-1), k)
-    return top_w / top_w.sum(axis=-1, keepdims=True), top_e.astype(jnp.int32)
+    # eps, bias and scale enter the program only where they are given:
+    # the defaults trace the operations they always did
+    if score == "softmax":
+        scores, eps = jax.nn.softmax(logits, axis=-1), 0.0
+    elif score == "sigmoid":
+        scores, eps = jax.nn.sigmoid(logits), 1e-20
+    else:
+        raise ValueError(f"unknown router score {score!r}")
+    if select_bias is None:
+        top_w, top_e = jax.lax.top_k(scores, k)
+    else:
+        _, top_e = jax.lax.top_k(scores + select_bias, k)
+        top_w = jnp.take_along_axis(scores, top_e, axis=-1)
+    total = top_w.sum(axis=-1, keepdims=True)
+    if eps:
+        total = total + eps
+    top_w = top_w / total
+    if scale != 1.0:
+        top_w = top_w * scale
+    return top_w, top_e.astype(jnp.int32)
+
+
+def swiglu(x: jax.Array, w_gate: jax.Array, w_up: jax.Array,
+           w_down: jax.Array) -> jax.Array:
+    """One expert on every row: down(silu(gate x) * up x), float32 sums
+    (what ``routed_experts`` writes out per held expert; a shared expert
+    or a dense feed-forward is this alone).  x: [N, D]; w_gate, w_up:
+    [D, F]; w_down: [F, D]."""
+    h = jax.nn.silu(
+        jnp.dot(x, w_gate, preferred_element_type=jnp.float32)
+    ) * jnp.dot(x, w_up, preferred_element_type=jnp.float32)
+    return jnp.dot(h.astype(x.dtype), w_down,
+                   preferred_element_type=jnp.float32)
 
 
 def routed_experts(
@@ -140,14 +182,20 @@ def routed_experts(
     over all N tokens, with weight zero where a token did not choose it, so
     any routing -- all tokens on one expert -- costs the same and loses
     nothing.  That is E_held / (k * E_held / E) times the products of the
-    pairs really routed here (8x at 8 of 64 held, 8 a token), all of them
-    dense MXU work.  Measured on one v5e chip at N = 16,384, D = 2,304, F =
-    896 (PERF.md section 6, PR 27), forward and backward: this form 40.5
-    ms; tokens sorted by expert with ``jax.lax.ragged_dot`` 115 ms (236 ms
-    when every choice lands here); the same with the Pallas grouped product
-    (megablox ``gmm``) 90 ms -- the sorts, the gathers there and back and
-    the worst-case buffers cost more than the products they save.  A
-    grouped kernel that gathers its own rows is the next step."""
+    pairs really routed here (8x at 8 of 64 held, 8 a token; 21.3x at 8 of
+    128 held, 6 a token), all of them dense MXU work.  Measured on one v5e
+    chip at N = 16,384, D = 2,304, F = 896 (PERF.md section 6, PR 27),
+    forward and backward: this form 40.5 ms; tokens sorted by expert with
+    ``jax.lax.ragged_dot`` 115 ms (236 ms when every choice lands here);
+    the same with the Pallas grouped product (megablox ``gmm``) 90 ms --
+    the sorts, the gathers there and back and the worst-case buffers cost
+    more than the products they save.  At 8 of
+    128 held, 6 a token (N = 16,384, D = 2,048, F = 768; PERF.md section
+    6, PR 31) the form computes 21.3x the routed pairs' products at even
+    routing, 15.6x as the traced window routed, and takes 38.4 ms a layer
+    of a 1,190 ms step (forward, rematerialised forward and backward): no
+    grouped form was timed there.  A grouped kernel that gathers its own
+    rows is the next step, judged on both ratios."""
     held = w_gate.shape[0]
     e_loc = jnp.where((top_e >= lo) & (top_e < lo + held), top_e - lo, held)
     load = jnp.bincount(e_loc.reshape(-1), length=held + 1)[:held]

@@ -27,9 +27,12 @@ On one device ``full_attention`` is that plain attention.  Its mask is a
 description -- ``causal``, and with it a ``window`` of keys -- and queries
 may be grouped over fewer key-value heads; asked for a window, a query
 block or grouped queries it runs blockwise (no [T, T] tensor, blocks
-outside the mask never computed).  ``rotary_tables`` / ``apply_rotary``
-are the rotary position code, plain and YaRN.  The ring and Ulysses forms
-take ``causal`` only: a window on them is not written yet.
+outside the mask never computed); the value head may have another width
+than the query/key head.  ``rotary_tables`` / ``apply_rotary`` are the
+rotary position code, plain and YaRN, pairing dimension i with i + D / 2
+or, ``interleaved``, 2i with 2i + 1; a caller that turns part of a head
+hands them that slice.  The ring and Ulysses forms take ``causal`` only: a
+window on them is not written yet.
 """
 
 from __future__ import annotations
@@ -49,7 +52,9 @@ def full_attention(
 ) -> jax.Array:
     """Softmax attention on one device.
 
-    q: [B, T, H, D]; k/v: [B, T, Hkv, D]; returns [B, T, H, D].
+    q: [B, T, H, D]; k: [B, T, Hkv, D]; v: [B, T, Hkv, Dv]; returns
+    [B, T, H, Dv] (Dv = D in most models; latent attention has a value
+    head narrower than its query/key head).
 
     The mask is described, never passed: ``causal`` (key j <= query i) and
     ``window`` (with causal: i - j < window, a query sees itself and the
@@ -100,8 +105,9 @@ def _visible_keys(q0: int, q1: int, t: int, causal: bool,
 def _strip_attention(qb, ks, vs, q0: int, k0: int, causal: bool,
                      window: Optional[int]):
     """One block of queries against the keys it may see.  qb: [B, bq, Hkv,
-    G, D] (query heads grouped over their key-value head); ks/vs: [B, S,
-    Hkv, D].  The scores [B, Hkv, G, bq, S] are the only score tensor."""
+    G, D] (query heads grouped over their key-value head); ks: [B, S, Hkv,
+    D]; vs: [B, S, Hkv, Dv].  The scores [B, Hkv, G, bq, S] are the only
+    score tensor."""
     d = qb.shape[-1]
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qb, ks,
                    preferred_element_type=jnp.float32) / jnp.sqrt(float(d))
@@ -146,15 +152,18 @@ def _blockwise_attention(
             lambda qb, ks, vs, q0=q0, k0=k0: _strip_attention(
                 qb, ks, vs, q0, k0, causal, window))
         out.append(strip(qg[:, q0:q1], k[:, k0:k1], v[:, k0:k1]))
-    return jnp.concatenate(out, axis=1).reshape(b, t, h, d)
+    return jnp.concatenate(out, axis=1).reshape(b, t, h, v.shape[-1])
 
 
 def rotary_tables(positions: jax.Array, head_dim: int, theta: float,
-                  yarn: Optional[dict] = None) -> tuple:
-    """(cos, sin), each [T, head_dim], of the rotary position code on the
-    whole head dimension: angle(t, i) = t * inv_freq[i], i < head_dim / 2,
-    laid out twice (the rotate-half pairing of dimension i with i +
-    head_dim / 2).  Plain: inv_freq[i] = theta ** (-2i / head_dim).
+                  yarn: Optional[dict] = None,
+                  interleaved: bool = False) -> tuple:
+    """(cos, sin), each [T, head_dim], of the rotary position code on
+    ``head_dim`` dimensions (a whole head, or the slice of it that is
+    turned): angle(t, i) = t * inv_freq[i], i < head_dim / 2, laid out
+    twice (the rotate-half pairing of dimension i with i + head_dim / 2)
+    or, ``interleaved``, each angle at 2i and 2i + 1 (adjacent pairs).
+    Plain: inv_freq[i] = theta ** (-2i / head_dim).
 
     ``yarn`` (keys ``factor``, ``original_max_position_embeddings``,
     ``beta_fast``, ``beta_slow``, ``attention_factor``) blends, per
@@ -184,14 +193,25 @@ def rotary_tables(positions: jax.Array, head_dim: int, theta: float,
         inv = inv / yarn["factor"] * ramp + inv * (1.0 - ramp)
         scale = float(yarn["attention_factor"])
     ang = positions.astype(jnp.float32)[:, None] * inv[None, :]
-    ang = jnp.concatenate([ang, ang], axis=-1)
+    if interleaved:
+        ang = jnp.repeat(ang, 2, axis=-1)
+    else:
+        ang = jnp.concatenate([ang, ang], axis=-1)
     return jnp.cos(ang) * scale, jnp.sin(ang) * scale
 
 
-def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array) -> jax.Array:
-    """x [B, T, H, D] turned by the tables of ``rotary_tables`` ([T, D])."""
-    half = x.shape[-1] // 2
-    turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
+def apply_rotary(x: jax.Array, cos: jax.Array, sin: jax.Array,
+                 interleaved: bool = False) -> jax.Array:
+    """x [B, T, H, D] turned by the tables of ``rotary_tables`` ([T, D],
+    built with the same ``interleaved``): dimension i with i + D / 2, or
+    2i with 2i + 1."""
+    if interleaved:
+        pairs = x.reshape(*x.shape[:-1], -1, 2)
+        turned = jnp.stack([-pairs[..., 1], pairs[..., 0]],
+                           axis=-1).reshape(x.shape)
+    else:
+        half = x.shape[-1] // 2
+        turned = jnp.concatenate([-x[..., half:], x[..., :half]], axis=-1)
     return x * cos[None, :, None, :] + turned * sin[None, :, None, :]
 
 
