@@ -62,7 +62,7 @@ from paddlebox_tpu.parallel.multiprocess import (
 )
 from paddlebox_tpu.parallel.sharded_table import ShardedBatchPlan, ShardedSparseTable
 from paddlebox_tpu.sparse.optimizer import sparse_adagrad_update
-from paddlebox_tpu.sparse.table import scatter_add_rows
+from paddlebox_tpu.sparse.table import merge_occurrences, scatter_add_rows
 from paddlebox_tpu.telemetry.compiles import counted_jit, stage_scope
 from paddlebox_tpu.utils import faults
 from paddlebox_tpu.train.slot_policy import (
@@ -238,15 +238,12 @@ def hybrid_hot_update(
     """
     H, W = hot_values.shape
     co = conf.cvm_offset
-    merged = jax.ops.segment_sum(row_grads, hot_occ, num_segments=H + 1)[:H]
-    show = jax.ops.segment_sum(key_mask, hot_occ, num_segments=H + 1)[:H]
-    clk = jax.ops.segment_sum(key_clicks, hot_occ, num_segments=H + 1)[:H]
-    counters = jnp.stack([show, clk], axis=1)
-    if co > 2:
-        counters = jnp.concatenate(
-            [counters, jnp.zeros((H, co - 2), counters.dtype)], axis=1
-        )
-    contrib = jnp.concatenate([counters, merged[:, co:]], axis=1)  # [H, W]
+    # the one occurrence merge, counters in the first co columns; segment
+    # H is the sink of occurrences served cold, dropped
+    contrib = merge_occurrences(
+        row_grads, key_mask, key_clicks, None, hot_occ, H + 1, co,
+        hot_values.dtype,
+    )[:H]  # [H, W]
     with jax.named_scope("hot_fold"):
         gathered = jax.lax.all_gather(contrib, DATA_AXIS)  # [n, H, W]
         acc = gathered[0]
@@ -291,15 +288,10 @@ def sharded_push_and_update(
     cap, W = values.shape
     US = serve_uniq.shape[0]
     nseg = n * C + 1  # last segment = padding/overflow sink, dropped
-    merged = jax.ops.segment_sum(row_grads, occ_flat, num_segments=nseg)[: n * C]
-    show_m = jax.ops.segment_sum(key_mask, occ_flat, num_segments=nseg)[: n * C]
-    clk_m = jax.ops.segment_sum(key_clicks, occ_flat, num_segments=nseg)[: n * C]
-    counters = jnp.stack([show_m, clk_m], axis=1)
-    if co > 2:
-        counters = jnp.concatenate(
-            [counters, jnp.zeros((n * C, co - 2), counters.dtype)], axis=1
-        )
-    send = jnp.concatenate([counters, merged[:, co:]], axis=1).reshape(n, C, W)
+    send = merge_occurrences(
+        row_grads, key_mask, key_clicks, None, occ_flat, nseg, co,
+        values.dtype,
+    )[: n * C].reshape(n, C, W)
     with jax.named_scope("exchange"):
         recv = jax.lax.all_to_all(send, DATA_AXIS, 0, 0)  # [n, C, W]
     # cross-requester merge: duplicate rows across devices fold together

@@ -24,7 +24,9 @@ BeginFeedPass/EndFeedPass trick, §3.4):
     side at the table's unique-slot bucket (``_uniq_slots``), which follows
     the distinct keys of the batches seen.
   * pull_rows / push_and_update — pure jittable functions: gather, and
-    segment-sum merge + sparse adagrad + show/clk counter scatter-add.
+    ONE segment-sum over the occurrences (merge_occurrences: the merged
+    gradient with the show/clk increments in its counter columns) + sparse
+    adagrad + one scatter-add of rows over the distinct keys.
   * end_pass() — write the working set back into the host store.
 
 The dead row (index P-1) serves padding keys and keys missing from the pass
@@ -1337,6 +1339,45 @@ def pull_rows(
     return rows
 
 
+def merge_occurrences(
+    row_grads: jax.Array,
+    key_mask: jax.Array,
+    key_clicks: jax.Array,
+    key_extras: Optional[jax.Array],
+    segment_ids: jax.Array,
+    num_segments: int,
+    cvm_offset: int,
+    dtype,
+) -> jax.Array:
+    """The push's ONE reduction over a batch's occurrences: [K, W] ->
+    [num_segments, W] with the counter increments (show, click, extras) in
+    columns ``[:cvm_offset]`` and the merged gradient in ``[cvm_offset:]``.
+
+    The first ``cvm_offset`` columns of ``row_grads`` are REPLACED by
+    ``key_mask``, ``key_clicks`` and ``key_extras`` (zeros when absent), not
+    added to: the counters ignore whatever gradient a loss gives show and
+    click.  A scatter-add on the TPU is paid per index, so the counters ride
+    the merge's index pass for free where a segment-sum of their own costs
+    as much as the merge.  ``dtype`` is the table's: a narrower
+    ``row_grads`` is widened to it, the counters are never cast down (whole
+    numbers far below 2^24: their float32 sums are exact in any order).
+
+    The replacement is a select by column, not a concatenate: XLA fuses
+    the select into whatever produces ``row_grads`` and the operand costs
+    no pass of its own, where slice + concatenate materialise a [K, 1]
+    column in a 128-lane tile for each counter and the operand after them.
+    """
+    counters = [key_mask, key_clicks] + [
+        jnp.zeros_like(key_mask) if key_extras is None else key_extras[:, j]
+        for j in range(cvm_offset - 2)
+    ]
+    col = jax.lax.broadcasted_iota(jnp.int32, row_grads.shape, 1)
+    occ = row_grads.astype(dtype)
+    for j, counter in enumerate(counters):
+        occ = jnp.where(col == j, counter.astype(dtype)[:, None], occ)
+    return jax.ops.segment_sum(occ, segment_ids, num_segments=num_segments)
+
+
 def push_and_update(
     values: jax.Array,
     g2sum: jax.Array,
@@ -1355,8 +1396,9 @@ def push_and_update(
     box_wrapper_impl.h:165-255 — CopyForPush merge of duplicate keys +
     closed-lib optimizer; semantics per sparse/optimizer.py).
 
-    row_grads: [K, W] cotangent of the pulled rows (show/clk columns are
-        zero thanks to stop_gradient in the CVM transform).
+    row_grads: [K, W] cotangent of the pulled rows; its show/clk columns
+        are replaced by the counters in the merge (merge_occurrences), so
+        whatever a loss puts there never reaches the table.
     key_clicks: [K] click/label of each occurrence's instance (masked).
     key_extras: [K, cvm_offset - 2] extra counter increments per occurrence
         (e.g. conversion events for the conv layout's third counter,
@@ -1371,27 +1413,18 @@ def push_and_update(
     del plan_idx  # pull-side only; kept in the signature for symmetry
     U = plan_uniq_idx.shape[0]
     co = conf.cvm_offset
-    # merge duplicate keys: [K, W] -> [U, W]
-    merged = jax.ops.segment_sum(row_grads, plan_inverse, num_segments=U)
-    show_inc = jax.ops.segment_sum(key_mask, plan_inverse, num_segments=U)
-    clk_inc = jax.ops.segment_sum(key_clicks, plan_inverse, num_segments=U)
+    # merge duplicate keys, counters in the first co columns: [K, W] -> [U, W]
+    merged = merge_occurrences(
+        row_grads, key_mask, key_clicks, key_extras, plan_inverse, U, co,
+        values.dtype,
+    )
     # sparse adagrad on the embedding columns
-    g = merged[:, co:]
     g2_rows = jnp.take(g2sum, plan_uniq_idx)
     lr = conf.learning_rate if uniq_lr is None else uniq_lr
     w_delta, g2_delta = sparse_adagrad_update(
-        g2_rows, g, lr, conf.initial_g2sum, conf.grad_clip,
+        g2_rows, merged[:, co:], lr, conf.initial_g2sum, conf.grad_clip,
     )
-    counter_delta = jnp.stack([show_inc, clk_inc], axis=1)
-    if co > 2:
-        if key_extras is not None:
-            extra_inc = jax.ops.segment_sum(
-                key_extras, plan_inverse, num_segments=U
-            )
-        else:
-            extra_inc = jnp.zeros((U, co - 2), counter_delta.dtype)
-        counter_delta = jnp.concatenate([counter_delta, extra_inc], axis=1)
-    delta = jnp.concatenate([counter_delta, w_delta], axis=1)
+    delta = jnp.concatenate([merged[:, :co], w_delta], axis=1)
     # plan_uniq_idx targets are unique EXCEPT possibly repeated dead-row
     # entries (slots the plan clamped when the scratch region was
     # under-provisioned — plan_keys).  Zero every dead-targeted delta so

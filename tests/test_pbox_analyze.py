@@ -11,6 +11,7 @@ findings over the repo's default roots.
 
 import json
 import os
+import resource
 import subprocess
 import sys
 import textwrap
@@ -1386,27 +1387,31 @@ def test_new_rules_listed():
         assert rule in r.stdout
 
 
+def _children_cpu_seconds() -> float:
+    ru = resource.getrusage(resource.RUSAGE_CHILDREN)
+    return ru.ru_utime + ru.ru_stime
+
+
 def test_full_run_wall_time_budget():
     """The interprocedural passes must not regress lint latency: a full
-    --all run stays under the 5s budget (pre-commit viability).  Best of
-    two runs: the budget pins the ANALYZER, not transient machine load
-    from the surrounding suite (jax worker threads, page-cache misses) —
-    a genuinely slow lint fails both attempts."""
-    import time as _time
-
+    --all run stays under the 5s budget (pre-commit viability).  Judged by
+    the child's CPU seconds (it is single-threaded, so on a free machine
+    they are its wall time): the budget pins the ANALYZER, and the wall
+    clock of a subprocess run beside five other xdist workers pins the
+    neighbours.  Best of two runs — a genuinely slow lint fails both."""
     best = None
     for _ in range(2):
-        t0 = _time.monotonic()
+        c0 = _children_cpu_seconds()
         r = subprocess.run(
             [sys.executable, CLI, "--all"],
             capture_output=True, text=True, timeout=60,
         )
-        elapsed = _time.monotonic() - t0
+        spent = _children_cpu_seconds() - c0
         assert r.returncode == 0, f"repo not clean:\n{r.stdout}"
-        best = elapsed if best is None else min(best, elapsed)
+        best = spent if best is None else min(best, spent)
         if best <= 5.0:
             break
-    assert best <= 5.0, f"pbox-lint --all took {best:.2f}s (> 5s)"
+    assert best <= 5.0, f"pbox-lint --all took {best:.2f} CPU-s (> 5s)"
 
 
 # --------------------------------------------------------------------------- #

@@ -6,6 +6,7 @@ fleet/box_wrapper_impl.h:24-255, box_wrapper.cc:609-673,496-499).
 """
 
 import dataclasses
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -109,6 +110,124 @@ def test_push_matches_numpy_adagrad_oracle():
     np.testing.assert_allclose(new_g2, exp_g2, rtol=1e-5, atol=1e-6)
     # dead row still zero
     np.testing.assert_allclose(new_v[t.dead_row], 0.0)
+
+
+def _merged_push_case(co, rng):
+    """A hand-made plan over a 12-row pass table (row 11 dead, row 10 a
+    scratch row): K = 16 occurrences of which 10 real — five live keys
+    with duplicates and one census-missing key at its scratch row — and 6
+    padding (key_mask 0, inverse at the last slot); U = 8 slots of which
+    the last two clamp to the dead row."""
+    conf = _conf(cvm_offset=co)
+    P, W, K, dead = 12, conf.row_width, 16, 11
+    uniq_idx = np.array([3, 0, 7, 5, 9, 10, dead, dead], dtype=np.int32)
+    inverse = np.array([0, 1, 0, 2, 3, 3, 3, 4, 5, 1] + [7] * 6, np.int32)
+    mask = np.array([1.0] * 10 + [0.0] * 6, dtype=np.float32)
+    values = rng.normal(size=(P, W)).astype(np.float32)
+    values[:, :co] = rng.integers(0, 50, size=(P, co))
+    g2sum = rng.uniform(0.0, 2.0, size=P).astype(np.float32)
+    values[dead] = 0.0
+    g2sum[dead] = 0.0
+    row_grads = np.zeros((K, W), dtype=np.float32)
+    row_grads[:, co:] = rng.normal(size=(K, W - co))  # padding rows too
+    clicks = (rng.integers(0, 2, size=K) * mask).astype(np.float32)
+    extras = (rng.integers(0, 3, size=(K, co - 2)) * mask[:, None]).astype(
+        np.float32)
+    lr = rng.uniform(0.01, 0.2, size=uniq_idx.shape[0]).astype(np.float32)
+    return conf, values, g2sum, row_grads, uniq_idx, inverse, mask, clicks, \
+        extras, lr
+
+
+def _numpy_push(conf, values, g2sum, row_grads, uniq_idx, inverse, mask,
+                clicks, extras, lr):
+    """Independent reference: np.add.at per column, adagrad per slot."""
+    co, U, dead = conf.cvm_offset, uniq_idx.shape[0], values.shape[0] - 1
+    inc = np.zeros((U, co), dtype=np.float32)
+    np.add.at(inc[:, 0], inverse, mask)
+    np.add.at(inc[:, 1], inverse, clicks)
+    if extras is not None:
+        for c in range(co - 2):
+            np.add.at(inc[:, 2 + c], inverse, extras[:, c])
+    g = np.zeros((U, values.shape[1] - co), dtype=np.float32)
+    for c in range(g.shape[1]):
+        np.add.at(g[:, c], inverse, row_grads[:, co + c])
+    g = np.clip(g, -conf.grad_clip, conf.grad_clip)
+    add_g2 = (g * g).mean(axis=1)
+    scale = (conf.learning_rate if lr is None else lr) * np.sqrt(
+        conf.initial_g2sum / (conf.initial_g2sum + g2sum[uniq_idx] + add_g2))
+    exp_v, exp_g2 = values.copy(), g2sum.copy()
+    for u, row in enumerate(uniq_idx):
+        if row == dead:
+            continue
+        exp_v[row, :co] += inc[u]
+        exp_v[row, co:] -= scale[u] * g[u]
+        exp_g2[row] += add_g2[u]
+    return exp_v, exp_g2
+
+
+@pytest.mark.parametrize("garbage", [False, True], ids=["clean", "garbage"])
+@pytest.mark.parametrize("with_lr", [False, True], ids=["lr0", "uniq_lr"])
+@pytest.mark.parametrize("co,with_extras", [(2, False), (3, False), (3, True)],
+                         ids=["co2", "co3-noext", "co3-ext"])
+def test_push_one_reduction_matches_numpy_reference(co, with_extras, with_lr,
+                                                    garbage):
+    """The push's one occurrence-sized reduction against np.add.at per
+    column: duplicates, padding at the last slot, a census-missing key at
+    its scratch row, dead-clamped slots.  Counters bit for bit, also when
+    the cotangent holds garbage in its show/clk columns (they are
+    replaced, not added to)."""
+    rng = np.random.default_rng(100 * co + 10 * with_extras + with_lr)
+    (conf, values, g2sum, row_grads, uniq_idx, inverse, mask, clicks,
+     extras, lr) = _merged_push_case(co, rng)
+    extras = extras if with_extras else None
+    lr = lr if with_lr else None
+    exp_v, exp_g2 = _numpy_push(conf, values, g2sum, row_grads, uniq_idx,
+                                inverse, mask, clicks, extras, lr)
+    if garbage:
+        row_grads = row_grads.copy()
+        row_grads[:, :co] = rng.normal(size=(row_grads.shape[0], co)) * 1e3
+    new_v, new_g2 = jax.jit(functools.partial(push_and_update, conf=conf))(
+        jnp.asarray(values), jnp.asarray(g2sum), jnp.asarray(row_grads),
+        jnp.zeros(inverse.shape[0], jnp.int32), jnp.asarray(uniq_idx),
+        jnp.asarray(inverse), jnp.asarray(mask), jnp.asarray(clicks),
+        key_extras=None if extras is None else jnp.asarray(extras),
+        uniq_lr=None if lr is None else jnp.asarray(lr),
+    )
+    new_v, new_g2 = np.asarray(new_v), np.asarray(new_g2)
+    np.testing.assert_array_equal(new_v[:, :co], exp_v[:, :co])
+    np.testing.assert_allclose(new_v[:, co:], exp_v[:, co:],
+                               rtol=1e-5, atol=1e-6)
+    np.testing.assert_allclose(new_g2, exp_g2, rtol=1e-5, atol=1e-6)
+    np.testing.assert_array_equal(new_v[-1], 0.0)
+    assert new_g2[-1] == 0.0
+
+
+def _scatter_adds(jaxpr):
+    """Every scatter-add equation of a jaxpr, sub-jaxprs included."""
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "scatter-add":
+            found.append(eqn)
+        for sub in jax.core.jaxprs_in_params(eqn.params):
+            found.extend(_scatter_adds(sub))
+    return found
+
+
+@pytest.mark.parametrize("co", [2, 3])
+def test_push_holds_one_occurrence_sized_scatter_add(co):
+    """Structural: of the jitted push's scatter-adds exactly ONE takes
+    updates with K rows (there were three, four with extras): show, click
+    and the extras ride the merge's index pass."""
+    rng = np.random.default_rng(co)
+    (conf, values, g2sum, row_grads, uniq_idx, inverse, mask, clicks,
+     extras, lr) = _merged_push_case(co, rng)
+    K, U = inverse.shape[0], uniq_idx.shape[0]
+    closed = jax.make_jaxpr(functools.partial(push_and_update, conf=conf))(
+        values, g2sum, row_grads, np.zeros(K, np.int32), uniq_idx, inverse,
+        mask, clicks, key_extras=extras if co > 2 else None, uniq_lr=lr)
+    rows = [e.invars[2].aval.shape[0] for e in _scatter_adds(closed.jaxpr)]
+    # the merge over the K occurrences, the two scatters over the U slots
+    assert sorted(rows) == [U, U, K], rows
 
 
 def test_missing_key_grads_do_not_corrupt_dead_row():
