@@ -22,6 +22,16 @@ documents state it, with no plan, no dedup of the gather and no fusion:
 All matmuls run under ``jax.default_matmul_precision("highest")``: on a TPU
 a float32 product is otherwise a single bfloat16 pass.  Shapes are padded
 to the configuration's key capacity so one compiled step serves every batch.
+
+What the check holds on the device is what a trainer holds: the step
+donates its state (``make_step``) and ``run_steps`` keeps nothing else
+there, so a model's reference has the chip less 16 bytes a dense parameter
+(parameters, gradient, Adam's ``mu`` and ``nu``) for its own temporaries.
+A new ``loss`` is sized by that rule: rematerialise (``jax.checkpoint``)
+what a sequence, a layer, a head or a block of logits would otherwise
+keep for the backward pass, as mellum2.py and kanana2.py do, and read
+the compiled step's ``memory_analysis()`` for a described chip before the
+first run (PERF.md section 4).
 """
 
 from __future__ import annotations
@@ -216,7 +226,17 @@ def make_step(model, cfg: dict, ops: Ops):
     ``rows_occ`` [capacity, 2 + D] is one row an occurrence, in ``batch``'s
     order; ``batch`` is ``batch_arrays``' with ``B`` and ``S`` as Python
     ints.  Counters and both optimizers are the step's own: a ``loss``
-    replaces the model half only."""
+    replaces the model half only.
+
+    The step donates ``params``, ``mu``, ``nu``, ``t`` and ``rows``: Adam's
+    update lands in the buffers it read, as a trainer's does, so a caller
+    holds four copies of the parameters across a call (the three and the
+    gradient that comes back) and hands in arrays that are its own to
+    lose.  Donation changes the lowered step's aliasing and nothing of its
+    arithmetic (``jax.jit(step.__wrapped__)`` is the same step without it:
+    tests/test_reference_state.py holds the two equal bit for bit on the
+    CPU; the TPU's compiler schedules the two otherwise, and their results
+    differ in the last bits: PERF.md section 6, PR 33)."""
     opt = cfg["optimizers"]
     lr_d, b1, b2, eps = (opt["dense_adam_lr"], opt["dense_adam_b1"],
                          opt["dense_adam_b2"], opt["dense_adam_eps"])
@@ -258,7 +278,7 @@ def make_step(model, cfg: dict, ops: Ops):
         with jax.default_matmul_precision("highest"):
             return step(*a)
 
-    return jax.jit(highest)
+    return jax.jit(highest, donate_argnums=(0, 1, 2, 3, 4))
 
 
 def leaf_norms(tree) -> list:
@@ -274,9 +294,18 @@ def run_steps(model, cfg: dict, params, table_keys: np.ndarray,
     Returns per-step loss, the first step's gradient norm per leaf (dense
     leaves, then the embedding rows as one leaf), the norm of each leaf's
     change after the last step, and the rows of every touched key at the
-    start, after the first step and after the last."""
+    start, after the first step and after the last.
+
+    It owns what the step donates: ``params`` (a host or a device tree,
+    the caller's and left as it is) is copied to the device, the start is
+    kept on the host, and each step's gradient is dropped before the next
+    call.  So the device holds the parameters, Adam's two moments and one
+    gradient -- 16 bytes a parameter, a trainer's state -- beside the
+    model's own temporaries, and nothing of it once this returns: what
+    comes back is on the host."""
     step = make_step(model, cfg, Ops(precision))
-    params0 = params
+    params0 = jax.tree.map(np.asarray, params)
+    params = jax.tree.map(jnp.array, params0)  # a copy: the step donates it
     seeded = cfg["seeded_state"]  # Adam as a job some passes old has it
     mu = jax.tree.map(jnp.zeros_like, params)
     nu = jax.tree.map(
@@ -293,21 +322,24 @@ def run_steps(model, cfg: dict, params, table_keys: np.ndarray,
             if r is not None:
                 rows[i] = r
         params, mu, nu, t, new_rows, loss, gp, g_rows = step(
-            params, mu, nu, t, jnp.asarray(rows), batch)
+            params, mu, nu, t, jnp.array(rows), batch)
         new_rows = np.asarray(new_rows)
         for i, k in enumerate(uniq.tolist()):
             rows_now[k] = new_rows[i]
         losses.append(float(loss))
         if grad_norms is None:
-            grads = [np.asarray(x) for x in jax.tree.leaves(gp)]
+            # copies: on the CPU np.asarray is a view that keeps gp alive
+            grads = [np.array(x) for x in jax.tree.leaves(gp)]
             grad_norms = leaf_norms(gp) + leaf_norms([g_rows])
             step1 = dict(rows_now)
+        del gp, g_rows  # the next call has room for its own
     touched = np.array(sorted(rows_now), dtype=np.uint64)
     final = np.stack([rows_now[k] for k in touched.tolist()])
     first = table_rows[np.searchsorted(table_keys, touched)]
     after_step1 = np.stack([step1.get(k, first[i])
                             for i, k in enumerate(touched.tolist())])
-    delta = jax.tree.map(lambda a, b: a - b, params, params0)
+    # leaf by leaf on the host: no second tree on the device
+    delta = jax.tree.map(lambda a, b: np.asarray(a) - b, params, params0)
     return {
         "loss": losses,
         "grad_norms": grad_norms,
@@ -315,7 +347,7 @@ def run_steps(model, cfg: dict, params, table_keys: np.ndarray,
         "first_rows": first,
         "step1_rows": after_step1,
         "update_norms": leaf_norms(delta)
-        + leaf_norms([jnp.asarray(final[:, 2:-1] - first[:, 2:-1])]),
+        + leaf_norms([final[:, 2:-1] - first[:, 2:-1]]),
         "touched_keys": touched,
         "final_rows": final,
     }

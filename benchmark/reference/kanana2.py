@@ -34,9 +34,10 @@ RMSNorm, eps 1e-6, learned scale; no biases; x [T, hidden]:
   cross-entropy against that token's class (its key's rank among the
   table's sorted keys: ``key_rank[inv]`` of the next occurrence).
 
-Written to fit beside eight copies of 392 M parameters (common.make_step is
-jitted without donation): one sequence at a time (``lax.map``), every
-layer rematerialised (``jax.checkpoint``), attention one head at a time
+Written to fit beside the four copies of 392 M parameters a step holds
+(common.make_step donates its state: parameters, Adam's two moments and
+the gradient, 16 bytes a parameter): one sequence at a time (``lax.map``),
+every layer rematerialised (``jax.checkpoint``), attention one head at a time
 (``lax.map`` over the heads, each rematerialised: one [T, T] block of
 scores alive), each held expert and each block of ``LOGIT_ROWS`` rows of
 logits rematerialised.  The arithmetic is the dense one: a [T, T] mask

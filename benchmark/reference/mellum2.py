@@ -27,12 +27,15 @@ it whole; ``cfg`` below is that file):
   cross-entropy against that token's class (its key's rank among the
   table's sorted keys: ``key_rank[inv]`` of the next occurrence).
 
-Written to fit beside eight copies of the parameters (common.make_step is
-jitted without donation): one sequence at a time (``lax.map``), every
-layer and every group of four query heads rematerialised
-(``jax.checkpoint``), so the largest tensor alive is one [4, T, T] block of
-scores.  The arithmetic is the dense one: a [T, T] mask from positions,
-every held expert on every token.  Every product goes through ``ops``.
+Written to fit beside the four copies of the parameters a step holds
+(common.make_step donates its state: parameters, Adam's two moments and
+the gradient, 16 bytes a parameter): one sequence at a time (``lax.map``),
+every layer, every group of four query heads and the head with its loss
+rematerialised (``jax.checkpoint``), so the largest tensor alive is one
+[4, T, T] block of scores and ``lax.map`` keeps a sequence's [T, hidden]
+inputs, not its [T, V] logits.  The arithmetic is the dense one: a [T, T]
+mask from positions, every held expert on every token.  Every product goes
+through ``ops``.
 """
 
 from __future__ import annotations
@@ -171,12 +174,18 @@ def sequence_loss(cfg: dict, ops, params: dict, x, target):
 
     for lp, kind in zip(params["layers"], kinds):
         x = jax.checkpoint(layer, static_argnums=(2,))(lp, x, kind)
-    logits = ops.dot(rms_norm(x, params["norm_f"], eps), params["head"].T)
-    logp = jax.nn.log_softmax(logits, axis=-1)
     scored = target >= 0
-    picked = jnp.take_along_axis(
-        logp, jnp.where(scored, target, 0)[:, None], axis=1)[:, 0]
-    return -(picked * scored).sum(), scored.sum()
+
+    @jax.checkpoint
+    def head(x):  # else ``lax.map`` keeps every sequence's [T, V] logits
+        logits = ops.dot(rms_norm(x, params["norm_f"], eps),
+                         params["head"].T)
+        logp = jax.nn.log_softmax(logits, axis=-1)
+        picked = jnp.take_along_axis(
+            logp, jnp.where(scored, target, 0)[:, None], axis=1)[:, 0]
+        return -(picked * scored).sum()
+
+    return head(x), scored.sum()
 
 
 def loss(cfg: dict, ops, params: dict, rows_occ, batch: dict):

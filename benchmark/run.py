@@ -351,6 +351,13 @@ def free_device() -> None:
         a.delete()
 
 
+def peak_bytes(devs) -> int:
+    """The process's high-water mark of device memory on its fullest chip
+    (it never falls again; 0 where the backend reports none)."""
+    return int(max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
+                   for d in devs))
+
+
 def one_pass(table, trainer, ds) -> dict:
     """begin_pass -> train_from_dataset -> end_pass as the examples' loop
     has it; wall seconds of the three parts."""
@@ -560,8 +567,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         if tracing:  # a failed pass ended the window inside the trace
             jax.profiler.stop_trace()
         after = telemetry.registry.snapshot()
-        peak = max((d.memory_stats() or {}).get("peak_bytes_in_use", 0)
-                   for d in devs)
+        peak = peak_bytes(devs)
         run = Run(
             cell=cell, passes=done, window_s=window_s, before=before,
             after=after, distinct_keys_per_step=distinct,
@@ -592,10 +598,14 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
                  key_capacity(cfg) * cell.chips)
         common = importlib.import_module("benchmark.reference.common")
         want = common.run_steps(*steps)
+        t_want = time.monotonic() - t
         # the same steps at the precision the configuration states: how
         # far that alone lies from float32 on this seed
         base = common.run_steps(*steps, precision=cfg["precision"]["products"])
         ref_seconds = time.monotonic() - t
+        # the mark is the process's: it shows the reference only where
+        # the reference is the larger of the two
+        ref_peak = peak_bytes(devs)
     numbers = check.compare(got, want, base, cfg["limits"])
     log("passes [begin_pass, train, end_pass ms; process CPU s; full "
         "collections, their s]: " + " ".join(
@@ -606,7 +616,10 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
     correct = bool(all(n["ok"] for n in numbers) and finite and not failed
                    and len(done) >= 2)
     log(f"window: {len(done)} passes, {run.steps} steps in {window_s:.2f}s; "
-        f"reference {ref_seconds:.1f}s (not in setup_s); finite={finite}")
+        f"reference {ref_seconds:.1f}s (not in setup_s): float32 "
+        f"{t_want:.1f}s, {cfg['precision']['products']} "
+        f"{ref_seconds - t_want:.1f}s; peak_bytes_in_use {peak} before "
+        f"it, {ref_peak} after; finite={finite}")
     for n in numbers:  # the last lines on stderr, and the line's last key
         log(f"check {n['name']}: {n['value']:.6g} (limit {n['limit']:g}) "
             f"{'ok' if n['ok'] else 'OVER'}")
@@ -618,7 +631,7 @@ def run_cell(cell: Cell, seed: int, seconds: float, trace: bool,
         "metrics": {},
         "device": {"platform": devs[0].platform,
                    "kind": devs[0].device_kind, "count": len(devs),
-                   "memory_peak_bytes": int(peak)},
+                   "memory_peak_bytes": peak},
         "counts": {"passes": len(done), "steps": run.steps,
                    "instances": sum(p["samples"] for p in done),
                    "window_compile_requests": run.counter_delta(

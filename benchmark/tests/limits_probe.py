@@ -68,6 +68,7 @@ def probe(workload: str, seeds: list, n_sound: int,
 
                 params, rows0 = run.seeded_weights(cell, seed, data.all_keys)
                 params0 = jax.tree.map(np.asarray, params)
+                del params  # the one device copy is run_steps' own
             steps = (ref, cfg, params0, data.all_keys, rows0, data.step_data,
                      run.key_capacity(cfg) * cell.chips)
             want = common.run_steps(*steps)
