@@ -24,22 +24,29 @@ object (no flag, no config key):
     are published as telemetry counters at the pass's read-back.
 
 The layer is assembled from the model's description, for x [B, T, H] (no
-biases; ``n`` = RMSNorm, eps 1e-6, learned scale):
+biases; ``n`` = RMSNorm, eps ``rms_eps``, learned scale):
 
-    x += attn_l(n1 x)        layer_types[l]: window, full or latent
+    x += op_l(n1 x)          layer_types[l]: one of four operator kinds
     x += ffn_l(n2 x)         mlp_types[l]: dense, or sparse (+ shared)
 
 ``layer_types[l]`` is ``"sliding_attention"`` (causal, a window of
 ``window`` keys, plain rotary code), ``"full_attention"`` (causal, YaRN
 rotary code) -- both ``o(attn(rope(q), rope(k), v))`` with grouped queries,
-``n_heads`` query heads over ``n_kv_heads`` key-value heads -- or
-``"latent_attention"`` (causal; ``latent`` gives its widths): the keys and
-values of all heads are projected up from ONE normed latent of ``kv_rank``
-floats a token, a head's query and key are ``qk_nope`` such floats beside
-``qk_rope`` floats that carry the rotary code (plain, adjacent pairs where
-``interleaved``), the turned key slice is one per token shared by all
-heads, and the value head is ``v_dim`` wide (parallel/sequence.py,
-blockwise: no [T, T] tensor).
+``n_heads`` query heads over ``n_kv_heads`` key-value heads, and where
+``qk_norm`` a learned RMSNorm over the ``head_dim`` floats of every query
+and key head before the rotary code -- ``"latent_attention"`` (causal;
+``latent`` gives its widths): the keys and values of all heads are
+projected up from ONE normed latent of ``kv_rank`` floats a token, a head's
+query and key are ``qk_nope`` such floats beside ``qk_rope`` floats that
+carry the rotary code (plain, adjacent pairs where ``interleaved``), the
+turned key slice is one per token shared by all heads, and the value head
+is ``v_dim`` wide (parallel/sequence.py, blockwise: no [T, T] tensor) -- or
+``"conv"``, the one kind that is not attention, a gated short convolution:
+``[b, c, u] = split3(h W_in)``, ``z = b * u``, a causal depthwise
+convolution of ``conv_kernel`` taps over time (one weight a channel and
+tap, zeros before the first token: ``y_t = sum_j w_j z_{t-K+1+j}``), then
+``(c * y) W_out``; written as ``conv_kernel`` shifted multiply-adds, so no
+[B, T, K, H] tensor exists.
 
 ``mlp_types[l]`` is ``"dense"`` (one SwiGLU of ``dense_width``) or
 ``"sparse"``: a router over all ``n_experts`` (``router_score`` softmax or
@@ -77,8 +84,8 @@ from paddlebox_tpu.parallel.sequence import (
     rotary_tables,
 )
 
-SLIDING, FULL, LATENT = (
-    "sliding_attention", "full_attention", "latent_attention")
+SLIDING, FULL, LATENT, CONV = (
+    "sliding_attention", "full_attention", "latent_attention", "conv")
 DENSE, SPARSE = "dense", "sparse"
 LATENT_KEYS = ("kv_rank", "qk_nope", "qk_rope", "v_dim", "interleaved")
 
@@ -115,9 +122,9 @@ _take_once.defvjp(
 
 class DecoderMoeLM:
     """Decoder whose layers are assembled from a description -- window,
-    full or latent attention; a dense feed-forward or token-routed experts
-    with or without shared ones -- trained on next-token prediction
-    through the pass loop."""
+    full or latent attention or a gated short convolution; a dense
+    feed-forward or token-routed experts with or without shared ones --
+    trained on next-token prediction through the pass loop."""
 
     uses_seq_pos = True
     n_sparse_slots = 1
@@ -155,13 +162,18 @@ class DecoderMoeLM:
         router_bias: bool = False,  # a selection-bias leaf, never updated
         router_scale: float = 1.0,
         latent: Optional[dict] = None,  # LATENT_KEYS, for latent layers
+        qk_norm: bool = False,  # a learned norm on every query and key head
+        conv_kernel: int = 0,  # taps of a "conv" layer's convolution
     ):
         vocab_keys = np.asarray(vocab_keys, dtype=np.uint64)
         if vocab_keys.ndim != 1 or not np.all(vocab_keys[1:] > vocab_keys[:-1]):
             raise ValueError("vocab_keys must be sorted, distinct feasigns")
-        bad = [t for t in layer_types if t not in (SLIDING, FULL, LATENT)]
+        bad = [t for t in layer_types
+               if t not in (SLIDING, FULL, LATENT, CONV)]
         if bad:
             raise ValueError(f"unknown layer types {sorted(set(bad))}")
+        if CONV in layer_types and conv_kernel <= 0:
+            raise ValueError("a conv layer needs conv_kernel")
         mlp_types = tuple(mlp_types or (SPARSE,) * len(layer_types))
         bad = [t for t in mlp_types if t not in (DENSE, SPARSE)]
         if bad or len(mlp_types) != len(layer_types):
@@ -194,6 +206,7 @@ class DecoderMoeLM:
         self.router_score, self.router_bias = router_score, router_bias
         self.router_scale = float(router_scale)
         self.latent = latent
+        self.qk_norm, self.conv_kernel = qk_norm, conv_kernel
         self.window = window
         self.n_experts, self.top_k = n_experts, n_experts_per_tok
         self.expert_width = expert_width
@@ -221,6 +234,11 @@ class DecoderMoeLM:
                   fan_in=z["kv_rank"]),
                 w("wo", nh * z["v_dim"], H, fan_in=nh * z["v_dim"]),
             ]
+        elif attn_kind == CONV:
+            K = self.conv_kernel
+            out = [w("conv_in", H, 3 * H, fan_in=H),
+                   w("conv_w", K, H, fan_in=K),
+                   w("conv_out", H, H, fan_in=H)]
         else:
             hq = self.n_heads * self.head_dim
             hkv = self.n_kv_heads * self.head_dim
@@ -256,6 +274,9 @@ class DecoderMoeLM:
                   "n2": jnp.ones((H,), jnp.float32)}
             if attn_kind == LATENT:
                 lp["n_kv"] = jnp.ones((self.latent["kv_rank"],), jnp.float32)
+            elif attn_kind != CONV and self.qk_norm:
+                lp["q_norm"] = jnp.ones((self.head_dim,), jnp.float32)
+                lp["k_norm"] = jnp.ones((self.head_dim,), jnp.float32)
             for k, (name, shape, div) in zip(
                     jax.random.split(lk, len(weights)), weights):
                 lp[name] = jax.random.normal(k, shape, jnp.float32) / div
@@ -281,6 +302,9 @@ class DecoderMoeLM:
             q = (h @ lp["wq"]).reshape(shape)
             k = (h @ lp["wk"]).reshape(shape)
             v = (h @ lp["wv"]).reshape(shape)
+            if self.qk_norm:
+                q = rms_norm(q, lp["q_norm"], self.rms_eps)
+                k = rms_norm(k, lp["k_norm"], self.rms_eps)
             cos, sin = rotary_tables(
                 jnp.arange(T), self.head_dim, self.rope_theta,
                 None if sliding else self.yarn)
@@ -314,13 +338,27 @@ class DecoderMoeLM:
                                block_q=self.block_q)
             return x + a.reshape(B, T, -1) @ lp["wo"]
 
+    def _conv_mix(self, lp: dict, x: jax.Array) -> jax.Array:
+        """x + the layer's gated short convolution over n1(x)."""
+        T, K = x.shape[1], self.conv_kernel
+        with jax.named_scope("conv_mixer"):
+            h = rms_norm(x, lp["n1"], self.rms_eps)
+            b, c, u = jnp.split(h @ lp["conv_in"], 3, axis=-1)
+            # zeros before the first token; tap j reads position t-K+1+j
+            z = jnp.pad(b * u, ((0, 0), (K - 1, 0), (0, 0)))
+            y = sum(lp["conv_w"][j] * z[:, j:j + T] for j in range(K))
+            return x + (c * y) @ lp["conv_out"]
+
     def _layer(self, lp: dict, x: jax.Array, valid: jax.Array, kinds: tuple):
-        """One decoder layer of ``kinds`` = (attention, feed-forward);
+        """One decoder layer of ``kinds`` = (operator, feed-forward);
         ``valid`` [B, T] marks the positions that hold a token (the others
         are routed to no expert).  Returns (x, [pairs held here, largest
         held expert's tokens]), zeros for a dense layer."""
         B, T, H = x.shape
-        x = self._attend(lp, x, kinds[0])
+        if kinds[0] == CONV:
+            x = self._conv_mix(lp, x)
+        else:
+            x = self._attend(lp, x, kinds[0])
         h = rms_norm(x, lp["n2"], self.rms_eps).reshape(B * T, H)
         if kinds[1] == DENSE:
             with jax.named_scope("dense_mlp"):
