@@ -5,12 +5,19 @@ framework/data_set.h:348-474, python/paddle/fluid/dataset.py:1081-1302) and the
 feed-pass half of ``BoxHelper`` (reference: fleet/box_wrapper.h:815-1084):
 
     ds.set_date(...)
-    ds.preload_into_memory()        # parallel read, overlaps prior pass train
-    ds.wait_preload_done()          # join + merge + key census
-    table.begin_pass(ds.unique_keys())
+    ds.preload_into_memory()        # parallel read + key census, overlaps
+                                    # the prior pass's training
+    ds.wait_preload_done()          # join
+    table.begin_pass(ds.unique_keys())   # the census the load made
     for batch in ds.batches(): train_step(...)
     table.end_pass()
     ds.release_memory()
+
+The key census is taken once, where the pass loads (reference:
+MergeInsKeys -> PSAgentBase::AddKeys, data_set.cc:1786-1795): every reader
+thread counts the file it parsed, the load merges the counts, and the
+loaded ``RecordBlock`` carries the result, so ``unique_keys()`` at a pass
+boundary returns an array instead of sorting every key occurrence again.
 
 Multi-node global shuffle (reference: data_set.cc:1916-2090 via
 boxps::PaddleShuffler) plugs in through the ``shuffler`` hook — see
@@ -38,6 +45,14 @@ from paddlebox_tpu.utils.retry import retry_call
 from paddlebox_tpu.utils.timer import Timer
 
 logger = logging.getLogger(__name__)
+
+
+def _census_build():
+    """Times a piece of the census a load makes (a file's scan on its
+    reader thread, the merge after the concat) as
+    ``data.census_build_seconds`` / ``pbox.data.census_build``."""
+    return timed("data.census_build_seconds", "data.census_build",
+                 "key census made at load (per-file scans + their merge)")
 
 
 @dataclasses.dataclass
@@ -83,6 +98,13 @@ class PadBoxSlotDataset:
         errors (ValueError) never do."""
         return retry_call(self.parser.parse_file, path, site="data.read")
 
+    def _parse_with_census(self, path: str) -> tuple:
+        """... and the file's keys counted on the same reader thread, which
+        has just written every one of them (the sort releases the GIL)."""
+        block = self._parse_with_retry(path)
+        with _census_build():
+            return block, np.unique(block.keys)
+
     def _check_quarantine(self, q0: int, p0: int) -> None:
         """Abort the load when the quarantined fraction of this load's
         lines exceeds the configured threshold — pervasive corruption is
@@ -103,13 +125,20 @@ class PadBoxSlotDataset:
             if not self.filelist:
                 raise RuntimeError("set_filelist before loading")
             q0, p0 = self.parser.quarantined_lines, self.parser.parsed_lines
-            blocks = list(
-                self._pool.map(self._parse_with_retry, self.filelist)
-            )
+            if self.shuffler is None:
+                blocks, censuses = zip(*self._pool.map(
+                    self._parse_with_census, self.filelist))
+            else:
+                # the exchange hands back other records than were parsed
+                # here: the census is of the exchanged block, whole
+                blocks, censuses = list(self._pool.map(
+                    self._parse_with_retry, self.filelist)), ()
             self._check_quarantine(q0, p0)
             block = RecordBlock.concat(blocks)
             if self.shuffler is not None:
                 block = self.shuffler.exchange(block)
+            with _census_build():
+                block.set_census(censuses)
             return block
         finally:
             self.read_timer.pause()
@@ -377,10 +406,14 @@ class PadBoxSlotDataset:
         return 0 if self._block is None else self._block.n_ins
 
     def unique_keys(self) -> np.ndarray:
-        """The pass's key census.  Timed on both paths (memory: the
-        ``np.unique`` over every occurrence; spill: the census taken at
-        spill time) as ``data.census_seconds`` / ``pbox.data.census``: the
-        examples' loop pays it inside every pass boundary."""
+        """The pass's key census: sorted, distinct, uint64.  Both load
+        paths make it while they load (memory: ``_read_all`` leaves it on
+        the block, timed as ``data.census_build_seconds``; spill: merged
+        per archive at spill time), so this call returns an array.  Only a
+        block that replaced the loaded one without a census of its own
+        (``AucRunner``'s redrawn slots) is counted here, once.  Timed as
+        ``data.census_seconds`` / ``pbox.data.census``: the examples' loop
+        calls it inside every pass boundary."""
         with timed("data.census_seconds", "data.census",
                    "dataset key census (unique_keys) wall time"):
             if self._spill is not None:

@@ -8,6 +8,12 @@ step further: a whole file/chunk of instances is parsed straight into one
 columnar CSR block (arrow-style), so batch assembly is pure array slicing and
 the padded device batch is one contiguous copy.  No per-record objects exist
 at all — the object pool becomes unnecessary.
+
+A block also carries its pass's key census (the sorted distinct keys that
+``begin_pass`` promotes): the dataset takes it once, where it loads the
+block (reference: MergeInsKeys -> PSAgentBase::AddKeys, data_set.cc:1786-
+1795, which counts keys while it drains the reader channels), and every
+``unique_keys()`` after that returns the same array.
 """
 
 from __future__ import annotations
@@ -17,6 +23,14 @@ from typing import Optional, Sequence
 
 import numpy as np
 
+from paddlebox_tpu.telemetry import metrics as _tm
+
+_SERVED = _tm.counter(
+    "data.census_served",
+    "unique_keys() calls by how they were answered: from=load, the block "
+    "held its census already; from=scan, np.unique over every occurrence "
+    "at the call")
+
 
 @dataclasses.dataclass
 class RecordBlock:
@@ -24,6 +38,13 @@ class RecordBlock:
 
     CSR layout: ``keys[key_offsets[i*S+s] : key_offsets[i*S+s+1]]`` are the
     uint64 feasigns of instance ``i``, sparse slot ``s``.
+
+    A block is immutable: every transformation (``concat``, ``select``,
+    a slot shuffle or replacement) returns a new block and none writes
+    into the arrays of an old one.  That is what lets a block keep its key
+    census (``unique_keys``): made once, at load or at the first request,
+    and true for as long as the block lives.  A new block starts without
+    one unless the code that made it hands one on (``set_census``).
     """
 
     n_ins: int
@@ -38,6 +59,10 @@ class RecordBlock:
     ranks: Optional[np.ndarray] = None  # int32 [n_ins]
     cmatches: Optional[np.ndarray] = None  # int32 [n_ins]
     task_labels: Optional[np.ndarray] = None  # float32 [n_ins, n_extra_tasks]
+    # sorted distinct uint64 keys, read-only; None until made (never an
+    # __init__ argument: a block built from arrays has yet to count them)
+    _census: Optional[np.ndarray] = dataclasses.field(
+        default=None, init=False, repr=False, compare=False)
 
     def __post_init__(self):
         assert self.key_offsets.shape[0] == self.n_ins * self.n_sparse_slots + 1
@@ -135,7 +160,24 @@ class RecordBlock:
             task_labels=self.task_labels[order] if self.task_labels is not None else None,
         )
 
+    def set_census(self, parts: Sequence[np.ndarray] = ()) -> None:
+        """Make the census now, ahead of its first request: the union of
+        ``parts`` (sorted distinct uint64 key arrays that together hold
+        exactly the keys of ``keys``: one per block this one was
+        concatenated from), else a scan of ``keys``."""
+        census = np.unique(np.concatenate(parts) if parts else self.keys)
+        census.flags.writeable = False  # shared from here on
+        self._census = census
+
     def unique_keys(self) -> np.ndarray:
-        """Key census for the pass (reference: PSAgentBase::AddKeys via
-        MergeInsKeys, data_set.cc:1795; consumed by FeedPass)."""
-        return np.unique(self.keys)
+        """Key census for the pass: sorted, distinct, uint64, read-only —
+        ``np.unique(self.keys)`` to the byte (reference:
+        PSAgentBase::AddKeys via MergeInsKeys, data_set.cc:1795; consumed
+        by FeedPass).  Made on the first request where the block came
+        without one, and kept."""
+        if self._census is None:
+            _SERVED.inc(**{"from": "scan"})
+            self.set_census()
+        else:
+            _SERVED.inc(**{"from": "load"})
+        return self._census

@@ -98,6 +98,7 @@ from paddlebox_tpu.sparse.table import (
     SparseTable,
     _count_begin,
     _next_pow2,
+    sorted_census,
 )
 from paddlebox_tpu.telemetry.compiles import stage_scope
 
@@ -989,7 +990,7 @@ class ShardedSparseTable(SparseTable):
             pass_keys = pass_keys()
         # single-process only (prepare_pass gates): the local census IS the
         # global census, no allgather needed off-thread
-        pk = np.unique(np.asarray(pass_keys, dtype=np.uint64))
+        pk = sorted_census(pass_keys)
         cache_keys, stage_seq, entries = self._stage_snapshot()
         # hot/cold split prediction: the stage resolves only the COLD tail
         # under the CURRENT resident hot set (the plan cannot change
@@ -1075,7 +1076,7 @@ class ShardedSparseTable(SparseTable):
 
         _count_begin("begin_entry", [c.rows for c in self._caches()])
         with _PASS.stage("census"):
-            pk = np.unique(np.asarray(pass_keys, dtype=np.uint64))
+            pk = sorted_census(pass_keys)
             # global census: the shared-dictionary exchange (hot/cached
             # keys ride as membership bits, the cold tail as varint deltas
             # — parallel/census.py) with byte-identical union semantics;

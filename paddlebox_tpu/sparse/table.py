@@ -76,6 +76,24 @@ _UNIQ_GROWS = _tm.counter(
     "bucket and moved it (each is a new step shape)")
 
 
+_RESORTED = _tm.counter(
+    "pass.census_resorted", "censuses handed to begin_pass / prepare_pass "
+    "that were not ascending and distinct and went through np.unique")
+
+
+def sorted_census(pass_keys) -> np.ndarray:
+    """A pass's census as the table holds it: uint64, ascending, distinct.
+    A dataset's ``unique_keys()`` already is (it was sorted where the pass
+    loaded), so one comparison of neighbours takes it as it is — the array
+    itself, not a copy: the caller leaves it alone while the pass is open.
+    Any other caller's keys are sorted and deduplicated here, as before."""
+    pk = np.asarray(pass_keys, dtype=np.uint64).reshape(-1)
+    if (pk[1:] > pk[:-1]).all():
+        return pk
+    _RESORTED.inc()
+    return np.unique(pk)
+
+
 def _count_begin(at: str, arrays) -> None:
     """Was the device still working when begin_pass got here?  ``is_ready``
     on the arrays the boundary's device work produces (the cache's rows
@@ -613,7 +631,7 @@ class SparseTable:
         t0 = time.perf_counter()
         if callable(pass_keys):
             pass_keys = pass_keys()
-        pk = np.unique(np.asarray(pass_keys, dtype=np.uint64))
+        pk = sorted_census(pass_keys)
         cache_keys, stage_seq, entries = self._stage_snapshot()
         w = self.conf.row_width
         cap = self._stage_cap(pk.shape[0])
@@ -817,7 +835,7 @@ class SparseTable:
         cache = self._get_cache()
         _count_begin("begin_entry", [cache.rows] if cache is not None else [])
         with _PASS.stage("census"):
-            pk = np.unique(np.asarray(pass_keys, dtype=np.uint64))
+            pk = sorted_census(pass_keys)
         w = self.conf.row_width
         # layout: [0, n) live rows | [n, cap-1) plan scratch | cap-1 dead.
         # Scratch rows give every padding/missing plan slot a distinct

@@ -49,6 +49,73 @@ def test_begin_pass_initializes_new_rows():
     np.testing.assert_allclose(vals[t.dead_row], 0.0)
 
 
+def _serial_table():
+    return SparseTable(_conf(), seed=0)
+
+
+def _sharded_table():
+    from paddlebox_tpu.parallel import make_mesh
+    from paddlebox_tpu.parallel.sharded_table import ShardedSparseTable
+
+    return ShardedSparseTable(_conf(), make_mesh(8), seed=0)
+
+
+def _rows_by_key(t):
+    """{key: row} of a finished pass, whatever the plane."""
+    st = t.state_dict()
+    return dict(zip(st["keys"].tolist(), map(tuple, st["values"].tolist())))
+
+
+_SORTED = np.array([3, 7, 19, 64, 99, 123, 1 << 40], dtype=np.uint64)
+
+
+@pytest.mark.parametrize("make", [_serial_table, _sharded_table],
+                         ids=["serial", "sharded"])
+@pytest.mark.parametrize("census,resorted", [
+    (_SORTED, 0),
+    (_SORTED[::-1].copy(), 1),
+    (np.concatenate([_SORTED, _SORTED[2:5]]), 1),
+    (_SORTED.astype(np.int64), 0),  # another dtype: converted, not sorted
+    (np.empty(0, np.uint64), 0),
+], ids=["sorted", "unsorted", "repeats", "int64", "empty"])
+def test_begin_pass_takes_a_sorted_census_as_it_is(make, census, resorted):
+    """begin_pass's first stage: a census that ascends strictly (what a
+    dataset's unique_keys() hands over) is not sorted again; any other
+    gives the pass table np.unique gave before."""
+    c = telemetry.counter("pass.census_resorted")
+    want = make()
+    want.begin_pass(np.unique(census.astype(np.uint64)))
+    want.end_pass()
+    before = c.value()
+    t = make()
+    t.begin_pass(census)
+    assert c.value() - before == resorted
+    if make is _serial_table:
+        np.testing.assert_array_equal(t._pass_keys, np.unique(census))
+        assert t._pass_keys.dtype == np.uint64
+        # taken as it is: the table holds the caller's array, not a copy
+        assert np.shares_memory(t._pass_keys, census) == (
+            resorted == 0 and census.dtype == np.uint64 and census.size > 0)
+    t.end_pass()
+    assert _rows_by_key(t) == _rows_by_key(want)
+
+
+def test_prepare_pass_takes_a_sorted_census_as_it_is():
+    """The staged path sorts through the same helper as begin_pass."""
+    c = telemetry.counter("pass.census_resorted")
+    t = SparseTable(_conf(), seed=0)
+    before = c.value()
+    t.prepare_pass(_SORTED)
+    t.begin_pass(_SORTED)
+    t.end_pass()
+    assert c.value() == before
+    t.prepare_pass(_SORTED[::-1].copy())
+    t.begin_pass(_SORTED)
+    assert c.value() == before + 1
+    np.testing.assert_array_equal(t._pass_keys, _SORTED)
+    t.end_pass()
+
+
 def test_pull_gathers_and_dead_row_reads_zero():
     t = SparseTable(_conf())
     t.begin_pass(np.array([10, 20, 30], dtype=np.uint64))
