@@ -40,9 +40,8 @@ from paddlebox_tpu.data.feed import BatchBuilder, HostBatch
 from paddlebox_tpu.data.record import RecordBlock
 from paddlebox_tpu.data.slot_parser import SlotParser
 from paddlebox_tpu.utils.monitor import stats
-from paddlebox_tpu.utils.profiler import timed
+from paddlebox_tpu.utils.profiler import START, timed
 from paddlebox_tpu.utils.retry import retry_call
-from paddlebox_tpu.utils.timer import Timer
 
 logger = logging.getLogger(__name__)
 
@@ -81,7 +80,6 @@ class PadBoxSlotDataset:
         self._preload_pool = futures.ThreadPoolExecutor(max_workers=1)
         self._rng = np.random.default_rng(0)
         self.shuffler = None  # optional multi-host shuffler (data/shuffle.py)
-        self.read_timer = Timer()
 
     # -- filelist / date ------------------------------------------------ #
     def set_filelist(self, files: Sequence[str]) -> None:
@@ -95,8 +93,11 @@ class PadBoxSlotDataset:
     def _parse_with_retry(self, path: str) -> RecordBlock:
         """One file read through the unified retry helper: transient fs
         failures (OSError, a failed `hadoop fs -cat` pipe) retry; parse
-        errors (ValueError) never do."""
-        return retry_call(self.parser.parse_file, path, site="data.read")
+        errors (ValueError) never do.  Timed as ``start.read_parse`` on
+        the reader thread it runs on: the stage's seconds are the sum of
+        the threads' wall, over ``dataset_load``'s where they overlap."""
+        with START.stage("read_parse"):
+            return retry_call(self.parser.parse_file, path, site="data.read")
 
     def _parse_with_census(self, path: str) -> tuple:
         """... and the file's keys counted on the same reader thread, which
@@ -119,29 +120,29 @@ class PadBoxSlotDataset:
                 f"quarantined, over quarantine_abort_frac={limit:.2%}"
             )
 
+    @START.wrap("dataset_load")
     def _read_all(self) -> RecordBlock:
-        self.read_timer.resume()
-        try:
-            if not self.filelist:
-                raise RuntimeError("set_filelist before loading")
-            q0, p0 = self.parser.quarantined_lines, self.parser.parsed_lines
-            if self.shuffler is None:
-                blocks, censuses = zip(*self._pool.map(
-                    self._parse_with_census, self.filelist))
-            else:
-                # the exchange hands back other records than were parsed
-                # here: the census is of the exchanged block, whole
-                blocks, censuses = list(self._pool.map(
-                    self._parse_with_retry, self.filelist)), ()
-            self._check_quarantine(q0, p0)
+        """Files -> the usable block (``start.dataset_load``: the reader
+        threads' ``read_parse``, then ``merge``)."""
+        if not self.filelist:
+            raise RuntimeError("set_filelist before loading")
+        q0, p0 = self.parser.quarantined_lines, self.parser.parsed_lines
+        if self.shuffler is None:
+            blocks, censuses = zip(*self._pool.map(
+                self._parse_with_census, self.filelist))
+        else:
+            # the exchange hands back other records than were parsed
+            # here: the census is of the exchanged block, whole
+            blocks, censuses = list(self._pool.map(
+                self._parse_with_retry, self.filelist)), ()
+        self._check_quarantine(q0, p0)
+        with START.stage("merge"):
             block = RecordBlock.concat(blocks)
             if self.shuffler is not None:
                 block = self.shuffler.exchange(block)
             with _census_build():
                 block.set_census(censuses)
-            return block
-        finally:
-            self.read_timer.pause()
+        return block
 
     def load_into_memory(self) -> None:
         self._block = self._read_all()
@@ -156,6 +157,7 @@ class PadBoxSlotDataset:
         self._preload = self._preload_pool.submit(self._read_all)
 
     # -- disk spill ------------------------------------------------------- #
+    @START.wrap("dataset_load")
     def _read_to_disk(self, spill_dir: str) -> _DiskSpill:
         """Parse -> archive each input file to local disk *incrementally*:
         at most ``read_threads`` parsed blocks are in flight at any moment,
@@ -173,72 +175,68 @@ class PadBoxSlotDataset:
 
         from paddlebox_tpu.data.archive import write_archive
 
-        self.read_timer.resume()
-        try:
-            os.makedirs(spill_dir, exist_ok=True)
-            if not self.filelist:
-                raise RuntimeError("set_filelist before loading")
-            q0, p0 = self.parser.quarantined_lines, self.parser.parsed_lines
-            if self.shuffler is not None:
-                blocks = list(
-                    self._pool.map(self._parse_with_retry, self.filelist)
-                )
-                self._check_quarantine(q0, p0)
-                block = RecordBlock.concat(blocks)
-                block = self.shuffler.exchange(block)
-                # chunk the exchanged pass so train-time _disk_batches
-                # streams one chunk at a time instead of the whole pass
-                n_chunks = max(len(self.filelist), 1)
-                chunk = max((block.n_ins + n_chunks - 1) // n_chunks, 1)
-                paths = []
-                for i, lo in enumerate(range(0, block.n_ins, chunk)):
-                    out = os.path.join(spill_dir, f"spill-{i:05d}.bin")
-                    write_archive(
-                        out,
-                        [block.select(
-                            np.arange(lo, min(lo + chunk, block.n_ins))
-                        )],
-                    )
-                    paths.append(out)
-                return _DiskSpill(paths, np.unique(block.keys), block.n_ins)
-
-            high_water = max(int(self.read_threads), 1)
-            inflight: deque = deque()
-            paths: list[str] = []
-            key_chunks: list[np.ndarray] = []
-            n_ins = 0
-            self.spill_peak_inflight = 0  # observability (tested)
-
-            def drain_one() -> None:
-                nonlocal n_ins
-                block = inflight.popleft().result()
-                i = len(paths)
-                out = os.path.join(spill_dir, f"spill-{i:05d}.bin")
-                write_archive(out, [block])
-                paths.append(out)
-                key_chunks.append(np.unique(block.keys))
-                n_ins += block.n_ins
-                # block goes out of scope here: peak residency is bounded by
-                # the in-flight window, never the whole pass
-
-            for f in self.filelist:
-                inflight.append(self._pool.submit(self._parse_with_retry, f))
-                self.spill_peak_inflight = max(
-                    self.spill_peak_inflight, len(inflight)
-                )
-                if len(inflight) >= high_water:
-                    drain_one()
-            while inflight:
-                drain_one()
-            self._check_quarantine(q0, p0)
-            uniq = (
-                np.unique(np.concatenate(key_chunks))
-                if key_chunks
-                else np.empty(0, dtype=np.uint64)
+        os.makedirs(spill_dir, exist_ok=True)
+        if not self.filelist:
+            raise RuntimeError("set_filelist before loading")
+        q0, p0 = self.parser.quarantined_lines, self.parser.parsed_lines
+        if self.shuffler is not None:
+            blocks = list(
+                self._pool.map(self._parse_with_retry, self.filelist)
             )
-            return _DiskSpill(paths, uniq, n_ins)
-        finally:
-            self.read_timer.pause()
+            self._check_quarantine(q0, p0)
+            block = RecordBlock.concat(blocks)
+            block = self.shuffler.exchange(block)
+            # chunk the exchanged pass so train-time _disk_batches
+            # streams one chunk at a time instead of the whole pass
+            n_chunks = max(len(self.filelist), 1)
+            chunk = max((block.n_ins + n_chunks - 1) // n_chunks, 1)
+            paths = []
+            for i, lo in enumerate(range(0, block.n_ins, chunk)):
+                out = os.path.join(spill_dir, f"spill-{i:05d}.bin")
+                write_archive(
+                    out,
+                    [block.select(
+                        np.arange(lo, min(lo + chunk, block.n_ins))
+                    )],
+                )
+                paths.append(out)
+            return _DiskSpill(paths, np.unique(block.keys), block.n_ins)
+
+        high_water = max(int(self.read_threads), 1)
+        inflight: deque = deque()
+        paths: list[str] = []
+        key_chunks: list[np.ndarray] = []
+        n_ins = 0
+        self.spill_peak_inflight = 0  # observability (tested)
+
+        def drain_one() -> None:
+            nonlocal n_ins
+            block = inflight.popleft().result()
+            i = len(paths)
+            out = os.path.join(spill_dir, f"spill-{i:05d}.bin")
+            write_archive(out, [block])
+            paths.append(out)
+            key_chunks.append(np.unique(block.keys))
+            n_ins += block.n_ins
+            # block goes out of scope here: peak residency is bounded by
+            # the in-flight window, never the whole pass
+
+        for f in self.filelist:
+            inflight.append(self._pool.submit(self._parse_with_retry, f))
+            self.spill_peak_inflight = max(
+                self.spill_peak_inflight, len(inflight)
+            )
+            if len(inflight) >= high_water:
+                drain_one()
+        while inflight:
+            drain_one()
+        self._check_quarantine(q0, p0)
+        uniq = (
+            np.unique(np.concatenate(key_chunks))
+            if key_chunks
+            else np.empty(0, dtype=np.uint64)
+        )
+        return _DiskSpill(paths, uniq, n_ins)
 
     def preload_into_disk(self, spill_dir: str) -> None:
         """Background parse-to-disk (PreLoadIntoDisk analog): the pass data

@@ -64,6 +64,13 @@ from paddlebox_tpu.parallel.sharded_table import ShardedBatchPlan, ShardedSparse
 from paddlebox_tpu.sparse.optimizer import sparse_adagrad_update
 from paddlebox_tpu.sparse.table import merge_occurrences, scatter_add_rows
 from paddlebox_tpu.telemetry.compiles import counted_jit, stage_scope
+from paddlebox_tpu.utils.profiler import (
+    HOST,
+    START,
+    CompletionWatcher,
+    StatsProfiler,
+    pass_seconds,
+)
 from paddlebox_tpu.utils import faults
 from paddlebox_tpu.train.slot_policy import (
     normalize_slot_mask,
@@ -322,6 +329,7 @@ class MultiChipTrainer:
     """Drives model + ShardedSparseTable over a mesh (BoxPSTrainer analog:
     one worker per device — here, one shard_map body per device)."""
 
+    @START.wrap("trainer_init")
     def __init__(
         self,
         model,
@@ -384,8 +392,6 @@ class MultiChipTrainer:
         self.async_dense = None  # lazily created in "async" mode
         self.global_step = 0
         self.last_metric_state = None  # dict after a pass (Trainer parity)
-        from paddlebox_tpu.utils.profiler import CompletionWatcher
-
         self._watch = CompletionWatcher()  # thread starts at first dispatch
 
     # -- jitted bodies ----------------------------------------------------- #
@@ -604,6 +610,7 @@ class MultiChipTrainer:
         take0 = lambda t: jax.tree.map(lambda x: local_view(x)[0], t)
         return take0(self.params), take0(self.opt_state)
 
+    @START.wrap("dense_load")
     def load_dense_state(self, params, opt_state=None) -> None:
         if params is not None:
             self.params = self._stack_local(params)
@@ -740,102 +747,124 @@ class MultiChipTrainer:
         staged via table.prepare_pass once this pass's groups are exhausted
         — the sharded half of pass-boundary pipelining (single-process
         only; multi-host prepare_pass no-ops, see sharded_table.py)."""
-        hot_cap = int(getattr(table, "hot_block_capacity", 0))
-        if self._step_fn is None or self._step_hot_cap != hot_cap:
-            self._step_fn = self._build_step(hot_cap)
-            self._step_hot_cap = hot_cap
-        if self._sync_fn is None and self.conf.sync_dense_mode == "kstep":
-            self._sync_fn = self._build_sync()
-        from paddlebox_tpu.parallel.multiprocess import is_multiprocess
-
-        multiproc = is_multiprocess()
-        async_dense = self.conf.sync_dense_mode == "async"
-        if async_dense and self.async_dense is None:
-            from paddlebox_tpu.parallel.async_dense import AsyncDenseTable
-
-            # every process hosts an identical table fed identical replicated
-            # grads, so multi-host needs no extra dense comm (the reference
-            # runs one table per node the same way)
-            p0 = jax.tree.map(lambda x: local_view(x)[0], self.params)
-            self.async_dense = AsyncDenseTable(
-                p0, optimizer=self.conf.dense_optimizer, lr=self.conf.dense_lr,
-            )
-        # telemetry: exporter/event log are process singletons (first pass
-        # starts them); host stage timing always feeds the per-stage
-        # latency histograms (plan/feed run on the producer thread; ``step``
-        # is the enqueue, the device's side is the completion watcher's)
-        from paddlebox_tpu import telemetry
-        from paddlebox_tpu.config import TelemetryConfig
-        from paddlebox_tpu.utils.profiler import StatsProfiler
-
-        tele = self.conf.telemetry or TelemetryConfig.from_flags()
-        telemetry.ensure_exporter(tele.metrics_port or None)
-        event_log = telemetry.ensure_event_log(tele.events_path or None)
         sprof = StatsProfiler()
+        # the pass's head: the step for this hot capacity, the telemetry's
+        # own set-up, the metric state and its baselines (eager programs
+        # and a read-back), the watchdog, the plan channel -- the devices
+        # idle under it, so it has a name
+        with sprof.stage("open"):
+            hot_cap = int(getattr(table, "hot_block_capacity", 0))
+            if self._step_fn is None or self._step_hot_cap != hot_cap:
+                self._step_fn = self._build_step(hot_cap)
+                self._step_hot_cap = hot_cap
+            if self._sync_fn is None and self.conf.sync_dense_mode == "kstep":
+                self._sync_fn = self._build_sync()
+            from paddlebox_tpu.parallel.multiprocess import is_multiprocess
 
-        watch = self._watch
-        pending_grads: list = []  # device grads fetched one step behind
-        pull_every = max(self.conf.sync_weight_step, 1)
-        from paddlebox_tpu.parallel.multiprocess import merge_device_axis
+            multiproc = is_multiprocess()
+            async_dense = self.conf.sync_dense_mode == "async"
+            if async_dense and self.async_dense is None:
+                from paddlebox_tpu.parallel.async_dense import AsyncDenseTable
 
-        with stage_scope("train.init"):
-            mstate = self._init_mstate(auc_state)
-            # grad-norm baseline: the accumulator carries across continued
-            # passes — snapshot NOW (a lockstep device-axis merge on every
-            # rank), the first step donates the buffer
-            gn_base = np.asarray(
-                merge_device_axis(mstate["gn"]), dtype=np.float64
-            )
-            counters_base = np.asarray(
-                merge_device_axis(mstate["counters"]), dtype=np.float64
-            ) if "counters" in mstate else None
-        vocab_keys = getattr(self.model, "vocab_keys", None)
-        pass_t0 = time.monotonic()
-        values, g2sum = table.values, table.g2sum
-        hot_values = hot_g2sum = None
-        if hot_cap:
+                # every process hosts an identical table fed identical
+                # replicated grads, so multi-host needs no extra dense comm
+                # (the reference runs one table per node the same way)
+                p0 = jax.tree.map(lambda x: local_view(x)[0], self.params)
+                self.async_dense = AsyncDenseTable(
+                    p0, optimizer=self.conf.dense_optimizer,
+                    lr=self.conf.dense_lr,
+                )
+            # telemetry: exporter/event log are process singletons (first pass
+            # starts them); host stage timing always feeds the per-stage
+            # latency histograms (plan/feed run on the producer thread;
+            # ``step`` is the enqueue, the device's side is the completion
+            # watcher's)
+            from paddlebox_tpu import telemetry
+            from paddlebox_tpu.config import TelemetryConfig
+
+            tele = self.conf.telemetry or TelemetryConfig.from_flags()
+            telemetry.ensure_exporter(tele.metrics_port or None)
+            event_log = telemetry.ensure_event_log(tele.events_path or None)
+
+            watch = self._watch
+            pending_grads: list = []  # device grads fetched one step behind
+            pull_every = max(self.conf.sync_weight_step, 1)
+            from paddlebox_tpu.parallel.multiprocess import merge_device_axis
+
             with stage_scope("train.init"):
-                hot_values, hot_g2sum = self._hot_state(table, hot_cap)
-        losses, counts, n_steps = [], [], 0
-        uses_rank = getattr(self.model, "uses_rank_offset", False)
-        uses_seq = getattr(self.model, "uses_seq_pos", False)
+                mstate = self._init_mstate(auc_state)
+                # grad-norm baseline: the accumulator carries across continued
+                # passes — snapshot NOW (a lockstep device-axis merge on
+                # every rank), the first step donates the buffer
+                gn_base = np.asarray(
+                    merge_device_axis(mstate["gn"]), dtype=np.float64
+                )
+                counters_base = np.asarray(
+                    merge_device_axis(mstate["counters"]), dtype=np.float64
+                ) if "counters" in mstate else None
+            vocab_keys = getattr(self.model, "vocab_keys", None)
+            pass_t0 = time.monotonic()
+            values, g2sum = table.values, table.g2sum
+            hot_values = hot_g2sum = None
+            if hot_cap:
+                with stage_scope("train.init"):
+                    hot_values, hot_g2sum = self._hot_state(table, hot_cap)
+            losses, counts, n_steps = [], [], 0
+            uses_rank = getattr(self.model, "uses_rank_offset", False)
+            uses_seq = getattr(self.model, "uses_seq_pos", False)
 
-        # distributed-liveness watchdog: heartbeats through the same KV
-        # store the planning plane rides, local + peer stall detection,
-        # poison-key coordinated abort.  Namespaced per pass (global_step
-        # advances in lockstep across processes) so heartbeat keys from a
-        # previous aborted pass can never poison a fresh one.
-        from paddlebox_tpu.parallel import watchdog as _wd_mod
+            # distributed-liveness watchdog: heartbeats through the same KV
+            # store the planning plane rides, local + peer stall detection,
+            # poison-key coordinated abort.  Namespaced per pass (global_step
+            # advances in lockstep across processes) so heartbeat keys from a
+            # previous aborted pass can never poison a fresh one.
+            from paddlebox_tpu.parallel import watchdog as _wd_mod
 
-        wd = None
-        if self.conf.liveness is not None:
-            wd = _wd_mod.for_trainer(
-                self.conf.liveness, namespace=f"train-{self.global_step}"
-            )
-            if wd is not None:
-                wd.start()
+            wd = None
+            if self.conf.liveness is not None:
+                wd = _wd_mod.for_trainer(
+                    self.conf.liveness, namespace=f"train-{self.global_step}"
+                )
+                if wd is not None:
+                    wd.start()
 
-        # the producer's collectives must be HOST-side: it runs concurrent
-        # with the consumer's device step, and two threads racing device
-        # collectives onto the queues in different orders across processes
-        # is a cross-process deadlock.  Each pass gets its own KV channel
-        # (deterministic name: every process increments in lockstep).
-        plan_channel = None
-        if multiproc:
-            from paddlebox_tpu.parallel.host_plane import KvChannel
+            # the producer's collectives must be HOST-side: it runs concurrent
+            # with the consumer's device step, and two threads racing device
+            # collectives onto the queues in different orders across processes
+            # is a cross-process deadlock.  Each pass gets its own KV channel
+            # (deterministic name: every process increments in lockstep).
+            plan_channel = None
+            if multiproc:
+                from paddlebox_tpu.parallel.host_plane import KvChannel
 
-            _PLAN_CHANNEL_SEQ[0] += 1
-            plan_channel = KvChannel(
-                f"plan-{_PLAN_CHANNEL_SEQ[0]}",
-                timeout_s=(
-                    self.conf.liveness.hostplane_timeout_s
-                    if self.conf.liveness is not None
-                    else self.conf.host_plane_timeout_s
-                ),
-            )
-            plan_gather = plan_channel.allgather
-        else:
-            plan_gather = host_allgather  # no-op [1, ...] wrap
+                _PLAN_CHANNEL_SEQ[0] += 1
+                plan_channel = KvChannel(
+                    f"plan-{_PLAN_CHANNEL_SEQ[0]}",
+                    timeout_s=(
+                        self.conf.liveness.hostplane_timeout_s
+                        if self.conf.liveness is not None
+                        else self.conf.host_plane_timeout_s
+                    ),
+                )
+                plan_gather = plan_channel.allgather
+            else:
+                plan_gather = host_allgather  # no-op [1, ...] wrap
+            dumper = None
+            if self.conf.need_dump_field and self.conf.dump_fields_path:
+                from paddlebox_tpu.train.dump import FieldDumper
+
+                # per-process file (the reference's per-node dump discipline):
+                # each process dumps exactly its local devices' instances
+                suffix = (
+                    f"-r{jax.process_index()}" if multiproc else ""
+                )
+                dumper = FieldDumper(
+                    os.path.join(
+                        self.conf.dump_fields_path,
+                        f"dump-{self.global_step}{suffix}.txt",
+                    ),
+                    self.conf.dump_fields,
+                )
 
         def produce_feeds():
             """Barrier + host planning + stack + H2D for every group.
@@ -915,22 +944,6 @@ class MultiChipTrainer:
                     group if dumper is not None else None,
                 )
 
-        dumper = None
-        if self.conf.need_dump_field and self.conf.dump_fields_path:
-            from paddlebox_tpu.train.dump import FieldDumper
-
-            # per-process file (the reference's per-node dump discipline):
-            # each process dumps exactly its local devices' instances
-            suffix = (
-                f"-r{jax.process_index()}" if multiproc else ""
-            )
-            dumper = FieldDumper(
-                os.path.join(
-                    self.conf.dump_fields_path,
-                    f"dump-{self.global_step}{suffix}.txt",
-                ),
-                self.conf.dump_fields,
-            )
         feed_iter = produce_feeds()
         prefetcher = None
         try:
@@ -1047,6 +1060,9 @@ class MultiChipTrainer:
             if losses:
                 losses[-1].block_until_ready()
             watch.settle()
+            # the devices have nothing queued: did the host let the pass's
+            # threads run (the feed producer answered before it exited)
+            HOST.after_drain(watch)
         with stage_scope("train.readback"), sprof.stage("readback"):
             metrics = self._read_back(mstate, losses, counts, gn_base,
                                       multiproc)
@@ -1056,51 +1072,57 @@ class MultiChipTrainer:
                     np.asarray(merge_device_axis(mstate["counters"]),
                                dtype=np.float64),
                     counters_base))
-        metrics["steps"] = n_steps
-        metrics["duration_s"] = time.monotonic() - pass_t0
-        metrics["missing_keys"] = table.missing_key_count
-        metrics["overflow_keys"] = table.overflow_key_count  # always 0 now
-        metrics["capacity_bumps"] = table.capacity_bumps
-        self.last_auc_state = mstate["auc"]
-        self.last_metric_state = mstate
-        # pass-boundary fleet view: allgather every rank's metric snapshot
-        # over the coordination-service KV and log ONE merged view on rank
-        # 0 (per-rank stage p99s, counters) — the PrintSyncTimer analog.
-        # Telemetry must never kill a healthy pass: failures log and move
-        # on.  Every rank participates (lockstep, like the collectives).
-        if multiproc and tele.fleet_snapshot:
-            _FLEET_SNAP_SEQ[0] += 1
-            try:
-                from paddlebox_tpu.parallel.watchdog import CoordKv
+        # the pass's tail is the telemetry's own -- the fleet view, the
+        # registry's delta over every series, the health rules, the
+        # pass_end record -- with the devices idle: it has a name too
+        with sprof.stage("observe"):
+            metrics["steps"] = n_steps
+            metrics["duration_s"] = time.monotonic() - pass_t0
+            pass_seconds().observe(metrics["duration_s"])
+            metrics["missing_keys"] = table.missing_key_count
+            metrics["overflow_keys"] = table.overflow_key_count  # always 0 now
+            metrics["capacity_bumps"] = table.capacity_bumps
+            self.last_auc_state = mstate["auc"]
+            self.last_metric_state = mstate
+            # pass-boundary fleet view: allgather every rank's metric snapshot
+            # over the coordination-service KV and log ONE merged view on rank
+            # 0 (per-rank stage p99s, counters) — the PrintSyncTimer analog.
+            # Telemetry must never kill a healthy pass: failures log and move
+            # on.  Every rank participates (lockstep, like the collectives).
+            if multiproc and tele.fleet_snapshot:
+                _FLEET_SNAP_SEQ[0] += 1
+                try:
+                    from paddlebox_tpu.parallel.watchdog import CoordKv
 
-                merged = telemetry.gather_fleet_snapshot(
-                    CoordKv(), rank=jax.process_index(),
-                    world=jax.process_count(), seq=_FLEET_SNAP_SEQ[0],
-                    namespace="pass", timeout_s=60.0,
-                )
-                if jax.process_index() == 0:
-                    # print, not logger: the per-pass fleet line is the
-                    # PrintSyncTimer/log_for_profile analog and must land
-                    # in the rank-0 log without logging configuration
-                    print(telemetry.format_fleet_view(
-                        merged, prefix=f"fleet pass step={self.global_step}",
-                    ), flush=True)
-            except Exception:
-                import logging
+                    merged = telemetry.gather_fleet_snapshot(
+                        CoordKv(), rank=jax.process_index(),
+                        world=jax.process_count(), seq=_FLEET_SNAP_SEQ[0],
+                        namespace="pass", timeout_s=60.0,
+                    )
+                    if jax.process_index() == 0:
+                        # print, not logger: the per-pass fleet line is the
+                        # PrintSyncTimer/log_for_profile analog and must land
+                        # in the rank-0 log without logging configuration
+                        print(telemetry.format_fleet_view(
+                            merged,
+                            prefix=f"fleet pass step={self.global_step}",
+                        ), flush=True)
+                except Exception:
+                    import logging
 
-                logging.getLogger(__name__).warning(
-                    "fleet snapshot gather failed", exc_info=True
-                )
-        # run-health plane: evaluate the rule catalog on the SAME window
-        # the pass_end record carries, BEFORE the record is written so
-        # the window's health_alert events precede its pass_end record
-        snap = telemetry.registry.delta_snapshot()
-        telemetry.observe_pass(
-            self.global_step, metrics=metrics, telemetry=snap, table=table
-        )
-        if event_log is not None:
-            event_log.log_pass(metrics, telemetry=snap,
-                               global_step=self.global_step)
+                    logging.getLogger(__name__).warning(
+                        "fleet snapshot gather failed", exc_info=True
+                    )
+            # run-health plane: evaluate the rule catalog on the SAME window
+            # the pass_end record carries, BEFORE the record is written so
+            # the window's health_alert events precede its pass_end record
+            snap = telemetry.registry.delta_snapshot()
+            telemetry.observe_pass(
+                self.global_step, metrics=metrics, telemetry=snap, table=table
+            )
+            if event_log is not None:
+                event_log.log_pass(metrics, telemetry=snap,
+                                   global_step=self.global_step)
         if plan_channel is not None:
             # every peer has joined the metric collectives above, which it
             # can only do after its producer read ALL of this channel's

@@ -49,6 +49,7 @@ from typing import Callable, Iterator, Optional, Tuple
 import numpy as np
 
 from paddlebox_tpu.utils.monitor import stats
+from paddlebox_tpu.utils.profiler import START
 
 logger = logging.getLogger(__name__)
 
@@ -294,7 +295,6 @@ class BucketStore:
                             thread_name_prefix="bucket-store",
                         )
                     pool = self._pool
-            stats.add("store.parallel_buckets", len(tasks))
             futs = [pool.submit(one, b, fn) for b, fn in tasks]
             return [f.result() for f in futs]
         return [one(b, fn) for b, fn in tasks]
@@ -421,19 +421,23 @@ class BucketStore:
 
     def load_bulk(self, keys: np.ndarray, vals: np.ndarray) -> None:
         """Replace the store content (checkpoint restore).  ``keys`` need not
-        be sorted; duplicates keep the LAST occurrence."""
+        be sorted; duplicates keep the LAST occurrence.  Two stages of the
+        ``start`` family: ``store_sort`` (the argsort, the gather of keys
+        and rows, the duplicate test) and ``store_split`` (the buckets)."""
         self.clear()
         keys = np.asarray(keys, dtype=np.uint64)
         vals = np.asarray(vals, dtype=np.float32)
-        if keys.shape[0]:
-            order = np.argsort(keys, kind="stable")
-            keys, vals = keys[order], vals[order]
-            uniq, last_idx = np.unique(keys[::-1], return_index=True)
-            if uniq.shape[0] != keys.shape[0]:
-                take = keys.shape[0] - 1 - last_idx  # last occurrence wins
-                keys, vals = uniq, vals[take]
-        for b, idx in self._split(keys):
-            self._set(b, keys[idx], vals[idx])
+        with START.stage("store_sort"):
+            if keys.shape[0]:
+                order = np.argsort(keys, kind="stable")
+                keys, vals = keys[order], vals[order]
+                uniq, last_idx = np.unique(keys[::-1], return_index=True)
+                if uniq.shape[0] != keys.shape[0]:
+                    take = keys.shape[0] - 1 - last_idx  # last one wins
+                    keys, vals = uniq, vals[take]
+        with START.stage("store_split"):
+            for b, idx in self._split(keys):
+                self._set(b, keys[idx], vals[idx])
 
     def stats(self) -> dict:
         """Bucket-by-bucket size/finiteness report WITHOUT materializing a

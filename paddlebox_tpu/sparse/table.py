@@ -53,7 +53,7 @@ from paddlebox_tpu.data.feed import HostBatch
 from paddlebox_tpu.sparse.optimizer import sparse_adagrad_update
 from paddlebox_tpu.telemetry import metrics as _tm
 from paddlebox_tpu.telemetry.compiles import stage_scope
-from paddlebox_tpu.utils.profiler import StatsProfiler
+from paddlebox_tpu.utils.profiler import START, StatsProfiler
 
 logger = logging.getLogger(__name__)
 
@@ -389,18 +389,13 @@ class SparseTable:
         """Host-tier fetch of cache-MISS rows — the begin-pass promotion
         patch, now O(cold keys).  Chaos site ``cache.fetch``: a failure
         here must degrade to the full synchronous host resolve, never
-        corrupt rows (the callers catch and call _cache_degrade)."""
-        from paddlebox_tpu import telemetry
+        corrupt rows (the callers catch and call _cache_degrade).  Timed by
+        its callers: ``pass.stage_seconds{stage=fetch}`` in ``begin_pass``,
+        ``pass.promote_seconds`` on the staging thread."""
         from paddlebox_tpu.utils import faults
 
         faults.inject("cache.fetch")
-        t0 = time.perf_counter()
-        rows = self._resolve_or_init(miss, _entries=_entries)
-        telemetry.histogram(
-            "cache.miss_fetch_seconds",
-            "host-tier fetch of the census cache misses (promotion patch)",
-        ).observe(time.perf_counter() - t0)
-        return rows
+        return self._resolve_or_init(miss, _entries=_entries)
 
     def _cache_degrade(self, pk: np.ndarray) -> None:
         """cache.fetch failed: push every dirty row to the host tier and
@@ -1246,7 +1241,12 @@ class SparseTable:
         return {"keys": keys, "values": vals}
 
     @stage_scope("table.load")
+    @START.wrap("table_load")
     def load_state_dict(self, state: dict) -> None:
+        """Restore the host store from a checkpoint's rows
+        (``start.table_load``; inside it ``store_sort`` and ``store_split``
+        (``HostStore.load_bulk``), ``invalidate``, and ``log_rewrite``
+        where a log is attached)."""
         self.flush()  # pending merges must not land on top of the restore
         self._discard_stage()  # a staged pass resolved pre-restore is stale
         self._store.load_bulk(
@@ -1254,12 +1254,14 @@ class SparseTable:
             np.asarray(state["values"], dtype=np.float32),
         )
         # every cached row is now stale relative to the restored store
-        self._invalidate_caches()
+        with START.stage("invalidate"):
+            self._invalidate_caches()
         if self._log is not None:
             # re-sync the durable chain: recovery must reproduce the
             # restored state, not the pre-restore one
-            lk, lv = self._store.materialize()
-            self._log.rewrite(lk, lv)
+            with START.stage("log_rewrite"):
+                lk, lv = self._store.materialize()
+                self._log.rewrite(lk, lv)
 
     def pass_state_dict(self) -> dict:
         """Snapshot usable mid-pass: the live working set when a pass is

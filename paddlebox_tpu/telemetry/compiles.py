@@ -22,7 +22,19 @@ thread-local scope string: ``train.step``, ``spmd.step``,
   * ``jit.compiles`` (counter, label ``stage``) — backend compiles per
     stage; steady state means the per-stage count stops moving;
   * ``jit.compile_seconds`` (histogram, label ``stage``) — where the
-    compile wall time goes (warmup cost is real and worth seeing).
+    compile wall time goes (warmup cost is real and worth seeing).  Where
+    the persistent compile cache served the executable (``jit.cache_hits``
+    counts those), the event's duration is the retrieval: reading and
+    deserializing the cached program, not a compile.
+
+The two phases before the backend are heard the same way, with the same
+attribution: ``/jax/core/compile/jaxpr_trace_duration`` into
+``jit.trace_seconds`` (Python tracing a function to a jaxpr; a function
+traced inside another's trace counts once, in its own observation) and
+``/jax/core/compile/jaxpr_to_mlir_module_duration`` into
+``jit.lower_seconds`` (the jaxpr to StableHLO).  Neither is skipped by the
+persistent cache, whose key is made from the lowered module; a decoder's
+``train.step`` is half a million characters of it.
 
 ``counted_jit(fn, stage=..., **jit_kwargs)`` is the adoption surface:
 a drop-in ``jax.jit`` replacement whose calls run inside the stage
@@ -41,6 +53,7 @@ from __future__ import annotations
 
 import contextlib
 import threading
+import time
 
 from paddlebox_tpu.telemetry import metrics
 
@@ -56,12 +69,23 @@ _CACHE_HIT_EVENT = "/jax/compilation_cache/cache_hits"
 #: library internals) — visible, not silently dropped.
 UNTAGGED = "untagged"
 
+#: the phases before it: Python -> jaxpr, jaxpr -> StableHLO
+_TRACE_EVENT = "/jax/core/compile/jaxpr_trace_duration"
+_LOWER_EVENT = "/jax/core/compile/jaxpr_to_mlir_module_duration"
+
 _COMPILES = metrics.counter(
     "jit.compiles",
     "XLA backend compiles by stage (zero per stage in steady state)",
 )
 _COMPILE_SECONDS = metrics.histogram(
-    "jit.compile_seconds", "XLA backend compile wall time by stage",
+    "jit.compile_seconds",
+    "XLA backend compile wall time by stage (a cache hit's: the retrieval)",
+)
+_TRACE_SECONDS = metrics.histogram(
+    "jit.trace_seconds", "time tracing functions to jaxprs, by stage",
+)
+_LOWER_SECONDS = metrics.histogram(
+    "jit.lower_seconds", "time lowering jaxprs to StableHLO, by stage",
 )
 _CACHE_HITS = metrics.counter(
     "jit.cache_hits",
@@ -105,11 +129,37 @@ class stage_scope(contextlib.ContextDecorator):
 
 
 def _on_event(event: str, duration_secs: float, **kwargs) -> None:
-    if event != _COMPILE_EVENT:
+    if event == _COMPILE_EVENT:
+        stage = current_stage()
+        _COMPILES.inc(stage=stage)
+        _COMPILE_SECONDS.observe(duration_secs, stage=stage)
         return
-    stage = current_stage()
-    _COMPILES.inc(stage=stage)
-    _COMPILE_SECONDS.observe(duration_secs, stage=stage)
+    if event == _TRACE_EVENT:
+        _TRACE_SECONDS.observe(_outside_inner_traces(duration_secs),
+                               stage=current_stage())
+    elif event == _LOWER_EVENT:
+        _LOWER_SECONDS.observe(duration_secs, stage=current_stage())
+
+
+def _outside_inner_traces(duration_secs: float) -> float:
+    """A trace's seconds without the traces that ran inside it.  The trace
+    event also fires for every function traced on the way (each ``jnp``
+    call of a model is a jitted function of its own), inside the outer
+    one's duration and before it: the events of a thread come in
+    post-order, so the inner ones are the latest that ended after this one
+    began.  What is observed then adds up to the outermost traces' wall.
+    The list holds the traces no later one enclosed: one entry a trace-
+    cache miss at the top level."""
+    done = getattr(_tls, "traced", None)
+    if done is None:
+        done = _tls.traced = []
+    now = time.perf_counter()
+    began = now - duration_secs
+    inner = 0.0
+    while done and done[-1][0] >= began:
+        inner += done.pop()[1]
+    done.append((now, duration_secs))
+    return max(duration_secs - inner, 0.0)
 
 
 def _on_plain_event(event: str, **kwargs) -> None:
@@ -155,18 +205,25 @@ def total_compiles() -> int:
 
 
 def compile_summary() -> dict:
-    """{stage: {"compiles", "cache_hits", "seconds"}}: XLA backend compiles
-    that really ran (compile events minus the ones the persistent cache
-    served), those cache hits, and the wall time of both."""
+    """{stage: {"compiles", "cache_hits", "trace_seconds", "lower_seconds",
+    "seconds"}}: XLA backend compiles that really ran (compile events
+    minus the ones the persistent cache served), those cache hits, and the
+    wall time of the three phases: tracing, lowering, and the backend's
+    (compiles and retrievals together).  A stage that only traced or
+    lowered (a ``.lower()`` never compiled) is listed too."""
+    events = compiles_by_stage()
+    phases = {"trace_seconds": _TRACE_SECONDS, "lower_seconds": _LOWER_SECONDS,
+              "seconds": _COMPILE_SECONDS}
+    stages = {dict(key).get("stage", UNTAGGED)
+              for hist in phases.values() for key in hist.series()}
     out: dict = {}
-    for stage, events in compiles_by_stage().items():
+    for stage in sorted(stages):
         hits = int(_CACHE_HITS.value(stage=stage))
-        out[stage] = {
-            "compiles": events - hits,
-            "cache_hits": hits,
-            "seconds": round(
-                _COMPILE_SECONDS.summary(stage=stage)["sum"] or 0.0, 3),
-        }
+        out[stage] = {"compiles": events.get(stage, 0) - hits,
+                      "cache_hits": hits}
+        for name, hist in phases.items():
+            out[stage][name] = round(
+                hist.summary(stage=stage)["sum"] or 0.0, 3)
     return out
 
 

@@ -38,6 +38,14 @@ from paddlebox_tpu.metrics.auc import (
 from paddlebox_tpu.metrics.variants import MetricGroup
 from paddlebox_tpu.sparse.table import SparseTable, pull_rows, push_and_update
 from paddlebox_tpu.telemetry.compiles import counted_jit, stage_scope
+from paddlebox_tpu.utils.profiler import (
+    HOST,
+    START,
+    CompletionWatcher,
+    StatsProfiler,
+    device_trace,
+    pass_seconds,
+)
 from paddlebox_tpu.utils import faults
 from paddlebox_tpu.utils.monitor import stats
 
@@ -218,7 +226,6 @@ class _FeedPrefetcher:
         import threading
 
         from paddlebox_tpu.telemetry import trace
-        from paddlebox_tpu.utils.profiler import StatsProfiler
 
         self._q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
         self._stop = False
@@ -249,6 +256,9 @@ class _FeedPrefetcher:
             for item in gen:
                 if self._stop or not put(item):
                     return
+            # this thread lives one pass: its run-queue wait is told
+            # before the sentinel lets the consumer go on
+            HOST.thread("feed")
             put(self._SENTINEL)
         except BaseException as e:  # surfaced to the consumer
             put(e)
@@ -308,6 +318,7 @@ class _FeedPrefetcher:
 class Trainer:
     """Drives model + SparseTable over a dataset's batches."""
 
+    @START.wrap("trainer_init")
     def __init__(
         self,
         model,
@@ -369,8 +380,6 @@ class Trainer:
         self.global_step = 0
         self._pass_idx = 0
         self.last_metric_state = None
-        from paddlebox_tpu.utils.profiler import CompletionWatcher
-
         self._watch = CompletionWatcher()  # thread starts at first dispatch
 
     def close(self) -> None:
@@ -562,6 +571,7 @@ class Trainer:
         """(params, opt_state) for CheckpointManager.save_*."""
         return self.params, self.opt_state
 
+    @START.wrap("dense_load")
     def load_dense_state(self, params, opt_state=None) -> None:
         if params is not None:
             self.params = params
@@ -628,83 +638,82 @@ class Trainer:
         the last completed pass and raises PassRolledBack — in that one
         case the pass was aborted and the caller must skip end_pass().
         """
-        if self._step_fn is None:
-            self._step_fn = self._build_step()
-        with stage_scope("train.init"):
-            mstate = self._init_mstate(auc_state)
-            # grad-norm baseline: the accumulator carries across continued
-            # passes, so the per-pass value is a delta between host
-            # snapshots (materialized NOW — the first step donates the
-            # buffer)
-            gn_base = np.asarray(mstate["gn"], dtype=np.float64)
-            counters_base = np.asarray(
-                mstate.get("counters", ()), dtype=np.float64)
-        vocab_keys = getattr(self.model, "vocab_keys", None)
-        pass_t0 = time.monotonic()
-        n_samples = [0.0]
-        values, g2sum = table.values, table.g2sum
-        losses, n_steps = [], 0
-        uses_rank = getattr(self.model, "uses_rank_offset", False)
-        uses_seq = getattr(self.model, "uses_seq_pos", False)
-        dumper = None
-        if self.conf.need_dump_field and self.conf.dump_fields_path:
-            from paddlebox_tpu.train.dump import FieldDumper
-
-            dumper = FieldDumper(
-                os.path.join(
-                    self.conf.dump_fields_path, f"dump-{self.global_step}.txt"
-                ),
-                self.conf.dump_fields,
-            )
-        from paddlebox_tpu.utils.profiler import (
-            CompletionWatcher,
-            StatsProfiler,
-            device_trace,
-        )
-        from paddlebox_tpu import telemetry
-
-        # telemetry policy: explicit config wins, env flags otherwise
-        # (PBOX_METRICS_PORT / PBOX_TRACE_DIR / PBOX_EVENTS_PATH — the
-        # launcher's per-rank knobs).  The exporter/event log are
-        # per-process singletons: first pass starts them, later passes
-        # are no-ops.
-        from paddlebox_tpu.config import TelemetryConfig
-
-        tele = self.conf.telemetry or TelemetryConfig.from_flags()
-        telemetry.ensure_exporter(tele.metrics_port or None)
-        event_log = telemetry.ensure_event_log(tele.events_path or None)
-        # host span tracing: TrainerConfig.trace_dir (which also drives the
-        # jax device trace) or the telemetry trace dir alone
-        host_trace_dir = self.conf.trace_dir or tele.trace_dir
-        if host_trace_dir:
-            from paddlebox_tpu.telemetry.events import _default_rank
-
-            telemetry.enable_tracing(pid=_default_rank())
-
         # ONE profiler, always on, and the same loop whatever is asked for:
         # profile / the trace dirs only decide what is reported and written
         # after the pass, from the registry's delta over it
         prof = StatsProfiler()
-        watch = self._watch
-        want_report = bool(self.conf.profile or host_trace_dir)
-        prof_mark = prof.mark() if want_report else None
-        complete_mark = CompletionWatcher.mark() if want_report else None
+        # the pass's head: the metric state and its baselines (eager
+        # programs and a read-back), the telemetry's own set-up, the
+        # watchdog's -- the device idles under it, so it has a name
+        with prof.stage("open"):
+            if self._step_fn is None:
+                self._step_fn = self._build_step()
+            with stage_scope("train.init"):
+                mstate = self._init_mstate(auc_state)
+                # grad-norm baseline: the accumulator carries across
+                # continued passes, so the per-pass value is a delta
+                # between host snapshots (materialized NOW — the first
+                # step donates the buffer)
+                gn_base = np.asarray(mstate["gn"], dtype=np.float64)
+                counters_base = np.asarray(
+                    mstate.get("counters", ()), dtype=np.float64)
+            vocab_keys = getattr(self.model, "vocab_keys", None)
+            pass_t0 = time.monotonic()
+            n_samples = [0.0]
+            values, g2sum = table.values, table.g2sum
+            losses, n_steps = [], 0
+            uses_rank = getattr(self.model, "uses_rank_offset", False)
+            uses_seq = getattr(self.model, "uses_seq_pos", False)
+            dumper = None
+            if self.conf.need_dump_field and self.conf.dump_fields_path:
+                from paddlebox_tpu.train.dump import FieldDumper
 
-        # distributed-liveness watchdog: stage-reported progress (feed /
-        # step) with a stall deadline; single-process runs get local stall
-        # detection, multi-process runs additionally publish heartbeats
-        # and converge on coordinated abort (parallel/watchdog.py)
-        wd_mod = _watchdog_mod()
-        wd = None
-        stall_exc: tuple = ()
-        if wd_mod is not None:
-            stall_exc = (wd_mod.DistributedStallError,)
-            if self.conf.liveness is not None:
-                wd = wd_mod.for_trainer(
-                    self.conf.liveness, namespace=f"train-{self.global_step}"
+                dumper = FieldDumper(
+                    os.path.join(self.conf.dump_fields_path,
+                                 f"dump-{self.global_step}.txt"),
+                    self.conf.dump_fields,
                 )
-                if wd is not None:
-                    wd.start()
+            from paddlebox_tpu import telemetry
+
+            # telemetry policy: explicit config wins, env flags otherwise
+            # (PBOX_METRICS_PORT / PBOX_TRACE_DIR / PBOX_EVENTS_PATH — the
+            # launcher's per-rank knobs).  The exporter/event log are
+            # per-process singletons: first pass starts them, later passes
+            # are no-ops.
+            from paddlebox_tpu.config import TelemetryConfig
+
+            tele = self.conf.telemetry or TelemetryConfig.from_flags()
+            telemetry.ensure_exporter(tele.metrics_port or None)
+            event_log = telemetry.ensure_event_log(tele.events_path or None)
+            # host span tracing: TrainerConfig.trace_dir (which also drives
+            # the jax device trace) or the telemetry trace dir alone
+            host_trace_dir = self.conf.trace_dir or tele.trace_dir
+            if host_trace_dir:
+                from paddlebox_tpu.telemetry.events import _default_rank
+
+                telemetry.enable_tracing(pid=_default_rank())
+
+            watch = self._watch
+            want_report = bool(self.conf.profile or host_trace_dir)
+            prof_mark = prof.mark() if want_report else None
+            complete_mark = CompletionWatcher.mark() if want_report else None
+
+            # distributed-liveness watchdog: stage-reported progress (feed
+            # / step) with a stall deadline; single-process runs get local
+            # stall detection, multi-process runs additionally publish
+            # heartbeats and converge on coordinated abort
+            # (parallel/watchdog.py)
+            wd_mod = _watchdog_mod()
+            wd = None
+            stall_exc: tuple = ()
+            if wd_mod is not None:
+                stall_exc = (wd_mod.DistributedStallError,)
+                if self.conf.liveness is not None:
+                    wd = wd_mod.for_trainer(
+                        self.conf.liveness,
+                        namespace=f"train-{self.global_step}")
+                    if wd is not None:
+                        wd.start()
 
         def feeds():
             """(batch, device feed) stream: validation, host planning and
@@ -870,6 +879,9 @@ class Trainer:
             if losses:
                 losses[-1].block_until_ready()
             watch.settle()
+            # the device has nothing queued: did the host let the pass's
+            # threads run (the feed producer answered before it exited)
+            HOST.after_drain(watch)
         with stage_scope("train.readback"), prof.stage("readback"):
             metrics = self._read_back(mstate, losses, gn_base)
             if "counters" in mstate:
@@ -877,34 +889,41 @@ class Trainer:
                     self.model,
                     np.asarray(mstate["counters"], dtype=np.float64),
                     counters_base))
-        metrics["steps"] = n_steps
-        # samples/s without trace files: the pass_end record carries
-        # wall-clock duration and the instance count it covered
-        metrics["duration_s"] = time.monotonic() - pass_t0
-        metrics["samples"] = float(n_samples[0])
-        if want_report:
-            metrics["profile"] = prof.report(prof_mark, n_steps, complete_mark)
-            if self.conf.profile:
-                print("[profile]", prof.log_line(metrics["profile"]))
-        if host_trace_dir:
-            from paddlebox_tpu.telemetry.events import _default_rank
+        # the pass's tail is the telemetry's own -- the pass report, the
+        # registry's delta over every series, the health rules, the
+        # pass_end record -- with the device idle: it has a name too
+        with prof.stage("observe"):
+            metrics["steps"] = n_steps
+            # samples/s without trace files: the pass_end record carries
+            # wall-clock duration and the instance count it covered
+            metrics["duration_s"] = time.monotonic() - pass_t0
+            metrics["samples"] = float(n_samples[0])
+            pass_seconds().observe(metrics["duration_s"])
+            if want_report:
+                metrics["profile"] = prof.report(
+                    prof_mark, n_steps, complete_mark)
+                if self.conf.profile:
+                    print("[profile]", prof.log_line(metrics["profile"]))
+            if host_trace_dir:
+                from paddlebox_tpu.telemetry.events import _default_rank
 
-            telemetry.flush_trace(os.path.join(
-                host_trace_dir,
-                f"host-trace-r{_default_rank()}-pass{self._pass_idx}.json",
-            ))
-        # run-health plane: evaluate the rule catalog against the SAME
-        # window the pass_end record carries (the delta snapshot resets
-        # its baseline per call — there is exactly one consumer chain),
-        # BEFORE the record is written so a consumer that tails up to
-        # pass_end already has the window's health_alert events
-        snap = telemetry.registry.delta_snapshot()
-        telemetry.observe_pass(
-            self._pass_idx, metrics=metrics, telemetry=snap, table=table
-        )
-        if event_log is not None:
-            event_log.log_pass(metrics, telemetry=snap,
-                               pass_idx=self._pass_idx)
+                telemetry.flush_trace(os.path.join(
+                    host_trace_dir,
+                    f"host-trace-r{_default_rank()}-pass{self._pass_idx}"
+                    ".json",
+                ))
+            # run-health plane: evaluate the rule catalog against the SAME
+            # window the pass_end record carries (the delta snapshot resets
+            # its baseline per call — there is exactly one consumer chain),
+            # BEFORE the record is written so a consumer that tails up to
+            # pass_end already has the window's health_alert events
+            snap = telemetry.registry.delta_snapshot()
+            telemetry.observe_pass(
+                self._pass_idx, metrics=metrics, telemetry=snap, table=table
+            )
+            if event_log is not None:
+                event_log.log_pass(metrics, telemetry=snap,
+                                   pass_idx=self._pass_idx)
         self._pass_idx += 1
         self.last_auc_state = mstate["auc"]
         self.last_metric_state = mstate

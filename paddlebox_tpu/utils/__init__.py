@@ -1,4 +1,3 @@
-from paddlebox_tpu.utils.timer import Timer  # noqa: F401
 from paddlebox_tpu.utils.monitor import StatRegistry, stats  # noqa: F401
 from paddlebox_tpu.utils.retry import (  # noqa: F401
     RetryPolicy,

@@ -27,13 +27,25 @@ it is fed, dispatched or synchronised.
 What the device did with each dispatch is the :class:`CompletionWatcher`'s
 to say: the dispatching thread never waits for the device, so a daemon
 thread does, in order, and observes ``trainer.step_complete_seconds``.
+
+The path that runs before the loop has the same primitive: ``START`` is the
+``start`` family (``start.stage_seconds{stage=}`` / ``pbox.start.<stage>``:
+dataset_load with read_parse and merge, table_load with store_sort,
+store_split, invalidate and log_rewrite, dense_load, trainer_init), one
+observation a call, a child's seconds inside its parent's.
+
+Whether the host let the threads run is :class:`HostStall`'s to say, once a
+pass: each thread's own run-queue wait, the host's CPU pressure and steal
+(``host.*`` counters, read from ``/proc``).
 """
 
 from __future__ import annotations
 
 import contextlib
 import functools
+import os
 import queue
+import resource
 import threading
 import time
 from typing import Iterator, Optional
@@ -193,6 +205,21 @@ class StatsProfiler:
         return " ".join(parts)
 
 
+#: the job-start path's stages: what runs between a restart and the first
+#: trained pass, outside the pass loop's ``trainer`` and ``pass`` families
+START = StatsProfiler("start.stage_seconds")
+
+
+def pass_seconds() -> _tm.Histogram:
+    """``trainer.pass_seconds``: one observation a ``train_from_dataset``,
+    the ``duration_s`` its metrics carry (no other series holds a whole
+    pass: the passes before a benchmark's window are its check steps and
+    its warm-up cycle)."""
+    return _tm.histogram(
+        "trainer.pass_seconds", "wall time of one train_from_dataset (s)",
+        buckets=STAGE_BUCKETS)
+
+
 def _delta_quantiles(boundaries, counts) -> tuple:
     """(p50, p99) of a window's bucket-count delta; a bucket's own edges
     stand in for the observed min and max, which a delta does not have."""
@@ -218,6 +245,7 @@ class CompletionWatcher:
     flight — the device had run dry and waited for the host."""
 
     _STOP = object()
+    _ACCOUNT = object()
 
     def __init__(self):
         self._q: "queue.Queue" = queue.Queue()
@@ -267,6 +295,9 @@ class CompletionWatcher:
             item = self._q.get()
             if item is self._STOP:
                 return
+            if item is self._ACCOUNT:
+                HOST.thread("watch")
+                continue
             result, t_dispatch = item
             try:
                 result.block_until_ready()
@@ -294,6 +325,13 @@ class CompletionWatcher:
                and time.perf_counter() < deadline):
             time.sleep(0.0002)
 
+    def account(self) -> None:
+        """Have the watcher thread add its own run-queue wait to
+        ``host.runqueue_wait_seconds{thread=watch}`` (once a pass, after
+        ``settle``: one queue put; no thread, nothing to account for)."""
+        if self._thread is not None:
+            self._q.put(self._ACCOUNT)
+
     def close(self) -> None:
         """Retire the thread (it finishes what it holds first)."""
         with self._lock:
@@ -301,6 +339,98 @@ class CompletionWatcher:
         if thread is not None:
             self._q.put(self._STOP)
             thread.join(timeout=5.0)
+
+
+class HostStall:
+    """Did the host let the pass's threads run?  When ``step_complete``,
+    ``batch`` and ``feed_wait`` stretch together, four cumulative readings
+    tell the causes apart: a thread was runnable and not running
+    (``host.runqueue_wait_seconds{thread=}``, the second field of its own
+    ``/proc/thread-self/schedstat``), the whole host lacked CPU
+    (``host.cpu_pressure_seconds``, ``some ... total=`` of
+    ``/proc/pressure/cpu``), the VM was not running
+    (``host.steal_seconds``, the ``steal`` column of ``/proc/stat``), the
+    kernel took the CPU from the process (``host.involuntary_switches``,
+    ``ru_nivcsw``) -- or none of them moved and the process stood still
+    for another reason.
+
+    Each thread reads its own file, once a pass (:meth:`thread`); the
+    dispatching thread reads the process's and the host's too
+    (:meth:`process`).  A reading adds its growth since the same thread's
+    (the process's) previous one, from zero at the first, so a series is
+    cumulative from the thread's (the host's: from the first reading's)
+    start.  A file the kernel does not have leaves its series absent,
+    never an error."""
+
+    def __init__(self, root: str = "/proc"):
+        self._root = root
+        self._tls = threading.local()
+        self._last: dict = {}
+        self._tick = float(os.sysconf("SC_CLK_TCK"))
+
+    def _first_line(self, *path: str, starts: str = "") -> Optional[list]:
+        try:
+            with open(os.path.join(self._root, *path)) as f:
+                for line in f:
+                    if line.startswith(starts):
+                        return line.split()
+        except OSError:
+            pass
+        return None
+
+    def thread(self, name: str) -> None:
+        """The calling thread's run-queue wait since its previous call."""
+        fields = self._first_line("thread-self", "schedstat")
+        if fields is None or len(fields) < 2:
+            return
+        now = int(fields[1]) * 1e-9
+        grew = now - getattr(self._tls, "wait", 0.0)
+        self._tls.wait = now
+        _tm.counter(
+            "host.runqueue_wait_seconds",
+            "time a thread was runnable and not running, by thread",
+        ).inc(max(grew, 0.0), thread=name)
+
+    def _grow(self, counter: _tm.Counter, now: float, first: float) -> None:
+        grew = now - self._last.get(counter.name, first)
+        self._last[counter.name] = now
+        counter.inc(max(grew, 0.0))
+
+    def process(self) -> None:
+        """The host's CPU pressure and steal and the process's involuntary
+        context switches since the previous call (the first call counts
+        the switches from the process's start, the host's two from now)."""
+        some = self._first_line("pressure", "cpu", starts="some")
+        total = [f for f in some or () if f.startswith("total=")]
+        if total:
+            now = int(total[0][len("total="):]) * 1e-6
+            self._grow(_tm.counter(
+                "host.cpu_pressure_seconds",
+                "time some runnable task of the host waited for a CPU "
+                "(PSI)"), now, now)
+        cpu = self._first_line("stat", starts="cpu ")
+        if cpu is not None and len(cpu) > 8:
+            now = int(cpu[8]) / self._tick
+            self._grow(_tm.counter(
+                "host.steal_seconds",
+                "CPU time the hypervisor gave to others, all CPUs"),
+                now, now)
+        self._grow(_tm.counter(
+            "host.involuntary_switches",
+            "times the kernel took the CPU from the process"),
+            float(resource.getrusage(resource.RUSAGE_SELF).ru_nivcsw), 0.0)
+
+    def after_drain(self, watch: "CompletionWatcher") -> None:
+        """What the dispatching thread does once a pass, after ``drain``:
+        it has the watcher's thread account for itself, and reads its own
+        wait, the process's and the host's."""
+        watch.account()
+        self.thread("dispatch")
+        self.process()
+
+
+#: the process's one reader of the host's stall counters
+HOST = HostStall()
 
 
 @contextlib.contextmanager
