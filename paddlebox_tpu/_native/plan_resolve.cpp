@@ -22,8 +22,10 @@
 // the numpy path BIT-FOR-BIT (pinned end-to-end by test_native_planner).
 //
 // Contract (order-insensitive form of plan_keys).  The occurrence side
-// is K long (the key buffer's capacity); the unique side is U long, the
-// caller's bucket for the batch's distinct keys (U <= K):
+// is K long: the caller's bucket for the batch's occurrences, the first K
+// slots of its key buffer (n_real <= K <= the buffer's capacity; the
+// caller knows n_real and sizes K before it asks).  The unique side is U
+// long, the caller's bucket for the batch's distinct keys (U <= K):
 //   idx[occ]      = found ? census_row : dead        (occ < n_real)
 //                 = dead                             (padding)
 //   uniq_idx[j]   = found ? census_row : min(scratch_base + j, dead)

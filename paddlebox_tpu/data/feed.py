@@ -61,7 +61,8 @@ class HostBatch:
     n_sparse_slots: int
     rank_offset: Optional[np.ndarray] = None  # int32 [B, C] (PV merge mode)
     # ordered per-instance positions (into the key buffer) of the
-    # configured sequence_slot's keys; padding = key capacity K
+    # configured sequence_slot's keys; padding = key capacity K (in the
+    # step's feed the plan's length L: train/trainer.py _host_batch_dict)
     seq_pos: Optional[np.ndarray] = None  # int32 [B, max_seq_len]
     # multi-task labels [B, T]: col 0 = primary label, cols 1.. = the
     # configured task_label_slots (present only when those are configured)

@@ -20,9 +20,10 @@ BeginFeedPass/EndFeedPass trick, §3.4):
     duplicate keys exactly like the reference's ``DedupKeysAndFillIdx`` +
     ``PushMergeCopy`` (box_wrapper.cu:457-1034), but on the host where
     dynamic shapes are free.  Everything handed to the device has a static
-    shape: the occurrence side at the batch's key capacity, the unique
-    side at the table's unique-slot bucket (``_uniq_slots``), which follows
-    the distinct keys of the batches seen.
+    shape: the occurrence side at the table's occurrence bucket
+    (``_occ_slots``), which follows the real occurrences of the batches
+    seen and not the key buffer's capacity, the unique side at the table's
+    unique-slot bucket (``_uniq_slots``), which follows their distinct keys.
   * pull_rows / push_and_update — pure jittable functions: gather, and
     ONE segment-sum over the occurrences (merge_occurrences: the merged
     gradient with the show/clk increments in its counter columns) + sparse
@@ -74,6 +75,17 @@ _UNIQ_SLOTS = _tm.counter(
 _UNIQ_GROWS = _tm.counter(
     "plan.uniq_grows", "times a batch did not fit the table's unique-slot "
     "bucket and moved it (each is a new step shape)")
+# the plan's occurrence side (SparseTable._occ_slots): keys / slots is its
+# fill, 1.0 where every slot of the key buffer is a real occurrence
+_OCC_KEYS = _tm.counter(
+    "plan.occ_keys", "real key occurrences of the planned batches "
+    "(HostBatch.n_keys)")
+_OCC_SLOTS = _tm.counter(
+    "plan.occ_slots", "occurrence slots of the planned batches: the length "
+    "of idx, inverse, key_mask and of every occurrence-sized feed leaf")
+_OCC_GROWS = _tm.counter(
+    "plan.occ_grows", "times a batch's occurrences did not fit the table's "
+    "occurrence bucket and moved it (each is a new step shape)")
 
 
 _RESORTED = _tm.counter(
@@ -154,19 +166,25 @@ class _SerialWorker:
 class BatchPlan:
     """Host-resolved device indices for one batch (all static shapes).
 
-    idx:      int32 [K] — table row per key occurrence (dead row for padding
+    The occurrence side is L long: the table's occurrence bucket
+    (SparseTable._occ_slots), which follows the real occurrences of the
+    batches planned so far, not the key buffer's capacity K (L <= K; the
+    plan covers the buffer's first L slots, which hold every real
+    occurrence).
+
+    idx:      int32 [L] — table row per key occurrence (dead row for padding
               or keys absent from the pass census).
     uniq_idx: int32 [U] — scatter target per *unique* batch key.  U is the
               table's unique-slot bucket (SparseTable._uniq_slots): it
               follows the distinct keys of the batches planned so far, not
-              the key capacity K (U <= K).  Slots [0, n_uniq) hold the
+              the occurrence side (U <= L).  Slots [0, n_uniq) hold the
               batch's distinct keys (live row, or the slot's scratch row
               for a census-missing key); every slot past them aims at its
               own scratch row.
-    inverse:  int32 [K] — position of each occurrence in uniq_idx (padding
+    inverse:  int32 [L] — position of each occurrence in uniq_idx (padding
               occurrences point at slot U-1, which is never a key's unless
-              the buffer has no padding).
-    key_mask: float32 [K] — 1.0 for real key occurrences.
+              the plan has no padding).
+    key_mask: float32 [L] — 1.0 for real key occurrences.
     n_missing: keys that were not in the pass census (observability).
     n_uniq:   distinct keys of the batch, found or missing (the slots in
               use; plan.uniq_keys / plan.uniq_slots is the fill).
@@ -182,6 +200,17 @@ class BatchPlan:
 
 def _next_pow2(n: int) -> int:
     return 1 << max(10, (n - 1).bit_length())
+
+
+def _occ_bucket(n_real: int) -> int:
+    """The occurrence bucket a batch of ``n_real`` occurrences asks for: a
+    sixteenth of headroom, rounded up to a sixteenth of the count's power
+    of two (1,024 at least, so tiles stay whole).  A batch's occurrences
+    are a sum over its instances and steady to a fraction of a percent, so
+    the headroom is far smaller than the unique side's quarter and a power
+    of two."""
+    step = max(1024, _next_pow2(n_real) >> 4)
+    return max(1, -(-(n_real + n_real // 16) // step)) * step
 
 
 def _key_uniform(keys: np.ndarray, seed: int, n_cols: int, rng_range: float) -> np.ndarray:
@@ -259,6 +288,11 @@ class SparseTable:
         # sizes the next pass's scratch region (pass 1 falls back to
         # conf.plan_scratch_rows)
         self._plan_uniq_slots = 0
+        # occurrence bucket of the plans (the length of idx, inverse,
+        # key_mask): the same kind of mark over the batches' real
+        # occurrences (_occ_slots).  The JOB's, not a dataset's: every
+        # dataset a trainer cycles through meets one length
+        self._plan_occ_slots = 0
         # native per-pass census hash index (lazily built on first plan;
         # borrows self._pass_keys, so it must drop with the pass)
         self._census_index = None
@@ -1102,9 +1136,29 @@ class SparseTable:
     def plan_batch(self, batch: HostBatch) -> BatchPlan:
         return self.plan_keys(batch.keys, batch.n_keys)
 
+    def _occ_slots(self, n_real: int, K: int) -> int:
+        """L, the length of a plan's occurrence side, for a batch of
+        ``n_real`` real occurrences in a ``K``-slot key buffer.
+
+        Gather, pooling and merge cost per occurrence slot, padding or not,
+        so L follows the occurrences the table has seen in a batch and not
+        the buffer's capacity.  Like U (_uniq_slots) it is the TABLE's
+        high-water mark: every plan of a settled stream has one length
+        (one compiled step).  A batch that does not fit moves the mark to
+        ``_occ_bucket`` of its count, never past K, where every batch fits
+        by construction (a buffer that is all real occurrences — a
+        decoder's token buffer — plans at K as before)."""
+        L = min(self._plan_occ_slots, K)
+        if L < K and (n_real > L or L == 0):
+            self._plan_occ_slots = max(
+                self._plan_occ_slots, min(K, _occ_bucket(n_real)))
+            L = min(self._plan_occ_slots, K)
+            _OCC_GROWS.inc()
+        return L
+
     def _uniq_slots(self, n_uniq: int, K: int) -> int:
         """U, the length of a plan's unique side, for a batch of ``n_uniq``
-        distinct keys in a ``K``-slot key buffer.
+        distinct keys on a ``K``-slot occurrence side.
 
         The push costs per scatter index, not per byte, so U follows the
         distinct keys the table has seen in a batch and not the buffer's
@@ -1127,23 +1181,28 @@ class SparseTable:
     def plan_keys(self, keys: np.ndarray, n_real: int) -> BatchPlan:
         """Resolve a padded key buffer to device row indices + dedup maps.
 
-        ``idx`` (the pull side, [K]) maps missing/padding occurrences to
-        the dead row (reads zeros).  ``uniq_idx`` (the push side, [U] with
-        U = _uniq_slots: the batch's distinct keys first, U <= K) maps
-        every non-live slot to its OWN scratch row (scratch_base + slot),
-        so push indices are unique by construction — push_and_update
-        scatters with unique_indices=True and XLA never pays the
-        duplicate-safe serial lowering.  Scratch rows are never pulled and
-        never merged back."""
+        The plan covers the buffer's first L slots (L = _occ_slots: every
+        real occurrence, then padding up to the table's occurrence bucket,
+        L <= K).  ``idx`` (the pull side, [L]) maps missing/padding
+        occurrences to the dead row (reads zeros).  ``uniq_idx`` (the push
+        side, [U] with U = _uniq_slots: the batch's distinct keys first,
+        U <= L) maps every non-live slot to its OWN scratch row
+        (scratch_base + slot), so push indices are unique by construction
+        — push_and_update scatters with unique_indices=True and XLA never
+        pays the duplicate-safe serial lowering.  Scratch rows are never
+        pulled and never merged back."""
         if not self._in_pass:
             raise RuntimeError("begin_pass before planning batches")
-        K = keys.shape[0]
+        # both planners size the occurrence side by the buffer they get
+        keys = keys[:self._occ_slots(n_real, keys.shape[0])]
         dead = self.dead_row
         scratch_base = self._pass_keys.shape[0]
         plan = self._plan_native(keys, n_real, dead, scratch_base)
         if plan is None:
             plan = self._plan_numpy(keys, n_real, dead, scratch_base)
         self.missing_key_count += plan.n_missing
+        _OCC_KEYS.inc(n_real)
+        _OCC_SLOTS.inc(keys.shape[0])
         _UNIQ_KEYS.inc(plan.n_uniq)
         _UNIQ_SLOTS.inc(plan.uniq_idx.shape[0])
         return plan

@@ -121,15 +121,23 @@ def _host_batch_dict(
     """Assemble the static-shape feed (numpy leaves) from a HostBatch +
     BatchPlan — _device_batch without the H2D transfer.
 
+    Every occurrence-sized leaf has the plan's length L (the table's
+    occurrence bucket, SparseTable._occ_slots), not the key buffer's K:
+    the buffer's first L slots hold every real occurrence, and a padding
+    position of ``seq_pos`` (K in the HostBatch) is L here, one past the
+    pulled rows as before.
+
     vocab_keys: a model's fixed vocabulary (sorted feasigns); the feed
-    then carries "key_class" [K], each occurrence's rank in it
+    then carries "key_class" [L], each occurrence's rank in it
     (data/feed.py key_classes).
 
     slot_lr_vec: [S] per-slot learning rates; when given the feed carries
     "uniq_lr" [U], each unique key's lr resolved from the slot of (one of)
     its occurrences — the host side of the BoxPS LR map
     (box_wrapper.h:631)."""
-    ins = np.minimum(batch.key_segments // n_slots, batch.batch_size - 1)
+    L = plan.idx.shape[0]
+    key_segments = batch.key_segments[:L]
+    ins = np.minimum(key_segments // n_slots, batch.batch_size - 1)
     key_clicks = batch.labels[ins] * plan.key_mask
     dev = {
         "idx": plan.idx,
@@ -137,7 +145,7 @@ def _host_batch_dict(
         "inverse": plan.inverse,
         "key_mask": plan.key_mask,
         "key_clicks": key_clicks,
-        "key_segments": batch.key_segments,
+        "key_segments": key_segments,
         "dense": batch.dense,
         "labels": batch.labels,
         "ins_mask": batch.ins_mask,
@@ -145,12 +153,12 @@ def _host_batch_dict(
     if batch.rank_offset is not None:
         dev["rank_offset"] = batch.rank_offset
     if batch.seq_pos is not None:
-        dev["seq_pos"] = batch.seq_pos
+        dev["seq_pos"] = np.minimum(batch.seq_pos, L)
     if batch.task_labels is not None:
         dev["task_labels"] = batch.task_labels
     if vocab_keys is not None:
         dev["key_class"] = key_classes(
-            batch.keys, batch.n_keys, vocab_keys, plan.inverse)
+            batch.keys[:L], batch.n_keys, vocab_keys, plan.inverse)
     if counter_label_tasks:
         if batch.task_labels is None:
             raise RuntimeError(

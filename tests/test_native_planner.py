@@ -104,6 +104,21 @@ def test_parity_under_provisioned_scratch():
     _assert_equal(*_plans(pass_keys, keys, 100, conf=conf))
 
 
+def _bucket_case(K, n_hit, n_miss, n_real):
+    """(census, K-slot key buffer): n_real occurrences drawn with duplicates
+    from n_hit census keys and n_miss keys the census lacks, then padding."""
+    rng = np.random.default_rng(n_real)
+    pass_keys = np.arange(1000, 9000, dtype=np.uint64)
+    pool = np.concatenate([
+        rng.choice(pass_keys, n_hit, replace=False),
+        np.arange(1 << 40, (1 << 40) + n_miss, dtype=np.uint64)])
+    keys = np.zeros(K, np.uint64)
+    keys[:n_real] = np.concatenate(
+        [pool, rng.choice(pool, n_real - pool.shape[0])
+         if n_real else pool])[rng.permutation(n_real)]
+    return pass_keys, keys
+
+
 @pytest.mark.parametrize("n_hit,n_miss,n_real", [
     (500, 60, 3000),   # duplicates, census-missing keys and padding
     (819, 0, 1500),    # 1.25 x 819 + 1 = 1,024: the headroom just fits
@@ -113,16 +128,8 @@ def test_parity_under_provisioned_scratch():
 def test_parity_bucketed_unique_side(n_hit, n_miss, n_real):
     """Both planners emit the unique side at the same bucket U_b < K, count
     the same distinct keys, and park the padding at slot U_b - 1."""
-    rng = np.random.default_rng(n_real)
-    pass_keys = np.arange(1000, 9000, dtype=np.uint64)
     K = 4096
-    pool = np.concatenate([
-        rng.choice(pass_keys, n_hit, replace=False),
-        np.arange(1 << 40, (1 << 40) + n_miss, dtype=np.uint64)])
-    keys = np.zeros(K, np.uint64)
-    keys[:n_real] = np.concatenate(
-        [pool, rng.choice(pool, n_real - pool.shape[0])
-         if n_real else pool])[rng.permutation(n_real)]
+    pass_keys, keys = _bucket_case(K, n_hit, n_miss, n_real)
     native, numpy_ = _plans(pass_keys, keys, n_real, conf=SparseTableConfig(
         embedding_dim=4, plan_scratch_rows=K))
     _assert_equal(native, numpy_)
@@ -138,6 +145,36 @@ def test_parity_bucketed_unique_side(n_hit, n_miss, n_real):
     # the slots past the keys are the same scratch rows on both sides
     np.testing.assert_array_equal(native[0].uniq_idx[n_uniq:],
                                   numpy_[0].uniq_idx[n_uniq:])
+
+
+@pytest.mark.parametrize("n_hit,n_miss,n_real,L", [
+    (500, 60, 3000, 4096),    # duplicates, census-missing keys and padding
+    (900, 0, 3855, 4096),     # 3855 + 240 = 4,095: the headroom just fits
+    (2000, 50, 3857, 5120),   # 3857 + 241 = 4,098: the next step of 1,024
+    (0, 0, 0, 1024),          # all padding
+    (700, 0, 16384, 16384),   # a full buffer (the decoder's shape): L == K
+])
+def test_parity_bucketed_occurrence_side(n_hit, n_miss, n_real, L):
+    """Both planners resolve the buffer's first L slots (L <= K, the
+    occurrence bucket): the same idx and mask, the same push target an
+    occurrence, padding in [n, L) at the dead row / slot U_b - 1 / mask 0."""
+    K = 16384
+    pass_keys, keys = _bucket_case(K, n_hit, n_miss, n_real)
+    native, numpy_ = _plans(pass_keys, keys, n_real, conf=SparseTableConfig(
+        embedding_dim=4, plan_scratch_rows=K))
+    _assert_equal(native, numpy_)
+    for plan, _ in (native, numpy_):
+        U = plan.uniq_idx.shape[0]
+        assert U <= L
+        for a in (plan.idx, plan.inverse, plan.key_mask):
+            assert a.shape == (L,)
+        assert (plan.key_mask[:n_real] == 1).all()
+        assert (plan.key_mask[n_real:] == 0).all()
+        assert (plan.inverse[n_real:] == U - 1).all()
+        # one row past the census and its scratch region: the dead row
+        assert (plan.idx[n_real:] >= pass_keys.shape[0] + K).all()
+        assert np.unique(plan.idx[n_real:]).shape[0] <= 1
+        assert plan.n_uniq == n_hit + n_miss and plan.n_missing == n_miss
 
 
 def test_e2e_training_same_result(tmp_path):

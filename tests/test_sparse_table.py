@@ -412,9 +412,13 @@ def _buffer(K, n_hit, n_miss, n_real, seed=0):
     return keys
 
 
-def _uniq_counters():
-    return tuple(telemetry.counter(f"plan.uniq_{n}").value()
+def _plan_counters(side):
+    return tuple(telemetry.counter(f"plan.{side}_{n}").value()
                  for n in ("keys", "slots", "grows"))
+
+
+_uniq_counters = functools.partial(_plan_counters, "uniq")
+_occ_counters = functools.partial(_plan_counters, "occ")
 
 
 @pytest.mark.parametrize("planner", _PLANNERS, indirect=True)
@@ -498,6 +502,20 @@ def test_plan_bucket_stops_at_the_key_capacity(planner):
     assert (small.inverse[40:] == 63).all()
 
 
+def _assert_same_job(got, want, counters=(0,)):
+    """Two runs' (metrics, live rows and g2sum, store state, dense leaves)
+    are bit-identical, and the counter columns counted something."""
+    (m_a, live_a, state_a, params_a), (m_b, live_b, state_b, params_b) = (
+        got, want)
+    assert m_a["loss"] == m_b["loss"]
+    for a, b in zip(live_a + tuple(params_a), live_b + tuple(params_b)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+    for c in counters:  # shows (and the third counter) were counted
+        assert live_a[0][:, c].sum() > 0
+    np.testing.assert_array_equal(state_a["keys"], state_b["keys"])
+    np.testing.assert_array_equal(state_a["values"], state_b["values"])
+
+
 # case -> (per-slot lr, unique side of the run under test, of the run it
 # must equal).  "bucket" is the plan as the table emits it, "capacity" the
 # same plan with the scratch slots it dropped appended again (uniq_idx at
@@ -565,11 +583,210 @@ def test_bucketed_push_equals_push_at_capacity(tmp_path, case):
         ds.close()
         return m, live, state, jax.tree.leaves(trainer.params)
 
-    m_a, live_a, state_a, params_a = run(under_test)
-    m_b, live_b, state_b, params_b = run(reference)
-    assert m_a["loss"] == m_b["loss"]
-    for a, b in zip(live_a + tuple(params_a), live_b + tuple(params_b)):
-        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
-    assert live_a[0][:, 0].sum() > 0  # shows were counted
-    np.testing.assert_array_equal(state_a["keys"], state_b["keys"])
-    np.testing.assert_array_equal(state_a["values"], state_b["values"])
+    _assert_same_job(run(under_test), run(reference))
+
+
+# -- the plan's occurrence side: a bucket over the real occurrences, not K - #
+@pytest.mark.parametrize("n_real,want", [
+    (0, 1024), (1, 1024), (964, 1024), (965, 2048),  # 964 + 60 = 1,024: full
+    (3000, 4096), (15421, 16384), (15422, 17408),
+    (106_496, 114_688), (108_000, 122_880),  # ctr_dnn_steady: 2048 x 52
+    (212_992, 229_376), (216_000, 245_760),  # xdeepfm_steady: 4096 x 52
+])
+def test_occ_bucket_rule(n_real, want):
+    """A sixteenth of headroom, rounded up to a sixteenth of the count's
+    power of two, 1,024 at least: whole tiles and one length for a count
+    that is steady to a percent."""
+    from paddlebox_tpu.sparse.table import _occ_bucket
+
+    got = _occ_bucket(n_real)
+    assert got == want and got % 1024 == 0
+    assert got >= n_real + n_real // 16
+
+
+@pytest.mark.parametrize("planner", _PLANNERS, indirect=True)
+def test_plan_occurrence_side_follows_real_occurrences(planner):
+    """Duplicates, census-missing keys and padding in one buffer: idx,
+    inverse and key_mask are L < K long, equal to the plan at K over the
+    real occurrences, and the padding in [n, L) keeps its meaning (dead
+    row, the bucket's last unique slot, mask 0)."""
+    K, n_real, n_uniq, L = 16384, 3000, 560, 4096
+    keys = _buffer(K, 500, 60, n_real)
+    plans = {}
+    for side in ("bucket", "capacity"):
+        t = SparseTable(_conf(), seed=0)
+        t.begin_pass(_CENSUS)
+        if side == "capacity":
+            t._plan_occ_slots = K  # the mark at K: the plan as it was
+        plans[side] = t.plan_keys(keys, n_real)
+    plan, full = plans["bucket"], plans["capacity"]
+    U = plan.uniq_idx.shape[0]
+    assert U == full.uniq_idx.shape[0] == 1024
+    for a, b in ((plan.idx, full.idx), (plan.inverse, full.inverse),
+                 (plan.key_mask, full.key_mask)):
+        assert a.shape == (L,) and b.shape == (K,)
+        np.testing.assert_array_equal(a, b[:L])  # padding alike: b's is longer
+    np.testing.assert_array_equal(plan.uniq_idx, full.uniq_idx)
+    assert (plan.n_uniq, plan.n_missing) == (n_uniq, 60) == (
+        full.n_uniq, full.n_missing)
+    assert (plan.key_mask[:n_real] == 1).all()
+    assert (plan.idx[n_real:] == t.dead_row).all()
+    assert (plan.inverse[n_real:] == U - 1).all()
+    assert (plan.key_mask[n_real:] == 0).all()
+    found = np.isin(keys[:n_real], _CENSUS)
+    np.testing.assert_array_equal(
+        plan.idx[:n_real][found],
+        np.searchsorted(_CENSUS, keys[:n_real][found]))
+    assert (plan.idx[:n_real][~found] == t.dead_row).all()
+
+
+@pytest.mark.parametrize("planner", _PLANNERS, indirect=True)
+def test_occ_bucket_grows_once_and_never_shrinks(planner):
+    t = SparseTable(_conf(), seed=0)
+    t.begin_pass(_CENSUS)
+    K = 16384
+    k0, s0, g0 = _occ_counters()
+    sizes = []
+    # (real occurrences, bucket): 4096 occurrences fill the bucket to its
+    # last slot and fit; one more moves it past 4097 + 256
+    for n_real, want in ((3000, 4096), (4096, 4096), (4097, 5120),
+                         (200, 5120), (5000, 5120)):
+        plan = t.plan_keys(_buffer(K, 150, 10, n_real, seed=n_real), n_real)
+        for a in (plan.idx, plan.inverse, plan.key_mask):
+            assert a.shape == (want,)
+        assert int(plan.key_mask.sum()) == n_real
+        assert plan.uniq_idx.shape[0] == 1024  # the unique side's own mark
+        sizes.append(want)
+    k1, s1, g1 = _occ_counters()
+    assert k1 - k0 == 3000 + 4096 + 4097 + 200 + 5000
+    assert s1 - s0 == sum(sizes)
+    assert g1 - g0 == 2  # the first plan's 0 -> 4096, then 4096 -> 5120
+    # the mark is the table's: it outlives the pass and meets the next
+    # dataset's batches at the same length
+    t.end_pass()
+    t.begin_pass(_CENSUS)
+    assert t.plan_keys(_buffer(K, 10, 0, 20), 20).idx.shape[0] == 5120
+    # never past K, where every batch fits by construction
+    assert t.plan_keys(_buffer(K, 150, 10, 16000), 16000).idx.shape == (K,)
+    assert t.plan_keys(_buffer(K, 150, 10, K), K).idx.shape == (K,)
+    assert _occ_counters()[2] - g0 == 3
+
+
+@pytest.mark.parametrize("planner", _PLANNERS, indirect=True)
+def test_occ_bucket_is_the_capacity_where_the_buffer_is_full(planner):
+    """A buffer whose every slot is a real occurrence (a decoder's token
+    buffer: duplicates, no padding) plans at L == K with a fill of 1.0, and
+    the unique side is capped by it."""
+    t = SparseTable(_conf(), seed=0)
+    t.begin_pass(_CENSUS)
+    K = 6000
+    k0, s0, g0 = _occ_counters()
+    for seed in (0, 1):
+        plan = t.plan_keys(_buffer(K, 700, 0, K, seed=seed), K)
+        for a in (plan.idx, plan.inverse, plan.key_mask):
+            assert a.shape == (K,)
+        assert (plan.key_mask == 1).all()
+        assert plan.uniq_idx.shape[0] == 1024
+    k1, s1, g1 = _occ_counters()
+    assert (k1 - k0, s1 - s0, g1 - g0) == (2 * K, 2 * K, 1)
+    # all distinct and no padding: the unique side stops at L == K too
+    plan = t.plan_keys(_CENSUS[:K].copy(), K)
+    assert plan.uniq_idx.shape[0] == K and plan.n_uniq == K
+    # a shorter buffer than the mark plans at its own capacity
+    assert t.plan_keys(_CENSUS[:64].copy(), 40).idx.shape == (64,)
+
+
+# case -> (table and trainer options, occurrence side of the run under
+# test, of the run it must equal).  "bucket" is the plan as the table emits
+# it (L < K), "capacity" the same job with the mark set to K beforehand
+# (every occurrence-sized leaf at the key capacity, as before the bucket),
+# "moved" the bucket for the first batch and K from the second on.
+_OCC_CASES = {
+    "plain": ({}, "bucket", "capacity"),
+    "slot_lr": ({"slot_lr": True}, "bucket", "capacity"),
+    "counter_label_tasks": ({"conv": True}, "bucket", "capacity"),
+    "sequence_slot": ({"seq": True}, "bucket", "capacity"),
+    "moved": ({}, "moved", "bucket"),
+    "moved_counter_label_tasks": ({"conv": True}, "moved", "bucket"),
+}
+
+
+@pytest.mark.parametrize("case", list(_OCC_CASES))
+def test_occurrence_bucket_equals_occurrences_at_capacity(tmp_path, case):
+    """Same work, same result: three train_from_dataset steps with the
+    occurrence side at its bucket against the same steps at the key
+    capacity K — live rows, g2sum, show/click (and the third counter), the
+    dense parameters and the loss are bit-identical.  Padding contributed
+    zeros before and is absent now."""
+    from paddlebox_tpu.models import LongSeqCtrDnn
+    from paddlebox_tpu.train.trainer import _host_batch_dict
+
+    S, B, T = 3, 64, 8
+    K, L = B * 128, 1024
+    opts, under_test, reference = _OCC_CASES[case]
+    conv, seq = opts.get("conv", False), opts.get("seq", False)
+    conf = make_synth_config(
+        n_sparse_slots=S, dense_dim=2, batch_size=B, max_feasigns_per_ins=128,
+        n_task_labels=int(conv),
+        **(dict(sequence_slot="slot0", max_seq_len=T) if seq else {}))
+    files = write_synth_files(str(tmp_path), n_files=1, ins_per_file=3 * B,
+                              n_sparse_slots=S, vocab_per_slot=40,
+                              dense_dim=2, seed=5, n_task_labels=int(conv))
+
+    def run(side):
+        ds = PadBoxSlotDataset(conf, read_threads=1)
+        ds.set_filelist(files)
+        ds.load_into_memory()
+        tconf = _conf(
+            slot_learning_rates=((0, 0.3), (2, 0.02))
+            if opts.get("slot_lr") else (),
+            cvm_offset=3 if conv else 2)
+        table = SparseTable(tconf, seed=0)
+        if seq:
+            model = LongSeqCtrDnn(S, tconf.row_width, dense_dim=2,
+                                  hidden=(8,), max_seq_len=T)
+        else:
+            model = CtrDnn(S, tconf.row_width, dense_dim=2, hidden=(8,),
+                           **(dict(layout="conv", cvm_offset=3)
+                              if conv else {}))
+        trainer = Trainer(model, tconf, TrainerConfig(
+            auc_buckets=1 << 10,
+            counter_label_tasks=(1,) if conv else ()), seed=0)
+        plan_keys = table.plan_keys
+        lengths = []
+        if side == "capacity":
+            table._plan_occ_slots = K
+
+        def plan_at(keys, n_real):
+            plan = plan_keys(keys, n_real)
+            lengths.append(plan.idx.shape[0])
+            if side == "moved":
+                table._plan_occ_slots = K  # as a batch that did not fit
+            return plan
+
+        table.plan_keys = plan_at
+        table.begin_pass(ds.unique_keys())
+        if seq:  # the feed's padding index follows the plan's length
+            batch = next(ds.batches())
+            plan = plan_keys(batch.keys, batch.n_keys)
+            n = plan.idx.shape[0]
+            assert batch.seq_pos.max() == K  # the builder pads with K
+            pos = _host_batch_dict(batch, plan, S)["seq_pos"]
+            assert pos.max() == n  # one past the pulled rows: the zero row
+            np.testing.assert_array_equal(pos == n, batch.seq_pos == K)
+            np.testing.assert_array_equal(pos[pos < n],
+                                          batch.seq_pos[batch.seq_pos < K])
+        m = trainer.train_from_dataset(ds, table)
+        assert m["steps"] == 3
+        assert lengths == {"bucket": [L] * 3, "capacity": [K] * 3,
+                           "moved": [L, K, K]}[side]
+        n = table._pass_keys.shape[0]
+        live = (np.asarray(table.values)[:n].copy(),
+                np.asarray(table.g2sum)[:n].copy())
+        table.end_pass()
+        state = table.state_dict()
+        ds.close()
+        return m, live, state, jax.tree.leaves(trainer.params)
+
+    _assert_same_job(run(under_test), run(reference),
+                     counters=(0, 2) if conv else (0,))
