@@ -26,7 +26,7 @@ object (no flag, no config key):
 The layer is assembled from the model's description, for x [B, T, H] (no
 biases; ``n`` = RMSNorm, eps ``rms_eps``, learned scale):
 
-    x += op_l(n1 x)          layer_types[l]: one of four operator kinds
+    x += op_l(n1 x)          layer_types[l]: one of five operator kinds
     x += ffn_l(n2 x)         mlp_types[l]: dense, or sparse (+ shared)
 
 ``layer_types[l]`` is ``"sliding_attention"`` (causal, a window of
@@ -40,13 +40,30 @@ projected up from ONE normed latent of ``kv_rank`` floats a token, a head's
 query and key are ``qk_nope`` such floats beside ``qk_rope`` floats that
 carry the rotary code (plain, adjacent pairs where ``interleaved``), the
 turned key slice is one per token shared by all heads, and the value head
-is ``v_dim`` wide (parallel/sequence.py, blockwise: no [T, T] tensor) -- or
-``"conv"``, the one kind that is not attention, a gated short convolution:
+is ``v_dim`` wide (parallel/sequence.py, blockwise: no [T, T] tensor); with
+``"rotary": False`` in ``latent`` the layer has no positional code at all
+and the ``qk_rope`` slice stays, unturned --, ``"conv"``, a gated short
+convolution:
 ``[b, c, u] = split3(h W_in)``, ``z = b * u``, a causal depthwise
 convolution of ``conv_kernel`` taps over time (one weight a channel and
 tap, zeros before the first token: ``y_t = sum_j w_j z_{t-K+1+j}``), then
 ``(c * y) W_out``; written as ``conv_kernel`` shifted multiply-adds, so no
-[B, T, K, H] tensor exists.
+[B, T, K, H] tensor exists -- or ``"kda"`` (``kda`` gives its sizes), the
+one kind that carries a state along the sequence, a gated delta rule with
+a decay a channel.  With h = n1 x, ``n_heads`` heads of ``head_dim`` = d:
+
+    q~, k~, v~ = h W_q, h W_k, h W_v, each through a causal depthwise
+        convolution of ``conv_kernel`` taps (as above) and SiLU
+    q_t = l2norm(q~_t) / sqrt(d), k_t = l2norm(k~_t), v_t = v~_t  (a head)
+    g_t = -exp(A_log) * softplus((h W_fa) W_fb + dt_bias)   <= 0, a channel
+    beta_t = sigmoid(h W_beta)                               one a head
+    S_t = (I - beta_t k_t k_t^T) diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+    o_t = S_t^T q_t              S [d, d] (key x value) a head, S_0 = 0
+    op = (rmsnorm_d(o_t) * sigmoid((h W_ga) W_gb)) W_o
+
+computed in chunks (``gated_delta_chunked``): inside a chunk the pairs'
+decays ``exp(G_i - G_j)`` as they stand, one unit-lower-triangular solve a
+chunk, and a ``lax.scan`` that carries S from chunk to chunk.
 
 ``mlp_types[l]`` is ``"dense"`` (one SwiGLU of ``dense_width``) or
 ``"sparse"``: a router over all ``n_experts`` (``router_score`` softmax or
@@ -84,14 +101,43 @@ from paddlebox_tpu.parallel.sequence import (
     rotary_tables,
 )
 
-SLIDING, FULL, LATENT, CONV = (
-    "sliding_attention", "full_attention", "latent_attention", "conv")
+SLIDING, FULL, LATENT, CONV, KDA = (
+    "sliding_attention", "full_attention", "latent_attention", "conv", "kda")
 DENSE, SPARSE = "dense", "sparse"
-LATENT_KEYS = ("kv_rank", "qk_nope", "qk_rope", "v_dim", "interleaved")
+LATENT_KEYS = ("kv_rank", "qk_nope", "qk_rope", "v_dim")
+# "rotary": False = no positional code; else "interleaved" says which pairs
+LATENT_OPTIONAL = ("interleaved", "rotary")
+KDA_KEYS = ("n_heads", "head_dim", "conv_kernel", "gate_rank")
+# gated_delta_chunked's two sizes, read on the chip (PERF.md section 6, PR
+# 39): the positions of a chunk, and how many (query, key, channel) decays
+# of the chunks' pairs are alive at once
+KDA_CHUNK = 32
+KDA_PAIR_ELEMS = 1 << 23
+
+
+def _described(what: str, given, required: tuple, optional: tuple = ()):
+    """Required keys present, unknown keys refused."""
+    given = set(given or ())
+    missing, unknown = set(required) - given, given - set(required) - set(
+        optional)
+    if missing or unknown:
+        raise ValueError(
+            f"{what} layers need {what}={required} (optional {optional}): "
+            f"missing {sorted(missing)}, unknown {sorted(unknown)}")
 
 
 def rms_norm(x: jax.Array, scale: jax.Array, eps: float) -> jax.Array:
     return x * jax.lax.rsqrt(jnp.mean(x * x, axis=-1, keepdims=True) + eps) * scale
+
+
+def causal_taps(u: jax.Array, w: jax.Array) -> jax.Array:
+    """A causal depthwise convolution over time as shifted multiply-adds:
+    u [B, T, ...], w [K, ...] -> y_t = sum_j w_j u_{t-K+1+j}, zeros before
+    the first token (tap j reads position t-K+1+j); no [B, T, K, ...]
+    tensor."""
+    T, K = u.shape[1], w.shape[0]
+    z = jnp.pad(u, ((0, 0), (K - 1, 0)) + ((0, 0),) * (u.ndim - 2))
+    return sum(w[j] * z[:, j:j + T] for j in range(K))
 
 
 @jax.custom_vjp
@@ -120,18 +166,120 @@ _take_once.defvjp(
     _take_once_bwd)
 
 
+def gated_delta_chunked(q, k, v, g, beta, chunk: int) -> jax.Array:
+    """The gated delta rule with a decay a channel, ``chunk`` positions at
+    a time.  q, k, g [B, T, nh, dk] (g <= 0: the decay's logarithm), v
+    [B, T, nh, dv], beta [B, T, nh]; returns o [B, T, nh, dv] of
+
+        S_t = (I - beta_t k_t k_t^T) diag(exp g_t) S_{t-1} + beta_t k_t v_t^T
+        o_t = S_t^T q_t                          S [dk, dv] a head, S_0 = 0
+
+    With G_t the sum of g from the chunk's start through t and S_0 the
+    state that enters the chunk:
+
+        A_ij = beta_i ((k_i * exp(G_i - G_j)) . k_j)                  j < i
+        u    = (I + A)^-1 (beta * (v - (k * exp G) S_0))
+        o_i  = (q_i * exp G_i)^T S_0
+               + sum over j <= i of ((q_i * exp(G_i - G_j)) . k_j) u_j
+        S_C  = diag(exp G_C) S_0 + sum over j of (k_j * exp(G_C - G_j)) u_j^T
+
+    Every exponent is <= 0 as written and stays so: the pairs' decays are
+    taken in the difference form, exp(G_i - G_j) for every (i, j <= i,
+    channel) of a chunk, never as (k_i exp G_i)(k_j exp -G_j), whose second
+    factor overflows float32 once a chunk's decays multiply to under 1e-38
+    (PERF.md section 6, PR 39).  That is [chunk, chunk, dk] decays a chunk
+    and head, so the chunk is short, and the chunks go ``n`` at a time
+    (``KDA_PAIR_ELEMS``) through three steps: the pairs' sums (``lax.map``,
+    each group rematerialised: its decays are alive only while it is
+    computed, forward and backward); ONE unit-lower-triangular solve for
+    all chunks, against [beta k exp G | beta v], since u = u0 - w S_0 is
+    linear in the state; and the ``lax.scan`` that carries S, four products
+    a chunk, in groups whose body is rematerialised, so that the backward
+    pass keeps one state a group and one a chunk of the group it is in."""
+    B, T, nh, dk = q.shape
+    dv = v.shape[-1]
+    C = min(chunk, T)
+    N = -(-T // C)
+    limit = max(1, KDA_PAIR_ELEMS // (B * nh * C * C * dk))
+    n = max(d for d in range(1, min(limit, N) + 1) if N % d == 0)
+
+    def grouped(a):  # [B, T, nh, ...] -> [N / n, n, B, nh, C, ...]
+        # padding: k = v = beta = 0 and g = 0 leave the state as it is
+        a = jnp.pad(a, ((0, 0), (0, N * C - T)) + ((0, 0),) * (a.ndim - 2))
+        a = a.reshape(B, N // n, n, C, *a.shape[2:])
+        return a.transpose(1, 2, 0, 4, 3, *range(5, a.ndim))
+
+    q, k, v, g, beta = (grouped(a) for a in (q, k, v, g, beta))
+    lower = jnp.tril(jnp.ones((C, C), bool))
+
+    @jax.checkpoint
+    def pairs(x):
+        q, k, g, beta = x
+        G = jnp.cumsum(g, axis=-2)
+        decay = jnp.exp(jnp.where(
+            lower[:, :, None], G[..., :, None, :] - G[..., None, :, :],
+            -jnp.inf))  # [.., i, j, dk]: exp(G_i - G_j), 0 where j > i
+        kd = k[..., None, :, :] * decay
+        A = beta[..., None] * (k[..., :, None, :] * kd).sum(-1)
+        P = (q[..., :, None, :] * kd).sum(-1)
+        return jnp.where(lower.T, 0.0, A), P, G  # A: j < i; P: j <= i
+
+    A, P, G = jax.lax.map(pairs, (q, k, g, beta))
+    eG = jnp.exp(G)
+    sol = jax.lax.linalg.triangular_solve(
+        A, beta[..., None] * jnp.concatenate([k * eG, v], axis=-1),
+        left_side=True, lower=True, unit_diagonal=True)
+    w, u0 = sol[..., :dk], sol[..., dk:]
+
+    def chunk_step(S, x):  # one chunk of all heads: [B, nh, C, .]
+        qG, P, w, u0, k_out, g_out = x
+        u = u0 - w @ S
+        o = qG @ S + P @ u
+        return g_out[..., None] * S + jnp.swapaxes(k_out, -1, -2) @ u, o
+
+    @jax.checkpoint
+    def group_step(S, x):
+        return jax.lax.scan(chunk_step, S, x)
+
+    _, o = jax.lax.scan(
+        group_step, jnp.zeros((B, nh, dk, dv), jnp.float32),
+        (q * eG, P, w, u0, k * jnp.exp(G[..., -1:, :] - G),
+         eG[..., -1, :]))
+    # [N / n, n, B, nh, C, dv] -> [B, T, nh, dv]
+    return o.transpose(2, 0, 1, 4, 3, 5).reshape(B, N * C, nh, dv)[:, :T]
+
+
+def _ones(key, shape):
+    return jnp.ones(shape, jnp.float32)
+
+
+def _log_of_uniform_1_16(key, shape):
+    """log A, A uniform in [1, 16): with the bias below, what the gated
+    delta rule's family seeds its decays with, weak to strong."""
+    return jnp.log(jax.random.uniform(key, shape, jnp.float32, 1.0, 16.0))
+
+
+def _softplus_inverse_of_log_uniform(key, shape):
+    """The bias whose softplus is log-uniform in [1e-3, 1e-1)."""
+    dt = jnp.exp(jax.random.uniform(
+        key, shape, jnp.float32, np.log(1e-3), np.log(1e-1)))
+    return dt + jnp.log(-jnp.expm1(-dt))
+
+
 class DecoderMoeLM:
     """Decoder whose layers are assembled from a description -- window,
-    full or latent attention or a gated short convolution; a dense
-    feed-forward or token-routed experts with or without shared ones --
-    trained on next-token prediction through the pass loop."""
+    full or latent attention, a gated short convolution or a gated delta
+    rule with a carried state; a dense feed-forward or token-routed experts
+    with or without shared ones -- trained on next-token prediction through
+    the pass loop."""
 
     uses_seq_pos = True
     n_sparse_slots = 1
     # sums over a pass's steps (and layers), published at the read-back:
     # positions with a target; token-expert pairs computed here and
     # n_experts_per_tok x tokens; the largest held expert's tokens beside
-    # the held experts' mean
+    # the held experts' mean; a description with "kda" layers adds
+    # ``kda.tokens``, the positions that went through such an operator
     step_counters = ("trainer.tokens", "moe.pairs_local", "moe.pairs_routed",
                      "moe.expert_load_max", "moe.expert_load_mean")
 
@@ -164,14 +312,17 @@ class DecoderMoeLM:
         latent: Optional[dict] = None,  # LATENT_KEYS, for latent layers
         qk_norm: bool = False,  # a learned norm on every query and key head
         conv_kernel: int = 0,  # taps of a "conv" layer's convolution
+        kda: Optional[dict] = None,  # KDA_KEYS, for "kda" layers
     ):
         vocab_keys = np.asarray(vocab_keys, dtype=np.uint64)
         if vocab_keys.ndim != 1 or not np.all(vocab_keys[1:] > vocab_keys[:-1]):
             raise ValueError("vocab_keys must be sorted, distinct feasigns")
-        bad = [t for t in layer_types
-               if t not in (SLIDING, FULL, LATENT, CONV)]
+        kinds = (SLIDING, FULL, LATENT, CONV, KDA)
+        bad = [t for t in layer_types if t not in kinds]
         if bad:
-            raise ValueError(f"unknown layer types {sorted(set(bad))}")
+            raise ValueError(
+                f"unknown layer types {sorted(set(bad))}: the kinds are "
+                f"{kinds}")
         if CONV in layer_types and conv_kernel <= 0:
             raise ValueError("a conv layer needs conv_kernel")
         mlp_types = tuple(mlp_types or (SPARSE,) * len(layer_types))
@@ -181,8 +332,12 @@ class DecoderMoeLM:
                 f"mlp_types {mlp_types} for {len(layer_types)} layers")
         if DENSE in mlp_types and dense_width <= 0:
             raise ValueError("a dense layer needs dense_width")
-        if LATENT in layer_types and set(latent or ()) != set(LATENT_KEYS):
-            raise ValueError(f"latent layers need latent={LATENT_KEYS}")
+        if LATENT in layer_types:
+            _described("latent", latent, LATENT_KEYS, LATENT_OPTIONAL)
+            if latent.get("rotary", True) and "interleaved" not in latent:
+                raise ValueError("a rotary code needs latent['interleaved']")
+        if KDA in layer_types:
+            _described("kda", kda, KDA_KEYS)
         if router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router score {router_score!r}")
         if n_heads % n_kv_heads:
@@ -207,6 +362,9 @@ class DecoderMoeLM:
         self.router_scale = float(router_scale)
         self.latent = latent
         self.qk_norm, self.conv_kernel = qk_norm, conv_kernel
+        self.kda = kda
+        if KDA in layer_types:  # one more sum a step, for such a description
+            self.step_counters = self.step_counters + ("kda.tokens",)
         self.window = window
         self.n_experts, self.top_k = n_experts, n_experts_per_tok
         self.expert_width = expert_width
@@ -217,8 +375,9 @@ class DecoderMoeLM:
 
     # -- params ------------------------------------------------------------ #
     def _layer_weights(self, attn_kind: str, mlp_kind: str) -> list:
-        """(name, shape, what a standard normal draw is divided by) of one
-        layer's seeded leaves, in the order their keys are drawn."""
+        """(name, shape, what a standard normal draw is divided by -- or
+        another draw, a function of key and shape) of one layer's seeded
+        leaves, in the order their keys are drawn."""
         H, F = self.hidden, self.expert_width
         held = self.experts_held[1] - self.experts_held[0]
 
@@ -239,6 +398,22 @@ class DecoderMoeLM:
             out = [w("conv_in", H, 3 * H, fan_in=H),
                    w("conv_w", K, H, fan_in=K),
                    w("conv_out", H, H, fan_in=H)]
+        elif attn_kind == KDA:
+            z = self.kda
+            nh, K, R = z["n_heads"], z["conv_kernel"], z["gate_rank"]
+            W = nh * z["head_dim"]
+            out = [w("kda_q", H, W, fan_in=H), w("kda_k", H, W, fan_in=H),
+                   w("kda_v", H, W, fan_in=H),
+                   w("kda_conv_q", K, W, fan_in=K),
+                   w("kda_conv_k", K, W, fan_in=K),
+                   w("kda_conv_v", K, W, fan_in=K),
+                   w("kda_fa", H, R, fan_in=H), w("kda_fb", R, W, fan_in=R),
+                   ("kda_A_log", (nh,), _log_of_uniform_1_16),
+                   ("kda_dt_bias", (W,), _softplus_inverse_of_log_uniform),
+                   w("kda_beta", H, nh, fan_in=H),
+                   w("kda_ga", H, R, fan_in=H), w("kda_gb", R, W, fan_in=R),
+                   ("kda_o_norm", (z["head_dim"],), _ones),
+                   w("kda_o", W, H, fan_in=W)]
         else:
             hq = self.n_heads * self.head_dim
             hkv = self.n_kv_heads * self.head_dim
@@ -263,7 +438,8 @@ class DecoderMoeLM:
         return out
 
     def init(self, key: jax.Array) -> dict:
-        """Normal weights scaled by 1/sqrt(fan-in), norm scales 1."""
+        """Normal weights scaled by 1/sqrt(fan-in), norm scales 1 (a "kda"
+        layer's decays as ``_layer_weights`` names them)."""
         H = self.hidden
         layers = []
         for lk, attn_kind, mlp_kind in zip(
@@ -274,12 +450,13 @@ class DecoderMoeLM:
                   "n2": jnp.ones((H,), jnp.float32)}
             if attn_kind == LATENT:
                 lp["n_kv"] = jnp.ones((self.latent["kv_rank"],), jnp.float32)
-            elif attn_kind != CONV and self.qk_norm:
+            elif attn_kind not in (CONV, KDA) and self.qk_norm:
                 lp["q_norm"] = jnp.ones((self.head_dim,), jnp.float32)
                 lp["k_norm"] = jnp.ones((self.head_dim,), jnp.float32)
-            for k, (name, shape, div) in zip(
+            for k, (name, shape, how) in zip(
                     jax.random.split(lk, len(weights)), weights):
-                lp[name] = jax.random.normal(k, shape, jnp.float32) / div
+                lp[name] = how(k, shape) if callable(how) else (
+                    jax.random.normal(k, shape, jnp.float32) / how)
             layers.append(lp)
         return {
             "layers": layers,
@@ -324,13 +501,18 @@ class DecoderMoeLM:
             kv_a = h @ lp["wkv_a"]  # [B, T, rank + rope]
             kv = (rms_norm(kv_a[..., :rank], lp["n_kv"], self.rms_eps)
                   @ lp["wkv_b"]).reshape(B, T, nh, nope + z["v_dim"])
-            cos, sin = rotary_tables(jnp.arange(T), rope, self.rope_theta,
-                                     interleaved=z["interleaved"])
-            q_pe = apply_rotary(q[..., nope:], cos, sin, z["interleaved"])
-            # the turned key slice: one per token, shared by every head
-            k_pe = apply_rotary(kv_a[:, :, None, rank:], cos, sin,
-                                z["interleaved"])
-            q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+            if z.get("rotary", True):
+                cos, sin = rotary_tables(
+                    jnp.arange(T), rope, self.rope_theta,
+                    interleaved=z["interleaved"])
+                q_pe = apply_rotary(q[..., nope:], cos, sin,
+                                    z["interleaved"])
+                # the turned key slice: one per token, shared by every head
+                k_pe = apply_rotary(kv_a[:, :, None, rank:], cos, sin,
+                                    z["interleaved"])
+                q = jnp.concatenate([q[..., :nope], q_pe], axis=-1)
+            else:  # no positional code: the slice stays, unturned
+                k_pe = kv_a[:, :, None, rank:]
             k = jnp.concatenate(
                 [kv[..., :nope], jnp.broadcast_to(k_pe, (B, T, nh, rope))],
                 axis=-1)
@@ -340,14 +522,45 @@ class DecoderMoeLM:
 
     def _conv_mix(self, lp: dict, x: jax.Array) -> jax.Array:
         """x + the layer's gated short convolution over n1(x)."""
-        T, K = x.shape[1], self.conv_kernel
         with jax.named_scope("conv_mixer"):
             h = rms_norm(x, lp["n1"], self.rms_eps)
             b, c, u = jnp.split(h @ lp["conv_in"], 3, axis=-1)
-            # zeros before the first token; tap j reads position t-K+1+j
-            z = jnp.pad(b * u, ((0, 0), (K - 1, 0), (0, 0)))
-            y = sum(lp["conv_w"][j] * z[:, j:j + T] for j in range(K))
+            y = causal_taps(b * u, lp["conv_w"])
             return x + (c * y) @ lp["conv_out"]
+
+    def _kda_mix(self, lp: dict, x: jax.Array) -> jax.Array:
+        """x + the layer's gated delta rule over n1(x) (the module's
+        docstring has the equations)."""
+        B, T, H = x.shape
+        z = self.kda
+        nh, hd = z["n_heads"], z["head_dim"]
+
+        def heads(a):
+            return a.reshape(B, T, nh, hd)
+
+        def conv(u, w):
+            # the taps over the heads' shape: reshaped after them, the chain
+            # ends in a bitcast of the compiler's, and a fusion rooted in one
+            # has no name for a trace to find its scope by (PERF.md 6, PR 39)
+            return jax.nn.silu(causal_taps(heads(u), w.reshape(-1, nh, hd)))
+
+        def l2norm(a):
+            return a * jax.lax.rsqrt(
+                jnp.sum(a * a, axis=-1, keepdims=True) + 1e-6)
+
+        with jax.named_scope("kda_mixer"):
+            h = rms_norm(x, lp["n1"], self.rms_eps)
+            q = l2norm(conv(h @ lp["kda_q"], lp["kda_conv_q"])) * hd ** -0.5
+            k = l2norm(conv(h @ lp["kda_k"], lp["kda_conv_k"]))
+            v = conv(h @ lp["kda_v"], lp["kda_conv_v"])
+            g = -jnp.exp(lp["kda_A_log"])[:, None] * jax.nn.softplus(heads(
+                (h @ lp["kda_fa"]) @ lp["kda_fb"] + lp["kda_dt_bias"]))
+            beta = jax.nn.sigmoid(h @ lp["kda_beta"])
+            with jax.named_scope("kda_scan"):
+                o = gated_delta_chunked(q, k, v, g, beta, KDA_CHUNK)
+            gate = jax.nn.sigmoid(heads((h @ lp["kda_ga"]) @ lp["kda_gb"]))
+            o = rms_norm(o, lp["kda_o_norm"], self.rms_eps) * gate
+            return x + o.reshape(B, T, -1) @ lp["kda_o"]
 
     def _layer(self, lp: dict, x: jax.Array, valid: jax.Array, kinds: tuple):
         """One decoder layer of ``kinds`` = (operator, feed-forward);
@@ -357,6 +570,8 @@ class DecoderMoeLM:
         B, T, H = x.shape
         if kinds[0] == CONV:
             x = self._conv_mix(lp, x)
+        elif kinds[0] == KDA:
+            x = self._kda_mix(lp, x)
         else:
             x = self._attend(lp, x, kinds[0])
         h = rms_norm(x, lp["n2"], self.rms_eps).reshape(B * T, H)
@@ -449,8 +664,9 @@ class DecoderMoeLM:
         preds = jnp.exp(-ce.sum(axis=1) / jnp.maximum(scored.sum(axis=1), 1.0))
         held = self.experts_held[1] - self.experts_held[0]
         n_tokens = valid.sum().astype(jnp.float32)
-        counts = jnp.stack([
-            n_scored, moe[0],
-            n_tokens * self.top_k * self.mlp_types.count(SPARSE),
-            moe[1], moe[0] / held])
-        return loss, preds, counts
+        counts = [n_scored, moe[0],
+                  n_tokens * self.top_k * self.mlp_types.count(SPARSE),
+                  moe[1], moe[0] / held]
+        if KDA in self.layer_types:
+            counts.append(n_tokens * self.layer_types.count(KDA))
+        return loss, preds, jnp.stack(counts)

@@ -194,8 +194,11 @@ def routed_experts(
     6, PR 31) the form computes 21.3x the routed pairs' products at even
     routing, 15.6x as the traced window routed, and takes 38.4 ms a layer
     of a 1,190 ms step (forward, rematerialised forward and backward): no
-    grouped form was timed there.  A grouped kernel that gathers its own
-    rows is the next step, judged on both ratios."""
+    grouped form was timed there.  At 8 of 256 held, 8 a token (N = 8,192,
+    D = 2,304, F = 1,024; PERF.md section 6, PR 39) it is 32x, the worst
+    ratio yet, and 26.6 ms a layer of an 835 ms step.  A grouped kernel
+    that gathers its own rows is the next step, judged on all three
+    ratios."""
     held = w_gate.shape[0]
     e_loc = jnp.where((top_e >= lo) & (top_e < lo + held), top_e - lo, held)
     load = jnp.bincount(e_loc.reshape(-1), length=held + 1)[:held]
