@@ -1,8 +1,9 @@
 """Native (C++) host-pipeline components, loaded via ctypes.
 
 The reference keeps its whole data layer in C++ because host feed was the
-production bottleneck (SURVEY.md §2.4); here the parser is the native hot
-path and the rest of the pipeline stays numpy (already vectorized).  The
+production bottleneck (SURVEY.md §2.4); here the parser, the batch
+builder's key pack and the batch planner are native and the rest of the
+pipeline stays numpy (already vectorized).  The
 shared library builds on demand with g++ (no pybind11 in the image — plain
 C ABI + ctypes) into a file named by a hash of its source and build flags,
 so a binary is only ever loaded if it was built from exactly this source
@@ -114,6 +115,11 @@ def get_lib():
             ctypes.c_char_p, ctypes.POINTER(ctypes.c_int64),
             ctypes.c_int64, ctypes.POINTER(ctypes.c_uint64),
         ]
+        lib.pbx_pack_batch.restype = ctypes.c_int64
+        lib.pbx_pack_batch.argtypes = (
+            [ctypes.c_void_p] * 3 + [ctypes.c_int64] * 3 + [ctypes.c_int32]
+            + [ctypes.c_void_p] * 3 + [ctypes.POINTER(ctypes.c_int64)]
+        )
         _lib = lib
         return _lib
 
@@ -135,6 +141,33 @@ def hash_ids_native(ins_ids) -> Optional[np.ndarray]:
         out.ctypes.data_as(ctypes.POINTER(ctypes.c_uint64)),
     )
     return out
+
+
+def pack_batch_native(block_keys: np.ndarray, key_offsets: np.ndarray,
+                      ids: np.ndarray, S: int, K: int, pad_seg: int):
+    """The batch builder's key pack in one native pass (GIL released):
+    (keys uint64 [K], key_segments int32 [K], lens int64 [len(ids)*S],
+    n_keys, dropped), every array fresh; None when the library is
+    unavailable or the block's arrays are not the contiguous uint64 /
+    int64 the parser makes.  The caller has checked ``ids`` against the
+    block (``BatchBuilder.build``)."""
+    lib = get_lib()
+    if lib is None or not (
+        block_keys.dtype == np.uint64 and block_keys.flags.c_contiguous
+        and key_offsets.dtype == np.int64 and key_offsets.flags.c_contiguous
+    ):
+        return None
+    ids = np.ascontiguousarray(ids, dtype=np.int64)
+    keys = np.empty(K, dtype=np.uint64)
+    segs = np.empty(K, dtype=np.int32)
+    lens = np.empty(ids.shape[0] * S, dtype=np.int64)
+    dropped = ctypes.c_int64(0)
+    n_keys = lib.pbx_pack_batch(
+        block_keys.ctypes.data, key_offsets.ctypes.data, ids.ctypes.data,
+        ids.shape[0], S, K, pad_seg, keys.ctypes.data, segs.ctypes.data,
+        lens.ctypes.data, ctypes.byref(dropped),
+    )
+    return keys, segs, lens, int(n_keys), int(dropped.value)
 
 
 _KIND_CODE = {"skip": 0, "label": 1, "task": 2, "dense": 3, "sparse": 4}
@@ -265,9 +298,10 @@ def get_plan_lib():
 
 def require_native() -> dict:
     """{"parser": bool, "planner": bool} — which native libraries the
-    flags ask for AND are loaded.  Raises when a flag asks for one that
-    did not build: entry points that measure or prove the system must not
-    run on the 4-5x slower Python fallback unnoticed."""
+    flags ask for AND are loaded ("parser" is the data layer's library:
+    the parser and the batch builder's key pack).  Raises when a flag
+    asks for one that did not build: entry points that measure or prove
+    the system must not run on the 4-5x slower Python fallback unnoticed."""
     from paddlebox_tpu.config import flags
 
     wanted = {"parser": (flags.use_native_parser, get_lib),

@@ -1,4 +1,5 @@
-// Native slot-text parser: the hot half of the host data pipeline.
+// Native data layer: the slot-text parser, the hot half of the host data
+// pipeline, and (at the end) the batch builder's key pack.
 //
 // TPU-native counterpart of the reference's C++ reader stack
 // (SlotPaddleBoxDataFeed::ParseOneInstance, data_feed.cc:3202, and the
@@ -16,6 +17,7 @@
 //   [ins_id] [search_id:rank:cmatch] <n> v1..vn  <n> v1..vn ...
 // Walk kinds: 0=skip, 1=label, 2=task, 3=dense, 4=sparse.
 
+#include <algorithm>
 #include <cstdint>
 #include <cstdio>
 #include <cstdlib>
@@ -260,6 +262,58 @@ void pbx_hash_ids(const char* buf, const int64_t* offs, int64_t n,
     }
     out[i] = h;
   }
+}
+
+// The batch builder's key pack (data/feed.py BatchBuilder.build): one pass
+// over the batch's own rows.  Row r = i*S + s (instance i of the batch,
+// slot s) is its own segment id; keys past the capacity K are dropped in
+// order, tail rows first, and counted.  Reads key_offsets only at the
+// selected instances, so a batch costs O(its keys), never the block's size.
+// Writes every element of keys[K], segs[K] and lens[b*S]; returns n_keys.
+int64_t pbx_pack_batch(const uint64_t* block_keys, const int64_t* key_offsets,
+                       const int64_t* ids, int64_t b, int64_t S, int64_t K,
+                       int32_t pad_seg, uint64_t* keys, int32_t* segs,
+                       int64_t* lens, int64_t* dropped) {
+  // shuffled ids land anywhere in a block far larger than the caches: ask
+  // for an instance's offsets 2*AHEAD instances early and, once those are
+  // in, for its keys AHEAD early (64-byte lines of 8 elements)
+  constexpr int64_t AHEAD = 8;
+  int64_t used = 0, drop = 0;
+  for (int64_t i = 0; i < b; ++i) {
+    if (i + 2 * AHEAD < b) {
+      const int64_t* o = key_offsets + ids[i + 2 * AHEAD] * S;
+      for (int64_t j = 0; j <= S; j += 8) __builtin_prefetch(o + j);
+    }
+    if (i + AHEAD < b) {
+      const int64_t* o = key_offsets + ids[i + AHEAD] * S;
+      for (int64_t j = o[0]; j < o[S]; j += 8)
+        __builtin_prefetch(block_keys + j);
+    }
+    const int64_t* off = key_offsets + ids[i] * S;
+    // an instance's slots are contiguous in the block: one copy for all
+    const int64_t n_ins = off[S] - off[0];
+    const int64_t take_ins = std::min(n_ins, K - used);
+    std::memcpy(keys + used, block_keys + off[0],
+                static_cast<size_t>(take_ins) * sizeof(uint64_t));
+    drop += n_ins - take_ins;
+    for (int64_t s = 0; s < S; ++s) {
+      const int64_t take = std::min(off[s + 1] - off[s], K - used);
+      const int32_t seg = static_cast<int32_t>(i * S + s);
+      if (take <= 4 && used + 4 <= K) {
+        // a slot's usual 1-3 keys: four stores and no loop to mispredict;
+        // what they write past the run, the next rows or the tail rewrite
+        segs[used] = segs[used + 1] = segs[used + 2] = segs[used + 3] = seg;
+      } else {
+        std::fill(segs + used, segs + used + take, seg);
+      }
+      lens[i * S + s] = take;
+      used += take;
+    }
+  }
+  std::fill(keys + used, keys + K, uint64_t{0});
+  std::fill(segs + used, segs + K, pad_seg);
+  *dropped = drop;
+  return used;
 }
 
 }  // extern "C"

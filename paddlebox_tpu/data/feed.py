@@ -24,8 +24,16 @@ from typing import Optional
 
 import numpy as np
 
+from paddlebox_tpu._native import pack_batch_native
 from paddlebox_tpu.config import DataFeedConfig
 from paddlebox_tpu.data.record import RecordBlock
+from paddlebox_tpu.telemetry import metrics as _tm
+
+_BUILT = _tm.counter(
+    "data.batches_built",
+    "BatchBuilder.build calls by what packed the keys: by=native, one pass "
+    "of the data layer's library; by=numpy, the fallback where it did not "
+    "build")
 
 _beat = None  # resolved once: liveness stage beat, or a no-op
 
@@ -204,12 +212,45 @@ def build_rank_offset(
     return mat
 
 
+def _pack_keys_numpy(block: RecordBlock, ids: np.ndarray, S: int, K: int,
+                     pad_seg: int):
+    """``pack_batch_native``'s numpy form, byte for byte: the fallback
+    where the library did not build, and the oracle its parity test holds
+    it to (tests/test_native_pack.py)."""
+    # lengths from the batch's own rows: O(b*S) offsets read, never a
+    # difference over the whole block (BatchBuilder's invariant)
+    sel_rows = (ids[:, None] * S + np.arange(S)[None, :]).reshape(-1)
+    starts = block.key_offsets[sel_rows]
+    lens = block.key_offsets[sel_rows + 1] - starts
+    total = int(lens.sum())
+    dropped = 0
+    if total > K:  # clip overflowing tail rows
+        cum = np.cumsum(lens)
+        lens = np.minimum(lens, np.maximum(K - (cum - lens), 0))
+        dropped = total - int(lens.sum())
+        total -= dropped
+    new_off = np.cumsum(lens) - lens
+    pos = np.arange(total, dtype=np.int64) - np.repeat(new_off, lens)
+    keys = np.zeros(K, dtype=np.uint64)
+    keys[:total] = block.keys[np.repeat(starts, lens) + pos]
+    segs = np.full(K, pad_seg, dtype=np.int32)
+    # row r = ins_in_batch * S + slot is its own segment id
+    segs[:total] = np.repeat(np.arange(sel_rows.shape[0], dtype=np.int32),
+                             lens)
+    return keys, segs, lens, total, dropped
+
+
 class BatchBuilder:
     """Packs instance index ranges of a RecordBlock into HostBatches.
 
     Invariant (pinned by tests/test_feed_batch_cost.py): a batch's host
     cost is O(its own keys and instances), independent of the block's
-    size — ``build`` reads the block only at the selected rows."""
+    size — ``build`` reads the block only at the selected rows.  The keys
+    and their segment ids are packed in one native pass where the data
+    layer's library is loaded (``_native/slot_parser.cpp pbx_pack_batch``),
+    by ``_pack_keys_numpy`` where it is not; ``data.batches_built`` says
+    which.  Every batch gets fresh arrays: the prefetch queue and the step
+    hold earlier ones."""
 
     def __init__(self, conf: DataFeedConfig):
         self.conf = conf
@@ -249,28 +290,20 @@ class BatchBuilder:
         b = int(ids.shape[0])
         assert b <= B
 
-        # lengths from the batch's own rows: O(b*S) offsets read, never a
-        # difference over the whole block (the class docstring's invariant)
-        sel_rows = (ids[:, None] * S + np.arange(S)[None, :]).reshape(-1)
-        starts = block.key_offsets[sel_rows]
-        lens = block.key_offsets[sel_rows + 1] - starts
-        total = int(lens.sum())
-        if total > K:
-            # clip overflowing tail rows (counted; raise capacity if it matters)
-            cum = np.cumsum(lens)
-            lens = np.minimum(lens, np.maximum(K - (cum - lens), 0))
-            self.dropped_keys += total - int(lens.sum())
-            total = int(lens.sum())
-        new_off = np.zeros(sel_rows.shape[0] + 1, dtype=np.int64)
-        np.cumsum(lens, out=new_off[1:])
-        pos = np.arange(total, dtype=np.int64) - np.repeat(new_off[:-1], lens)
-        src_idx = np.repeat(starts, lens) + pos
+        if b and not 0 <= int(ids.min()) <= int(ids.max()) < block.n_ins:
+            raise IndexError(
+                f"batch ids outside the block's {block.n_ins} instances")
 
-        keys = np.zeros(K, dtype=np.uint64)
-        keys[:total] = block.keys[src_idx]
-        segs = np.full(K, B * S, dtype=np.int32)
-        # row r = ins_in_batch * S + slot is its own segment id
-        segs[:total] = np.repeat(np.arange(b * S, dtype=np.int32), lens)
+        packed = pack_batch_native(
+            block.keys, block.key_offsets, ids, S, K, B * S)
+        by = "native"
+        if packed is None:
+            packed = _pack_keys_numpy(block, ids, S, K, B * S)
+            by = "numpy"
+        _BUILT.inc(by=by)
+        keys, segs, lens, total, dropped = packed
+        # clipped tail rows are counted; raise the capacity if it matters
+        self.dropped_keys += dropped
 
         seq_pos = None
         if self.seq_slot_idx is not None:
@@ -279,11 +312,12 @@ class BatchBuilder:
             # r = i*S + slot (file order == behavior order); pad with K
             T = self.conf.max_seq_len
             seq_pos = np.full((B, T), K, dtype=np.int32)
+            new_off = np.cumsum(lens) - lens
             rr = np.arange(b, dtype=np.int64) * S + self.seq_slot_idx
             col = np.arange(T, dtype=np.int64)[None, :]
             seq_pos[:b] = np.where(
                 col < np.minimum(lens[rr], T)[:, None],
-                new_off[:-1][rr][:, None] + col,
+                new_off[rr][:, None] + col,
                 K,
             ).astype(np.int32)
 

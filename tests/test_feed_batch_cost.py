@@ -5,6 +5,9 @@ per-instance packer (a Python loop over instances and slots, the oracle
 kept here) on seeded ragged blocks.  (b) A batch's host cost is O(the
 batch), independent of the block it is taken from: the allocation peak of
 one ``build`` of the same ids is the same on a block ten times as long.
+Both hold for each form of the key pack (``pack_path``): the native pass
+and the numpy form it falls back to.  (c) Over a dataset's pass no batch is
+packed by numpy where the library is loaded (``data.batches_built``).
 Counts and equality only; nothing here reads a clock."""
 
 import dataclasses
@@ -13,9 +16,34 @@ import tracemalloc
 import numpy as np
 import pytest
 
+from paddlebox_tpu import telemetry
+from paddlebox_tpu._native import get_lib
 from paddlebox_tpu.config import DataFeedConfig, SlotConfig
-from paddlebox_tpu.data import BatchBuilder, RecordBlock
+from paddlebox_tpu.data import BatchBuilder, RecordBlock, feed
 from paddlebox_tpu.data.feed import build_rank_offset
+from paddlebox_tpu.data.synth import make_synth_config, write_synth_files
+
+
+def built():
+    """``data.batches_built`` as {path: count}."""
+    c = telemetry.counter("data.batches_built")
+    return {by: c.value(by=by) for by in ("native", "numpy")}
+
+
+@pytest.fixture(params=["native", "numpy"])
+def pack_path(request, monkeypatch):
+    """Runs the test once on each form of the key pack and, after it,
+    holds every ``build`` of the test to have been counted under it."""
+    if request.param == "numpy":
+        monkeypatch.setattr(feed, "pack_batch_native", lambda *a: None)
+    elif get_lib() is None:
+        pytest.skip("the data layer's native library did not build")
+    before = built()
+    yield request.param
+    other = "numpy" if request.param == "native" else "native"
+    after = built()
+    assert after[request.param] > before[request.param]
+    assert after[other] == before[other]
 
 
 def feed_conf(n_sparse, batch_size, **kw):
@@ -134,7 +162,7 @@ CASES = {
 
 
 @pytest.mark.parametrize("case", sorted(CASES))
-def test_build_matches_per_instance_packer(case):
+def test_build_matches_per_instance_packer(case, pack_path):
     ids, conf_kw, block_kw = CASES[case]
     conf = feed_conf(S, B, max_feasigns_per_ins=16, **conf_kw)
     block = ragged_block(11, N, S, **block_kw)
@@ -149,7 +177,7 @@ def test_build_matches_per_instance_packer(case):
 
 
 @pytest.mark.parametrize("cmatch_filter", [(222, 223), None])
-def test_build_pv_matches_per_instance_packer(cmatch_filter):
+def test_build_pv_matches_per_instance_packer(cmatch_filter, pack_path):
     """Same packing, plus the rank matrix of ``build_rank_offset`` (which
     tests/test_host_vectorized.py holds to its own loop oracle) under this
     configuration's batch size, max rank and cmatch filter."""
@@ -205,7 +233,8 @@ def build_peak_bytes(method, block, ids):
 
 
 @pytest.mark.parametrize("method", ["build", "build_pv"])
-def test_build_cost_is_independent_of_block_size(pass_blocks, method):
+def test_build_cost_is_independent_of_block_size(pass_blocks, method,
+                                                 pack_path):
     small, big = pass_blocks
     ids = np.random.default_rng(22).permutation(small.n_ins)[:PASS_BATCH]
     peak_small, from_small = build_peak_bytes(method, small, ids)
@@ -217,3 +246,26 @@ def test_build_cost_is_independent_of_block_size(pass_blocks, method):
     assert peak_small > 1_000_000
     assert abs(peak_big - peak_small) <= 0.1 * peak_small, (
         peak_small, peak_big)
+
+
+# ------------------------------------------------------- (c) a pass's path
+def test_no_batch_of_a_pass_is_packed_by_numpy(tmp_path):
+    from paddlebox_tpu.data.dataset import PadBoxSlotDataset
+
+    if get_lib() is None:
+        pytest.skip("the data layer's native library did not build")
+
+    conf = make_synth_config(n_sparse_slots=3, dense_dim=2, batch_size=16,
+                             max_feasigns_per_ins=16)
+    ds = PadBoxSlotDataset(conf, read_threads=2)
+    ds.set_filelist(write_synth_files(
+        str(tmp_path / "data"), n_files=3, ins_per_file=40, n_sparse_slots=3,
+        vocab_per_slot=50, dense_dim=2, seed=5))
+    ds.load_into_memory()
+    ds.local_shuffle()
+    before = built()
+    n_batches = sum(1 for _ in ds.batches())
+    after = built()
+    assert n_batches == 8  # 120 instances in batches of 16
+    assert after["native"] - before["native"] == n_batches
+    assert after["numpy"] == before["numpy"]
