@@ -80,6 +80,34 @@ positions t < T-1 with a next token of the softmax cross-entropy against
 that token's class, in token chunks so the [tokens, classes] logits are
 never whole in memory.  Each layer is rematerialised in the backward pass
 (``jax.checkpoint``, one per layer).
+
+That loss is the default ``objective``, ``"next_token"``.  The other is
+``"block_diffusion"`` (``diffusion`` gives ``block_len`` L, ``eps`` and
+``noise_seed``): denoising over blocks, the objective a block-diffusion
+model is trained or adapted from an autoregressive one with.  A sequence
+x0 of T tokens gets a noise level t in [0, 1] from its instance's FIRST
+DENSE FEATURE (``batch["dense"][:, 0]`` = t - 0.5 on a grid of 1/1000: a
+corpus whose preprocessing wrote each instance's level there), p = eps +
+(1 - eps) t, and each position is masked with probability p, the draw a
+pure function of (``noise_seed``, the level, the sequence's first class):
+the same instance draws the same mask in every pass.  A masked position's
+input is the learned ``mask_embed`` [hidden], a dense leaf (the table's
+rows are pulled by the batch's keys, and the mask token is no instance's
+key); the layers run ONCE over 2T positions, the noised stream then the
+clean one, both at rotary positions 0 .. T-1, every attention layer under
+the block mask of parallel/sequence.py (``full_attention``'s third mask
+word), block of i = i // L:
+
+    a noised query sees the noised keys of its own block, both directions,
+        and the clean keys of the blocks before it;
+    a clean query sees the clean keys of its own block and of the blocks
+        before it, and no noised key.
+
+The head scores the noised stream only, position i against token x0_i
+ITSELF (no shift: generation fills a block's masked positions in place),
+and the loss is sum over masked positions of CE / p, over B * T.  Only
+``full_attention`` layers have a two-stream form: a description with
+another operator kind under this objective is refused by name.
 """
 
 from __future__ import annotations
@@ -108,6 +136,9 @@ LATENT_KEYS = ("kv_rank", "qk_nope", "qk_rope", "v_dim")
 # "rotary": False = no positional code; else "interleaved" says which pairs
 LATENT_OPTIONAL = ("interleaved", "rotary")
 KDA_KEYS = ("n_heads", "head_dim", "conv_kernel", "gate_rank")
+NEXT_TOKEN, BLOCK_DIFFUSION = "next_token", "block_diffusion"
+DIFFUSION_KEYS = ("block_len", "eps", "noise_seed")
+NOISE_GRID = 1000  # the dense feature's grid: a noise level is n / 1000
 # gated_delta_chunked's two sizes, read on the chip (PERF.md section 6, PR
 # 39): the positions of a chunk, and how many (query, key, channel) decays
 # of the chunks' pairs are alive at once
@@ -270,8 +301,9 @@ class DecoderMoeLM:
     """Decoder whose layers are assembled from a description -- window,
     full or latent attention, a gated short convolution or a gated delta
     rule with a carried state; a dense feed-forward or token-routed experts
-    with or without shared ones -- trained on next-token prediction through
-    the pass loop."""
+    with or without shared ones -- trained through the pass loop on
+    next-token prediction or, ``objective="block_diffusion"``, on
+    denoising over blocks (the module's docstring)."""
 
     uses_seq_pos = True
     n_sparse_slots = 1
@@ -279,7 +311,10 @@ class DecoderMoeLM:
     # positions with a target; token-expert pairs computed here and
     # n_experts_per_tok x tokens; the largest held expert's tokens beside
     # the held experts' mean; a description with "kda" layers adds
-    # ``kda.tokens``, the positions that went through such an operator
+    # ``kda.tokens``, the positions that went through such an operator, one
+    # with the block-diffusion objective ``diffusion.positions``, the
+    # positions that went through the layers (both streams; there
+    # ``trainer.tokens`` counts the masked positions, the ones scored)
     step_counters = ("trainer.tokens", "moe.pairs_local", "moe.pairs_routed",
                      "moe.expert_load_max", "moe.expert_load_mean")
 
@@ -313,6 +348,8 @@ class DecoderMoeLM:
         qk_norm: bool = False,  # a learned norm on every query and key head
         conv_kernel: int = 0,  # taps of a "conv" layer's convolution
         kda: Optional[dict] = None,  # KDA_KEYS, for "kda" layers
+        objective: str = NEXT_TOKEN,  # or "block_diffusion"
+        diffusion: Optional[dict] = None,  # DIFFUSION_KEYS, for the latter
     ):
         vocab_keys = np.asarray(vocab_keys, dtype=np.uint64)
         if vocab_keys.ndim != 1 or not np.all(vocab_keys[1:] > vocab_keys[:-1]):
@@ -340,6 +377,23 @@ class DecoderMoeLM:
             _described("kda", kda, KDA_KEYS)
         if router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router score {router_score!r}")
+        if objective not in (NEXT_TOKEN, BLOCK_DIFFUSION):
+            raise ValueError(f"unknown objective {objective!r}")
+        if objective == BLOCK_DIFFUSION:
+            _described("diffusion", diffusion, DIFFUSION_KEYS)
+            other = sorted(set(layer_types) - {FULL})
+            if other:
+                raise ValueError(
+                    f"the block_diffusion objective runs {FULL} layers "
+                    f"under the block mask over two streams; the two-stream "
+                    f"form of {other} is not written")
+            if diffusion["block_len"] < 1 or block_q % diffusion["block_len"]:
+                raise ValueError(
+                    f"block_q {block_q} is no multiple of the block length "
+                    f"{diffusion['block_len']}")
+        elif diffusion is not None:
+            raise ValueError("diffusion describes the block_diffusion "
+                             "objective only")
         if n_heads % n_kv_heads:
             raise ValueError(
                 f"{n_heads} query heads over {n_kv_heads} key-value heads")
@@ -365,6 +419,9 @@ class DecoderMoeLM:
         self.kda = kda
         if KDA in layer_types:  # one more sum a step, for such a description
             self.step_counters = self.step_counters + ("kda.tokens",)
+        self.objective, self.diffusion = objective, diffusion
+        if objective == BLOCK_DIFFUSION:
+            self.step_counters = self.step_counters + ("diffusion.positions",)
         self.window = window
         self.n_experts, self.top_k = n_experts, n_experts_per_tok
         self.expert_width = expert_width
@@ -439,7 +496,10 @@ class DecoderMoeLM:
 
     def init(self, key: jax.Array) -> dict:
         """Normal weights scaled by 1/sqrt(fan-in), norm scales 1 (a "kda"
-        layer's decays as ``_layer_weights`` names them)."""
+        layer's decays as ``_layer_weights`` names them); under the
+        block-diffusion objective ``mask_embed`` too, a normal draw at a
+        table row's scale, from a key of its own so that every other leaf
+        is what it is without the objective."""
         H = self.hidden
         layers = []
         for lk, attn_kind, mlp_kind in zip(
@@ -458,22 +518,31 @@ class DecoderMoeLM:
                 lp[name] = how(k, shape) if callable(how) else (
                     jax.random.normal(k, shape, jnp.float32) / how)
             layers.append(lp)
-        return {
+        params = {
             "layers": layers,
             "norm_f": jnp.ones((H,), jnp.float32),
             "head": jax.random.normal(
                 jax.random.split(key)[0], (self.n_classes, H), jnp.float32
             ) / np.sqrt(H),
         }
+        if self.objective == BLOCK_DIFFUSION:
+            params["mask_embed"] = 0.02 * jax.random.normal(
+                jax.random.fold_in(key, len(layers) + 1), (H,), jnp.float32)
+        return params
 
     # -- forward ----------------------------------------------------------- #
     def _attend(self, lp: dict, x: jax.Array, kind: str) -> jax.Array:
-        """x + the layer's attention over n1(x)."""
+        """x + the layer's attention over n1(x).  Under the block-diffusion
+        objective x is [B, 2T, H], the noised stream then the clean one:
+        both at rotary positions 0 .. T-1, under the block mask."""
         B, T, H = x.shape
         if kind == LATENT:
             return self._attend_latent(lp, x)
         sliding = kind == SLIDING
-        with jax.named_scope("attn_window" if sliding else "attn_full"):
+        streams = self.objective == BLOCK_DIFFUSION
+        with jax.named_scope(
+                "attn_block_diffusion" if streams
+                else "attn_window" if sliding else "attn_full"):
             h = rms_norm(x, lp["n1"], self.rms_eps)
             shape = (B, T, -1, self.head_dim)
             q = (h @ lp["wq"]).reshape(shape)
@@ -483,12 +552,16 @@ class DecoderMoeLM:
                 q = rms_norm(q, lp["q_norm"], self.rms_eps)
                 k = rms_norm(k, lp["k_norm"], self.rms_eps)
             cos, sin = rotary_tables(
-                jnp.arange(T), self.head_dim, self.rope_theta,
+                jnp.arange(T) % (T // 2) if streams else jnp.arange(T),
+                self.head_dim, self.rope_theta,
                 None if sliding else self.yarn)
+            mask = ({"block_diffusion": self.diffusion["block_len"]}
+                    if streams else
+                    {"causal": True,
+                     "window": self.window if sliding else None})
             a = full_attention(
                 apply_rotary(q, cos, sin), apply_rotary(k, cos, sin), v,
-                causal=True, window=self.window if sliding else None,
-                block_q=self.block_q)
+                block_q=self.block_q, **mask)
             return x + a.reshape(B, T, -1) @ lp["wo"]
 
     def _attend_latent(self, lp: dict, x: jax.Array) -> jax.Array:
@@ -621,15 +694,19 @@ class DecoderMoeLM:
     def loss(self, params: dict, rows: jax.Array, batch: dict):
         """The model half of the training step (train/step_loss.py):
         ``rows`` [K, emb_width] the pulled occurrence rows, ``batch`` the
-        step's device feed (``seq_pos`` [B, T], ``key_class`` [K]).
+        step's device feed, of which it reads ``seq_pos`` [B, T],
+        ``key_class`` [K] and, under the block-diffusion objective,
+        ``dense`` [B, >= 1] (each instance's noise level less a half).
 
         Returns (loss, preds [B], counts): the mean next-token
-        cross-entropy over the positions that have a next token; a number
-        in (0, 1] a sequence -- exp(-its mean loss), the geometric mean of
-        the probability it gave its next tokens -- so that the trainers'
-        AUC and metric state keep their shapes (the AUC of such numbers
-        against ``click`` means nothing; the loss is the metric); and
-        ``step_counters``' values for this step."""
+        cross-entropy over the positions that have a next token -- or the
+        denoising loss, sum over the masked positions of CE / p over B * T
+        --; a number in (0, 1] a sequence -- exp(-its mean loss), the
+        geometric mean of the probability it gave its scored tokens, 1
+        where it has none -- so that the trainers' AUC and metric state
+        keep their shapes (the AUC of such numbers against ``click`` means
+        nothing; the loss is the metric); and ``step_counters``' values for
+        this step."""
         seq_pos = batch["seq_pos"]
         B, T = seq_pos.shape
         K = rows.shape[0]
@@ -644,11 +721,17 @@ class DecoderMoeLM:
             cls = jnp.take(
                 jnp.concatenate([batch["key_class"],
                                  jnp.full((1,), -1, jnp.int32)]), seq_pos)
-        # position t is scored against the class at t + 1
-        target = jnp.concatenate(
-            [cls[:, 1:], jnp.full((B, 1), -1, jnp.int32)], axis=1)
+        streams = self.objective == BLOCK_DIFFUSION
+        if streams:
+            x, target, weight = self._noised(params, x, cls, seq_pos < K,
+                                             batch["dense"])
+        else:  # position t is scored against the class at t + 1
+            target = jnp.concatenate(
+                [cls[:, 1:], jnp.full((B, 1), -1, jnp.int32)], axis=1)
         scored = (target >= 0).astype(jnp.float32)
         valid = seq_pos < K
+        if streams:
+            valid = jnp.concatenate([valid, valid], axis=1)
         moe = jnp.zeros((2,), jnp.float32)
         for lp, kinds in zip(params["layers"],
                              zip(self.layer_types, self.mlp_types)):
@@ -656,11 +739,15 @@ class DecoderMoeLM:
                 lp, x, valid, kinds)
             moe = moe + m
         with jax.named_scope("lm_head"):
+            # the noised stream's positions where there are two
             ce = self._token_losses(
-                params, x.reshape(B * T, -1), target.reshape(-1))
+                params, x[:, :T].reshape(B * T, -1), target.reshape(-1))
             ce = ce.reshape(B, T) * scored
         n_scored = scored.sum()
-        loss = ce.sum() / jnp.maximum(n_scored, 1.0)
+        if streams:
+            loss = (ce * weight[:, None]).sum() / (B * T)
+        else:
+            loss = ce.sum() / jnp.maximum(n_scored, 1.0)
         preds = jnp.exp(-ce.sum(axis=1) / jnp.maximum(scored.sum(axis=1), 1.0))
         held = self.experts_held[1] - self.experts_held[0]
         n_tokens = valid.sum().astype(jnp.float32)
@@ -669,4 +756,32 @@ class DecoderMoeLM:
                   moe[1], moe[0] / held]
         if KDA in self.layer_types:
             counts.append(n_tokens * self.layer_types.count(KDA))
+        if streams:
+            counts.append(n_tokens)
         return loss, preds, jnp.stack(counts)
+
+    def _noised(self, params: dict, x0: jax.Array, cls: jax.Array,
+                valid: jax.Array, dense: jax.Array) -> tuple:
+        """The block-diffusion objective's inputs from the clean ones: x0
+        [B, T, H] the tokens' embeddings, cls [B, T] their classes, valid
+        [B, T], dense [B, >= 1] the feed's dense features.  Returns the
+        layers' input [B, 2T, H] (noised stream, then clean), the noised
+        stream's targets [B, T] (the token's own class where it is masked,
+        -1 elsewhere) and each sequence's loss weight 1 / p."""
+        z = self.diffusion
+        T = x0.shape[1]
+        with jax.named_scope("noise"):
+            # the level is an integer n of the dense feature's grid, and the
+            # draw a function of (seed, n, first class): the instance's own
+            n = jnp.round(NOISE_GRID * dense[:, 0]).astype(jnp.int32) + (
+                NOISE_GRID // 2)
+            p = z["eps"] + (1.0 - z["eps"]) * (
+                n.astype(jnp.float32) / NOISE_GRID)
+            seed = jax.random.PRNGKey(z["noise_seed"])
+            u = jax.vmap(lambda n_b, c: jax.random.uniform(
+                jax.random.fold_in(jax.random.fold_in(seed, n_b), c), (T,))
+            )(n, cls[:, 0])
+            masked = (u < p[:, None]) & valid
+            xt = jnp.where(masked[..., None], params["mask_embed"], x0)
+            return (jnp.concatenate([xt, x0], axis=1),
+                    jnp.where(masked, cls, -1), 1.0 / p)

@@ -196,9 +196,12 @@ def routed_experts(
     of a 1,190 ms step (forward, rematerialised forward and backward): no
     grouped form was timed there.  At 8 of 256 held, 8 a token (N = 8,192,
     D = 2,304, F = 1,024; PERF.md section 6, PR 39) it is 32x, the worst
-    ratio yet, and 26.6 ms a layer of an 835 ms step.  A grouped kernel
-    that gathers its own rows is the next step, judged on all three
-    ratios."""
+    ratio yet, and 26.6 ms a layer of an 835 ms step.  At 8 of 128 held, 8
+    a token, under the block-diffusion objective, whose every sequence goes
+    through the layers twice (N = 16,384 positions of 2 x 4,096 tokens in
+    two streams, D = 2,048, F = 768; PERF.md section 6, PR 42), it is 16x
+    and 38.0 ms a layer of a 1,112 ms step.  A grouped kernel that gathers
+    its own rows is the next step, judged on all four ratios."""
     held = w_gate.shape[0]
     e_loc = jnp.where((top_e >= lo) & (top_e < lo + held), top_e - lo, held)
     load = jnp.bincount(e_loc.reshape(-1), length=held + 1)[:held]

@@ -24,11 +24,13 @@ Both are pure shard_map bodies (jit + autodiff through scan/ppermute/
 all_to_all work out of the box) and reduce to plain attention at P=1.
 
 On one device ``full_attention`` is that plain attention.  Its mask is a
-description -- ``causal``, and with it a ``window`` of keys -- and queries
-may be grouped over fewer key-value heads; asked for a window, a query
-block or grouped queries it runs blockwise (no [T, T] tensor, blocks
-outside the mask never computed); the value head may have another width
-than the query/key head.  ``rotary_tables`` / ``apply_rotary`` are the
+description in three words -- ``causal``, with it a ``window`` of keys, or
+``block_diffusion``, the block mask over a noised and a clean stream that
+block-diffusion training runs under -- and queries may be grouped over
+fewer key-value heads; asked for a window, a query block, grouped queries
+or the block mask it runs blockwise (no [T, T] tensor, blocks outside the
+mask never computed); the value head may have another width than the
+query/key head.  ``rotary_tables`` / ``apply_rotary`` are the
 rotary position code, plain and YaRN, pairing dimension i with i + D / 2
 or, ``interleaved``, 2i with 2i + 1; a caller that turns part of a head
 hands them that slice.  The ring and Ulysses forms take ``causal`` only: a
@@ -37,6 +39,7 @@ window on them is not written yet.
 
 from __future__ import annotations
 
+import functools
 from typing import Optional
 
 import jax
@@ -48,7 +51,7 @@ SEQ_AXIS = "seq"
 def full_attention(
     q: jax.Array, k: jax.Array, v: jax.Array, causal: bool = False,
     key_valid: Optional[jax.Array] = None, window: Optional[int] = None,
-    block_q: Optional[int] = None,
+    block_q: Optional[int] = None, block_diffusion: Optional[int] = None,
 ) -> jax.Array:
     """Softmax attention on one device.
 
@@ -56,22 +59,41 @@ def full_attention(
     [B, T, H, Dv] (Dv = D in most models; latent attention has a value
     head narrower than its query/key head).
 
-    The mask is described, never passed: ``causal`` (key j <= query i) and
-    ``window`` (with causal: i - j < window, a query sees itself and the
-    window - 1 keys before it).  Given ``window``, ``block_q`` or fewer
-    key-value heads than query heads (grouped queries: query head h reads
-    key-value head h // (H / Hkv); K and V are never repeated in memory),
-    the blockwise form runs: the queries in blocks of ``block_q``, no
-    [T, T] tensor, blocks wholly outside the mask never computed.
+    The mask is described, never passed, in three words: ``causal`` (key
+    j <= query i); ``window`` (with causal: i - j < window, a query sees
+    itself and the window - 1 keys before it); and ``block_diffusion`` = L
+    (neither of the other two), the mask of block-diffusion training over
+    two streams: the first half of the T positions is the noised stream,
+    the second the clean one, each numbered 0 .. T/2 - 1 in blocks of L
+    (block of i = i // L), and
+        a noised query i sees the noised keys of its own block (both
+            directions) and the clean keys of the blocks before it,
+        a clean query i sees the clean keys of its own block and of the
+            blocks before it, and no noised key.
+    Given ``window``, ``block_q``, ``block_diffusion`` or fewer key-value
+    heads than query heads (grouped queries: query head h reads key-value
+    head h // (H / Hkv); K and V are never repeated in memory), the
+    blockwise form runs: the queries in blocks of ``block_q``, no [T, T]
+    tensor, blocks wholly outside the mask never computed.
 
     key_valid: optional bool [B, Tk] (dense form only) — padded key
     positions read zero attention weight (variable-length sequences); a
     query whose keys are ALL masked reads a zero vector, not NaN.
     """
-    if (window is not None or block_q is not None
-            or q.shape[2] != k.shape[2]):
-        if key_valid is not None:
-            raise ValueError("the blockwise form takes no key_valid mask")
+    blockwise = (window is not None or block_q is not None
+                 or block_diffusion is not None or q.shape[2] != k.shape[2])
+    if blockwise and key_valid is not None:
+        raise ValueError(
+            "the blockwise form takes no key_valid: its mask is one of the "
+            "three described ones (causal, window, block_diffusion)")
+    if block_diffusion is not None:
+        if causal or window is not None:
+            raise ValueError(
+                "block_diffusion is a mask of its own: neither causal nor "
+                "a window goes with it")
+        return _block_diffusion_attention(
+            q, k, v, block_diffusion, block_q or DEFAULT_BLOCK_Q)
+    if blockwise:
         return _blockwise_attention(
             q, k, v, causal, window, block_q or DEFAULT_BLOCK_Q)
     d = q.shape[-1]
@@ -102,26 +124,44 @@ def _visible_keys(q0: int, q1: int, t: int, causal: bool,
     return (k0 // _KEY_ALIGN) * _KEY_ALIGN, k1
 
 
-def _strip_attention(qb, ks, vs, q0: int, k0: int, causal: bool,
-                     window: Optional[int]):
+def _strip_attention(qb, ks, vs, visible=None):
     """One block of queries against the keys it may see.  qb: [B, bq, Hkv,
     G, D] (query heads grouped over their key-value head); ks: [B, S, Hkv,
-    D]; vs: [B, S, Hkv, Dv].  The scores [B, Hkv, G, bq, S] are the only
-    score tensor."""
+    D]; vs: [B, S, Hkv, Dv]; ``visible(bq, S)`` gives the strip's mask,
+    bool [bq, S] (None: every key).  The scores [B, Hkv, G, bq, S] are the
+    only score tensor."""
     d = qb.shape[-1]
     s = jnp.einsum("bqhgd,bkhd->bhgqk", qb, ks,
                    preferred_element_type=jnp.float32) / jnp.sqrt(float(d))
-    if causal:
-        qi = q0 + jnp.arange(qb.shape[1], dtype=jnp.int32)[:, None]
-        kj = k0 + jnp.arange(ks.shape[1], dtype=jnp.int32)[None, :]
-        mask = kj <= qi
-        if window is not None:
-            mask &= qi - kj < window
-        s = jnp.where(mask, s, -jnp.inf)
-    # causal: every query sees itself, so no row is all -inf
+    if visible is not None:
+        s = jnp.where(visible(qb.shape[1], ks.shape[1]), s, -jnp.inf)
+    # every query sees itself under each described mask: no row is all -inf
     p = jax.nn.softmax(s, axis=-1)
     return jnp.einsum("bhgqk,bkhd->bqhgd", p.astype(vs.dtype), vs,
                       preferred_element_type=jnp.float32).astype(qb.dtype)
+
+
+def _causal_strip_mask(q0: int, k0: int, window: Optional[int]):
+    """Queries from q0 against keys from k0: key j <= query i, and with a
+    window i - j < window."""
+    def visible(n_q: int, n_k: int):
+        qi = q0 + jnp.arange(n_q, dtype=jnp.int32)[:, None]
+        kj = k0 + jnp.arange(n_k, dtype=jnp.int32)[None, :]
+        mask = kj <= qi
+        if window is not None:
+            mask &= qi - kj < window
+        return mask
+    return visible
+
+
+def _grouped(q: jax.Array, k: jax.Array) -> jax.Array:
+    """q [B, T, H, D] as [B, T, Hkv, H / Hkv, D]: the query heads grouped
+    over the key-value head each reads."""
+    b, t, h, d = q.shape
+    hkv = k.shape[2]
+    if h % hkv:
+        raise ValueError(f"{h} query heads over {hkv} key-value heads")
+    return q.reshape(b, t, hkv, h // hkv, d)
 
 
 def _blockwise_attention(
@@ -140,19 +180,77 @@ def _blockwise_attention(
     if window is not None and not causal:
         raise ValueError("a window is defined on causal attention only")
     b, t, h, d = q.shape
-    hkv = k.shape[2]
-    if h % hkv:
-        raise ValueError(f"{h} query heads over {hkv} key-value heads")
-    qg = q.reshape(b, t, hkv, h // hkv, d)
+    qg = _grouped(q, k)
     out = []
     for q0 in range(0, t, block_q):
         q1 = min(q0 + block_q, t)
         k0, k1 = _visible_keys(q0, q1, k.shape[1], causal, window)
-        strip = jax.checkpoint(
-            lambda qb, ks, vs, q0=q0, k0=k0: _strip_attention(
-                qb, ks, vs, q0, k0, causal, window))
+        strip = jax.checkpoint(functools.partial(
+            _strip_attention,
+            visible=_causal_strip_mask(q0, k0, window) if causal else None))
         out.append(strip(qg[:, q0:q1], k[:, k0:k1], v[:, k0:k1]))
     return jnp.concatenate(out, axis=1).reshape(b, t, h, v.shape[-1])
+
+
+def _diffusion_strip_mask(q0: int, block: int, n_clean: Optional[int] = None):
+    """Queries from position q0 of their stream against clean keys from
+    position 0 -- all of the strip's keys for clean queries; for noised
+    ones the first ``n_clean``, the noised keys from q0 after them: a clean
+    key is seen from a block before the query's -- and by a clean query
+    from its own block too --, a noised key from the query's own block."""
+    def visible(n_q: int, n_k: int):
+        qb = (q0 + jnp.arange(n_q, dtype=jnp.int32)[:, None]) // block
+        col = jnp.arange(n_k, dtype=jnp.int32)[None, :]
+        if n_clean is None:
+            return col // block <= qb
+        return jnp.where(col < n_clean, col // block < qb,
+                         (q0 + col - n_clean) // block == qb)
+    return visible
+
+
+def _block_diffusion_attention(
+    q: jax.Array, k: jax.Array, v: jax.Array, block: int, block_q: int,
+) -> jax.Array:
+    """``full_attention`` under the block mask over two streams (its
+    docstring has the three rules), in the strips of the blockwise form:
+    a strip of noised queries [q0, q1) goes against the clean keys of the
+    blocks before its last block and the noised keys [q0, q1) of its own
+    blocks, under ONE softmax over both; a strip of clean queries against
+    the clean keys [0, q1).  ``block_q`` is a multiple of the block
+    length, so a strip holds whole blocks and nothing outside those keys
+    is ever computed; the clean keys' end sits on a lane-tile boundary
+    where that is inside the strip (the few keys more are masked)."""
+    b, t2, h, d = q.shape
+    if t2 % 2 or k.shape[1] != t2:
+        raise ValueError(
+            f"block_diffusion runs over two streams of one length: {t2} "
+            f"query and {k.shape[1]} key positions")
+    if block < 1 or block_q % block:
+        raise ValueError(
+            f"block_q {block_q} is no multiple of the block length {block}")
+    t = t2 // 2
+    qg = _grouped(q, k)
+    k_clean, v_clean = k[:, t:], v[:, t:]
+    noised, clean = [], []
+    for q0 in range(0, t, block_q):
+        q1 = min(q0 + block_q, t)
+        # clean keys a noised query of the strip may see: the blocks before
+        # the strip's last
+        n_clean = (q1 - 1) // block * block
+        n_clean = min(-(-n_clean // _KEY_ALIGN) * _KEY_ALIGN, q1)
+        strip = jax.checkpoint(functools.partial(
+            _strip_attention,
+            visible=_diffusion_strip_mask(q0, block, n_clean)))
+        noised.append(strip(
+            qg[:, q0:q1],
+            jnp.concatenate([k_clean[:, :n_clean], k[:, q0:q1]], axis=1),
+            jnp.concatenate([v_clean[:, :n_clean], v[:, q0:q1]], axis=1)))
+        strip = jax.checkpoint(functools.partial(
+            _strip_attention, visible=_diffusion_strip_mask(q0, block)))
+        clean.append(strip(qg[:, t + q0:t + q1], k_clean[:, :q1],
+                           v_clean[:, :q1]))
+    return jnp.concatenate(noised + clean, axis=1).reshape(
+        b, t2, h, v.shape[-1])
 
 
 def rotary_tables(positions: jax.Array, head_dim: int, theta: float,

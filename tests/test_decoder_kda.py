@@ -618,6 +618,16 @@ PINNED = {  # description -> (train.step's StableHLO text, init's leaves)
         517322,
         "c0dfa9a8e03491d188df11a40361e376e5960bb75844b6fd0f9f4d260874d69a",
         "6cb580f06b34aa2f36f9118be8c391881525b2b81aabac1cb1f04afb838a3564"),
+    # taken on 488ad8c (PR 40), the commit before the block-diffusion
+    # objective, with this very code and this file's chunks of eight
+    "kimi_linear": (dict(
+        PIN_COMMON, n_kv_heads=4, experts_held=(0, 4), rms_eps=EPS,
+        layer_types=OPS_OF, mlp_types=MLPS, kda=KDA, latent=LATENT,
+        dense_width=96, shared_width=32, router_score="sigmoid",
+        router_bias=True, router_scale=SCALE),
+        1396629,
+        "dd781c9a04320beb5fe97beccf5cab5c8f6e25b4166a9fc37a6f59ccf91431be",
+        "6b3c3d8906f666eee9b9da2b4211d72ddb2cb6c638668a0593f6326d19c46a0d"),
 }
 
 
@@ -625,11 +635,13 @@ PINNED = {  # description -> (train.step's StableHLO text, init's leaves)
 def test_an_accepted_description_lowers_to_the_program_it_lowered_to(
         name, tmp_path):
     """A description without ``"kda"`` and without ``"rotary"`` is the
-    program it was before there were such words: the ``train.step``
-    StableHLO text of a toy description of each accepted decoder
-    configuration, taken on the commit before this operator kind (9670c68,
-    PR 38) with this very code, by length and sha256, and ``init``'s bits
-    leaf by leaf (under tests/conftest.py's XLA flags: a normal draw's
+    program it was before there were such words, and one without the
+    block-diffusion objective the program it was before there was a second
+    objective: the ``train.step`` StableHLO text of a toy description of
+    each accepted decoder configuration, taken on the commit before this
+    operator kind (9670c68, PR 38; the fourth, which has the kind, on
+    488ad8c, PR 40, the commit before the second objective) with this very
+    code, by length and sha256, and ``init``'s bits leaf by leaf (under tests/conftest.py's XLA flags: a normal draw's
     last bit follows the CPU's instruction set).  A later change to code
     these descriptions run moves the pins: take them anew on its parent
     first, and say so."""
@@ -664,4 +676,5 @@ def test_an_accepted_description_lowers_to_the_program_it_lowered_to(
     for leaf in jax.tree.leaves(model.init(jax.random.PRNGKey(11))):
         bits.update(np.asarray(leaf).tobytes())
     assert bits.hexdigest() == init_sha
-    assert model.step_counters == DecoderMoeLM.step_counters
+    assert model.step_counters == DecoderMoeLM.step_counters + (
+        ("kda.tokens",) if "kda" in kw["layer_types"] else ())

@@ -554,6 +554,12 @@ def test_kvchannel_wait_interrupted_by_watchdog_abort(fake_world):
             ch.allgather(np.asarray([1], dtype=np.int64))
         assert time.monotonic() - t0 < 5.0  # nowhere near the 30s timeout
     finally:
+        # the other peer's read is still polling in the channel's pool: let
+        # the pending abort end it too, while the watchdog is still the
+        # current one -- left behind it polls for 30 s and beats
+        # "hostplane:plan-9" into whichever watchdog a later test of this
+        # process starts (tests/test_postmortem.py then reads that stage)
+        ch._pool.shutdown(wait=True)
         wd.close()
 
 
