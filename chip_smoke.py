@@ -22,6 +22,12 @@ entry points a user calls and fails by raising; nothing is caught.
     instances.
   * sparse_ops — the gather and scatter-add the step is built from, at the
     train leg's shapes, against numpy (duplicate indices included).
+  * attention — the flash-form kernel (parallel/flash_attention.py: what
+    ``full_attention``'s blockwise form runs on a TPU) against the strips,
+    at kanana2's and kimi_linear's published head shapes and sequence
+    lengths (a value head narrower than the key head, 4 x 4,096 and 1 x
+    8,192 positions): one line each with the distance of the output and of
+    the gradients and the milliseconds of both forms.
   * four_chips — with >= 4 devices (demanded by ``--require-chips 4``):
     the train leg through make_mesh(4) + ShardedSparseTable +
     MultiChipTrainer, hash placement and the realized hybrid placement,
@@ -294,6 +300,73 @@ def leg_sparse_ops(sz: Sizes, capacity_rows: int, row_width: int) -> dict:
         "take", "scatter_add(duplicates)", "scatter_add(unique_indices)"]}
 
 
+# two accepted decoder configurations' causal attention (benchmark/configs/),
+# the widest heads and the longest sequence: name -> (B, T, H, Hkv, D, Dv)
+ATTENTION = {
+    "kanana2_30b_ep16.latent": (4, 4096, 32, 32, 192, 128),
+    "kimi_linear_48b_ep32.latent": (1, 8192, 32, 32, 192, 128),
+}
+
+
+def leg_attention(shapes: dict, block_q: int = 256, repeats: int = 3) -> dict:
+    """``full_attention``'s blockwise form under the causal mask as this
+    backend takes it against the strips (the oracle, and every other
+    backend's form), forward and gradients, at ``shapes``.  On a TPU the
+    kernel is run whichever form ``full_attention`` takes there (``form``);
+    its distance from the strips is one bfloat16 pass's (both round their
+    products' operands to bfloat16, in another order)."""
+    import jax
+    import jax.numpy as jnp
+
+    from paddlebox_tpu.parallel import sequence as sq
+
+    def timed(f, *args):
+        out = jax.block_until_ready(f(*args))
+        t0 = time.perf_counter()
+        for _ in range(repeats):
+            out = f(*args)
+        jax.block_until_ready(out)
+        return out, 1e3 * (time.perf_counter() - t0) / repeats
+
+    def gap(want, got) -> float:
+        return float(jnp.linalg.norm((want - got).ravel())
+                     / jnp.linalg.norm(want.ravel()))
+
+    on_tpu = jax.default_backend() == "tpu"
+    report = {}
+    for name, (b, t, h, hkv, d, dv) in shapes.items():
+        ks = jax.random.split(jax.random.PRNGKey(len(report)), 4)
+        q = jax.random.normal(ks[0], (b, t, h, d), jnp.float32)
+        k = jax.random.normal(ks[1], (b, t, hkv, d), jnp.float32)
+        v = jax.random.normal(ks[2], (b, t, hkv, dv), jnp.float32)
+        w = jax.random.normal(ks[3], (b, t, h, dv), jnp.float32)
+
+        def measure(tag, attend) -> dict:
+            def loss(q, k, v, w):  # w an argument: closed over, a constant
+                return (attend(q, k, v) * w).sum()
+            out, line[f"{tag}_fwd_ms"] = timed(jax.jit(attend), q, k, v)
+            (dq, dk, dv_), line[f"{tag}_grad_ms"] = timed(
+                jax.jit(jax.grad(loss, (0, 1, 2))), q, k, v, w)
+            return {"out": out, "dq": dq, "dk": dk, "dv": dv_}
+
+        line = {"form": sq._attention_form(q, k, v, "causal")[0]}
+        want = measure("strips", lambda q, k, v: sq._blockwise_attention(
+            q, k, v, True, None, block_q))
+        got = want  # off the chip there is one form: nothing to tell apart
+        if on_tpu:  # the kernel itself, whichever form full_attention takes
+            from paddlebox_tpu.parallel import flash_attention as fa
+            line["blocks"] = fa.blocks_for(t, t, h // hkv, d, dv, hkv)
+            spec = fa.Spec("causal", None, *line["blocks"])
+            got = measure("kernel", lambda q, k, v: fa.flash_attention(
+                q, k, v, spec))
+        line["gap"] = {x: gap(want[x], got[x]) for x in want}
+        if max(line["gap"].values()) > 2e-2:
+            raise AssertionError(f"{name}: kernel from strips {line['gap']}")
+        log(f"attention {name}: {json.dumps(line)}")
+        report[name] = line
+    return report
+
+
 def _train_sharded(sz: Sizes, ds, mesh, placement: str, passes: int):
     """The train leg on a mesh; returns (report, final host state)."""
     from paddlebox_tpu.config import SparseTableConfig, TrainerConfig
@@ -427,6 +500,7 @@ def main() -> None:
             sz, legs["train"]["passes"][-1]["capacity_rows"],
             legs["train"]["row_width"])
         table.close()
+        legs["attention"] = leg_attention(ATTENTION)
         if len(devs) >= 4:
             legs["four_chips"] = leg_four_chips(sz, ds)
         else:

@@ -30,7 +30,15 @@ block-diffusion training runs under -- and queries may be grouped over
 fewer key-value heads; asked for a window, a query block, grouped queries
 or the block mask it runs blockwise (no [T, T] tensor, blocks outside the
 mask never computed); the value head may have another width than the
-query/key head.  ``rotary_tables`` / ``apply_rotary`` are the
+query/key head.  The blockwise form is one algorithm in two forms, told
+apart by what the code can observe (``_attention_form``): on a TPU, for the
+three described masks and lengths a block divides, one flash-form Pallas
+kernel and its backward (parallel/flash_attention.py: a tile's scores in
+VMEM only, the output and a row's log-sum-exp the only things written; q,
+k and v read where they lie, nothing copied around the call); everywhere
+else -- every other backend, and the kernel's oracle -- the strips below,
+each two products with a softmax between.  ``attn.form`` counts which form
+a traced call took.  ``rotary_tables`` / ``apply_rotary`` are the
 rotary position code, plain and YaRN, pairing dimension i with i + D / 2
 or, ``interleaved``, 2i with 2i + 1; a caller that turns part of a head
 hands them that slice.  The ring and Ulysses forms take ``causal`` only: a
@@ -40,12 +48,22 @@ window on them is not written yet.
 from __future__ import annotations
 
 import functools
+import logging
 from typing import Optional
 
 import jax
 import jax.numpy as jnp
 
+from paddlebox_tpu.telemetry import metrics as _tm
+
 SEQ_AXIS = "seq"
+_log = logging.getLogger(__name__)
+
+_FORM = _tm.counter(
+    "attn.form", "traced calls of full_attention's blockwise form by the "
+    "form they took (kernel: the flash-form Pallas kernel, on a TPU; "
+    "strips: two products and a softmax a block of queries) and the mask "
+    "(causal, window, block_diffusion, none)")
 
 
 def full_attention(
@@ -86,16 +104,13 @@ def full_attention(
         raise ValueError(
             "the blockwise form takes no key_valid: its mask is one of the "
             "three described ones (causal, window, block_diffusion)")
-    if block_diffusion is not None:
-        if causal or window is not None:
-            raise ValueError(
-                "block_diffusion is a mask of its own: neither causal nor "
-                "a window goes with it")
-        return _block_diffusion_attention(
-            q, k, v, block_diffusion, block_q or DEFAULT_BLOCK_Q)
+    if block_diffusion is not None and (causal or window is not None):
+        raise ValueError(
+            "block_diffusion is a mask of its own: neither causal nor "
+            "a window goes with it")
     if blockwise:
-        return _blockwise_attention(
-            q, k, v, causal, window, block_q or DEFAULT_BLOCK_Q)
+        return _blockwise(q, k, v, causal, window, block_diffusion,
+                          block_q or DEFAULT_BLOCK_Q)
     d = q.shape[-1]
     s = jnp.einsum("bqhd,bkhd->bhqk", q, k) / jnp.sqrt(float(d))
     if causal:
@@ -114,6 +129,64 @@ def full_attention(
 
 DEFAULT_BLOCK_Q = 256
 _KEY_ALIGN = 128  # a strip's first key sits on a lane-tile boundary
+
+
+def _attention_form(q: jax.Array, k: jax.Array, v: jax.Array,
+                    mask: str) -> tuple:
+    """("kernel", (block_q, block_kv, key-value heads a grid step)) where
+    the flash-form kernel runs the blockwise form, ("strips", why not) where
+    the strips do: the kernel is a TPU's, takes the three described masks,
+    one dtype for q, k and v, and lengths that one of its blocks divides."""
+    if jax.default_backend() != "tpu":
+        return "strips", "not a TPU"
+    if mask == "none":
+        return "strips", "no mask: the kernel takes the described three"
+    if not (q.dtype == k.dtype == v.dtype
+            and jnp.issubdtype(q.dtype, jnp.floating)):
+        return "strips", f"dtypes {q.dtype}, {k.dtype}, {v.dtype}"
+    from paddlebox_tpu.parallel import flash_attention
+    blocks = flash_attention.blocks_for(
+        q.shape[1], k.shape[1], q.shape[2] // k.shape[2], q.shape[3],
+        v.shape[3], k.shape[2])
+    if blocks is None:
+        return "strips", (f"no block divides {q.shape[1]} query and "
+                          f"{k.shape[1]} key positions")
+    return "kernel", blocks
+
+
+def _blockwise(q, k, v, causal: bool, window: Optional[int],
+               block_diffusion: Optional[int], block_q: int) -> jax.Array:
+    """``full_attention``'s blockwise form, in the form ``_attention_form``
+    finds: the same arguments are refused on every backend, then the choice
+    is counted (it is static: once a traced call) and taken."""
+    if window is not None and not causal:
+        raise ValueError("a window is defined on causal attention only")
+    t, h = q.shape[1:3]
+    if h % k.shape[2]:
+        raise ValueError(
+            f"{h} query heads over {k.shape[2]} key-value heads")
+    if block_diffusion is not None:
+        if t % 2 or k.shape[1] != t:
+            raise ValueError(
+                f"block_diffusion runs over two streams of one length: {t} "
+                f"query and {k.shape[1]} key positions")
+        if block_diffusion < 1 or block_q % block_diffusion:
+            raise ValueError(
+                f"block_q {block_q} is no multiple of the block length "
+                f"{block_diffusion}")
+    mask = ("block_diffusion" if block_diffusion is not None
+            else "window" if window is not None
+            else "causal" if causal else "none")
+    form, found = _attention_form(q, k, v, mask)
+    _FORM.inc(form=form, mask=mask)
+    if form == "kernel":
+        from paddlebox_tpu.parallel import flash_attention
+        return flash_attention.flash_attention(q, k, v, flash_attention.Spec(
+            mask, block_diffusion if window is None else window, *found))
+    _log.debug("full_attention %s in strips: %s", mask, found)
+    if block_diffusion is not None:
+        return _block_diffusion_attention(q, k, v, block_diffusion, block_q)
+    return _blockwise_attention(q, k, v, causal, window, block_q)
 
 
 def _visible_keys(q0: int, q1: int, t: int, causal: bool,

@@ -323,6 +323,23 @@ class _FeedPrefetcher:
             )
 
 
+def _step_schedule() -> dict:
+    """How the TPU's compiler orders the step: by its list scheduler, the
+    one of its three memory schedulers (list, depth-first, post-order) that
+    orders a step for the least memory.  Left to itself it runs all three
+    and keeps the one whose own estimate is smallest, and for a decoder
+    step that estimate flips with what the step contains: with attention
+    in unrolled strips it kept list, with attention as a kernel (or left
+    out altogether) it keeps depth-first, whose schedule of the very same
+    layers fills the chip -- 17.2 GB against 14.5 for a step that holds
+    7.3 GB of state -- and leaves the pass boundary's and the read-back's
+    eager programs no room beside it (PERF.md section 6, PR 44).  Off the
+    TPU nothing is named: the option is the TPU compiler's."""
+    if jax.default_backend() != "tpu":
+        return {}
+    return {"compiler_options": {"xla_memory_scheduler": "list"}}
+
+
 class Trainer:
     """Drives model + SparseTable over a dataset's batches."""
 
@@ -531,9 +548,11 @@ class Trainer:
                 return (*state, loss, finite, primary)
 
             return counted_jit(
-                guarded, stage="train.step", donate_argnums=(0, 1, 2, 3, 4))
+                guarded, stage="train.step", donate_argnums=(0, 1, 2, 3, 4),
+                **_step_schedule())
         return counted_jit(
-            step, stage="train.step", donate_argnums=(0, 1, 2, 3, 4))
+            step, stage="train.step", donate_argnums=(0, 1, 2, 3, 4),
+            **_step_schedule())
 
     def _init_mstate(self, auc_state=None) -> dict:
         """Fresh metric state, or continuation: pass the previous pass's

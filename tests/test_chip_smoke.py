@@ -43,6 +43,19 @@ def test_train_serve_and_sparse_ops_legs(toy_data):
     table.close()
 
 
+def test_attention_leg_at_toy_shapes():
+    """The leg's table at a toy length; on the CPU both forms are the
+    strips, so the leg's own distance check reads 0."""
+    toy = {name: (1, 128, 8, hkv * 8 // h, d // 8, dv // 8)
+           for name, (_, _, h, hkv, d, dv) in chip_smoke.ATTENTION.items()}
+    report = chip_smoke.leg_attention(toy, block_q=32, repeats=1)
+    assert list(report) == list(chip_smoke.ATTENTION)
+    for line in report.values():
+        assert line["form"] == "strips"
+        assert set(line["gap"]) == {"out", "dq", "dk", "dv"}
+        assert max(line["gap"].values()) == 0.0
+
+
 def test_four_chip_leg_on_four_of_the_fake_devices(toy_data):
     """The leg's own assertions are the test: 4 shards of values/g2sum on
     4 distinct devices, each shard's cache rows on that shard's device,
