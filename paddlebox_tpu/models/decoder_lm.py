@@ -63,7 +63,18 @@ a decay a channel.  With h = n1 x, ``n_heads`` heads of ``head_dim`` = d:
 
 computed in chunks (``gated_delta_chunked``): inside a chunk the pairs'
 decays ``exp(G_i - G_j)`` as they stand, one unit-lower-triangular solve a
-chunk, and a ``lax.scan`` that carries S from chunk to chunk.
+chunk, and a walk that carries S from chunk to chunk -- one recurrence in
+two forms, told apart by what the code can observe (``_kda_form``).  On a
+TPU, for heads one lane tile (128) wide, the Pallas kernels of
+parallel/kda_kernel.py: a pair's decays live in a register while its sums
+are formed, the cumulative sum and the solve go with them, and the states
+lie in VMEM while the chunks are walked, forward and backward; the backward
+keeps the cumulative sum, the pairs' sums, the solve's result and one state
+a block of 4 chunks.  Everywhere else -- every
+other backend, and the kernels' oracle -- ``jax.numpy``: the decays of a
+group of chunks through memory under ``lax.map``, a two-level ``lax.scan``,
+both rematerialised, the backward keeping one state a group of chunks.
+``kda.form`` counts which form a traced call took.
 
 ``mlp_types[l]`` is ``"dense"`` (one SwiGLU of ``dense_width``) or
 ``"sparse"``: a router over all ``n_experts`` (``router_score`` softmax or
@@ -112,6 +123,7 @@ another operator kind under this objective is refused by name.
 
 from __future__ import annotations
 
+import logging
 from typing import Optional, Sequence
 
 import jax
@@ -128,6 +140,14 @@ from paddlebox_tpu.parallel.sequence import (
     full_attention,
     rotary_tables,
 )
+from paddlebox_tpu.telemetry import metrics as _tm
+
+_log = logging.getLogger(__name__)
+_FORM = _tm.counter(
+    "kda.form", "traced calls of gated_delta_chunked by the form they took "
+    "(kernel: the Pallas kernels of parallel/kda_kernel.py, on a TPU; "
+    "chunks: the jax.numpy form, everywhere else and for shapes the kernels "
+    "do not take)")
 
 SLIDING, FULL, LATENT, CONV, KDA = (
     "sliding_attention", "full_attention", "latent_attention", "conv", "kda")
@@ -197,6 +217,22 @@ _take_once.defvjp(
     _take_once_bwd)
 
 
+def _kda_form(q, k, v, g, beta, chunk: int) -> tuple:
+    """("kernel", its ``Spec``) where the Pallas kernels run the chunk
+    recurrence, ("chunks", why not) where the ``jax.numpy`` form does: the
+    kernels are a TPU's and take float32 or bfloat16 operands, heads one
+    lane tile (128) wide, heads and a chunk of whole sublane tiles."""
+    if jax.default_backend() != "tpu":
+        return "chunks", "not a TPU"
+    dtypes = {jnp.dtype(a.dtype) for a in (q, k, v, g, beta)}
+    if not dtypes <= {jnp.dtype(jnp.float32), jnp.dtype(jnp.bfloat16)}:
+        return "chunks", f"dtypes {sorted(map(str, dtypes))}"
+    from paddlebox_tpu.parallel import kda_kernel
+    spec, why = kda_kernel.spec_for(
+        q.shape[1], q.shape[2], q.shape[3], v.shape[3], chunk)
+    return ("chunks", why) if spec is None else ("kernel", spec)
+
+
 def gated_delta_chunked(q, k, v, g, beta, chunk: int) -> jax.Array:
     """The gated delta rule with a decay a channel, ``chunk`` positions at
     a time.  q, k, g [B, T, nh, dk] (g <= 0: the decay's logarithm), v
@@ -226,7 +262,26 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int) -> jax.Array:
     all chunks, against [beta k exp G | beta v], since u = u0 - w S_0 is
     linear in the state; and the ``lax.scan`` that carries S, four products
     a chunk, in groups whose body is rematerialised, so that the backward
-    pass keeps one state a group and one a chunk of the group it is in."""
+    pass keeps one state a group and one a chunk of the group it is in.
+
+    That is the ``jax.numpy`` form: every backend's but the TPU's, and the
+    oracle of the other.  On a TPU, for float operands with heads one lane
+    tile (128) wide, heads and a chunk of whole sublane tiles
+    (``_kda_form``), the same recurrence runs as the Pallas kernels of
+    parallel/kda_kernel.py, two each way and nothing between them: a pair's
+    decays live in a register while its sums are formed, the cumulative sum
+    and the solve (by substitution) go with them, and the states lie in VMEM
+    while the chunks are walked; q, k, v and g are read where they are.
+    Kept for the backward are G, the pairs' sums, the solve's result and
+    one state a block of 4 chunks (no decay, no state a chunk), and
+    ``KDA_PAIR_ELEMS`` and the checkpoints below play no part.
+    ``kda.form`` counts which form a traced call took."""
+    form, found = _kda_form(q, k, v, g, beta, chunk)
+    _FORM.inc(form=form)
+    if form == "kernel":
+        from paddlebox_tpu.parallel import kda_kernel
+        return kda_kernel.gated_delta(q, k, v, g, beta, found)
+    _log.debug("gated_delta_chunked in chunks: %s", found)
     B, T, nh, dk = q.shape
     dv = v.shape[-1]
     C = min(chunk, T)
