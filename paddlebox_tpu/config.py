@@ -634,7 +634,9 @@ class LivenessConfig:
     shuffle_timeout_s: float = 120.0
     # on a stall abort, roll the process back to the newest valid
     # checkpoint (PR 1's find_valid_tag / PassRolledBack machinery) so no
-    # partially-applied pass survives; requires trainer.checkpointer
+    # partially-applied pass survives; requires trainer.checkpointer.
+    # Trainer only: MultiChipTrainer ignores it (an abort there is the
+    # driver's to recover: restart and resume from the newest valid tag)
     rollback_on_abort: bool = False
     # multi-process only: a thread blocked INSIDE a device collective
     # cannot be unwound from Python, so after an abort the watchdog gives
@@ -865,6 +867,8 @@ class TrainerConfig:
     dump_fields_path: str = ""
     dump_param: Sequence[str] = ()
     need_dump_field: bool = False
+    # Trainer only: MultiChipTrainer ignores need_dump_param (its params
+    # are stacked a device and its table sharded; dump_params takes neither)
     need_dump_param: bool = False
     # task-label columns (indices into the batch's task_labels matrix, whose
     # col 0 is the primary label and cols 1.. are the configured
@@ -897,6 +901,9 @@ class TrainerConfig:
     #                  state are restored to the last completed pass;
     #                  train_from_dataset raises PassRolledBack so the
     #                  driver re-runs from there
+    # Trainer only: MultiChipTrainer ignores nan_policy (its step has no
+    # guarded form) — it knows check_nan_inf alone and raises a bare
+    # FloatingPointError, the verdict psummed so every rank raises
     nan_policy: str = "raise"
     # device-feed double buffering: a background thread runs key planning +
     # host->device transfer for the next batches while the current step

@@ -25,14 +25,12 @@ TPU-native redesign of the reference's multi-GPU path (SURVEY.md §2.9/§3.2):
     use metrics.auc.psum_auc_state to fold it into the step if desired).
 
 The whole step runs under one jit(shard_map(...)) with donated state, so XLA
-overlaps the all_to_alls with the dense tower compute where possible.
+overlaps the all_to_alls with the dense tower compute where possible.  The
+pass around it is train/pass_loop.py run_pass, the single-chip trainer's too.
 """
 
 from __future__ import annotations
 
-import math
-import os
-import time
 from typing import Iterable, Iterator, Optional, Sequence
 
 import jax
@@ -41,14 +39,13 @@ import numpy as np
 import optax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
+from paddlebox_tpu import telemetry
 from paddlebox_tpu.config import SparseTableConfig, TrainerConfig
 from paddlebox_tpu.data.feed import HostBatch, empty_like, key_classes
 from paddlebox_tpu.metrics.auc import (
     AucState,
     compute_metrics,
-    compute_metrics_stacked,
     init_auc_state,
-    stack_auc_states,
     update_auc_state,
 )
 from paddlebox_tpu.metrics.variants import MetricGroup
@@ -56,33 +53,24 @@ from paddlebox_tpu.parallel.mesh import DATA_AXIS
 from paddlebox_tpu.parallel.multiprocess import (
     global_from_local,
     host_allgather,
+    is_multiprocess,
     local_device_indices,
     local_view,
+    merge_device_axis,
     read_replicated,
 )
 from paddlebox_tpu.parallel.sharded_table import ShardedBatchPlan, ShardedSparseTable
 from paddlebox_tpu.sparse.optimizer import sparse_adagrad_update
 from paddlebox_tpu.sparse.table import merge_occurrences, scatter_add_rows
 from paddlebox_tpu.telemetry.compiles import counted_jit, stage_scope
-from paddlebox_tpu.utils.profiler import (
-    HOST,
-    START,
-    CompletionWatcher,
-    StatsProfiler,
-    pass_seconds,
-)
-from paddlebox_tpu.utils import faults
+from paddlebox_tpu.train import pass_loop
 from paddlebox_tpu.train.slot_policy import (
     normalize_slot_mask,
     resolve_slot_lr_vec,
     slot_participation_vec,
 )
-from paddlebox_tpu.train.step_loss import (
-    add_counts,
-    counter_names,
-    make_model_loss,
-    publish_counters,
-)
+from paddlebox_tpu.train.step_loss import add_counts, make_model_loss
+from paddlebox_tpu.utils.profiler import START, CompletionWatcher
 
 
 # process-wide pass counter for host-plane channel names: advances once per
@@ -325,6 +313,248 @@ def sharded_push_and_update(
     return values, g2sum
 
 
+class _GroupPass(pass_loop.PassHooks):
+    """What the sharded trainer says about one pass: its producer (the
+    ragged-tail barrier, the group plan, the stack), its step on the state
+    it carries (the table's buffers, the hot block, the per-device counts,
+    the async gradient), its dense sync (the protocol is
+    pass_loop.run_pass)."""
+
+    merge = staticmethod(merge_device_axis)
+
+    def __init__(self, trainer: "MultiChipTrainer", table, groups):
+        self.trainer, self.table, self.groups = trainer, table, groups
+        self.counts: list = []
+        self.pending_grads: list = []  # device grads fetched one step behind
+        self.plan_channel = None
+
+    def open(self) -> None:
+        t, table, conf = self.trainer, self.table, self.trainer.conf
+        self.hot_cap = hot_cap = int(getattr(table, "hot_block_capacity", 0))
+        if t._step_fn is None or t._step_hot_cap != hot_cap:
+            t._step_fn = t._build_step(hot_cap)
+            t._step_hot_cap = hot_cap
+        if t._sync_fn is None and conf.sync_dense_mode == "kstep":
+            t._sync_fn = t._build_sync()
+        self.multiproc = multiproc = is_multiprocess()
+        self.async_dense = conf.sync_dense_mode == "async"
+        if self.async_dense and t.async_dense is None:
+            from paddlebox_tpu.parallel.async_dense import AsyncDenseTable
+
+            # every process hosts an identical table fed identical
+            # replicated grads, so multi-host needs no extra dense comm
+            # (the reference runs one table per node the same way)
+            p0 = jax.tree.map(lambda x: local_view(x)[0], t.params)
+            t.async_dense = AsyncDenseTable(
+                p0, optimizer=conf.dense_optimizer, lr=conf.dense_lr,
+            )
+        self.sync_every = max(conf.sync_weight_step, 1)
+        self.values, self.g2sum = table.values, table.g2sum
+        self.hot_values = self.hot_g2sum = None
+        if hot_cap:
+            with stage_scope("train.init"):
+                self.hot_values, self.hot_g2sum = t._hot_state(table, hot_cap)
+        # the producer's collectives must be HOST-side: it runs concurrent
+        # with the consumer's device step, and two threads racing device
+        # collectives onto the queues in different orders across processes
+        # is a cross-process deadlock.  Each pass gets its own KV channel
+        # (deterministic name: every process increments in lockstep).
+        self.plan_gather = host_allgather  # no-op [1, ...] wrap
+        if multiproc:
+            from paddlebox_tpu.parallel.host_plane import KvChannel
+
+            _PLAN_CHANNEL_SEQ[0] += 1
+            self.plan_channel = KvChannel(
+                f"plan-{_PLAN_CHANNEL_SEQ[0]}",
+                timeout_s=(
+                    conf.liveness.hostplane_timeout_s
+                    if conf.liveness is not None
+                    else conf.host_plane_timeout_s
+                ),
+            )
+            self.plan_gather = self.plan_channel.allgather
+            # per-process file (the reference's per-node dump discipline):
+            # each process dumps exactly its local devices' instances
+            self.dump_suffix = f"-r{jax.process_index()}"
+
+    def feeds(self):
+        """Barrier + host planning + stack + H2D for every group.
+
+        Runs on the prefetch thread so the per-batch want-matrix
+        allgather and feed assembly overlap the device step (the
+        single-chip _FeedPrefetcher discipline).
+        All its cross-process exchanges ride the host-plane KV channel
+        above — it never touches the device queues, so it cannot
+        deadlock against the consumer's step collectives."""
+        t, table, sprof, wd = self.trainer, self.table, self.prof, self.wd
+        multiproc, plan_gather = self.multiproc, self.plan_gather
+        vocab_keys = getattr(t.model, "vocab_keys", None)
+        uses_rank = getattr(t.model, "uses_rank_offset", False)
+        uses_seq = getattr(t.model, "uses_seq_pos", False)
+        dumping = self.dumper is not None
+        groups_it = sprof.iterate("batch", self.groups)
+        template = None  # last real batch: shapes for tail-padding
+        n_slots = None
+        while True:
+            if wd is not None:
+                wd.report("feed")
+            group = next(groups_it, None)
+            if multiproc:
+                # ragged-tail barrier: a process out of groups must keep
+                # stepping with empty batches while any peer still has
+                # data, or the peers hang in the next all_to_all
+                left = plan_gather(
+                    np.asarray([0 if group is None else 1], np.int64)
+                )
+                if int(left.sum()) == 0:
+                    return
+                if group is None:
+                    if template is None:
+                        raise RuntimeError(
+                            "this process received no batches at all: "
+                            "give every process at least one file"
+                        )
+                    group = [empty_like(template)] * t.n_local
+                else:
+                    template = group[0]
+            elif group is None:
+                return
+            if n_slots is None:
+                n_slots = group[0].n_sparse_slots
+            pass_loop.validate_batch(group[0], uses_rank, uses_seq, t.n_tasks)
+            with sprof.stage("plan"):
+                plan = table.plan_group(
+                    group, gather=plan_gather,
+                    slot_lr_vec=t._slot_lr_vec, n_slots=n_slots,
+                )
+            with sprof.stage("feed"):
+                feed = _stack_group(
+                    group, plan, n_slots, t.metric_group,
+                    vocab_keys=vocab_keys,
+                )
+            yield (
+                global_from_local(t._sharding, feed),
+                group if dumping else None,
+            )
+
+    def dispatch(self, feed) -> tuple:
+        t = self.trainer
+        hot = (self.hot_values, self.hot_g2sum) if self.hot_cap else ()
+        out = t._step_fn(
+            t.params, t.opt_state, self.values, self.g2sum, self.mstate,
+            feed[0], *hot,
+        )
+        # what follows the fixed outputs: the async gradient, then the
+        # field dump's predictions, each only in its mode
+        if self.hot_cap:
+            (t.params, t.opt_state, self.values, self.g2sum, self.hot_values,
+             self.hot_g2sum, self.mstate, loss, self.cnt, finite,
+             *self.extra) = out
+        else:
+            (t.params, t.opt_state, self.values, self.g2sum, self.mstate,
+             loss, self.cnt, finite, *self.extra) = out
+        return loss, finite
+
+    def after_step(self, feed, finite) -> bool:
+        t = self.trainer
+        if self.dumper is not None:
+            with self.prof.stage("dump"):
+                # [L, B] local predictions; pad batches dump nothing
+                preds = local_view(self.extra[-1])
+                for d, b in enumerate(feed[1]):
+                    self.dumper.dump_batch(b, np.asarray(preds[d]))
+        if self.async_dense:
+            # push one step BEHIND: step t's grad is already computed
+            # when step t+1 dispatches, so reading it never stalls
+            # the device pipeline
+            self.pending_grads.append(self.extra[0])
+            if len(self.pending_grads) > 1:
+                t._push_async_grad(self.pending_grads.pop(0))
+            if (t.global_step + 1) % self.sync_every == 0:
+                t.params = t._stack_local(t.async_dense.pull())
+        if t.conf.check_nan_inf and not bool(local_view(finite).all()):
+            raise FloatingPointError(
+                f"non-finite loss/grad at step {t.global_step} "
+                "(FLAGS_check_nan_inf analog)"
+            )
+        self.counts.append(self.cnt)
+        if (
+            t.conf.sync_dense_mode == "kstep"
+            and (t.global_step + 1) % self.sync_every == 0
+        ):
+            t.params, t.opt_state = t._sync_fn(t.params, t.opt_state)
+        return True
+
+    def finish_loop(self) -> None:
+        if self.async_dense:
+            # pass boundary: flush the lagged grad, wait for the master
+            # copy to absorb everything, refresh device params
+            t = self.trainer
+            for g in self.pending_grads:
+                t._push_async_grad(g)
+            self.pending_grads.clear()
+            t.async_dense.drain()
+            t.params = t._stack_local(t.async_dense.pull())
+
+    def hand_back(self) -> None:
+        table = self.table
+        table.values, table.g2sum = self.values, self.g2sum
+        if self.hot_cap and self.hot_values is not None:
+            table.hot_values, table.hot_g2sum = (
+                self.hot_values, self.hot_g2sum)
+
+    def read_back(self, losses: list, gn_base) -> dict:
+        return self.trainer._read_back(
+            self.mstate, losses, self.counts, gn_base, self.multiproc)
+
+    def observe(self, metrics: dict, tele) -> None:
+        table = self.table
+        metrics["missing_keys"] = table.missing_key_count
+        metrics["overflow_keys"] = table.overflow_key_count  # always 0 now
+        metrics["capacity_bumps"] = table.capacity_bumps
+        # pass-boundary fleet view: allgather every rank's metric snapshot
+        # over the coordination-service KV and log ONE merged view on rank
+        # 0 (per-rank stage p99s, counters) — the PrintSyncTimer analog.
+        # Telemetry must never kill a healthy pass: failures log and move
+        # on.  Every rank participates (lockstep, like the collectives).
+        if self.multiproc and tele.fleet_snapshot:
+            _FLEET_SNAP_SEQ[0] += 1
+            try:
+                from paddlebox_tpu.parallel.watchdog import CoordKv
+
+                merged = telemetry.gather_fleet_snapshot(
+                    CoordKv(), rank=jax.process_index(),
+                    world=jax.process_count(), seq=_FLEET_SNAP_SEQ[0],
+                    namespace="pass", timeout_s=60.0,
+                )
+                if jax.process_index() == 0:
+                    # print, not logger: the per-pass fleet line is the
+                    # PrintSyncTimer/log_for_profile analog and must land
+                    # in the rank-0 log without logging configuration
+                    print(telemetry.format_fleet_view(
+                        merged,
+                        prefix=f"fleet pass step={self.trainer.global_step}",
+                    ), flush=True)
+            except Exception:
+                import logging
+
+                logging.getLogger(__name__).warning(
+                    "fleet snapshot gather failed", exc_info=True
+                )
+
+    def index(self) -> tuple:
+        return "global_step", self.trainer.global_step
+
+    def close(self) -> None:
+        if self.plan_channel is not None:
+            # every peer has joined the metric collectives above, which it
+            # can only do after its producer read ALL of this channel's
+            # keys — deleting the final two sequences is now race-free.
+            # (Skipped on the exception path: peers may still be blocked on
+            # a get; two leaked keys on a dying pass is the lesser evil.)
+            self.plan_channel.close()
+
+
 class MultiChipTrainer:
     """Drives model + ShardedSparseTable over a mesh (BoxPSTrainer analog:
     one worker per device — here, one shard_map body per device)."""
@@ -391,6 +621,7 @@ class MultiChipTrainer:
         self._copy_fn = None
         self.async_dense = None  # lazily created in "async" mode
         self.global_step = 0
+        self._pass_idx = 0
         self.last_metric_state = None  # dict after a pass (Trainer parity)
         self._watch = CompletionWatcher()  # thread starts at first dispatch
 
@@ -499,8 +730,15 @@ class MultiChipTrainer:
             primary = preds[:, 0] if n_tasks > 1 else preds
             mstate = add_counts(dict(mstate), counts)
             with jax.named_scope("metrics"):
-                mstate, finite = step_metrics(
-                    mstate, batch, loss, preds, primary, pgrads, row_grads)
+                mstate, finite = pass_loop.step_metrics(
+                    mstate, batch, loss, preds, primary, pgrads, row_grads,
+                    n_tasks=n_tasks, has_group=has_group, check_nan=check_nan)
+                if check_nan:
+                    # globalize: every device (hence every process) sees
+                    # the same verdict, so a multi-host raise can't strand
+                    # the other ranks mid-collective
+                    bad = jax.lax.psum((~finite).astype(jnp.int32), DATA_AXIS)
+                    finite = bad == 0
             restack = lambda t: jax.tree.map(lambda x: x[None], t)
             cnt = batch["ins_mask"].sum()
             hot_out = (
@@ -520,50 +758,6 @@ class MultiChipTrainer:
                 # production multi-GPU workers, device_worker.cc)
                 out = out + (primary[None],)
             return out
-
-        def step_metrics(mstate, batch, loss, preds, primary, pgrads,
-                         row_grads):
-            mstate["auc"] = update_auc_state(
-                mstate["auc"], primary, batch["labels"], batch["ins_mask"]
-            )
-            # grad-norm health stream in the donated metric state (no
-            # step-signature change): [sum of squared grad norms,
-            # steps] per device; pass end sums the device axis.  With
-            # sync_step the psummed pgrads are identical per device —
-            # the device-axis mean (sum/steps) stays the step value.
-            # "gn" is always present: _init_mstate seeds it and the
-            # restore path backfills it.
-            gsq = jnp.zeros((), jnp.float32)
-            for leaf in jax.tree.leaves(pgrads):
-                gsq += jnp.sum(jnp.square(leaf.astype(jnp.float32)))
-            gsq += jnp.sum(jnp.square(row_grads.astype(jnp.float32)))
-            mstate["gn"] = mstate["gn"] + jnp.stack(
-                [gsq, jnp.ones((), jnp.float32)]
-            )
-            if n_tasks > 1:
-                mstate["task"] = jax.vmap(
-                    lambda s, pr, lb: update_auc_state(
-                        s, pr, lb, batch["ins_mask"]
-                    )
-                )(mstate["task"], preds.T, batch["task_labels"].T)
-            if has_group:
-                mstate["group"] = MetricGroup.update(
-                    mstate["group"], primary, batch["labels"],
-                    batch["metric_masks"],
-                )
-            if check_nan:
-                finite = jnp.isfinite(loss)
-                for leaf in jax.tree.leaves(pgrads):
-                    finite &= jnp.isfinite(leaf).all()
-                finite &= jnp.isfinite(row_grads).all()
-                # globalize: every device (hence every process) sees the same
-                # verdict, so a multi-host raise can't strand the other ranks
-                # mid-collective
-                bad = jax.lax.psum((~finite).astype(jnp.int32), DATA_AXIS)
-                finite = bad == 0
-            else:
-                finite = jnp.array(True)
-            return mstate, finite
 
         spec = P(DATA_AXIS)
         n_state = 8 if hot_cap else 6
@@ -679,42 +873,8 @@ class MultiChipTrainer:
     def _init_mstate(self, auc_state=None) -> dict:
         """Per-device metric streams, each leaf stacked [n_dev, ...] and
         mesh-sharded (merged by summing over devices at read time)."""
-        n_counters = len(counter_names(self.model))
-        if isinstance(auc_state, dict):
-            # the step donates mstate: copy so the caller's reference (often
-            # trainer.last_metric_state itself) is not invalidated by the
-            # first step's buffer donation
-            out = self._copy_state(auc_state)
-            if "gn" not in out:
-                out["gn"] = self._stack_local(jnp.zeros((2,), jnp.float32))
-            if n_counters and "counters" not in out:
-                out["counters"] = self._stack_local(
-                    jnp.zeros((n_counters,), jnp.float32))
-            return out
-        if auc_state is not None and (self.n_tasks > 1 or self.metric_group):
-            raise ValueError(
-                "pass trainer.last_metric_state (dict) to continue metrics "
-                "across passes — a bare AucState would reset the task/group "
-                "streams while continuing the primary one"
-            )
-        mstate = {
-            "auc": self._copy_state(auc_state)
-            if auc_state is not None
-            else self.init_auc(),
-            "gn": self._stack_local(jnp.zeros((2,), jnp.float32)),
-        }
-        if n_counters:
-            # the model's per-step sums (step_loss.counter_names)
-            mstate["counters"] = self._stack_local(
-                jnp.zeros((n_counters,), jnp.float32))
-        if self.n_tasks > 1:
-            base = stack_auc_states(
-                init_auc_state(self.conf.auc_buckets), self.n_tasks
-            )
-            mstate["task"] = self._stack_local(base)
-        if self.metric_group is not None:
-            mstate["group"] = self._stack_local(self.metric_group.init_state())
-        return mstate
+        return pass_loop.init_metric_state(
+            self, auc_state, place=self._stack_local, copy=self._copy_state)
 
     def train_from_dataset(
         self,
@@ -743,418 +903,29 @@ class MultiChipTrainer:
         auc_state: Optional[AucState] = None,
         next_pass_keys=None,
     ) -> dict:
-        """next_pass_keys: next pass's census (array or zero-arg callable),
+        """One pass over ``groups``: pass_loop.run_pass around this
+        trainer's producer and step.
+
+        next_pass_keys: next pass's census (array or zero-arg callable),
         staged via table.prepare_pass once this pass's groups are exhausted
         — the sharded half of pass-boundary pipelining (single-process
-        only; multi-host prepare_pass no-ops, see sharded_table.py)."""
-        sprof = StatsProfiler()
-        # the pass's head: the step for this hot capacity, the telemetry's
-        # own set-up, the metric state and its baselines (eager programs
-        # and a read-back), the watchdog, the plan channel -- the devices
-        # idle under it, so it has a name
-        with sprof.stage("open"):
-            hot_cap = int(getattr(table, "hot_block_capacity", 0))
-            if self._step_fn is None or self._step_hot_cap != hot_cap:
-                self._step_fn = self._build_step(hot_cap)
-                self._step_hot_cap = hot_cap
-            if self._sync_fn is None and self.conf.sync_dense_mode == "kstep":
-                self._sync_fn = self._build_sync()
-            from paddlebox_tpu.parallel.multiprocess import is_multiprocess
+        only; multi-host prepare_pass no-ops, see sharded_table.py).
 
-            multiproc = is_multiprocess()
-            async_dense = self.conf.sync_dense_mode == "async"
-            if async_dense and self.async_dense is None:
-                from paddlebox_tpu.parallel.async_dense import AsyncDenseTable
-
-                # every process hosts an identical table fed identical
-                # replicated grads, so multi-host needs no extra dense comm
-                # (the reference runs one table per node the same way)
-                p0 = jax.tree.map(lambda x: local_view(x)[0], self.params)
-                self.async_dense = AsyncDenseTable(
-                    p0, optimizer=self.conf.dense_optimizer,
-                    lr=self.conf.dense_lr,
-                )
-            # telemetry: exporter/event log are process singletons (first pass
-            # starts them); host stage timing always feeds the per-stage
-            # latency histograms (plan/feed run on the producer thread;
-            # ``step`` is the enqueue, the device's side is the completion
-            # watcher's)
-            from paddlebox_tpu import telemetry
-            from paddlebox_tpu.config import TelemetryConfig
-
-            tele = self.conf.telemetry or TelemetryConfig.from_flags()
-            telemetry.ensure_exporter(tele.metrics_port or None)
-            event_log = telemetry.ensure_event_log(tele.events_path or None)
-
-            watch = self._watch
-            pending_grads: list = []  # device grads fetched one step behind
-            pull_every = max(self.conf.sync_weight_step, 1)
-            from paddlebox_tpu.parallel.multiprocess import merge_device_axis
-
-            with stage_scope("train.init"):
-                mstate = self._init_mstate(auc_state)
-                # grad-norm baseline: the accumulator carries across continued
-                # passes — snapshot NOW (a lockstep device-axis merge on
-                # every rank), the first step donates the buffer
-                gn_base = np.asarray(
-                    merge_device_axis(mstate["gn"]), dtype=np.float64
-                )
-                counters_base = np.asarray(
-                    merge_device_axis(mstate["counters"]), dtype=np.float64
-                ) if "counters" in mstate else None
-            vocab_keys = getattr(self.model, "vocab_keys", None)
-            pass_t0 = time.monotonic()
-            values, g2sum = table.values, table.g2sum
-            hot_values = hot_g2sum = None
-            if hot_cap:
-                with stage_scope("train.init"):
-                    hot_values, hot_g2sum = self._hot_state(table, hot_cap)
-            losses, counts, n_steps = [], [], 0
-            uses_rank = getattr(self.model, "uses_rank_offset", False)
-            uses_seq = getattr(self.model, "uses_seq_pos", False)
-
-            # distributed-liveness watchdog: heartbeats through the same KV
-            # store the planning plane rides, local + peer stall detection,
-            # poison-key coordinated abort.  Namespaced per pass (global_step
-            # advances in lockstep across processes) so heartbeat keys from a
-            # previous aborted pass can never poison a fresh one.
-            from paddlebox_tpu.parallel import watchdog as _wd_mod
-
-            wd = None
-            if self.conf.liveness is not None:
-                wd = _wd_mod.for_trainer(
-                    self.conf.liveness, namespace=f"train-{self.global_step}"
-                )
-                if wd is not None:
-                    wd.start()
-
-            # the producer's collectives must be HOST-side: it runs concurrent
-            # with the consumer's device step, and two threads racing device
-            # collectives onto the queues in different orders across processes
-            # is a cross-process deadlock.  Each pass gets its own KV channel
-            # (deterministic name: every process increments in lockstep).
-            plan_channel = None
-            if multiproc:
-                from paddlebox_tpu.parallel.host_plane import KvChannel
-
-                _PLAN_CHANNEL_SEQ[0] += 1
-                plan_channel = KvChannel(
-                    f"plan-{_PLAN_CHANNEL_SEQ[0]}",
-                    timeout_s=(
-                        self.conf.liveness.hostplane_timeout_s
-                        if self.conf.liveness is not None
-                        else self.conf.host_plane_timeout_s
-                    ),
-                )
-                plan_gather = plan_channel.allgather
-            else:
-                plan_gather = host_allgather  # no-op [1, ...] wrap
-            dumper = None
-            if self.conf.need_dump_field and self.conf.dump_fields_path:
-                from paddlebox_tpu.train.dump import FieldDumper
-
-                # per-process file (the reference's per-node dump discipline):
-                # each process dumps exactly its local devices' instances
-                suffix = (
-                    f"-r{jax.process_index()}" if multiproc else ""
-                )
-                dumper = FieldDumper(
-                    os.path.join(
-                        self.conf.dump_fields_path,
-                        f"dump-{self.global_step}{suffix}.txt",
-                    ),
-                    self.conf.dump_fields,
-                )
-
-        def produce_feeds():
-            """Barrier + host planning + stack + H2D for every group.
-
-            Runs on the prefetch thread so the per-batch want-matrix
-            allgather and feed assembly overlap the device step (the
-            single-chip _FeedPrefetcher discipline).
-            All its cross-process exchanges ride the host-plane KV channel
-            above — it never touches the device queues, so it cannot
-            deadlock against the consumer's step collectives."""
-            groups_it = sprof.iterate("batch", groups)
-            template = None  # last real batch: shapes for tail-padding
-            n_slots = None
-            while True:
-                if wd is not None:
-                    wd.report("feed")
-                group = next(groups_it, None)
-                if multiproc:
-                    # ragged-tail barrier: a process out of groups must keep
-                    # stepping with empty batches while any peer still has
-                    # data, or the peers hang in the next all_to_all
-                    left = plan_gather(
-                        np.asarray([0 if group is None else 1], np.int64)
-                    )
-                    if int(left.sum()) == 0:
-                        return
-                    if group is None:
-                        if template is None:
-                            raise RuntimeError(
-                                "this process received no batches at all: "
-                                "give every process at least one file"
-                            )
-                        group = [empty_like(template)] * self.n_local
-                    else:
-                        template = group[0]
-                elif group is None:
-                    return
-                if n_slots is None:
-                    n_slots = group[0].n_sparse_slots
-                if uses_seq and group[0].seq_pos is None:
-                    raise RuntimeError(
-                        "model consumes an ordered behavior sequence: set "
-                        "DataFeedConfig.sequence_slot (and max_seq_len) so "
-                        "batches carry seq_pos"
-                    )
-                if uses_rank and group[0].rank_offset is None:
-                    raise RuntimeError(
-                        "model requires PV-merged batches with rank_offset: "
-                        "set enable_pv_merge and call dataset.preprocess_instance()"
-                    )
-                if self.n_tasks > 1 and (
-                    group[0].task_labels is None
-                    or group[0].task_labels.shape[1] != self.n_tasks
-                ):
-                    got = (
-                        0 if group[0].task_labels is None
-                        else group[0].task_labels.shape[1]
-                    )
-                    raise RuntimeError(
-                        f"model has {self.n_tasks} tasks but the batch carries "
-                        f"{got} task label columns: configure "
-                        "DataFeedConfig.task_label_slots with "
-                        f"{self.n_tasks - 1} slots (task 0 is the primary label)"
-                    )
-                with sprof.stage("plan"):
-                    plan = table.plan_group(
-                        group, gather=plan_gather,
-                        slot_lr_vec=self._slot_lr_vec, n_slots=n_slots,
-                    )
-                with sprof.stage("feed"):
-                    feed = _stack_group(
-                        group, plan, n_slots, self.metric_group,
-                        vocab_keys=vocab_keys,
-                    )
-                yield (
-                    global_from_local(self._sharding, feed),
-                    group if dumper is not None else None,
-                )
-
-        feed_iter = produce_feeds()
-        prefetcher = None
-        try:
-          with telemetry.span("pass", global_step=self.global_step):
-              if self.conf.prefetch_batches > 0:
-                  from paddlebox_tpu.train.trainer import _FeedPrefetcher
-
-                  # started inside the pass span: the producer's plan/feed
-                  # spans inherit it as their parent
-                  prefetcher = _FeedPrefetcher(
-                      feed_iter, self.conf.prefetch_batches, sprof
-                  )
-                  feed_iter = prefetcher
-              for feed, dump_group in feed_iter:
-                  # chaos site: a hang here simulates a stalled device step
-                  # on this process; the watchdog bounds it fleet-wide
-                  faults.inject("train.step")
-                  t_dispatch = time.perf_counter()
-                  with sprof.stage("step"):
-                      hot = (hot_values, hot_g2sum) if hot_cap else ()
-                      out = self._step_fn(
-                          self.params, self.opt_state, values, g2sum, mstate,
-                          feed, *hot,
-                      )
-                  if hot_cap:
-                      (self.params, self.opt_state, values, g2sum, hot_values,
-                       hot_g2sum, mstate, loss, cnt, finite) = out[:10]
-                      n_fixed = 10
-                  else:
-                      (self.params, self.opt_state, values, g2sum, mstate, loss,
-                       cnt, finite) = out[:8]
-                      n_fixed = 8
-                  watch.dispatched(loss, t_dispatch)
-                  if wd is not None:
-                      wd.report("step")
-                  if dumper is not None:
-                      # [L, B] local predictions; pad batches dump nothing
-                      preds = local_view(out[-1])
-                      for d, b in enumerate(dump_group):
-                          dumper.dump_batch(b, np.asarray(preds[d]))
-                  if async_dense:
-                      # push one step BEHIND: step t's grad is already computed
-                      # when step t+1 dispatches, so reading it never stalls
-                      # the device pipeline
-                      pending_grads.append(out[n_fixed])
-                      if len(pending_grads) > 1:
-                          self._push_async_grad(pending_grads.pop(0))
-                      if (self.global_step + 1) % pull_every == 0:
-                          self.params = self._stack_local(self.async_dense.pull())
-                  if self.conf.check_nan_inf and not bool(
-                      local_view(finite).all()
-                  ):
-                      raise FloatingPointError(
-                          f"non-finite loss/grad at step {self.global_step} "
-                          "(FLAGS_check_nan_inf analog)"
-                      )
-                  losses.append(loss)
-                  counts.append(cnt)
-                  n_steps += 1
-                  self.global_step += 1
-                  if (
-                      self.conf.sync_dense_mode == "kstep"
-                      and self.global_step % max(self.conf.sync_weight_step, 1) == 0
-                  ):
-                      self.params, self.opt_state = self._sync_fn(
-                          self.params, self.opt_state
-                      )
-              if async_dense:
-                  # pass boundary: flush the lagged grad, wait for the master
-                  # copy to absorb everything, refresh device params
-                  for g in pending_grads:
-                      self._push_async_grad(g)
-                  pending_grads.clear()
-                  self.async_dense.drain()
-                  self.params = self._stack_local(self.async_dense.pull())
-        except _wd_mod.DistributedStallError:
-            # coordinated abort: every process converges on the same
-            # structured error (poison key); teardown in the finally below
-            # leaves no dangling producer thread.  Recovery is the
-            # driver's: restart the job and resume from the newest valid
-            # checkpoint (AutoCheckpointer.resume / find_valid_tag) — the
-            # aborted pass never reached after_pass, so nothing partial
-            # survives the replay.
-            from paddlebox_tpu.utils.monitor import stats
-
-            stats.add("train.stall_aborts")
-            raise
-        finally:
-            # the old table buffers were donated to the jitted step: always
-            # hand the live ones back so end_pass() can salvage the pass even
-            # when check_nan_inf raises mid-loop.  The watchdog retires
-            # FIRST so its abort latch cannot fire into the teardown.
-            if wd is not None:
-                wd.close()
-            table.values, table.g2sum = values, g2sum
-            if hot_cap and hot_values is not None:
-                table.hot_values, table.hot_g2sum = hot_values, hot_g2sum
-            if prefetcher is not None:
-                prefetcher.close()
-            if dumper is not None:
-                dumper.close()
-        # pre-promotion: groups are exhausted but the device still drains
-        # queued steps (the metric merge below blocks on them) — stage the
-        # next pass's working set in that window (single-chip Trainer
-        # discipline; sharded prepare_pass no-ops multi-host)
-        if next_pass_keys is not None:
-            prepare = getattr(table, "prepare_pass", None)
-            if prepare is not None:
-                prepare(next_pass_keys)
-        # the devices' tail: the metric merge below waits for the last
-        # queued step anyway; waiting here first gives the wait its own
-        # name and leaves ``readback`` the merges and eager programs alone
-        with sprof.stage("drain"):
-            if losses:
-                losses[-1].block_until_ready()
-            watch.settle()
-            # the devices have nothing queued: did the host let the pass's
-            # threads run (the feed producer answered before it exited)
-            HOST.after_drain(watch)
-        with stage_scope("train.readback"), sprof.stage("readback"):
-            metrics = self._read_back(mstate, losses, counts, gn_base,
-                                      multiproc)
-            if counters_base is not None:
-                metrics.update(publish_counters(
-                    self.model,
-                    np.asarray(merge_device_axis(mstate["counters"]),
-                               dtype=np.float64),
-                    counters_base))
-        # the pass's tail is the telemetry's own -- the fleet view, the
-        # registry's delta over every series, the health rules, the
-        # pass_end record -- with the devices idle: it has a name too
-        with sprof.stage("observe"):
-            metrics["steps"] = n_steps
-            metrics["duration_s"] = time.monotonic() - pass_t0
-            pass_seconds().observe(metrics["duration_s"])
-            metrics["missing_keys"] = table.missing_key_count
-            metrics["overflow_keys"] = table.overflow_key_count  # always 0 now
-            metrics["capacity_bumps"] = table.capacity_bumps
-            self.last_auc_state = mstate["auc"]
-            self.last_metric_state = mstate
-            # pass-boundary fleet view: allgather every rank's metric snapshot
-            # over the coordination-service KV and log ONE merged view on rank
-            # 0 (per-rank stage p99s, counters) — the PrintSyncTimer analog.
-            # Telemetry must never kill a healthy pass: failures log and move
-            # on.  Every rank participates (lockstep, like the collectives).
-            if multiproc and tele.fleet_snapshot:
-                _FLEET_SNAP_SEQ[0] += 1
-                try:
-                    from paddlebox_tpu.parallel.watchdog import CoordKv
-
-                    merged = telemetry.gather_fleet_snapshot(
-                        CoordKv(), rank=jax.process_index(),
-                        world=jax.process_count(), seq=_FLEET_SNAP_SEQ[0],
-                        namespace="pass", timeout_s=60.0,
-                    )
-                    if jax.process_index() == 0:
-                        # print, not logger: the per-pass fleet line is the
-                        # PrintSyncTimer/log_for_profile analog and must land
-                        # in the rank-0 log without logging configuration
-                        print(telemetry.format_fleet_view(
-                            merged,
-                            prefix=f"fleet pass step={self.global_step}",
-                        ), flush=True)
-                except Exception:
-                    import logging
-
-                    logging.getLogger(__name__).warning(
-                        "fleet snapshot gather failed", exc_info=True
-                    )
-            # run-health plane: evaluate the rule catalog on the SAME window
-            # the pass_end record carries, BEFORE the record is written so
-            # the window's health_alert events precede its pass_end record
-            snap = telemetry.registry.delta_snapshot()
-            telemetry.observe_pass(
-                self.global_step, metrics=metrics, telemetry=snap, table=table
-            )
-            if event_log is not None:
-                event_log.log_pass(metrics, telemetry=snap,
-                                   global_step=self.global_step)
-        if plan_channel is not None:
-            # every peer has joined the metric collectives above, which it
-            # can only do after its producer read ALL of this channel's
-            # keys — deleting the final two sequences is now race-free.
-            # (Skipped on the exception path: peers may still be blocked on
-            # a get; two leaked keys on a dying pass is the lesser evil.)
-            plan_channel.close()
-        return metrics
+        A coordinated liveness abort (DistributedStallError) leaves recovery
+        to the driver: restart the job and resume from the newest valid
+        checkpoint (AutoCheckpointer.resume / find_valid_tag) — the aborted
+        pass never reached after_pass, so nothing partial survives the
+        replay."""
+        return pass_loop.run_pass(
+            self, _GroupPass(self, table, groups), table, auc_state,
+            next_pass_keys)
 
     def _read_back(self, mstate: dict, losses: list, counts: list,
                    gn_base, multiproc: bool) -> dict:
         """The pass's metrics from the devices' metric state (eager
         programs and lockstep device-axis merges, tagged ``train.readback``
         by the caller)."""
-        from paddlebox_tpu import telemetry
-        from paddlebox_tpu.parallel.multiprocess import merge_device_axis
-
-        # cross-device merge: sum each stream's histograms over the device
-        # axis (multi-host: jitted replicated sum + local read,
-        # collect_data_nccl analog)
-        merged = merge_device_axis(mstate["auc"])
-        metrics = compute_metrics(merged)
-        if self.n_tasks > 1:
-            task_merged = merge_device_axis(mstate["task"])
-            metrics.update(
-                compute_metrics_stacked(
-                    task_merged, [f"task{t}" for t in range(self.n_tasks)]
-                )
-            )
-        if self.metric_group is not None:
-            group_merged = merge_device_axis(mstate["group"])
-            metrics.update(self.metric_group.compute(group_merged))
+        own = {"loss": 0.0, "samples": 0.0}
         if losses:
             # [T, L] local views; multi-host: gather to [T, D]
             per_step = np.stack([local_view(l) for l in losses])
@@ -1171,34 +942,14 @@ class MultiChipTrainer:
                 # instance counts so padded empty batches don't bias the pass
                 num = (per_step * cnts).sum(axis=1)
                 den = np.maximum(cnts.sum(axis=1), 1.0)
-                metrics["loss"] = float((num / den).mean())
+                own["loss"] = float((num / den).mean())
             else:
                 # psummed loss is replicated across the axis
-                metrics["loss"] = float(per_step[:, 0].mean())
-            metrics["samples"] = float(cnts.sum())
-        else:
-            metrics["loss"] = 0.0
-            metrics["samples"] = 0.0
-        gn_now = np.asarray(merge_device_axis(mstate["gn"]), dtype=np.float64)
-        d_sq, d_n = gn_now[0] - gn_base[0], gn_now[1] - gn_base[1]
-        if d_n > 0:
-            grad_norm = float(np.sqrt(d_sq / d_n)) if d_sq >= 0 else float(
-                "nan")
-            metrics["grad_norm"] = grad_norm
-            telemetry.gauge(
-                "train.grad_norm",
-                "per-pass RMS global gradient norm (dense + sparse)",
-            ).set(grad_norm)
-        wsq = sum(
-            float(jnp.sum(jnp.square(read_replicated(leaf).astype(
-                jnp.float32))))
-            for leaf in jax.tree.leaves(self.params)
-        )
-        metrics["weight_norm"] = math.sqrt(wsq) if wsq >= 0 else float("nan")
-        telemetry.gauge(
-            "train.weight_norm", "dense parameter L2 norm at pass end"
-        ).set(metrics["weight_norm"])
-        return metrics
+                own["loss"] = float(per_step[:, 0].mean())
+            own["samples"] = float(cnts.sum())
+        return pass_loop.read_back_common(
+            mstate, gn_base, self.params, self.n_tasks, self.metric_group,
+            own, merge=merge_device_axis, read=read_replicated)
 
     # -- inference / evaluation -------------------------------------------- #
     def _build_eval(self, hot_cap: int = 0):
@@ -1251,11 +1002,6 @@ class MultiChipTrainer:
             self._eval_fn = self._build_eval(hot_cap)
             self._eval_hot_cap = hot_cap
         hot_values = self._hot_state(table, hot_cap)[0] if hot_cap else None
-        from paddlebox_tpu.parallel.multiprocess import (
-            is_multiprocess,
-            merge_device_axis,
-        )
-
         multiproc = is_multiprocess()
         uses_rank = getattr(self.model, "uses_rank_offset", False)
         uses_seq = getattr(self.model, "uses_seq_pos", False)
@@ -1284,17 +1030,7 @@ class MultiChipTrainer:
                 break
             if n_slots is None:
                 n_slots = group[0].n_sparse_slots
-            if uses_seq and group[0].seq_pos is None:
-                raise RuntimeError(
-                    "model consumes an ordered behavior sequence: set "
-                    "DataFeedConfig.sequence_slot (and max_seq_len) so "
-                    "batches carry seq_pos"
-                )
-            if uses_rank and group[0].rank_offset is None:
-                raise RuntimeError(
-                    "model requires PV-merged batches with rank_offset: "
-                    "set enable_pv_merge and call dataset.preprocess_instance()"
-                )
+            pass_loop.validate_batch(group[0], uses_rank, uses_seq, 1)
             plan = table.plan_group(group)
             feed = _stack_group(group, plan, n_slots)
             feed = global_from_local(self._sharding, feed)

@@ -25,6 +25,7 @@ import optax
 from paddlebox_tpu.metrics.auc import update_auc_state
 from paddlebox_tpu.sparse.table import pull_rows, push_and_update
 from paddlebox_tpu.telemetry.compiles import counted_jit
+from paddlebox_tpu.train import pass_loop
 from paddlebox_tpu.train.trainer import Trainer
 from paddlebox_tpu.train.slot_policy import slot_participation_vec
 
@@ -112,25 +113,9 @@ class RetrievalTrainer(Trainer):
                 key_extras=key_extras,
                 uniq_lr=batch.get("uniq_lr"),
             )
-            mstate = dict(mstate)
-            mstate["auc"] = update_auc_state(
-                mstate["auc"], preds, batch["labels"], batch["ins_mask"]
-            )
-            if "gn" in mstate:
-                gsq = jnp.zeros((), jnp.float32)
-                for leaf in jax.tree.leaves(pgrads):
-                    gsq += jnp.sum(jnp.square(leaf.astype(jnp.float32)))
-                gsq += jnp.sum(jnp.square(row_grads.astype(jnp.float32)))
-                mstate["gn"] = mstate["gn"] + jnp.stack(
-                    [gsq, jnp.ones((), jnp.float32)]
-                )
-            if check_nan:
-                finite = jnp.isfinite(loss)
-                for leaf in jax.tree.leaves(pgrads):
-                    finite &= jnp.isfinite(leaf).all()
-                finite &= jnp.isfinite(row_grads).all()
-            else:
-                finite = jnp.array(True)
+            mstate, finite = pass_loop.step_metrics(
+                dict(mstate), batch, loss, preds, preds, pgrads, row_grads,
+                n_tasks=1, has_group=False, check_nan=check_nan)
             return params, opt_state, values, g2sum, mstate, loss, finite, preds
 
         self._step_body = step

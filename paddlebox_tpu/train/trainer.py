@@ -1,4 +1,6 @@
-"""Single-chip training loop.
+"""Single-chip trainer: the step program and what it says about a pass (the
+pass loop itself is train/pass_loop.py run_pass, shared with the sharded
+trainer).
 
 TPU-native redesign of ``BoxPSWorker::TrainFiles`` (reference:
 framework/boxps_worker.cc:542-598) + ``Executor.train_from_dataset``
@@ -15,9 +17,7 @@ from __future__ import annotations
 
 import dataclasses
 import logging
-import math
 import os
-import time
 from typing import Any, Iterable, Optional
 
 import jax
@@ -30,40 +30,19 @@ from paddlebox_tpu.data.feed import HostBatch, key_classes
 from paddlebox_tpu.metrics.auc import (
     AucState,
     compute_metrics,
-    compute_metrics_stacked,
     init_auc_state,
-    stack_auc_states,
     update_auc_state,
 )
 from paddlebox_tpu.metrics.variants import MetricGroup
 from paddlebox_tpu.sparse.table import SparseTable, pull_rows, push_and_update
 from paddlebox_tpu.telemetry.compiles import counted_jit, stage_scope
-from paddlebox_tpu.utils.profiler import (
-    HOST,
-    START,
-    CompletionWatcher,
-    StatsProfiler,
-    device_trace,
-    pass_seconds,
-)
+from paddlebox_tpu.train import pass_loop
+# the feed prefetcher lives with the pass protocol; re-exported here for
+# its historical import path
+from paddlebox_tpu.train.pass_loop import _FeedPrefetcher  # noqa: F401
+from paddlebox_tpu.utils.profiler import START, CompletionWatcher
 from paddlebox_tpu.utils import faults
 from paddlebox_tpu.utils.monitor import stats
-
-
-def _watchdog_mod():
-    """The liveness watchdog module (parallel/watchdog.py), or None on a
-    build where the parallel package cannot import — the single-chip
-    trainer must keep working there, just without liveness guarding."""
-    try:
-        from paddlebox_tpu.parallel import watchdog
-
-        return watchdog
-    # pbox-lint: ignore[swallowed-exception] gated-import fallback: a build
-    # without the parallel package is the handled case
-    except Exception:
-        import sys
-
-        return sys.modules.get("paddlebox_tpu.parallel.watchdog")
 
 
 class NonFiniteBatchError(FloatingPointError):
@@ -94,12 +73,7 @@ from paddlebox_tpu.train.slot_policy import (  # noqa: E402,F401
     resolve_slot_lr_vec,
     slot_participation_vec,
 )
-from paddlebox_tpu.train.step_loss import (  # noqa: E402
-    add_counts,
-    counter_names,
-    make_model_loss,
-    publish_counters,
-)
+from paddlebox_tpu.train.step_loss import add_counts, make_model_loss  # noqa: E402
 
 
 @dataclasses.dataclass
@@ -209,120 +183,6 @@ def _device_batch(
     return _to_device(_host_batch_dict(batch, plan, n_slots, counter_label_tasks))
 
 
-# how long close() waits for the producer thread before declaring it stuck
-# (module-level so chaos tests can shrink it)
-_PREFETCH_JOIN_S = 5.0
-
-
-class _FeedPrefetcher:
-    """Bounded background feed assembly: the producer thread runs host key
-    planning + H2D staging up to ``depth`` batches ahead of the consumer
-    (the pinned-arena double buffer of SURVEY.md §2.3, as a thread + queue;
-    JAX's device_put already stages through pinned runtime buffers, so the
-    missing piece was only the OVERLAP, provided here).  Exceptions raised
-    by the producer re-raise at the consumer's next() call.
-
-    Both sides of the queue are timed (``prof``, the trainer's
-    StatsProfiler): ``feed_wait`` is the consumer blocked on an empty
-    queue — the device's next feed was not ready — and ``feed_put_wait``
-    the producer blocked on a full one, the host's slack."""
-
-    _SENTINEL = object()
-
-    def __init__(self, gen, depth: int, prof=None):
-        import queue
-        import threading
-
-        from paddlebox_tpu.telemetry import trace
-
-        self._q: "queue.Queue" = queue.Queue(maxsize=max(depth, 1))
-        self._stop = False
-        self._done = False
-        self._prof = prof or StatsProfiler()
-        # the producer's plan/feed spans name the consumer's open span
-        # (the pass) as the span that caused them
-        self._parent_span = trace.current_span()
-        self._thread = threading.Thread(
-            target=self._run, args=(gen,), name="feed-prefetch", daemon=True
-        )
-        self._thread.start()
-
-    def _run(self, gen) -> None:
-        from paddlebox_tpu.telemetry import trace
-        from paddlebox_tpu.utils.queues import bounded_put
-
-        trace.adopt_span(self._parent_span)
-
-        def put(item) -> bool:
-            # re-checks _stop: close() drains the queue, so a blocking put
-            # would otherwise race it and the producer could keep planning
-            # batches (and touching the table) after the caller ended the pass
-            with self._prof.stage("feed_put_wait"):
-                return bounded_put(self._q, item, lambda: self._stop)
-
-        try:
-            for item in gen:
-                if self._stop or not put(item):
-                    return
-            # this thread lives one pass: its run-queue wait is told
-            # before the sentinel lets the consumer go on
-            HOST.thread("feed")
-            put(self._SENTINEL)
-        except BaseException as e:  # surfaced to the consumer
-            put(e)
-
-    def __iter__(self):
-        return self
-
-    def __next__(self):
-        import queue
-
-        if self._done:  # keep raising after exhaustion/producer death —
-            raise StopIteration  # the producer will never put again
-        wd_mod = _watchdog_mod()
-        with self._prof.stage("feed_wait"):
-            while True:
-                # bounded get: a coordinated liveness abort must interrupt
-                # a consumer blocked on a stalled producer within one poll
-                # slice
-                if wd_mod is not None:
-                    wd_mod.check()
-                try:
-                    item = self._q.get(timeout=0.2)
-                    break
-                except queue.Empty:
-                    continue
-        if item is self._SENTINEL:
-            self._done = True
-            raise StopIteration
-        if isinstance(item, BaseException):
-            self._done = True
-            raise item
-        return item
-
-    def close(self) -> None:
-        """Unblock and retire the producer (call on early exit)."""
-        import queue
-
-        self._stop = True
-        try:
-            while True:
-                self._q.get_nowait()
-        except queue.Empty:
-            pass
-        self._thread.join(timeout=_PREFETCH_JOIN_S)
-        if self._thread.is_alive():
-            # the producer is stuck in planning/H2D staging; it will exit at
-            # its next _stop check, but make the leak visible instead of
-            # silent (advisor r3) — and countable, so chaos tests can assert
-            # a stuck producer was detected rather than scraping logs
-            stats.add("trainer.prefetch_close_timeout")
-            logging.getLogger(__name__).warning(
-                "feed-prefetch producer did not exit within 5s of close(); "
-                "daemon thread will retire at its next stop check"
-            )
-
-
 def _step_schedule() -> dict:
     """How the TPU's compiler orders the step: by its list scheduler, the
     one of its three memory schedulers (list, depth-first, post-order) that
@@ -338,6 +198,115 @@ def _step_schedule() -> dict:
     if jax.default_backend() != "tpu":
         return {}
     return {"compiler_options": {"xla_memory_scheduler": "list"}}
+
+
+class _Pass(pass_loop.PassHooks):
+    """What the single-chip trainer says about one pass: its producer, its
+    step on ``values, g2sum, mstate``, its nan policies, its field and
+    parameter dumps (the protocol is pass_loop.run_pass)."""
+
+    def __init__(self, trainer: "Trainer", dataset, table, drop_last: bool):
+        self.trainer, self.dataset, self.table = trainer, dataset, table
+        self.drop_last = drop_last
+        self.n_samples = 0.0
+
+    def open(self) -> None:
+        t = self.trainer
+        if t._step_fn is None:
+            t._step_fn = t._build_step()
+        self.values, self.g2sum = self.table.values, self.table.g2sum
+        self.check_nan = t._check_nan
+        self.skip_batches = (
+            self.check_nan and t.conf.nan_policy == "skip_batch")
+
+    def feeds(self):
+        """(batch, device feed) stream: validation, host planning and
+        the transfer."""
+        t, table, prof, wd = self.trainer, self.table, self.prof, self.wd
+        vocab_keys = getattr(t.model, "vocab_keys", None)
+        uses_rank = getattr(t.model, "uses_rank_offset", False)
+        uses_seq = getattr(t.model, "uses_seq_pos", False)
+        for batch in prof.iterate(
+                "batch", self.dataset.batches(drop_last=self.drop_last)):
+            if wd is not None:
+                wd.report("feed")
+            pass_loop.validate_batch(batch, uses_rank, uses_seq, t.n_tasks)
+            with prof.stage("plan"):
+                plan = table.plan_batch(batch)
+            with prof.stage("feed"):
+                host = _host_batch_dict(
+                    batch, plan, batch.n_sparse_slots,
+                    t.conf.counter_label_tasks,
+                    slot_lr_vec=t._slot_lr_vec,
+                    vocab_keys=vocab_keys,
+                )
+                if t.metric_group is not None:
+                    host["metric_masks"] = t.metric_group.masks(batch)
+            if faults.fire("train.nan"):
+                # chaos injection: poison this batch's labels so the
+                # loss/grads genuinely go NaN and the configured
+                # nan_policy is exercised end to end on device
+                host["labels"] = np.full_like(host["labels"], np.nan)
+            self.n_samples += float(batch.ins_mask.sum())
+            with prof.stage("feed"):
+                dev = _to_device(host)
+            yield batch, dev
+
+    def dispatch(self, feed) -> tuple:
+        t = self.trainer
+        (t.params, t.opt_state, self.values, self.g2sum, self.mstate,
+         loss, finite, self.preds) = t._step_fn(
+            t.params, t.opt_state, self.values, self.g2sum, self.mstate,
+            feed[1])
+        return loss, finite
+
+    def after_step(self, feed, finite) -> bool:
+        # pbox-lint: ignore[host-sync-in-hot-loop] nan gate: with
+        # check_nan on, the per-step finite readback IS the
+        # feature (opt-in; default-off config pays nothing —
+        # `check_nan and` short-circuits before bool(finite))
+        if self.check_nan and not bool(finite):
+            if self.skip_batches:
+                # the guarded step already returned the pre-batch
+                # state: this batch contributed nothing — no
+                # update, no metrics, no dump, no step count
+                stats.add("train.nan_skipped_steps")
+                stats.add(
+                    "train.nan_skipped_ins", float(feed[0].ins_mask.sum()))
+                return False
+            raise NonFiniteBatchError(
+                f"non-finite loss/grad at step {self.trainer.global_step} "
+                "(FLAGS_check_nan_inf analog)"
+            )
+        if self.dumper is not None:
+            with self.prof.stage("dump"):
+                self.dumper.dump_batch(feed[0], np.asarray(self.preds))
+        return True
+
+    def hand_back(self) -> None:
+        self.table.values, self.table.g2sum = self.values, self.g2sum
+
+    def dump_params(self) -> None:
+        conf = self.trainer.conf
+        if conf.need_dump_param and conf.dump_fields_path:
+            from paddlebox_tpu.train.dump import dump_params
+
+            dump_params(
+                os.path.join(
+                    conf.dump_fields_path,
+                    f"param-{self.trainer.global_step}"),
+                self.trainer.params,
+                table=self.table,
+                select=conf.dump_param,
+            )
+
+    def read_back(self, losses: list, gn_base) -> dict:
+        metrics = self.trainer._read_back(self.mstate, losses, gn_base)
+        metrics["samples"] = self.n_samples
+        return metrics
+
+    def index(self) -> tuple:
+        return "pass_idx", self.trainer._pass_idx
 
 
 class Trainer:
@@ -489,45 +458,10 @@ class Trainer:
             primary = preds[:, 0] if n_tasks > 1 else preds
             mstate = add_counts(dict(mstate), counts)
             with jax.named_scope("metrics"):
-                mstate, finite = step_metrics(
-                    mstate, batch, loss, preds, primary, pgrads, row_grads)
+                mstate, finite = pass_loop.step_metrics(
+                    mstate, batch, loss, preds, primary, pgrads, row_grads,
+                    n_tasks=n_tasks, has_group=has_group, check_nan=check_nan)
             return params, opt_state, values, g2sum, mstate, loss, finite, primary
-
-        def step_metrics(mstate, batch, loss, preds, primary, pgrads,
-                         row_grads):
-            mstate["auc"] = update_auc_state(
-                mstate["auc"], primary, batch["labels"], batch["ins_mask"]
-            )
-            if "gn" in mstate:
-                # grad-norm health stream rides the donated metric state —
-                # no step-signature change: [sum of squared global grad
-                # norms, steps]; a skip_batch discard drops its sample too
-                gsq = jnp.zeros((), jnp.float32)
-                for leaf in jax.tree.leaves(pgrads):
-                    gsq += jnp.sum(jnp.square(leaf.astype(jnp.float32)))
-                gsq += jnp.sum(jnp.square(row_grads.astype(jnp.float32)))
-                mstate["gn"] = mstate["gn"] + jnp.stack(
-                    [gsq, jnp.ones((), jnp.float32)]
-                )
-            if n_tasks > 1:
-                mstate["task"] = jax.vmap(
-                    lambda s, pr, lb: update_auc_state(
-                        s, pr, lb, batch["ins_mask"]
-                    )
-                )(mstate["task"], preds.T, batch["task_labels"].T)
-            if has_group:
-                mstate["group"] = MetricGroup.update(
-                    mstate["group"], primary, batch["labels"],
-                    batch["metric_masks"],
-                )
-            if check_nan:
-                finite = jnp.isfinite(loss)
-                for leaf in jax.tree.leaves(pgrads):
-                    finite &= jnp.isfinite(leaf).all()
-                finite &= jnp.isfinite(row_grads).all()
-            else:
-                finite = jnp.array(True)
-            return mstate, finite
 
         self._step_body = step
         if check_nan and self.conf.nan_policy == "skip_batch":
@@ -555,43 +489,9 @@ class Trainer:
             **_step_schedule())
 
     def _init_mstate(self, auc_state=None) -> dict:
-        """Fresh metric state, or continuation: pass the previous pass's
-        ``trainer.last_metric_state`` (a dict) to carry EVERY stream forward;
-        a bare AucState continues only the primary stream and is rejected
-        when task/group streams exist (they would silently reset)."""
-        n_counters = len(counter_names(self.model))
-        if isinstance(auc_state, dict):
-            # the step donates mstate: copy so the caller's reference (often
-            # trainer.last_metric_state itself) is not invalidated by the
-            # first step's buffer donation
-            out = jax.tree.map(jnp.array, auc_state)
-            if "gn" not in out:
-                out["gn"] = jnp.zeros((2,), jnp.float32)
-            if n_counters and "counters" not in out:
-                out["counters"] = jnp.zeros((n_counters,), jnp.float32)
-            return out
-        if auc_state is not None and (self.n_tasks > 1 or self.metric_group):
-            raise ValueError(
-                "pass trainer.last_metric_state (dict) to continue metrics "
-                "across passes — a bare AucState would reset the task/group "
-                "streams while continuing the primary one"
-            )
-        mstate = {
-            "auc": jax.tree.map(jnp.array, auc_state)
-            if auc_state is not None
-            else init_auc_state(self.conf.auc_buckets),
-            "gn": jnp.zeros((2,), jnp.float32),
-        }
-        if n_counters:
-            # the model's per-step sums (step_loss.counter_names)
-            mstate["counters"] = jnp.zeros((n_counters,), jnp.float32)
-        if self.n_tasks > 1:
-            mstate["task"] = stack_auc_states(
-                init_auc_state(self.conf.auc_buckets), self.n_tasks
-            )
-        if self.metric_group is not None:
-            mstate["group"] = self.metric_group.init_state()
-        return mstate
+        """Fresh metric state, or the continuation of ``auc_state``
+        (pass_loop.init_metric_state)."""
+        return pass_loop.init_metric_state(self, auc_state)
 
     # -- dense persistence -------------------------------------------------- #
     def dense_state(self) -> tuple:
@@ -647,17 +547,15 @@ class Trainer:
         drop_last: bool = False,
         next_pass_keys=None,
     ) -> dict:
-        """Run one pass over the dataset's batches (the TrainFiles analog).
+        """Run one pass over the dataset's batches (the TrainFiles analog):
+        pass_loop.run_pass around this trainer's producer and step.
 
         The caller owns the pass lifecycle: table.begin_pass() before,
         table.end_pass() after.  Returns the pass metrics.
 
-        next_pass_keys: the NEXT pass's key census (array, or a zero-arg
-        callable returning one — evaluated on the table's staging thread,
-        so it may block on a dataset preload).  Handed to
-        table.prepare_pass once this pass's feeds are exhausted, while the
-        device still drains its queued tail steps — the pre-promotion half
-        of pass-boundary pipelining (no-op on serial tables).
+        next_pass_keys: the NEXT pass's key census, handed to
+        table.prepare_pass once this pass's feeds are exhausted
+        (pass_loop.run_pass).
 
         Non-finite batches follow TrainerConfig.nan_policy: "raise" aborts
         (NonFiniteBatchError), "skip_batch" discards the batch on device
@@ -665,338 +563,41 @@ class Trainer:
         the last completed pass and raises PassRolledBack — in that one
         case the pass was aborted and the caller must skip end_pass().
         """
-        # ONE profiler, always on, and the same loop whatever is asked for:
-        # profile / the trace dirs only decide what is reported and written
-        # after the pass, from the registry's delta over it
-        prof = StatsProfiler()
-        # the pass's head: the metric state and its baselines (eager
-        # programs and a read-back), the telemetry's own set-up, the
-        # watchdog's -- the device idles under it, so it has a name
-        with prof.stage("open"):
-            if self._step_fn is None:
-                self._step_fn = self._build_step()
-            with stage_scope("train.init"):
-                mstate = self._init_mstate(auc_state)
-                # grad-norm baseline: the accumulator carries across
-                # continued passes, so the per-pass value is a delta
-                # between host snapshots (materialized NOW — the first
-                # step donates the buffer)
-                gn_base = np.asarray(mstate["gn"], dtype=np.float64)
-                counters_base = np.asarray(
-                    mstate.get("counters", ()), dtype=np.float64)
-            vocab_keys = getattr(self.model, "vocab_keys", None)
-            pass_t0 = time.monotonic()
-            n_samples = [0.0]
-            values, g2sum = table.values, table.g2sum
-            losses, n_steps = [], 0
-            uses_rank = getattr(self.model, "uses_rank_offset", False)
-            uses_seq = getattr(self.model, "uses_seq_pos", False)
-            dumper = None
-            if self.conf.need_dump_field and self.conf.dump_fields_path:
-                from paddlebox_tpu.train.dump import FieldDumper
-
-                dumper = FieldDumper(
-                    os.path.join(self.conf.dump_fields_path,
-                                 f"dump-{self.global_step}.txt"),
-                    self.conf.dump_fields,
-                )
-            from paddlebox_tpu import telemetry
-
-            # telemetry policy: explicit config wins, env flags otherwise
-            # (PBOX_METRICS_PORT / PBOX_TRACE_DIR / PBOX_EVENTS_PATH — the
-            # launcher's per-rank knobs).  The exporter/event log are
-            # per-process singletons: first pass starts them, later passes
-            # are no-ops.
-            from paddlebox_tpu.config import TelemetryConfig
-
-            tele = self.conf.telemetry or TelemetryConfig.from_flags()
-            telemetry.ensure_exporter(tele.metrics_port or None)
-            event_log = telemetry.ensure_event_log(tele.events_path or None)
-            # host span tracing: TrainerConfig.trace_dir (which also drives
-            # the jax device trace) or the telemetry trace dir alone
-            host_trace_dir = self.conf.trace_dir or tele.trace_dir
-            if host_trace_dir:
-                from paddlebox_tpu.telemetry.events import _default_rank
-
-                telemetry.enable_tracing(pid=_default_rank())
-
-            watch = self._watch
-            want_report = bool(self.conf.profile or host_trace_dir)
-            prof_mark = prof.mark() if want_report else None
-            complete_mark = CompletionWatcher.mark() if want_report else None
-
-            # distributed-liveness watchdog: stage-reported progress (feed
-            # / step) with a stall deadline; single-process runs get local
-            # stall detection, multi-process runs additionally publish
-            # heartbeats and converge on coordinated abort
-            # (parallel/watchdog.py)
-            wd_mod = _watchdog_mod()
-            wd = None
-            stall_exc: tuple = ()
-            if wd_mod is not None:
-                stall_exc = (wd_mod.DistributedStallError,)
-                if self.conf.liveness is not None:
-                    wd = wd_mod.for_trainer(
-                        self.conf.liveness,
-                        namespace=f"train-{self.global_step}")
-                    if wd is not None:
-                        wd.start()
-
-        def feeds():
-            """(batch, device feed) stream: validation, host planning and
-            the transfer."""
-            for batch in prof.iterate(
-                    "batch", dataset.batches(drop_last=drop_last)):
-                if wd is not None:
-                    wd.report("feed")
-                if uses_rank and batch.rank_offset is None:
-                    raise RuntimeError(
-                        "model requires PV-merged batches with rank_offset: "
-                        "set enable_pv_merge and call dataset.preprocess_instance()"
-                    )
-                if uses_seq and batch.seq_pos is None:
-                    raise RuntimeError(
-                        "model consumes an ordered behavior sequence: set "
-                        "DataFeedConfig.sequence_slot (and max_seq_len) so "
-                        "batches carry seq_pos"
-                    )
-                if self.n_tasks > 1 and (
-                    batch.task_labels is None
-                    or batch.task_labels.shape[1] != self.n_tasks
-                ):
-                    got = (
-                        0 if batch.task_labels is None
-                        else batch.task_labels.shape[1]
-                    )
-                    raise RuntimeError(
-                        f"model has {self.n_tasks} tasks but the batch carries "
-                        f"{got} task label columns: configure "
-                        "DataFeedConfig.task_label_slots with "
-                        f"{self.n_tasks - 1} slots (task 0 is the primary label)"
-                    )
-                with prof.stage("plan"):
-                    plan = table.plan_batch(batch)
-                with prof.stage("feed"):
-                    host = _host_batch_dict(
-                        batch, plan, batch.n_sparse_slots,
-                        self.conf.counter_label_tasks,
-                        slot_lr_vec=self._slot_lr_vec,
-                        vocab_keys=vocab_keys,
-                    )
-                    if self.metric_group is not None:
-                        host["metric_masks"] = self.metric_group.masks(batch)
-                if faults.fire("train.nan"):
-                    # chaos injection: poison this batch's labels so the
-                    # loss/grads genuinely go NaN and the configured
-                    # nan_policy is exercised end to end on device
-                    host["labels"] = np.full_like(host["labels"], np.nan)
-                n_samples[0] += float(batch.ins_mask.sum())
-                with prof.stage("feed"):
-                    dev = _to_device(host)
-                yield batch, dev
-
-        prefetcher = None
-        check_nan = self._check_nan
-        skip_batches = check_nan and self.conf.nan_policy == "skip_batch"
         try:
-          try:
-            with telemetry.span("pass", pass_idx=self._pass_idx,
-                                global_step=self.global_step), \
-                 device_trace(self.conf.trace_dir or None):
-              if self.conf.prefetch_batches > 0:
-                # feed assembly overlaps the device step.  Started inside
-                # the pass span: the producer's plan/feed spans inherit it
-                # as their parent.
-                prefetcher = _FeedPrefetcher(
-                    feeds(), self.conf.prefetch_batches, prof)
-                feed_iter = prefetcher
-              else:
-                feed_iter = feeds()
-              for batch, dev in feed_iter:
-                # chaos site: a hang here simulates a stalled device step;
-                # the watchdog bounds it and names this process + stage
-                faults.inject("train.step")
-                t_dispatch = time.perf_counter()
-                with prof.stage("step"):
-                    (self.params, self.opt_state, values, g2sum, mstate,
-                     loss, finite, preds) = (
-                        self._step_fn(self.params, self.opt_state, values,
-                                      g2sum, mstate, dev)
-                    )
-                watch.dispatched(loss, t_dispatch)
-                if wd is not None:
-                    wd.report("step")
-                # pbox-lint: ignore[host-sync-in-hot-loop] nan gate: with
-                # check_nan on, the per-step finite readback IS the
-                # feature (opt-in; default-off config pays nothing —
-                # `check_nan and` short-circuits before bool(finite))
-                if check_nan and not bool(finite):
-                    if skip_batches:
-                        # the guarded step already returned the pre-batch
-                        # state: this batch contributed nothing — no
-                        # update, no metrics, no dump, no step count
-                        stats.add("train.nan_skipped_steps")
-                        stats.add(
-                            "train.nan_skipped_ins",
-                            float(batch.ins_mask.sum()),
-                        )
-                        continue
-                    raise NonFiniteBatchError(
-                        f"non-finite loss/grad at step {self.global_step} "
-                        "(FLAGS_check_nan_inf analog)"
-                    )
-                if dumper is not None:
-                    with prof.stage("dump"):
-                        dumper.dump_batch(batch, np.asarray(preds))
-                losses.append(loss)  # device scalars; synced once at pass end
-                n_steps += 1
-                self.global_step += 1
-          finally:
-            # old buffers were donated to the jitted step: always hand the
-            # live ones back so end_pass() works even after a NaN raise.
-            # The watchdog retires FIRST so its abort latch cannot fire
-            # into the teardown itself.
-            if wd is not None:
-                wd.close()
-            table.values, table.g2sum = values, g2sum
-            if prefetcher is not None:
-                prefetcher.close()
-            if dumper is not None:
-                dumper.close()
+            return pass_loop.run_pass(
+                self, _Pass(self, dataset, table, drop_last), table,
+                auc_state, next_pass_keys)
         except NonFiniteBatchError:
             if self.conf.nan_policy == "rollback":
                 self._rollback_to_checkpoint(table)  # raises PassRolledBack
             raise
-        except stall_exc:
+        except pass_loop.stall_errors():
             # coordinated abort: the pass is torn down (prefetcher closed,
             # buffers handed back).  With rollback_on_abort + an attached
             # checkpointer, restore the last completed pass so no
             # partially-applied pass survives; resumed replay is then
             # bit-exact (PassRolledBack tells the driver where to re-run).
-            stats.add("train.stall_aborts")
             if (
                 self.conf.liveness is not None
                 and self.conf.liveness.rollback_on_abort
             ):
                 self._rollback_to_checkpoint(table)  # raises PassRolledBack
             raise
-        # pre-promotion: the feed loop is done but the device is still
-        # draining queued steps (and the metric readback below blocks on
-        # them) — exactly the tail window the next pass's census resolve +
-        # init + staging can hide in
-        if next_pass_keys is not None:
-            prepare = getattr(table, "prepare_pass", None)
-            if prepare is not None:
-                prepare(next_pass_keys)
-        if self.conf.need_dump_param and self.conf.dump_fields_path:
-            from paddlebox_tpu.train.dump import dump_params
-
-            dump_params(
-                os.path.join(
-                    self.conf.dump_fields_path, f"param-{self.global_step}"
-                ),
-                self.params,
-                table=table,
-                select=self.conf.dump_param,
-            )
-        # the device's tail: the read-back below waits for the last queued
-        # step anyway; waiting here first gives the wait its own name and
-        # leaves ``readback`` the eager metric programs alone
-        with prof.stage("drain"):
-            if losses:
-                losses[-1].block_until_ready()
-            watch.settle()
-            # the device has nothing queued: did the host let the pass's
-            # threads run (the feed producer answered before it exited)
-            HOST.after_drain(watch)
-        with stage_scope("train.readback"), prof.stage("readback"):
-            metrics = self._read_back(mstate, losses, gn_base)
-            if "counters" in mstate:
-                metrics.update(publish_counters(
-                    self.model,
-                    np.asarray(mstate["counters"], dtype=np.float64),
-                    counters_base))
-        # the pass's tail is the telemetry's own -- the pass report, the
-        # registry's delta over every series, the health rules, the
-        # pass_end record -- with the device idle: it has a name too
-        with prof.stage("observe"):
-            metrics["steps"] = n_steps
-            # samples/s without trace files: the pass_end record carries
-            # wall-clock duration and the instance count it covered
-            metrics["duration_s"] = time.monotonic() - pass_t0
-            metrics["samples"] = float(n_samples[0])
-            pass_seconds().observe(metrics["duration_s"])
-            if want_report:
-                metrics["profile"] = prof.report(
-                    prof_mark, n_steps, complete_mark)
-                if self.conf.profile:
-                    print("[profile]", prof.log_line(metrics["profile"]))
-            if host_trace_dir:
-                from paddlebox_tpu.telemetry.events import _default_rank
-
-                telemetry.flush_trace(os.path.join(
-                    host_trace_dir,
-                    f"host-trace-r{_default_rank()}-pass{self._pass_idx}"
-                    ".json",
-                ))
-            # run-health plane: evaluate the rule catalog against the SAME
-            # window the pass_end record carries (the delta snapshot resets
-            # its baseline per call — there is exactly one consumer chain),
-            # BEFORE the record is written so a consumer that tails up to
-            # pass_end already has the window's health_alert events
-            snap = telemetry.registry.delta_snapshot()
-            telemetry.observe_pass(
-                self._pass_idx, metrics=metrics, telemetry=snap, table=table
-            )
-            if event_log is not None:
-                event_log.log_pass(metrics, telemetry=snap,
-                                   pass_idx=self._pass_idx)
-        self._pass_idx += 1
-        self.last_auc_state = mstate["auc"]
-        self.last_metric_state = mstate
-        return metrics
 
     def _read_back(self, mstate: dict, losses: list, gn_base) -> dict:
         """The pass's metrics from the device's metric state: AUC streams,
         mean loss, gradient and weight norms (eager programs, tagged
         ``train.readback`` by the caller)."""
-        from paddlebox_tpu import telemetry
-
-        metrics = compute_metrics(mstate["auc"])
-        if self.n_tasks > 1:
-            metrics.update(
-                compute_metrics_stacked(
-                    mstate["task"], [f"task{t}" for t in range(self.n_tasks)]
-                )
-            )
-        if self.metric_group is not None:
-            metrics.update(self.metric_group.compute(mstate["group"]))
-        metrics["loss"] = (
+        loss = (
             float(
                 jnp.concatenate([jnp.atleast_1d(l) for l in losses]).mean()
             )
             if losses
             else 0.0
         )
-        gn_now = np.asarray(mstate["gn"], dtype=np.float64)
-        d_sq, d_n = gn_now[0] - gn_base[0], gn_now[1] - gn_base[1]
-        if d_n > 0:
-            grad_norm = float(np.sqrt(d_sq / d_n)) if d_sq >= 0 else float(
-                "nan")
-            metrics["grad_norm"] = grad_norm
-            telemetry.gauge(
-                "train.grad_norm",
-                "per-pass RMS global gradient norm (dense + sparse)",
-            ).set(grad_norm)
-        wsq = sum(
-            float(jnp.sum(jnp.square(leaf.astype(jnp.float32))))
-            for leaf in jax.tree.leaves(self.params)
-        )
-        metrics["weight_norm"] = math.sqrt(wsq) if wsq >= 0 else float("nan")
-        telemetry.gauge(
-            "train.weight_norm", "dense parameter L2 norm at pass end"
-        ).set(metrics["weight_norm"])
-        return metrics
+        return pass_loop.read_back_common(
+            mstate, gn_base, self.params, self.n_tasks, self.metric_group,
+            {"loss": loss})
 
     # -- inference / evaluation -------------------------------------------- #
     def _build_eval_step(self):
@@ -1036,17 +637,7 @@ class Trainer:
         uses_seq = getattr(self.model, "uses_seq_pos", False)
         auc = init_auc_state(self.conf.auc_buckets)
         for batch in dataset.batches(drop_last=drop_last):
-            if uses_rank and batch.rank_offset is None:
-                raise RuntimeError(
-                    "model requires PV-merged batches with rank_offset: "
-                    "set enable_pv_merge and call dataset.preprocess_instance()"
-                )
-            if uses_seq and batch.seq_pos is None:
-                raise RuntimeError(
-                    "model consumes an ordered behavior sequence: set "
-                    "DataFeedConfig.sequence_slot (and max_seq_len) so "
-                    "batches carry seq_pos"
-                )
+            pass_loop.validate_batch(batch, uses_rank, uses_seq, 1)
             plan = table.plan_batch(batch)
             dev = _device_batch(batch, plan, batch.n_sparse_slots)
             auc = self._eval_fn(self.params, table.values, auc, dev)

@@ -660,7 +660,7 @@ class TestServerErrorPaths:
 def test_prefetch_close_timeout_counted(monkeypatch):
     import threading
 
-    from paddlebox_tpu.train import trainer as trainer_mod
+    from paddlebox_tpu.train import pass_loop as trainer_mod
 
     monkeypatch.setattr(trainer_mod, "_PREFETCH_JOIN_S", 0.05)
     release = threading.Event()

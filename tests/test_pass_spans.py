@@ -301,7 +301,7 @@ def test_completion_watcher_samples_in_order_without_blocking_the_caller():
 # --------------------------------------------------------------------------- #
 @pytest.mark.parametrize("mode", ["profile", "telemetry_trace_dir"])
 def test_profiling_runs_the_same_loop(tmp_path, mode, monkeypatch):
-    from paddlebox_tpu.train import trainer as trainer_mod
+    from paddlebox_tpu.train import pass_loop as trainer_mod
 
     threads = []
     real = trainer_mod._FeedPrefetcher

@@ -24,9 +24,12 @@ planes document in prose:
     is a silent recompile per step — ``jit-retrace-hazard``.
   * inside a per-batch/per-step loop the host must not synchronize with
     the device ("nothing syncs with the host inside a step",
-    train/trainer.py module docstring); pass-boundary D2H snapshots and
-    end-of-pass merges are the designed exceptions, recognized by loop
-    position, and profiling/dump-gated readbacks by their guard.
+    train/trainer.py module docstring; the loop itself is
+    train/pass_loop.py run_pass, for both trainers, and the nan gate's
+    opt-in readback sits in each trainer's after_step); pass-boundary D2H
+    snapshots and end-of-pass merges are the designed exceptions,
+    recognized by loop position, and profiling/dump-gated readbacks by
+    their guard.
 """
 
 from __future__ import annotations

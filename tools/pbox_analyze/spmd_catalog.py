@@ -25,7 +25,8 @@ hang family a review round has chased by hand:
     receivers count (``SparseTable.flush`` alone is process-local).
   * ``gather_fleet_snapshot`` — the pass-boundary metric allgather over
     the coordination KV ("Every rank participates (lockstep, like the
-    collectives)", parallel/trainer.py).
+    collectives)", parallel/trainer.py _GroupPass.observe, entered from
+    the observe stage of train/pass_loop.py run_pass).
   * ``ShardedSparseTable.broadcast_hot_rows`` — hot-promotion rows ride
     the census channel as keycodec frames; every rank contributes and
     receives in lockstep inside ``begin_pass`` (main thread, between the
@@ -120,7 +121,7 @@ FUNCTION_COLLECTIVES = {
     "gather_fleet_snapshot": CollectiveSpec(
         op="gather_fleet_snapshot", thread_safe=True,
         why="pass-boundary metric gather: every rank participates in "
-            "lockstep (trainer.py fleet snapshot)",
+            "lockstep (parallel/trainer.py fleet snapshot)",
     ),
 }
 
