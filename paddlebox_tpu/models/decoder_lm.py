@@ -119,6 +119,49 @@ ITSELF (no shift: generation fills a block's masked positions in place),
 and the loss is sum over masked positions of CE / p, over B * T.  Only
 ``full_attention`` layers have a two-stream form: a description with
 another operator kind under this objective is refused by name.
+
+A description may also say that the stack runs more than once: ``loops`` =
+R applies the L layers R times over the SAME leaves (a looped language
+model: a leaf's gradient is the sum over its R uses), with ``norm_f`` at
+the end of every round, its output the next round's input; ``sandwich``
+puts a second norm on each operator's output, inside the residual.  With
+the third ``objective``, ``"looped_exit"`` (``exit`` gives ``beta``), the
+head scores every round and a learned gate says where to stop:
+
+    x = E[tokens]
+    for r = 1 .. R:                              the same leaves every round
+        for l = 1 .. L:
+            x = x + n1b_l(op_l(n1_l x))          sandwich: without it, as
+            x = x + n2b_l(ffn_l(n2_l x))         above, no n1b, no n2b
+        h_r = norm_f(x);  x = h_r                feeds round r + 1
+        z_r = h_r head^T                         logits of round r
+        lam_r = sigmoid(h_r . w_g + b_g)         exit gate, a position
+    p_1 = lam_1,  p_r = lam_r prod_{j<r} (1 - lam_j)  (r < R),
+    p_R = prod_{j<R} (1 - lam_j)                 lam_R is not read
+    loss = mean over the positions i with a next token of
+           sum_r p_r(i) CE(z_r(i), next token) - beta H(p(i)),
+           H(p) = -sum_r p_r ln p_r
+
+the expected loss under the exit distribution less ``beta`` times its
+entropy (a uniform prior over the rounds); ``exit_gate`` (``w`` [hidden],
+``b`` [1]) is a dense leaf.  ``loops`` > 1 under ``next_token`` scores the
+last round alone, which is what ``looped_exit`` gives when the gate never
+stops early.  The rounds are ONE traced body (``_rounds``: a ``lax.scan``
+whose body is the L rematerialised layers, so the compiler sees the layers
+once and the backward pass keeps R x L layer boundaries), scopes
+``round_norm`` and ``exit_gate`` beside the layers' and ``lm_head``; the R
+head passes are one chunked pass over R x tokens.  Such a description adds
+to ``step_counters``: ``loop.layer_passes`` (positions x rounds x layers)
+and, under the objective, ``loop.exit_mass_<r>`` (the sum over the scored
+positions of p_r) for r = 1 .. R and ``loop.exit_entropy`` (of H(p)).
+Refused by name: ``loops`` > 1 under ``block_diffusion`` and with
+``"conv"`` or ``"kda"`` layers (a state carried across the rounds is not
+written), ``sandwich`` with an operator kind other than the two plain
+attentions or with a sparse feed-forward, ``exit`` without its objective and the objective without a
+second round.  Generation with an exit at a threshold of the cumulative
+mass, and the gate trained alone, are not here (ROADMAP.md A4).  A
+description whose ``mlp_types`` are all ``"dense"`` leaves the experts'
+three sizes out; one with a sparse layer is refused without them.
 """
 
 from __future__ import annotations
@@ -156,8 +199,10 @@ LATENT_KEYS = ("kv_rank", "qk_nope", "qk_rope", "v_dim")
 # "rotary": False = no positional code; else "interleaved" says which pairs
 LATENT_OPTIONAL = ("interleaved", "rotary")
 KDA_KEYS = ("n_heads", "head_dim", "conv_kernel", "gate_rank")
-NEXT_TOKEN, BLOCK_DIFFUSION = "next_token", "block_diffusion"
+NEXT_TOKEN, BLOCK_DIFFUSION, LOOPED_EXIT = (
+    "next_token", "block_diffusion", "looped_exit")
 DIFFUSION_KEYS = ("block_len", "eps", "noise_seed")
+EXIT_KEYS = ("beta",)
 NOISE_GRID = 1000  # the dense feature's grid: a noise level is n / 1000
 # gated_delta_chunked's two sizes, read on the chip (PERF.md section 6, PR
 # 39): the positions of a chunk, and how many (query, key, channel) decays
@@ -335,6 +380,21 @@ def gated_delta_chunked(q, k, v, g, beta, chunk: int) -> jax.Array:
     return o.transpose(2, 0, 1, 4, 3, 5).reshape(B, N * C, nh, dv)[:, :T]
 
 
+def exit_distribution(gates: jax.Array) -> tuple:
+    """Where a looped stack would stop: gates [R, ...] the rounds' gate
+    logits, lam_r = sigmoid(gates[r]) the chance of stopping at round r
+    having come that far.  Returns p [R, ...], p_r = lam_r * prod over
+    j < r of (1 - lam_j) and the last round what is left (its own gate is
+    not read), and the entropy -sum_r p_r ln p_r [...]; in logarithms, so a
+    saturated gate gives 0 and no NaN."""
+    stay = jnp.cumsum(jax.nn.log_sigmoid(-gates[:-1]), axis=0)
+    zero = jnp.zeros_like(gates[:1])
+    logp = (jnp.concatenate([zero, stay])
+            + jnp.concatenate([jax.nn.log_sigmoid(gates[:-1]), zero]))
+    p = jnp.exp(logp)
+    return p, -(p * logp).sum(axis=0)
+
+
 def _ones(key, shape):
     return jnp.ones(shape, jnp.float32)
 
@@ -356,9 +416,11 @@ class DecoderMoeLM:
     """Decoder whose layers are assembled from a description -- window,
     full or latent attention, a gated short convolution or a gated delta
     rule with a carried state; a dense feed-forward or token-routed experts
-    with or without shared ones -- trained through the pass loop on
-    next-token prediction or, ``objective="block_diffusion"``, on
-    denoising over blocks (the module's docstring)."""
+    with or without shared ones; the stack run once or ``loops`` times over
+    the same leaves -- trained through the pass loop on next-token
+    prediction, ``objective="block_diffusion"``, on denoising over blocks
+    or, ``"looped_exit"``, on every round's prediction weighted by a
+    learned exit distribution (the module's docstring)."""
 
     uses_seq_pos = True
     n_sparse_slots = 1
@@ -369,7 +431,9 @@ class DecoderMoeLM:
     # ``kda.tokens``, the positions that went through such an operator, one
     # with the block-diffusion objective ``diffusion.positions``, the
     # positions that went through the layers (both streams; there
-    # ``trainer.tokens`` counts the masked positions, the ones scored)
+    # ``trainer.tokens`` counts the masked positions, the ones scored), one
+    # whose stack runs more than once ``loop.layer_passes`` and, under the
+    # looped-exit objective, the rounds' exit masses and the exit entropy
     step_counters = ("trainer.tokens", "moe.pairs_local", "moe.pairs_routed",
                      "moe.expert_load_max", "moe.expert_load_mean")
 
@@ -383,9 +447,9 @@ class DecoderMoeLM:
         head_dim: int,
         layer_types: Sequence[str],
         window: int,
-        n_experts: int,
-        n_experts_per_tok: int,
-        expert_width: int,
+        n_experts: int = 0,  # the three sizes of a "sparse" layer's experts
+        n_experts_per_tok: int = 0,
+        expert_width: int = 0,
         experts_held: Optional[tuple] = None,  # (lo, hi); None = all
         rope_theta: float = 10000.0,
         yarn: Optional[dict] = None,  # rotary_tables' keys, full layers
@@ -403,8 +467,11 @@ class DecoderMoeLM:
         qk_norm: bool = False,  # a learned norm on every query and key head
         conv_kernel: int = 0,  # taps of a "conv" layer's convolution
         kda: Optional[dict] = None,  # KDA_KEYS, for "kda" layers
-        objective: str = NEXT_TOKEN,  # or "block_diffusion"
-        diffusion: Optional[dict] = None,  # DIFFUSION_KEYS, for the latter
+        objective: str = NEXT_TOKEN,  # "block_diffusion", "looped_exit"
+        diffusion: Optional[dict] = None,  # DIFFUSION_KEYS, for the second
+        loops: int = 1,  # how many times the stack runs, over the same leaves
+        sandwich: bool = False,  # a norm after each operator too
+        exit: Optional[dict] = None,  # EXIT_KEYS, for "looped_exit"
     ):
         vocab_keys = np.asarray(vocab_keys, dtype=np.uint64)
         if vocab_keys.ndim != 1 or not np.all(vocab_keys[1:] > vocab_keys[:-1]):
@@ -424,6 +491,13 @@ class DecoderMoeLM:
                 f"mlp_types {mlp_types} for {len(layer_types)} layers")
         if DENSE in mlp_types and dense_width <= 0:
             raise ValueError("a dense layer needs dense_width")
+        if SPARSE in mlp_types:
+            missing = [name for name, size in (
+                ("n_experts", n_experts),
+                ("n_experts_per_tok", n_experts_per_tok),
+                ("expert_width", expert_width)) if size <= 0]
+            if missing:
+                raise ValueError(f"a sparse layer needs {missing}")
         if LATENT in layer_types:
             _described("latent", latent, LATENT_KEYS, LATENT_OPTIONAL)
             if latent.get("rotary", True) and "interleaved" not in latent:
@@ -432,8 +506,10 @@ class DecoderMoeLM:
             _described("kda", kda, KDA_KEYS)
         if router_score not in ("softmax", "sigmoid"):
             raise ValueError(f"unknown router score {router_score!r}")
-        if objective not in (NEXT_TOKEN, BLOCK_DIFFUSION):
+        if objective not in (NEXT_TOKEN, BLOCK_DIFFUSION, LOOPED_EXIT):
             raise ValueError(f"unknown objective {objective!r}")
+        if loops < 1:
+            raise ValueError(f"the stack runs {loops} times")
         if objective == BLOCK_DIFFUSION:
             _described("diffusion", diffusion, DIFFUSION_KEYS)
             other = sorted(set(layer_types) - {FULL})
@@ -446,14 +522,37 @@ class DecoderMoeLM:
                 raise ValueError(
                     f"block_q {block_q} is no multiple of the block length "
                     f"{diffusion['block_len']}")
+            if loops > 1:
+                raise ValueError(
+                    "the block_diffusion objective runs its two streams "
+                    f"through the stack once; loops={loops} under it is not "
+                    "written")
         elif diffusion is not None:
             raise ValueError("diffusion describes the block_diffusion "
                              "objective only")
+        carried = sorted({CONV, KDA} & set(layer_types))
+        if loops > 1 and carried:
+            raise ValueError(
+                f"loops={loops} with {carried} layers: a state carried "
+                "across the rounds is not written")
+        if objective == LOOPED_EXIT:
+            _described("exit", exit, EXIT_KEYS)
+            if loops < 2:
+                raise ValueError(
+                    "the looped_exit objective weighs the rounds of a stack "
+                    f"that runs more than once; loops={loops}")
+        elif exit is not None:
+            raise ValueError("exit describes the looped_exit objective only")
+        unnormed = sorted((set(layer_types) - {SLIDING, FULL})
+                          | (set(mlp_types) - {DENSE}))
+        if sandwich and unnormed:
+            raise ValueError(
+                f"the sandwich form of {unnormed} layers is not written")
         if n_heads % n_kv_heads:
             raise ValueError(
                 f"{n_heads} query heads over {n_kv_heads} key-value heads")
         lo, hi = experts_held or (0, n_experts)
-        if not 0 <= lo < hi <= n_experts:
+        if SPARSE in mlp_types and not 0 <= lo < hi <= n_experts:
             raise ValueError(f"experts_held {(lo, hi)} of {n_experts}")
         self.vocab_keys = vocab_keys
         self.n_classes = int(vocab_keys.shape[0])
@@ -477,6 +576,13 @@ class DecoderMoeLM:
         self.objective, self.diffusion = objective, diffusion
         if objective == BLOCK_DIFFUSION:
             self.step_counters = self.step_counters + ("diffusion.positions",)
+        self.loops, self.sandwich, self.exit = loops, sandwich, exit
+        if loops > 1:
+            self.step_counters = self.step_counters + ("loop.layer_passes",)
+        if objective == LOOPED_EXIT:
+            self.step_counters = self.step_counters + tuple(
+                f"loop.exit_mass_{r}" for r in range(1, loops + 1)) + (
+                    "loop.exit_entropy",)
         self.window = window
         self.n_experts, self.top_k = n_experts, n_experts_per_tok
         self.expert_width = expert_width
@@ -553,8 +659,11 @@ class DecoderMoeLM:
         """Normal weights scaled by 1/sqrt(fan-in), norm scales 1 (a "kda"
         layer's decays as ``_layer_weights`` names them); under the
         block-diffusion objective ``mask_embed`` too, a normal draw at a
-        table row's scale, from a key of its own so that every other leaf
-        is what it is without the objective."""
+        table row's scale, and under the looped-exit objective
+        ``exit_gate`` (``w`` normal / sqrt(hidden), ``b`` 0: the gate's
+        logit is a standard normal draw a position, no round preferred),
+        each from a key of its own so that every other leaf is what it is
+        without the objective."""
         H = self.hidden
         layers = []
         for lk, attn_kind, mlp_kind in zip(
@@ -563,6 +672,9 @@ class DecoderMoeLM:
             weights = self._layer_weights(attn_kind, mlp_kind)
             lp = {"n1": jnp.ones((H,), jnp.float32),
                   "n2": jnp.ones((H,), jnp.float32)}
+            if self.sandwich:
+                lp["n1b"] = jnp.ones((H,), jnp.float32)
+                lp["n2b"] = jnp.ones((H,), jnp.float32)
             if attn_kind == LATENT:
                 lp["n_kv"] = jnp.ones((self.latent["kv_rank"],), jnp.float32)
             elif attn_kind not in (CONV, KDA) and self.qk_norm:
@@ -583,6 +695,12 @@ class DecoderMoeLM:
         if self.objective == BLOCK_DIFFUSION:
             params["mask_embed"] = 0.02 * jax.random.normal(
                 jax.random.fold_in(key, len(layers) + 1), (H,), jnp.float32)
+        if self.objective == LOOPED_EXIT:
+            params["exit_gate"] = {
+                "w": jax.random.normal(
+                    jax.random.fold_in(key, len(layers) + 2), (H,),
+                    jnp.float32) / np.sqrt(H),
+                "b": jnp.zeros((1,), jnp.float32)}
         return params
 
     # -- forward ----------------------------------------------------------- #
@@ -617,7 +735,10 @@ class DecoderMoeLM:
             a = full_attention(
                 apply_rotary(q, cos, sin), apply_rotary(k, cos, sin), v,
                 block_q=self.block_q, **mask)
-            return x + a.reshape(B, T, -1) @ lp["wo"]
+            y = a.reshape(B, T, -1) @ lp["wo"]
+            if self.sandwich:
+                y = rms_norm(y, lp["n1b"], self.rms_eps)
+            return x + y
 
     def _attend_latent(self, lp: dict, x: jax.Array) -> jax.Array:
         B, T, H = x.shape
@@ -706,6 +827,8 @@ class DecoderMoeLM:
         if kinds[1] == DENSE:
             with jax.named_scope("dense_mlp"):
                 y = swiglu(h, lp["mlp_gate"], lp["mlp_up"], lp["mlp_down"])
+                if self.sandwich:
+                    y = rms_norm(y, lp["n2b"], self.rms_eps)
             return x + y.reshape(B, T, H), jnp.zeros((2,), jnp.float32)
         with jax.named_scope("router"):
             top_w, top_e = route_tokens(
@@ -723,11 +846,14 @@ class DecoderMoeLM:
         return x + y.reshape(B, T, H), jnp.stack(
             [load.sum(), load.max()]).astype(jnp.float32)
 
-    def _token_losses(self, params: dict, x: jax.Array, target: jax.Array):
+    def _token_losses(self, params: dict, x: jax.Array, target: jax.Array,
+                      normed: bool = False):
         """Softmax cross-entropy of every token against ``target`` (class
         ids; anything where there is none), [N], in chunks of
         ``loss_chunk`` tokens: one chunk's [chunk, classes] logits are all
-        that is held, forward and (rematerialised) backward."""
+        that is held, forward and (rematerialised) backward.  ``normed``:
+        x has been through ``norm_f`` (a stack that runs more than once
+        applies it at the end of every round)."""
         N, H = x.shape
         chunk = min(self.loss_chunk, N)
         pad = -N % chunk
@@ -737,7 +863,8 @@ class DecoderMoeLM:
         @jax.checkpoint
         def one(args):
             xc, tc = args
-            xc = rms_norm(xc, params["norm_f"], self.rms_eps)
+            if not normed:
+                xc = rms_norm(xc, params["norm_f"], self.rms_eps)
             logits = jnp.dot(xc, params["head"].T,
                              preferred_element_type=jnp.float32)
             picked = jnp.take_along_axis(
@@ -755,13 +882,15 @@ class DecoderMoeLM:
 
         Returns (loss, preds [B], counts): the mean next-token
         cross-entropy over the positions that have a next token -- or the
-        denoising loss, sum over the masked positions of CE / p over B * T
-        --; a number in (0, 1] a sequence -- exp(-its mean loss), the
-        geometric mean of the probability it gave its scored tokens, 1
-        where it has none -- so that the trainers' AUC and metric state
-        keep their shapes (the AUC of such numbers against ``click`` means
-        nothing; the loss is the metric); and ``step_counters``' values for
-        this step."""
+        denoising loss, sum over the masked positions of CE / p over B * T,
+        or the looped-exit loss, the mean over those positions of the
+        rounds' cross-entropies weighted by the exit distribution less beta
+        times its entropy --; a number in (0, 1] a sequence -- exp(-its
+        mean loss), the geometric mean of the probability it gave its
+        scored tokens, 1 where it has none -- so that the trainers' AUC and
+        metric state keep their shapes (the AUC of such numbers against
+        ``click`` means nothing; the loss is the metric); and
+        ``step_counters``' values for this step."""
         seq_pos = batch["seq_pos"]
         B, T = seq_pos.shape
         K = rows.shape[0]
@@ -787,33 +916,88 @@ class DecoderMoeLM:
         valid = seq_pos < K
         if streams:
             valid = jnp.concatenate([valid, valid], axis=1)
+        exits = self.objective == LOOPED_EXIT
+        if self.loops > 1:
+            x, rounds, gates, moe = self._rounds(params, x, valid)
+        else:
+            x, moe = self._stack(params, x, valid)
+        with jax.named_scope("lm_head"):
+            if exits:  # every round's state against the same targets
+                R = self.loops
+                ce = self._token_losses(
+                    params, rounds.reshape(R * B * T, -1),
+                    jnp.tile(target.reshape(-1), R), normed=True)
+                ce = ce.reshape(R, B, T)
+            else:  # the noised stream's positions where there are two
+                ce = self._token_losses(
+                    params, x[:, :T].reshape(B * T, -1), target.reshape(-1),
+                    normed=self.loops > 1)
+                ce = ce.reshape(B, T) * scored
+        n_scored = scored.sum()
+        if exits:
+            with jax.named_scope("exit_gate"):
+                p, entropy = exit_distribution(gates)
+                ce = (p * ce).sum(axis=0) * scored
+                entropy = entropy * scored
+                loss = (ce.sum() - self.exit["beta"] * entropy.sum()
+                        ) / jnp.maximum(n_scored, 1.0)
+        elif streams:
+            loss = (ce * weight[:, None]).sum() / (B * T)
+        else:
+            loss = ce.sum() / jnp.maximum(n_scored, 1.0)
+        preds = jnp.exp(-ce.sum(axis=1) / jnp.maximum(scored.sum(axis=1), 1.0))
+        held = max(self.experts_held[1] - self.experts_held[0], 1)
+        n_tokens = valid.sum().astype(jnp.float32)
+        counts = [n_scored, moe[0],
+                  n_tokens * self.top_k * (
+                      self.mlp_types.count(SPARSE) * self.loops),
+                  moe[1], moe[0] / held]
+        if KDA in self.layer_types:
+            counts.append(n_tokens * self.layer_types.count(KDA))
+        if streams:
+            counts.append(n_tokens)
+        if self.loops > 1:
+            counts.append(n_tokens * self.loops * len(self.layer_types))
+        if exits:
+            counts += list((p * scored).sum(axis=(1, 2))) + [entropy.sum()]
+        return loss, preds, jnp.stack(counts)
+
+    def _stack(self, params: dict, x: jax.Array, valid: jax.Array) -> tuple:
+        """The layers once, each rematerialised in the backward pass.
+        Returns (x, the two sums of ``_layer`` over the layers)."""
         moe = jnp.zeros((2,), jnp.float32)
         for lp, kinds in zip(params["layers"],
                              zip(self.layer_types, self.mlp_types)):
             x, m = jax.checkpoint(self._layer, static_argnums=(3,))(
                 lp, x, valid, kinds)
             moe = moe + m
-        with jax.named_scope("lm_head"):
-            # the noised stream's positions where there are two
-            ce = self._token_losses(
-                params, x[:, :T].reshape(B * T, -1), target.reshape(-1))
-            ce = ce.reshape(B, T) * scored
-        n_scored = scored.sum()
-        if streams:
-            loss = (ce * weight[:, None]).sum() / (B * T)
-        else:
-            loss = ce.sum() / jnp.maximum(n_scored, 1.0)
-        preds = jnp.exp(-ce.sum(axis=1) / jnp.maximum(scored.sum(axis=1), 1.0))
-        held = self.experts_held[1] - self.experts_held[0]
-        n_tokens = valid.sum().astype(jnp.float32)
-        counts = [n_scored, moe[0],
-                  n_tokens * self.top_k * self.mlp_types.count(SPARSE),
-                  moe[1], moe[0] / held]
-        if KDA in self.layer_types:
-            counts.append(n_tokens * self.layer_types.count(KDA))
-        if streams:
-            counts.append(n_tokens)
-        return loss, preds, jnp.stack(counts)
+        return x, moe
+
+    def _rounds(self, params: dict, x: jax.Array, valid: jax.Array) -> tuple:
+        """The stack ``loops`` times over the same leaves, as ONE traced
+        body (a ``lax.scan`` over the rounds: the compiler sees the layers
+        once, a leaf's gradient is the sum over its uses, and the backward
+        pass keeps a boundary a layer and round): ``_stack``, then
+        ``norm_f`` at the end of every round, its output the next round's
+        input.  Returns the last round's normed state [B, T, H]; under the
+        looped-exit objective every round's normed state [R, B, T, H] and
+        gate logit [R, B, T] (else None, None); and the two sums of
+        ``_layer`` over layers and rounds."""
+        exits = self.objective == LOOPED_EXIT
+
+        def one_round(x, _):
+            x, moe = self._stack(params, x, valid)
+            with jax.named_scope("round_norm"):
+                h = rms_norm(x, params["norm_f"], self.rms_eps)
+            if not exits:
+                return h, (moe, None, None)
+            with jax.named_scope("exit_gate"):
+                gate = h @ params["exit_gate"]["w"] + params["exit_gate"]["b"]
+            return h, (moe, h, gate)
+
+        x, (moe, rounds, gates) = jax.lax.scan(
+            one_round, x, None, length=self.loops)
+        return x, rounds, gates, moe.sum(axis=0)
 
     def _noised(self, params: dict, x0: jax.Array, cls: jax.Array,
                 valid: jax.Array, dense: jax.Array) -> tuple:
