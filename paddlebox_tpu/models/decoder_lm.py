@@ -90,7 +90,12 @@ scores the ``len(vocab_keys)`` classes held here; the loss is the mean over
 positions t < T-1 with a next token of the softmax cross-entropy against
 that token's class, in token chunks so the [tokens, classes] logits are
 never whole in memory.  Each layer is rematerialised in the backward pass
-(``jax.checkpoint``, one per layer).
+(``jax.checkpoint``, one per layer).  What a layer keeps is its input and,
+where its attention came as the flash-form kernel (a TPU), the kernel's
+output and log-sum-exp, which the kernel's forward rule names and
+``kernel_residuals`` of parallel/sequence.py makes the checkpoint's policy:
+the forward kernel runs once a step, everything else of the layer twice.
+Elsewhere no policy is named and the input is all a layer keeps.
 
 That loss is the default ``objective``, ``"next_token"``.  The other is
 ``"block_diffusion"`` (``diffusion`` gives ``block_len`` L, ``eps`` and
@@ -181,6 +186,7 @@ from paddlebox_tpu.parallel.expert import (
 from paddlebox_tpu.parallel.sequence import (
     apply_rotary,
     full_attention,
+    kernel_residuals,
     rotary_tables,
 )
 from paddlebox_tpu.telemetry import metrics as _tm
@@ -963,13 +969,17 @@ class DecoderMoeLM:
         return loss, preds, jnp.stack(counts)
 
     def _stack(self, params: dict, x: jax.Array, valid: jax.Array) -> tuple:
-        """The layers once, each rematerialised in the backward pass.
+        """The layers once, each rematerialised in the backward pass: a
+        layer keeps its input and, where its attention came as the kernel,
+        the kernel's output and log-sum-exp (``kernel_residuals``: on a TPU
+        the forward kernel runs once a step; elsewhere no policy is named).
         Returns (x, the two sums of ``_layer`` over the layers)."""
         moe = jnp.zeros((2,), jnp.float32)
+        keep = kernel_residuals()
         for lp, kinds in zip(params["layers"],
                              zip(self.layer_types, self.mlp_types)):
-            x, m = jax.checkpoint(self._layer, static_argnums=(3,))(
-                lp, x, valid, kinds)
+            x, m = jax.checkpoint(self._layer, static_argnums=(3,),
+                                  policy=keep)(lp, x, valid, kinds)
             moe = moe + m
         return x, moe
 
@@ -977,7 +987,9 @@ class DecoderMoeLM:
         """The stack ``loops`` times over the same leaves, as ONE traced
         body (a ``lax.scan`` over the rounds: the compiler sees the layers
         once, a leaf's gradient is the sum over its uses, and the backward
-        pass keeps a boundary a layer and round): ``_stack``, then
+        pass keeps a boundary a layer and round, stacked over the rounds --
+        with it, on a TPU, an attention kernel's output and log-sum-exp a
+        layer and round): ``_stack``, then
         ``norm_f`` at the end of every round, its output the next round's
         input.  Returns the last round's normed state [B, T, H]; under the
         looped-exit objective every round's normed state [R, B, T, H] and

@@ -24,6 +24,16 @@ What a layer keeps of its attention for the backward is therefore q, k and
 v as they were given, the output as it was returned and the log-sum-exp
 ([B, H, T] float32); never a probability, and never a second copy.
 
+Of those five only the output and the log-sum-exp are the kernel's own to
+make, so the forward rule names them (``ATTN_OUT``, ``ATTN_LSE``:
+``checkpoint_name``).  A caller that rematerialises a whole layer
+(``jax.checkpoint``) and says nothing keeps the layer's input alone and runs
+the forward kernel a second time to have them again; one whose policy saves
+the two names (parallel/sequence.py ``kernel_residuals``) keeps them beside
+the input, remakes q, k and v -- projections, norms, rotary codes -- and
+runs the forward kernel once.  ``attn.kept`` counts the traced calls whose
+forward rule named them.
+
 The mask is one of the three descriptions of ``full_attention`` -- ``causal``,
 ``window``, ``block_diffusion`` -- and is never passed: from the description
 and the block sizes a table is made at trace time that lists, for every
@@ -50,10 +60,23 @@ from typing import NamedTuple, Optional
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.ad_checkpoint import checkpoint_name
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from paddlebox_tpu.telemetry import metrics as _tm
+
 CAUSAL, WINDOW, BLOCK_DIFFUSION = "causal", "window", "block_diffusion"
+# what the forward rule names for a rematerialising caller's policy: the
+# two residuals of the backward that only the kernel can make
+ATTN_OUT, ATTN_LSE = "attn_out", "attn_lse"
+
+_KEPT = _tm.counter(
+    "attn.kept", "traced calls of the flash-form kernel whose forward rule "
+    "named its output and log-sum-exp (ATTN_OUT, ATTN_LSE) for the "
+    "backward, by the mask (causal, window, block_diffusion): under a "
+    "layer's checkpoint whose policy saves the two names the forward "
+    "kernel runs once a step, not twice")
 
 # a masked score: finite, so a row whose first tile shows it nothing reads
 # exp(0) there and not exp(-inf - -inf); every row sees itself under each
@@ -521,12 +544,25 @@ def _shape_of(q, k, v, spec: Spec) -> _Shape:
 def flash_attention(q, k, v, spec: Spec):
     """q [B, T, H, D], k [B, Tk, Hkv, D], v [B, Tk, Hkv, Dv] -> [B, T, H,
     Dv] in q's dtype, under the mask ``spec`` describes."""
-    return _flash_fwd(q, k, v, spec)[0]
+    return _kernel(q, k, v, spec)[0].reshape(*q.shape[:3], v.shape[3])
+
+
+def _kernel(q, k, v, spec: Spec):
+    """(the output as the kernel writes it, [B, T, H * Dv], and lse)."""
+    return _forward(_flat(q), _flat(k), _flat(v), spec,
+                    _shape_of(q, k, v, spec))
 
 
 def _flash_fwd(q, k, v, spec: Spec):
-    out, lse = _forward(_flat(q), _flat(k), _flat(v), spec,
-                        _shape_of(q, k, v, spec))
+    out, lse = _kernel(q, k, v, spec)
+    # named: what a rematerialised layer may keep in place of a second run
+    # of the kernel (identities unless a checkpoint's policy asks for them).
+    # The output is named as the kernel wrote it, flat: kept as [B, T, H,
+    # Dv] it would lie tiled by heads, a relayout of the whole array each
+    # way between the kernels (3-5 ms a layer at 268 MB: PERF.md section 6,
+    # PR 48)
+    _KEPT.inc(mask=spec.kind)
+    out, lse = checkpoint_name(out, ATTN_OUT), checkpoint_name(lse, ATTN_LSE)
     out = out.reshape(*q.shape[:3], v.shape[3])
     # kept for the backward: the operands as given, the output as
     # returned, a row's log-sum-exp -- no copy of any of them

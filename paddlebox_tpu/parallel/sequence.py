@@ -38,7 +38,11 @@ VMEM only, the output and a row's log-sum-exp the only things written; q,
 k and v read where they lie, nothing copied around the call); everywhere
 else -- every other backend, and the kernel's oracle -- the strips below,
 each two products with a softmax between.  ``attn.form`` counts which form
-a traced call took.  ``rotary_tables`` / ``apply_rotary`` are the
+a traced call took.  ``kernel_residuals`` tells a caller that rematerialises
+a whole layer what to keep of the layer's attention, from the backend alone:
+where the form may be the kernel, its output and log-sum-exp, so that the
+forward kernel is not run a second time; elsewhere nothing.
+``rotary_tables`` / ``apply_rotary`` are the
 rotary position code, plain and YaRN, pairing dimension i with i + D / 2
 or, ``interleaved``, 2i with 2i + 1; a caller that turns part of a head
 hands them that slice.  The ring and Ulysses forms take ``causal`` only: a
@@ -131,13 +135,37 @@ DEFAULT_BLOCK_Q = 256
 _KEY_ALIGN = 128  # a strip's first key sits on a lane-tile boundary
 
 
+def _kernel_backend() -> bool:
+    """Whether the blockwise form may come as the kernel at all: on a TPU."""
+    return jax.default_backend() == "tpu"
+
+
+def kernel_residuals():
+    """What a caller that rematerialises a whole layer (``jax.checkpoint``)
+    should keep of the layer's attention beside the layer's input, as a
+    checkpoint policy, or None where nothing: on a backend whose blockwise
+    form may be the kernel, the two residuals of its backward that only the
+    kernel can make, its output and a row's log-sum-exp -- q, k and v are
+    projections, norms and rotary codes away from the input, but the output
+    costs the forward kernel again.  The kernel's forward rule names the
+    two (parallel/flash_attention.py); a call that fell back to the strips
+    names nothing, so it keeps nothing and costs nothing.  Elsewhere no
+    policy is named and the caller's program is what it was: the answer
+    follows the backend alone, as the form does."""
+    if not _kernel_backend():
+        return None
+    from paddlebox_tpu.parallel import flash_attention
+    return jax.checkpoint_policies.save_only_these_names(
+        flash_attention.ATTN_OUT, flash_attention.ATTN_LSE)
+
+
 def _attention_form(q: jax.Array, k: jax.Array, v: jax.Array,
                     mask: str) -> tuple:
     """("kernel", (block_q, block_kv, key-value heads a grid step)) where
     the flash-form kernel runs the blockwise form, ("strips", why not) where
     the strips do: the kernel is a TPU's, takes the three described masks,
     one dtype for q, k and v, and lengths that one of its blocks divides."""
-    if jax.default_backend() != "tpu":
+    if not _kernel_backend():
         return "strips", "not a TPU"
     if mask == "none":
         return "strips", "no mask: the kernel takes the described three"

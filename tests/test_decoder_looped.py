@@ -490,3 +490,45 @@ def test_the_looped_stack_trains_through_the_pass_loop(tmp_path):
     ds.close()
     ds1.close()
     trainer.close()
+
+
+# ------------------------------------------------ what a layer keeps on a TPU
+@pytest.mark.parametrize("R", [1, 3])
+def test_a_layer_keeps_its_kernels_output_on_a_tpu_only(monkeypatch, R):
+    """``_stack`` asks ``sequence.kernel_residuals`` what a layer's
+    checkpoint keeps, and the answer follows the backend alone.  As a TPU
+    answers (the backend's name patched; traced, not run): every layer's
+    attention is the kernel and the gradient's jaxpr holds 3
+    ``pallas_call``s a layer -- forward, dq, dk/dv, the forward not again
+    when the layer is rematerialised -- with the rounds as one ``lax.scan``
+    body as without them.  On the CPU no policy is named and there is no
+    kernel: the step is the text it was, which is what
+    tests/test_decoder_kda.py's sha256 pins of the four accepted
+    descriptions guard, unedited, for this change."""
+    from jax._src.core import jaxprs_in_params
+    from paddlebox_tpu.parallel import sequence
+    t = 128  # a length the kernel's block divides
+    loop = {} if R > 1 else dict(
+        loops=1, objective="next_token", exit=None, sandwich=False)
+    rows = jnp.ones((B * t, H + 2))
+    feed = {"seq_pos": jnp.arange(B * t, dtype=jnp.int32).reshape(B, t),
+            "key_class": jnp.arange(B * t, dtype=jnp.int32) % V}
+
+    def calls(jaxpr) -> int:  # those of inner jaxprs too
+        return sum((eqn.primitive.name == "pallas_call") + sum(
+            calls(sub) for sub in jaxprs_in_params(eqn.params))
+            for eqn in jaxpr.eqns)
+
+    def kernel_calls() -> int:
+        """``pallas_call``s of the gradient, of a model of its own: a
+        traced layer is remembered."""
+        model = make_model(R, max_seq_len=t, **loop)
+        return calls(jax.make_jaxpr(jax.grad(
+            lambda p: model.loss(p, rows, feed)[0]))(
+                model.init(jax.random.PRNGKey(0))).jaxpr)
+
+    assert sequence.kernel_residuals() is None
+    assert kernel_calls() == 0
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert sequence.kernel_residuals() is not None
+    assert kernel_calls() == 3 * LAYERS

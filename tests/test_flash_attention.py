@@ -11,7 +11,14 @@
   * the choice of form: strips on the CPU (the lowered text holds no custom
     call), the kernel on a TPU for shapes it takes, the strips -- saying
     why -- for those it does not, ``attn.form`` counting each traced call;
-  * the kernels compile for a described v5e at the five cells' real shapes
+  * what a rematerialised layer keeps: under the policy a TPU names
+    (``sequence.kernel_residuals``) the kernel's output and log-sum-exp
+    beside the layer's input, so the gradient holds the forward kernel once
+    and not twice, to the same bits; around the strips the policy keeps
+    nothing; ``attn.kept`` counts each traced forward rule;
+  * the kernels compile for a described v5e at the six cells' real shapes,
+    and so does each cell's whole ``train.step``: one forward kernel a
+    layer, under the memory the chip has
     (no chip: the TPU's compiler alone; skipped where it is not installed).
 """
 
@@ -275,7 +282,148 @@ def test_form_counts_once_a_traced_call():
     assert _counted(before) == {("strips", "block_diffusion"): 1}
 
 
-# the five decoder cells' attention: mask, its number, B, T, H, Hkv, D, Dv
+# ------------------------------------------ what a rematerialised layer keeps
+LAYER_H = 64  # the toy layer's width; its heads are the kernel's own sizes
+
+
+def _layer_inputs(g=2, d=128, t=256):
+    ks = jax.random.split(jax.random.PRNGKey(5), 5)
+    w = {name: 0.1 * jax.random.normal(k, shape) for name, k, shape in (
+        ("q", ks[0], (LAYER_H, g * d)), ("k", ks[1], (LAYER_H, d)),
+        ("v", ks[2], (LAYER_H, d)), ("o", ks[3], (g * d, LAYER_H)))}
+    return w, jax.random.normal(ks[4], (1, t, LAYER_H))
+
+
+def _layer(attend):
+    """A layer as the decoder's: q, k and v projected from the input, one
+    key-value head, the attention, the output projection, the residual."""
+    def layer(w, x):
+        b, t, _ = x.shape
+        q, k, v = ((x @ w[n]).reshape(b, t, -1, w["k"].shape[1])
+                   for n in "qkv")
+        return x + attend(q, k, v).reshape(b, t, -1) @ w["o"]
+    return layer
+
+
+def _kernel_layer():
+    spec = _spec("causal", interpret=True)
+    return _layer(lambda q, k, v: fa.flash_attention(q, k, v, spec))
+
+
+def _tpu_policy(monkeypatch):
+    """The policy as a TPU names it; the CPU names none."""
+    assert sq.kernel_residuals() is None
+    with monkeypatch.context() as m:
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        policy = sq.kernel_residuals()
+    assert policy is not None
+    return policy
+
+
+def _loss(layer, policy, rounds=None):
+    """The sum of the layer's output under ``jax.checkpoint`` (a loss that
+    saves nothing of its own); with ``rounds`` the layer that many times
+    over the same weights as the body of a ``lax.scan`` (the looped
+    decoder's ``_rounds``)."""
+    def loss(w, x):
+        f = jax.checkpoint(layer, policy=policy)
+        if rounds is not None:
+            x, _ = jax.lax.scan(lambda x, _: (f(w, x), None), x, None,
+                                length=rounds)
+        else:
+            x = f(w, x)
+        return x.sum()
+    return loss
+
+
+def _kernel_calls(f, *args) -> int:
+    """``pallas_call``s of ``f``'s jaxpr, those of its inner jaxprs too."""
+    from jax._src.core import jaxprs_in_params
+
+    def calls(jaxpr):
+        return sum((eqn.primitive.name == "pallas_call") + sum(
+            calls(sub) for sub in jaxprs_in_params(eqn.params))
+            for eqn in jaxpr.eqns)
+    return calls(jax.make_jaxpr(f)(*args).jaxpr)
+
+
+def _residuals(loss, w, x) -> list:
+    """(shape, how it is described) of what the gradient saves beside
+    its arguments."""
+    from jax._src.ad_checkpoint import saved_residuals
+    return [(tuple(a.shape), why) for a, why in saved_residuals(loss, w, x)
+            if "argument" not in why and "constant" not in why]
+
+
+@pytest.mark.parametrize("rounds", [None, 3])
+def test_a_layers_checkpoint_keeps_the_kernels_output(monkeypatch, rounds):
+    """Under the policy the gradient's jaxpr holds 3 ``pallas_call``s an
+    attending layer -- forward, dq, dk/dv -- where it holds 4 without it
+    (the forward again when the layer is rematerialised); what is saved is
+    the layer's input (the argument itself, or the rounds' inputs stacked)
+    and, only under the policy, the output -- flat, [B, T, H * Dv], the
+    kernel's own array and no relayout of it -- and the log-sum-exp; as the
+    body of a ``lax.scan`` both stacked [R, ...], one a round."""
+    w, x = _layer_inputs()
+    policy = _tpu_policy(monkeypatch)
+    stacked = () if rounds is None else (rounds,)
+    carried = [] if rounds is None else [stacked + x.shape]
+    for keep, calls, kept in (
+            (None, 4, []),
+            (policy, 3, [stacked + (1, 256, 2 * 128),  # as the kernel wrote
+                         stacked + (1, 1, 2, 256)])):
+        loss = _loss(_kernel_layer(), keep, rounds)
+        assert _kernel_calls(jax.grad(loss), w, x) == calls
+        saved = _residuals(loss, w, x)
+        assert sorted(s for s, _ in saved) == sorted(kept + carried)
+        if keep is not None and rounds is None:
+            assert any(fa.ATTN_LSE in why for _, why in saved)
+
+
+def test_the_policy_changes_no_bit_of_the_gradient(monkeypatch):
+    """The same kernel output is used, once: gradients by the weights and
+    by the input with and without the policy are bit-equal."""
+    w, x = _layer_inputs()
+    policy = _tpu_policy(monkeypatch)
+    want, got = (jax.jit(jax.grad(_loss(_kernel_layer(), keep), (0, 1)))(
+        w, x) for keep in (None, policy))
+    for a, b in zip(jax.tree.leaves(want), jax.tree.leaves(got)):
+        np.testing.assert_array_equal(np.asarray(a), np.asarray(b))
+        assert np.abs(np.asarray(a)).max() > 0
+
+
+def test_the_policy_keeps_nothing_of_the_strips(monkeypatch):
+    """Only the kernel's forward rule names anything: a layer whose
+    attention fell back to the strips saves under the policy what it saves
+    without it, the layer's input alone."""
+    w, x = _layer_inputs(d=16, t=192)
+    policy = _tpu_policy(monkeypatch)
+    for keep in (None, policy):
+        loss = _loss(_layer(_strips("causal")), keep)
+        assert _kernel_calls(jax.grad(loss), w, x) == 0
+        assert _residuals(loss, w, x) == []
+
+
+def test_kept_counts_once_a_traced_forward_rule():
+    """``attn.kept`` counts where the names are applied, in the kernel's
+    forward rule: once a traced call that is differentiated, whatever its
+    caller's policy, by the mask; a call that is only evaluated names
+    nothing and counts nothing."""
+    kept = metrics.counter("attn.kept")
+    w, x = _layer_inputs()
+    spec = _spec("window", interpret=True)
+    layer = _layer(lambda q, k, v: fa.flash_attention(q, k, v, spec))
+    before = kept.value(mask="window"), kept.value(mask="causal")
+    jax.make_jaxpr(layer)(w, x)
+    assert kept.value(mask="window") == before[0]
+    f = jax.jit(jax.grad(_loss(layer, None)))
+    for _ in range(2):
+        f(w, x)
+    assert kept.value(mask="window") == before[0] + 1
+    assert kept.value(mask="causal") == before[1]
+
+
+# the six decoder cells' attention: mask, its number, B, T, H, Hkv, D, Dv
 CELLS = {
     "mellum2_full": ("causal", None, 4, 4096, 32, 4, 128, 128),
     "mellum2_window": ("window", 1024, 4, 4096, 32, 4, 128, 128),
@@ -283,6 +431,7 @@ CELLS = {
     "lfm2": ("causal", None, 4, 4096, 32, 8, 64, 64),
     "kimi_linear": ("causal", None, 1, 8192, 32, 32, 192, 128),
     "sdar": ("block_diffusion", 4, 2, 8192, 32, 4, 128, 128),
+    "ouro": ("causal", None, 1, 4096, 16, 16, 128, 128),
 }
 
 
@@ -342,3 +491,108 @@ def test_kernels_compile_for_a_v5e(one_chip, cell):
     ).lower(shape(b, t, h, d), shape(b, t, hkv, d),
             shape(b, t, hkv, dv)).compile()
     assert compiled.as_text().count("tpu_custom_call") >= 3
+
+
+# each decoder cell's whole step: the attending layers of its stack (a
+# looped stack's rounds are one ``lax.scan`` body: its layers once), the
+# other kernel calls of the step (kimi_linear's KDA kernels: pairs and scan,
+# forward, rematerialised and backward, in four layers), and the most the
+# compiler may count for it in GB (PERF.md section 6, PR 48: this change's
+# counts and a few percent of room; five under the 14.5 at which
+# kimi_linear's boundary was read sound, PR 44, and ouro, the fullest step
+# there is, at 14.85 of the chip's 16.9)
+STEPS = {
+    "mellum2_ep8_train_4k": (4, 0, 10.5),
+    "kanana2_ep16_train_4k": (5, 0, 11.2),
+    "sdar_ep16_denoise_4k": (6, 0, 11.6),
+    "lfm2_ep8_train_4k": (1, 0, 11.9),
+    "kimi_linear_ep32_train_8k": (1, 24, 13.8),
+    "ouro_loop4_train_4k": (8, 0, 15.2),
+}
+
+
+def _cell_step(workload: str, chip, monkeypatch, work):
+    """The cell's ``train.step`` as ``Trainer`` jits it on a TPU, compiled
+    for the described chip from shapes alone: the cell's own model, table
+    and feed at full size (one batch of random tokens through the dataset
+    and the table's plan), the dense parameters and Adam's state as
+    ``jax.eval_shape`` gives them."""
+    import importlib
+
+    import optax
+    from benchmark import run as harness
+    from paddlebox_tpu.config import SparseTableConfig, TrainerConfig
+    from paddlebox_tpu.data.dataset import DatasetFactory
+    from paddlebox_tpu.sparse.table import SparseTable
+    from paddlebox_tpu.train.trainer import Trainer, _host_batch_dict
+
+    cfg = harness.Cell.resolve(workload).cfg
+    tconf = SparseTableConfig(embedding_dim=cfg["embedding_dim"],
+                              hbm_cache_rows=cfg["hbm_cache_rows"])
+    model = importlib.import_module(
+        "benchmark.models." + cfg["model"]).build(cfg, tconf)
+    init, adam = model.init, optax.adam
+
+    def shapes_of_adam(lr):
+        tx = adam(lr)
+        return optax.GradientTransformation(
+            lambda params: jax.eval_shape(tx.init, params), tx.update)
+
+    with monkeypatch.context() as m:  # no parameter is ever made
+        m.setattr(model, "init", lambda key: jax.eval_shape(init, key))
+        m.setattr(optax, "adam", shapes_of_adam)
+        trainer = Trainer(model, tconf, TrainerConfig(), seed=0)
+    b, t = cfg["batch_size"], cfg["feed"]["max_seq_len"]
+    vocab = np.asarray(model.vocab_keys)
+    path = work / "part-0"
+    with open(path, "w") as f:
+        for seq in np.random.default_rng(3).integers(0, len(vocab), (b, t)):
+            keys = " ".join(str(int(vocab[i])) for i in seq)
+            f.write(f"1 1 {t} {keys} 1 0.25\n")
+    ds = DatasetFactory().create_dataset(
+        "BoxPSDataset", harness.feed_config(cfg))
+    ds.set_filelist([str(path)])
+    ds.load_into_memory()
+    table = SparseTable(tconf, seed=0)
+    table.begin_pass(ds.unique_keys())
+    batch = next(iter(ds.batches()))
+    args = jax.tree.map(
+        lambda a: jax.ShapeDtypeStruct(np.shape(a), a.dtype, sharding=chip),
+        (trainer.params, trainer.opt_state, table.values, table.g2sum,
+         trainer._init_mstate(), _host_batch_dict(
+             batch, table.plan_batch(batch), batch.n_sparse_slots,
+             vocab_keys=model.vocab_keys)))
+    table.abort_pass()
+    ds.close()
+    trainer.close()
+    with monkeypatch.context() as m:  # the forms and options a TPU takes
+        m.setattr(jax, "default_backend", lambda: "tpu")
+        return trainer._build_step().lower(*args).compile()
+
+
+@pytest.mark.parametrize("workload", list(STEPS))
+def test_a_cells_step_runs_the_forward_kernel_once_a_layer(
+        one_chip, monkeypatch, tmp_path, workload):
+    """The whole ``train.step`` of each decoder cell, compiled for a
+    described v5e: an attending layer is three kernel calls -- forward, dq,
+    dk/dv -- and none of them in the rematerialised half of the backward
+    pass (the layer's checkpoint kept the forward's output and log-sum-exp);
+    and the step stays under the memory the cell has had on the chip, so a
+    change that fills a cell fails here and not at the pass boundary there
+    (PERF.md section 6, PR 43: 17.3 GB doubled ``pass_gap_ms``)."""
+    import re
+    layers, others, most_gb = STEPS[workload]
+    compiled = _cell_step(workload, one_chip, monkeypatch, tmp_path)
+    calls = [re.search(r'op_name="([^"]*)"', line).group(1)
+             for line in compiled.as_text().splitlines()
+             if 'custom_call_target="tpu_custom_call"' in line]
+    attn = [name for name in calls if "/attn" in name]
+    assert len(calls) - len(attn) == others
+    forward = [name for name in attn if "transpose(" not in name]
+    again = [name for name in attn if "rematted_computation" in name]
+    assert (len(forward), len(again), len(attn)) == (layers, 0, 3 * layers)
+    m = compiled.memory_analysis()
+    total = (m.argument_size_in_bytes + m.output_size_in_bytes
+             - m.alias_size_in_bytes + m.temp_size_in_bytes
+             + m.generated_code_size_in_bytes)
+    assert total <= most_gb * 1e9, total
