@@ -2,8 +2,9 @@
 
 The reference keeps its whole data layer in C++ because host feed was the
 production bottleneck (SURVEY.md §2.4); here the parser, the batch
-builder's key pack and the batch planner are native and the rest of the
-pipeline stays numpy (already vectorized).  The
+builder's key pack, the batch planner and the row cache's directory work
+at a pass boundary (the census resolve and the touch of its hits) are
+native and the rest of the pipeline stays numpy (already vectorized).  The
 shared library builds on demand with g++ (no pybind11 in the image — plain
 C ABI + ctypes) into a file named by a hash of its source and build flags,
 so a binary is only ever loaded if it was built from exactly this source
@@ -291,7 +292,11 @@ def get_plan_lib():
         if so is None:
             return None
         lib = ctypes.CDLL(so)
-        _bind_plan_symbols(lib)
+        try:
+            _bind_plan_symbols(lib)
+        except AttributeError as e:  # not the library of this source
+            logger.warning("native planner %s lacks a symbol: %s", so, e)
+            return None
         _plan_lib = lib
         return _plan_lib
 
@@ -299,7 +304,9 @@ def get_plan_lib():
 def require_native() -> dict:
     """{"parser": bool, "planner": bool} — which native libraries the
     flags ask for AND are loaded ("parser" is the data layer's library:
-    the parser and the batch builder's key pack).  Raises when a flag
+    the parser and the batch builder's key pack; "planner" the batch
+    planner's, which also holds the row cache's directory resolve and
+    touch).  Raises when a flag
     asks for one that did not build: entry points that measure or prove
     the system must not run on the 4-5x slower Python fallback unnoticed."""
     from paddlebox_tpu.config import flags
@@ -346,6 +353,17 @@ def _bind_plan_symbols(lib) -> None:
         ctypes.POINTER(ctypes.c_uint64), ctypes.c_int64, ctypes.c_int64,
         ctypes.POINTER(ctypes.c_int32), ctypes.POINTER(ctypes.c_uint64),
         ctypes.POINTER(ctypes.c_int64),
+    ]
+    lib.pbx_cache_lookup.restype = ctypes.c_int64
+    lib.pbx_cache_lookup.argtypes = (
+        [ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+         ctypes.c_void_p, ctypes.c_int64, ctypes.c_void_p, ctypes.c_int64]
+        + [ctypes.c_void_p] * 3
+    )
+    lib.pbx_cache_touch.restype = None
+    lib.pbx_cache_touch.argtypes = [
+        ctypes.c_void_p, ctypes.c_void_p, ctypes.c_void_p, ctypes.c_int64,
+        ctypes.c_double, ctypes.c_int64,
     ]
 
 
@@ -456,3 +474,61 @@ def dedup_rows_native(rows: np.ndarray):
     i32p = lambda a: a.ctypes.data_as(ctypes.POINTER(ctypes.c_int32))
     n_uniq = lib.pbx_dedup_rows(i32p(rows), n, i32p(inverse), i32p(uniq))
     return inverse, uniq[:n_uniq]
+
+
+# --------------------------------------------------------------------------- #
+# The row cache's directory at a pass boundary (HbmCache.lookup / touch):
+# same library as the planner, same flag
+# --------------------------------------------------------------------------- #
+# plan_resolve.cpp kDirStride: the directory's sample holds every 16th of
+# its sorted keys (17 MB at the CTR cells' 33.8 M rows)
+DIRECTORY_STRIDE = 16
+
+
+def _directory_lib():
+    from paddlebox_tpu.config import flags
+
+    return get_plan_lib() if flags.use_native_planner else None
+
+
+def cache_lookup_native(sorted_keys: np.ndarray, sorted_slots: np.ndarray,
+                        sample: np.ndarray, pk: np.ndarray):
+    """Sorted unique ``pk`` against a cache directory's sorted view in one
+    native merge (GIL released): (hit_mask bool [n], hit_pos int32 [H]
+    ascending, hit_slots int32 [H]), equal to ``HbmCache.lookup``'s numpy
+    form to the element; None when the planner's library is off or did not
+    build.  ``sample`` is ``sorted_keys[::DIRECTORY_STRIDE]``; the three
+    directory arrays are the cache's own (contiguous uint64 / int32)."""
+    lib = _directory_lib()
+    if lib is None:
+        return None
+    pk = np.ascontiguousarray(pk, dtype=np.uint64)
+    n = pk.shape[0]
+    hit_mask = np.empty(n, dtype=bool)
+    hit_pos = np.empty(n, dtype=np.int32)
+    hit_slots = np.empty(n, dtype=np.int32)
+    n_hits = lib.pbx_cache_lookup(
+        sorted_keys.ctypes.data, sorted_slots.ctypes.data,
+        sorted_keys.shape[0], sample.ctypes.data, sample.shape[0],
+        pk.ctypes.data, n, hit_mask.ctypes.data, hit_pos.ctypes.data,
+        hit_slots.ctypes.data,
+    )
+    if n_hits < 0:
+        raise ValueError(
+            f"a sample of {sample.shape[0]} keys is not every "
+            f"{DIRECTORY_STRIDE}th of a directory of {sorted_keys.shape[0]}")
+    return hit_mask, hit_pos[:n_hits], hit_slots[:n_hits]
+
+
+def cache_touch_native(freq: np.ndarray, last_seen: np.ndarray,
+                       slots: np.ndarray, unit: float, tick: int) -> bool:
+    """``freq[slots] += unit; last_seen[slots] = tick`` over DISTINCT
+    int32 ``slots`` in one native pass; False (nothing written) when the
+    planner's library is off or did not build."""
+    lib = _directory_lib()
+    if lib is None:
+        return False
+    slots = np.ascontiguousarray(slots, dtype=np.int32)
+    lib.pbx_cache_touch(freq.ctypes.data, last_seen.ctypes.data,
+                        slots.ctypes.data, slots.shape[0], unit, tick)
+    return True

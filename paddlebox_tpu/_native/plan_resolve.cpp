@@ -38,9 +38,16 @@
 // *n_uniq_out whether they fit: the outputs are a plan when n_uniq <= U-1
 // (slot U-1 is the padding's and stays non-live) or U == K; otherwise
 // slots >= U were not written and the caller asks again with a larger U.
+//
+// The same library holds the row cache's directory work of a pass boundary
+// (sparse/engine/hbm_cache.py lookup / touch): pbx_cache_lookup and
+// pbx_cache_touch, at the end of this file.
 
+#include <algorithm>
 #include <cstdint>
 #include <cstring>
+#include <system_error>
+#include <thread>
 #include <vector>
 
 namespace {
@@ -272,6 +279,158 @@ long long pbx_dedup_rows(const int* rows, long long n,
     inverse[i] = (int)slot;
   }
   return n_uniq;
+}
+
+}  // extern "C"
+
+// --------------------------------------------------------------------- //
+// The row cache's directory at a pass boundary (HbmCache.lookup / touch).
+//
+// lookup: a sorted unique census against the directory's sorted view
+// (keys ascending, a slot beside each).  numpy's form is one binary search
+// a census key, ~25 dependent cache misses each through an array far
+// beyond the caches.  Both arrays ascend, so this is a merge: the census
+// walks forward through ``sample`` (every kDirStride-th directory key, a
+// sequential read), which names the one block of kDirStride keys that can
+// hold the key; the block's key and slot lines are prefetched kDirAhead
+// census keys before they are read, so the misses overlap instead of
+// queueing.  A long census is cut into a few ranges, one a thread: each
+// finds its start with one binary search of the sample, writes hit_mask in
+// place and its hits at its range's offset, and the hits are closed up
+// after the join.  Output equals numpy's to the element.
+//
+// touch: the two indexed writes over the hits' slots (distinct, so the
+// ranges of two threads never meet), prefetched the same way.
+// --------------------------------------------------------------------- //
+
+namespace {
+
+constexpr long long kDirStride = 16;  // _native.DIRECTORY_STRIDE
+constexpr int kDirAhead = 32;         // a power of two
+constexpr long long kPerThread = 32768;  // census keys that pay for a thread
+constexpr long long kMaxThreads = 4;
+
+int threads_for(long long n) {
+  const long long cores = (long long)std::thread::hardware_concurrency();
+  const long long t = std::min({kMaxThreads, n / kPerThread, cores});
+  return (int)std::max(1LL, t);
+}
+
+// f(t, a, b) over T contiguous ranges of [0, n), range 0 on this thread
+template <class F>
+void run_ranges(int T, long long n, F f) {
+  std::vector<std::thread> workers;
+  workers.reserve((size_t)T);
+  for (int t = 1; t < T; ++t) {
+    const long long a = n * t / T, b = n * (t + 1) / T;
+    try {
+      workers.emplace_back(f, t, a, b);
+    } catch (const std::system_error&) {  // no thread to be had: run it here
+      f(t, a, b);
+    }
+  }
+  f(0, 0, n / T);
+  for (auto& w : workers) w.join();
+}
+
+// census [a, b): hit[] in place, the hits' positions and slots from index a
+long long lookup_range(const unsigned long long* sk, const int* ss,
+                       long long N, const unsigned long long* sample,
+                       long long M, const unsigned long long* pk,
+                       long long a, long long b, unsigned char* hit,
+                       int* hit_pos, int* hit_slots) {
+  // j: the last sample <= the key being placed, -1 below the first (and
+  // for every key of an empty directory)
+  long long j = (std::upper_bound(sample, sample + M, pk[a]) - sample) - 1;
+  auto place = [&](unsigned long long k) {
+    while (j + 1 < M && sample[j + 1] <= k) ++j;
+    if (j >= 0) {
+      const long long base = j * kDirStride;
+      __builtin_prefetch(sk + base);
+      __builtin_prefetch(sk + base + kDirStride / 2);
+      __builtin_prefetch(sk + base + kDirStride - 1);
+      __builtin_prefetch(ss + base);
+      __builtin_prefetch(ss + base + kDirStride - 1);
+    }
+    return j;
+  };
+  long long block[kDirAhead];  // ring: the block of census key i + r
+  for (long long t = 0; t < kDirAhead && a + t < b; ++t)
+    block[t] = place(pk[a + t]);
+  long long h = a;
+  for (long long i = a; i < b; ++i) {
+    const unsigned long long k = pk[i];
+    const long long r = (i - a) & (kDirAhead - 1);
+    const long long jj = block[r];
+    if (i + kDirAhead < b) block[r] = place(pk[i + kDirAhead]);
+    bool found = false;
+    long long pos = 0;
+    if (jj >= 0) {
+      const long long base = jj * kDirStride;
+      const long long end = std::min(N, base + kDirStride);
+      pos = base;
+      for (long long q = base; q < end; ++q) pos += sk[q] < k;
+      found = pos < end && sk[pos] == k;
+    }
+    hit[i] = found;
+    if (found) {
+      hit_pos[h] = (int)i;
+      hit_slots[h] = ss[pos];
+      ++h;
+    }
+  }
+  return h - a;
+}
+
+}  // namespace
+
+extern "C" {
+
+// Outputs (preallocated, length n): hit_mask[i] for every census key;
+// hit_pos / hit_slots filled for the first H entries.  Returns H, or -1
+// when ``sample`` is not every kDirStride-th of the n_dir keys.
+long long pbx_cache_lookup(
+    const unsigned long long* sorted_keys, const int* sorted_slots,
+    long long n_dir, const unsigned long long* sample, long long n_sample,
+    const unsigned long long* census, long long n,
+    unsigned char* hit_mask, int* hit_pos, int* hit_slots) {
+  if (n < 0 || n_dir < 0 ||
+      n_sample != (n_dir + kDirStride - 1) / kDirStride)
+    return -1;
+  if (n == 0) return 0;
+  const int T = threads_for(n);
+  std::vector<long long> hits((size_t)T, 0);
+  run_ranges(T, n, [&](int t, long long a, long long b) {
+    hits[(size_t)t] = lookup_range(sorted_keys, sorted_slots, n_dir, sample,
+                                   n_sample, census, a, b, hit_mask, hit_pos,
+                                   hit_slots);
+  });
+  long long h = hits[0];
+  for (int t = 1; t < T; ++t) {  // close the hits up: range t began at a
+    const long long a = n * t / T, c = hits[(size_t)t];
+    if (a != h) {
+      std::memmove(hit_pos + h, hit_pos + a, (size_t)c * sizeof(int));
+      std::memmove(hit_slots + h, hit_slots + a, (size_t)c * sizeof(int));
+    }
+    h += c;
+  }
+  return h;
+}
+
+// freq[slots] += unit; last_seen[slots] = tick, over distinct slots.
+void pbx_cache_touch(double* freq, long long* last_seen, const int* slots,
+                     long long n, double unit, long long tick) {
+  if (n <= 0) return;
+  run_ranges(threads_for(n), n, [=](int, long long a, long long b) {
+    for (long long i = a; i < b; ++i) {
+      if (i + kDirAhead < b) {
+        __builtin_prefetch(freq + slots[i + kDirAhead], 1);
+        __builtin_prefetch(last_seen + slots[i + kDirAhead], 1);
+      }
+      freq[slots[i]] += unit;
+      last_seen[slots[i]] = tick;
+    }
+  });
 }
 
 }  // extern "C"

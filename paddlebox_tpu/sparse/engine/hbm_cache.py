@@ -58,11 +58,22 @@ import jax.numpy as jnp
 import numpy as np
 
 from paddlebox_tpu import telemetry
+from paddlebox_tpu._native import (
+    DIRECTORY_STRIDE,
+    cache_lookup_native,
+    cache_touch_native,
+)
 from paddlebox_tpu.utils.profiler import StatsProfiler
 
 # the directory's share of a pass boundary, by stage (lookup / touch /
 # plan_update / commit): pass.stage_seconds, pbox.pass.<stage> on a trace
 _PASS = StatsProfiler("pass.stage_seconds")
+
+_LOOKUPS = telemetry.counter(
+    "cache.lookups",
+    "HbmCache.lookup calls by what resolved the census: form=native, one "
+    "merge over the two sorted arrays in the planner's library; form=numpy, "
+    "a binary search a key where that library is off or did not build")
 
 _EMPTY_U64 = np.empty(0, dtype=np.uint64)
 _EMPTY_I32 = np.empty(0, dtype=np.int32)
@@ -138,9 +149,12 @@ class HbmCache:
         self.last_seen = np.full(self.capacity, -1, dtype=np.int64)
         self.dirty = np.zeros(self.capacity, dtype=bool)
         self.tick = 0
-        # sorted view for the key→slot resolve (rebuilt on membership change)
+        # sorted view for the key→slot resolve (rebuilt on membership
+        # change); the sample, every DIRECTORY_STRIDE-th sorted key, is what
+        # the native resolve walks to name a census key's block of the view
         self._sorted_keys = _EMPTY_U64
         self._sorted_slots = _EMPTY_I32
+        self._sorted_sample = _EMPTY_U64
 
     # -- introspection ---------------------------------------------------- #
     @property
@@ -179,12 +193,22 @@ class HbmCache:
         else:
             self._sorted_keys = _EMPTY_U64
             self._sorted_slots = _EMPTY_I32
+        self._sorted_sample = np.ascontiguousarray(
+            self._sorted_keys[::DIRECTORY_STRIDE])
 
     @_PASS.wrap("lookup")
     def lookup(self, pk: np.ndarray) -> CachePlan:
-        """Resolve a sorted unique census against the directory."""
-        n = pk.shape[0]
+        """Resolve a sorted unique census against the directory: one native
+        merge over the two sorted arrays, or the numpy form below (the same
+        arrays to the element) where the planner's library is off or did
+        not build; ``cache.lookups{form=}`` says which."""
         sk = self._sorted_keys
+        resolved = cache_lookup_native(
+            sk, self._sorted_slots, self._sorted_sample, pk)
+        _LOOKUPS.inc(form="numpy" if resolved is None else "native")
+        if resolved is not None:
+            return CachePlan(*resolved)
+        n = pk.shape[0]
         if n == 0 or sk.shape[0] == 0:
             return CachePlan(np.zeros(n, dtype=bool), _EMPTY_I32, _EMPTY_I32)
         pos = np.searchsorted(sk, pk)
@@ -211,7 +235,9 @@ class HbmCache:
             self._freq *= down
             self._unit *= down
         self._unit /= self.aging
-        if plan.n_hits:
+        if plan.n_hits and not cache_touch_native(
+                self._freq, self.last_seen, plan.hit_slots, self._unit,
+                self.tick):
             self._freq[plan.hit_slots] += self._unit
             self.last_seen[plan.hit_slots] = self.tick
         telemetry.counter(

@@ -540,3 +540,176 @@ class TestTelemetryAndKillSwitch:
         ram.update(keys, np.ones((4000, 3), np.float32))
         st = ram.stats()
         assert st["spilled_buckets"] == 0 and st["resident_rows"] == 4000
+
+
+# --------------------------------------------------------------------------- #
+# The directory resolve's two forms (ISSUE 49): the native merge of
+# _native/plan_resolve.cpp pbx_cache_lookup against the numpy form it
+# replaced, which stays as the fallback and is the oracle here
+# --------------------------------------------------------------------------- #
+def _directory(keys: np.ndarray, seed: int = 0):
+    """A metadata-only cache holding ``keys`` in shuffled slots."""
+    from paddlebox_tpu.sparse.engine.hbm_cache import HbmCache
+
+    keys = np.asarray(keys, dtype=np.uint64)
+    cache = HbmCache(max(int(keys.shape[0]), 1) + 3, 4,
+                     materialize_rows=False)
+    slots = np.random.default_rng(seed).permutation(cache.capacity)
+    slots = slots[: keys.shape[0]]
+    cache.keys[slots] = keys
+    cache.used[slots] = True
+    cache._rebuild_index()
+    return cache
+
+
+def _u64(*values) -> np.ndarray:
+    return np.array(values, dtype=np.uint64)
+
+
+def _interleaved(seed: int):
+    rng = np.random.default_rng(seed)
+    resident = np.unique(rng.integers(0, 1 << 40, 3000, dtype=np.uint64))
+    census = np.unique(np.concatenate([
+        rng.choice(resident, 900, replace=False),
+        rng.integers(0, 1 << 40, 900, dtype=np.uint64)]))
+    return _directory(resident, seed), census
+
+
+def _after_evictions():
+    """A directory whose slots are no longer in key order: a small cache
+    run through passes that admit into freed slots."""
+    from paddlebox_tpu.sparse.engine.hbm_cache import HbmCache
+
+    rng = np.random.default_rng(11)
+    cache = HbmCache(256, 4, aging=0.5, materialize_rows=False)
+    n_victims = 0
+    for p in range(8):
+        pk = np.unique(rng.integers(0, 4000, 200 if p < 2 else 500)
+                       .astype(np.uint64))
+        plan = cache.lookup(pk)
+        cache.touch(plan)
+        upd = cache.plan_update(pk, plan)
+        cache.commit_update(plan, upd)
+        n_victims += upd.victim_slots.shape[0]
+    assert n_victims > 0 and np.any(np.diff(cache._sorted_slots) < 0)
+    return cache, np.unique(rng.integers(0, 4000, 700).astype(np.uint64))
+
+
+def _long_census():
+    """Long enough for every range of the native form's threads (4 x
+    32,768 census keys), with hits and misses on both sides of every
+    range's edge and of every block of the directory's sample."""
+    rng = np.random.default_rng(5)
+    resident = np.unique(rng.integers(0, 1 << 22, 400_000, dtype=np.uint64))
+    census = np.unique(rng.integers(0, 1 << 22, 300_000, dtype=np.uint64))
+    assert census.shape[0] >= 4 * 32768
+    return _directory(resident), census
+
+
+_HIGH = np.uint64(1) << np.uint64(63)
+
+_RESOLVE_CASES = {
+    "empty_census": lambda: (_directory(np.arange(10, 50)), _u64()),
+    "empty_directory": lambda: (_directory(_u64()),
+                                np.arange(5, 40, dtype=np.uint64)),
+    "all_hits": lambda: (_directory(np.arange(3, 900, 3)),
+                         np.arange(3, 900, 6, dtype=np.uint64)),
+    "no_hit": lambda: (_directory(np.arange(3, 900, 3)),
+                       np.arange(4, 900, 3, dtype=np.uint64)),
+    "below_first_and_above_last": lambda: (
+        _directory(np.arange(100, 200)),
+        _u64(0, 7, 99, 100, 150, 199, 200, 5000, 2 ** 64 - 1)),
+    "keys_past_2_63": lambda: (
+        _directory(np.concatenate([
+            np.arange(1, 40, dtype=np.uint64),
+            _HIGH + np.arange(0, 80, 2, dtype=np.uint64)])),
+        np.concatenate([
+            _u64(5, 39, 40),
+            _HIGH - np.uint64(1) + np.arange(0, 90, dtype=np.uint64),
+            _u64(2 ** 64 - 1)])),
+    "census_larger_than_directory": lambda: (
+        _directory(np.arange(0, 170, 10)), np.arange(0, 400, dtype=np.uint64)),
+    "one_key_hit": lambda: (_directory(np.arange(20, 60)), _u64(33)),
+    "one_key_miss": lambda: (_directory(np.arange(20, 60, 2)), _u64(33)),
+    "one_resident_key": lambda: (_directory(_u64(33)),
+                                 np.arange(30, 36, dtype=np.uint64)),
+    "interleaved_seed0": lambda: _interleaved(0),
+    "interleaved_seed1": lambda: _interleaved(1),
+    "interleaved_seed2": lambda: _interleaved(2),
+    "after_commit_update_with_evictions": _after_evictions,
+    "long_census_across_every_range": _long_census,
+    # directory sizes and census lengths on both sides of the sample's
+    # stride (16) and of the prefetch ring (32)
+    **{f"edges_dir{n_dir}_census{n}": (
+        lambda n_dir=n_dir, n=n: (_directory(np.arange(0, 2 * n_dir, 2)),
+                                  np.arange(n, dtype=np.uint64)))
+       for n_dir in (15, 16, 17, 33) for n in (31, 32, 33, 65)},
+}
+
+
+def _lookup_both(cache, pk):
+    from paddlebox_tpu.config import flags
+
+    native = cache.lookup(pk)
+    flags.set("use_native_planner", False)
+    try:
+        oracle = cache.lookup(pk)
+    finally:
+        flags.set("use_native_planner", True)
+    return native, oracle
+
+
+def _lookups() -> dict:
+    from paddlebox_tpu import telemetry
+
+    counters = telemetry.registry.snapshot()["counters"]
+    return {form: counters.get(f"cache.lookups{{form={form}}}", 0.0)
+            for form in ("native", "numpy")}
+
+
+@pytest.mark.parametrize("case", list(_RESOLVE_CASES))
+def test_native_resolve_equals_the_numpy_form(case):
+    from paddlebox_tpu._native import get_plan_lib
+
+    if get_plan_lib() is None:
+        pytest.skip("native planner did not build")
+    cache, pk = _RESOLVE_CASES[case]()
+    before = _lookups()
+    native, oracle = _lookup_both(cache, pk)
+    after = _lookups()
+    assert {f: after[f] - before[f] for f in after} == {
+        "native": 1.0, "numpy": 1.0}
+    for name in ("hit_mask", "hit_pos", "hit_slots"):
+        got, want = getattr(native, name), getattr(oracle, name)
+        assert got.dtype == want.dtype, name
+        np.testing.assert_array_equal(got, want, err_msg=name)
+    assert native.hit_mask.shape == pk.shape
+    # and the touch of those hits writes what numpy's two indexed writes do
+    freq, seen = cache._freq.copy(), cache.last_seen.copy()
+    cache.touch(native)
+    want_freq, want_seen = freq.copy(), seen.copy()
+    want_freq[oracle.hit_slots] += cache._unit
+    want_seen[oracle.hit_slots] = cache.tick - 1
+    np.testing.assert_array_equal(cache._freq, want_freq)
+    np.testing.assert_array_equal(cache.last_seen, want_seen)
+
+
+def test_without_the_library_the_numpy_form_answers(monkeypatch):
+    """The planner's library made unavailable: lookup and touch fall to
+    numpy, and ``cache.lookups{form=numpy}`` says so."""
+    from paddlebox_tpu import _native
+
+    cache, pk = _interleaved(3)
+    with_library = cache.lookup(pk)
+    monkeypatch.setattr(_native, "get_plan_lib", lambda: None)
+    before = _lookups()
+    plan = cache.lookup(pk)
+    after = _lookups()
+    assert after["numpy"] - before["numpy"] == 1.0
+    assert after["native"] == before["native"]
+    for name in ("hit_mask", "hit_pos", "hit_slots"):
+        np.testing.assert_array_equal(getattr(plan, name),
+                                      getattr(with_library, name))
+    freq = cache._freq.copy()
+    cache.touch(plan)
+    assert np.count_nonzero(cache._freq != freq) == plan.n_hits > 0

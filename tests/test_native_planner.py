@@ -269,3 +269,81 @@ def test_sharded_plan_native_matches_numpy(tmp_path):
     assert m1["auc"] == m2["auc"]
     np.testing.assert_array_equal(s1["keys"], s2["keys"])
     np.testing.assert_array_equal(s1["values"], s2["values"])
+
+
+# --------------------------------------------------------------------------- #
+# The row cache's directory resolve rides the planner's library (ISSUE 49)
+# --------------------------------------------------------------------------- #
+@pytest.fixture
+def fresh_plan_lib():
+    """``get_plan_lib`` loads anew inside the test; the process's own
+    library is put back after it."""
+    from paddlebox_tpu import _native
+
+    saved = (_native._plan_lib, _native._plan_tried)
+    _native._plan_lib, _native._plan_tried = None, False
+    yield _native
+    _native._plan_lib, _native._plan_tried = saved
+
+
+@pytest.mark.parametrize("symbol", ["pbx_cache_lookup", "pbx_cache_touch"])
+def test_require_native_fails_on_a_library_without_the_resolve(
+        fresh_plan_lib, monkeypatch, symbol):
+    """A loaded library that lacks a directory entry point is no planner
+    library: a run that must not degrade stops, it does not fall to the
+    numpy form at every boundary."""
+    import ctypes
+
+    _native = fresh_plan_lib
+    load = ctypes.CDLL
+
+    class Without:
+        """The real library with one symbol taken out."""
+
+        def __init__(self, path):
+            self._lib = load(path)
+
+        def __getattr__(self, name):
+            if name == symbol:
+                raise AttributeError(f"undefined symbol: {name}")
+            return getattr(self._lib, name)
+
+    monkeypatch.setattr(_native.ctypes, "CDLL", Without)
+    assert _native.get_plan_lib() is None
+    with pytest.raises(RuntimeError, match="planner did not build"):
+        _native.require_native()
+
+
+def test_require_native_loads_the_resolve(fresh_plan_lib):
+    loaded = fresh_plan_lib.require_native()
+    assert loaded["planner"]
+    lib = fresh_plan_lib.get_plan_lib()
+    assert lib.pbx_cache_lookup and lib.pbx_cache_touch
+
+
+def test_begin_pass_resolves_its_census_natively_once():
+    """On a cached table every ``begin_pass`` is one native lookup, timed
+    by the span it always had."""
+    from paddlebox_tpu import telemetry
+
+    def read():
+        snap = telemetry.registry.snapshot()
+        return (snap["counters"].get("cache.lookups{form=native}", 0.0),
+                snap["counters"].get("cache.lookups{form=numpy}", 0.0),
+                snap["histograms"].get("pass.stage_seconds{stage=lookup}",
+                                       {"count": 0})["count"])
+
+    t = SparseTable(
+        SparseTableConfig(embedding_dim=4, plan_scratch_rows=64,
+                          hbm_cache_rows=1 << 10, placement="hash"), seed=0)
+    t.begin_pass(np.arange(1, 401, dtype=np.uint64))
+    t.end_pass()
+    for census in (np.arange(1, 51), np.arange(30, 600, 7)):
+        native, fallback, spans = read()
+        t.begin_pass(census.astype(np.uint64))
+        native1, fallback1, spans1 = read()
+        assert native1 - native == 1.0
+        assert fallback1 == fallback
+        assert spans1 - spans == 1
+        t.end_pass()
+    t.flush()
